@@ -370,7 +370,7 @@ def parse_svm_range_payload(payload: str) -> Tuple[np.ndarray, np.ndarray]:
             # the index regions must be INTEGER-shaped bytes, not merely
             # integer-valued floats: "3.0:w" or "3e0:w" must fail here and
             # raise on the per-token int() path below, exactly like the
-            # exact path always did (ADVICE r2).  Region [start, colon) is
+            # exact path always did.  Region [start, colon) is
             # clean iff it contains only digits/sign — checked in one
             # cumulative-sum pass, no per-token work.
             digit = (buf >= ord("0")) & (buf <= ord("9"))

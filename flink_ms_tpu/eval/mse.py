@@ -155,9 +155,9 @@ def _compute_mse_offline_batched(
 ) -> Tuple[Optional[float], int, int]:
     """Same semantics as compute_mse, but predictions in one device op."""
     from ..ops.als import ALSModel, predict
-    from ..parallel.mesh import honor_platform_env
+    from ..parallel.mesh import acquire_devices
 
-    honor_platform_env()  # explicit JAX_PLATFORMS pin must reach the device op
+    acquire_devices()
 
     def numeric_ids(suffix: str):
         out = set()

@@ -27,9 +27,9 @@ from ..core.params import Params
 
 
 def generate_bucket_rows(num_features: int, range_: int, seed: int = 0) -> Iterator[str]:
-    from ..parallel.mesh import honor_platform_env
+    from ..parallel.mesh import acquire_devices
 
-    honor_platform_env()  # explicit JAX_PLATFORMS pin must reach the RNG
+    acquire_devices()
     n_buckets = num_features // range_ + 1
     key = jax.random.PRNGKey(seed)
     for bucket in range(n_buckets):

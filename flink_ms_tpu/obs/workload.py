@@ -728,6 +728,7 @@ def run_rehearsal(
     update_plane: bool = True,
     abusive_qps: float = 0.0,
     watch: bool = False,
+    worker_env: Optional[dict] = None,
     watch_rules=None,
     watch_canary=None,
     watch_interval_s: float = 0.5,
@@ -778,6 +779,10 @@ def run_rehearsal(
     ``tpums_push_latency_seconds`` — and the overall gate additionally
     requires that p99 under ``push_p99_ms`` with at least one delta
     delivered: push freshness becomes an SLO, not a hope.
+
+    ``worker_env`` is the environment of the spawned workers (default:
+    inherited).  A caller that holds the chip passes ``JAX_PLATFORMS=cpu``
+    there: a chip belongs to one process.
     """
     from . import slo as obs_slo
     from .scrape import scrape_fleet
@@ -861,7 +866,7 @@ def run_rehearsal(
             ctl = ScaleController(group, journal.dir, "models",
                                   port_dir=os.path.join(base, "ports"),
                                   ready_timeout_s=180,
-                                  extra_args=extra_args)
+                                  extra_args=extra_args, env=worker_env)
             ctl.scale_to(shards, replicas=replication)
             live_group = group
             if edge > 0:

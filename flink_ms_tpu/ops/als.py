@@ -46,13 +46,14 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.mesh import (
     BLOCK_AXIS,
     block_sharding,
+    host_device,
     num_blocks,
-    shard_map,  # version-compat shim (jax.experimental on 0.4.x)
 )
 
 # ---------------------------------------------------------------------------
@@ -570,7 +571,7 @@ def _assembly_chunk_bytes() -> int:
 
 
 def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
-                       precision, post=None, extra=None, platform=None):
+                       precision, post=None, extra=None):
     """One bucket's (A, b): gather the opposite factors for each row's
     rating list and contract over the rating axis on the MXU.
 
@@ -591,19 +592,6 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     Chunking is over the batch row axis only (the contraction axis w is
     untouched), so chunked and unchunked results are arithmetically
     identical per row."""
-    # fused gather+contract kernel (FLINK_MS_ALS_ASSEMBLY=pallas): the
-    # whole opposite table rides VMEM and the (r, w, k) gather transient
-    # never touches HBM — see ops/gather_assembly.py.  Unfused-solve mode
-    # only (the fused-solve `post` stage keeps the XLA chunk path).
-    if post is None:
-        from .gather_assembly import fused_bucket_assembly, use_fused_gather
-
-        if use_fused_gather(y_all.shape, y_all.dtype):
-            return fused_bucket_assembly(
-                y_all, idx, val, dtype, platform or "cpu",
-                precision=precision, implicit=implicit, alpha=alpha,
-            )
-
     def compute(idx_c, val_c, extra_c, in_scan=False):
         y = jnp.take(y_all, idx_c, axis=0)                   # (r, w, k)
         # HIGHEST keeps f32 products (bf16 single-pass shifts the normal
@@ -678,7 +666,7 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
 
 
 def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
-                         precision="highest", platform=None):
+                         precision="highest"):
     """A_u = Σ w·y yᵀ and b_u = Σ t·y per slot, as batched MXU matmuls.
 
     y_all:   (n_slots_global, k) gathered opposite-side factor table
@@ -697,7 +685,6 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     for idx, val in buckets:
         A, b = _bucket_normal_eqs(
             y_all, idx, val, implicit, alpha, dtype, precision,
-            platform=platform,
         )
         As.append(A)
         bs.append(b)
@@ -881,7 +868,9 @@ def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
         # lane-major operand relayout is uncompilable there (degenerate-
         # dim copy, 62.5 GB AOT OOM) -- force the batch-major variant
         layout = "batch_major" if in_scan else None
-        return cholesky_solve_batched(A, b, layout=layout).astype(A.dtype)
+        return cholesky_solve_batched(
+            A, b, interpret=platform != "tpu", layout=layout
+        ).astype(A.dtype)
     if choice == "panel":
         return _chol_solve_panel(A, b)
     if choice == "unrolled" or (choice == "auto" and k <= _UNROLL_MAX_K):
@@ -1007,7 +996,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             return jnp.concatenate(xs, axis=0)[None]
         A, b = _assemble_normal_eqs(
             y_all, buckets, implicit, alpha, dtype,
-            precision=config.assembly_precision, platform=platform,
+            precision=config.assembly_precision,
         )
         if implicit:
             A = A + yty[None, :, :]
@@ -1083,10 +1072,6 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         _solver_choice(),          # env overrides are baked in at trace
         _assembly_chunk_bytes(),   # time, so they key the executable
         _fused_solve(),
-        os.environ.get("FLINK_MS_ALS_ASSEMBLY", "auto"),
-        os.environ.get("FLINK_MS_ALS_ASSEMBLY_VMEM_BYTES", ""),
-        os.environ.get("FLINK_MS_ALS_ASSEMBLY_ROW_TILE", ""),
-        os.environ.get("FLINK_MS_ALS_ASSEMBLY_W_CHUNK", ""),
         # the Pallas solver reads its layout knob at trace time too (when
         # layout=None inside cholesky_solve_batched) — omitting it here
         # would silently reuse an executable compiled under the old layout
@@ -1233,14 +1218,11 @@ def init_factors(n_pad: int, k: int, key, dtype) -> jnp.ndarray:
     """Uniform(0,1)/sqrt(k) init.  FlinkML seeds per-block uniform factors
     [dep]; bit-parity is impossible across runtimes, so parity is defined as
     equal-or-better RMSE at equal iterations (SURVEY.md §7 'hard parts').
-    Drawn on the HOST backend — threefry is device-deterministic so the
+    Drawn on the HOST backend where the process has one
+    (``mesh.host_device``) — threefry is device-deterministic so the
     values are identical, and a (10M, 64) accelerator-side draw was 2.6 GB
-    of HBM transient that the 10M×1M scale envelope could not afford.
-    local_devices, NOT jax.devices: in a multi-process run the global list
-    starts with process 0's device, and pinning another process's default
-    device to a non-addressable device wedges the whole DCN collective
-    sequence (round-3 two-process regression)."""
-    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+    of HBM transient that the 10M×1M scale envelope could not afford."""
+    with jax.default_device(host_device()):
         return jax.random.uniform(key, (n_pad, k), dtype=dtype) / jnp.sqrt(
             jnp.asarray(k, dtype)
         )

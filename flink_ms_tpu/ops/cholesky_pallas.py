@@ -15,9 +15,10 @@ Pallas kernel:
 - the k-step Cholesky, forward- and back-substitution all run on the tile
   while it lives in VMEM; HBM sees one read of A/b and one write of x.
 
-Like every kernel in this repo it has an interpreter-mode path so CPU
-tests pin numerics (``interpret=None`` auto-selects off-TPU); selection
-happens in ``ops/als._chol_solve`` via FLINK_MS_ALS_SOLVER=pallas.
+The caller says where it runs: ``interpret=True`` is the interpreter-mode
+path CPU tests pin numerics with, ``interpret=False`` compiles for the TPU.
+``ops/als._chol_solve`` derives it from its mesh's platform; selection of
+this solver is ``resolve_solver`` (default on TPU, FLINK_MS_ALS_SOLVER).
 
 Reference capability: the per-ID regularized solves inside FlinkML's
 blocked ALS [dep], reached from ``ALSImpl.scala:52`` (SURVEY.md §2.2).
@@ -145,14 +146,15 @@ def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool):
     )(Ab, bb)
 
 
-def cholesky_solve_batched(A, b, tile: int = 128, interpret=None,
+def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
                            layout=None):
     """Batched SPD solve A x = b.  A (n, k, k), b (n, k) -> x (n, k).
 
     ``tile`` batch elements ride the lane axis per grid step; VMEM holds
     ~3·k²·tile·4 bytes (A tile, L, downdate temps) — tile=128 keeps k=64
-    under the ~16 MB budget.  ``interpret=None`` auto-selects interpreter
-    mode off-TPU.
+    under the ~16 MB budget.  ``interpret`` comes from the platform of the
+    caller's mesh, never from the process's default backend: a host-side
+    fit in a process that also holds a chip must still interpret.
 
     ``layout``: "lane_major" transposes A/b to (k, k, n)/(k, n) at the
     XLA level before the kernel; "batch_major" feeds (n, k, k) blocks
@@ -164,8 +166,6 @@ def cholesky_solve_batched(A, b, tile: int = 128, interpret=None,
     a degenerate-dim copy lane-padded x128 (62.5 GB for a (43648, 50, 50)
     chunk — the round-3 fused-mode AOT OOM), which batch_major sidesteps
     by never asking XLA for that layout."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if layout is None:
         layout = os.environ.get("FLINK_MS_PALLAS_LAYOUT", "lane_major")
     n, k = b.shape

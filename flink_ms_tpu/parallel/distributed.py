@@ -35,8 +35,6 @@ import jax
 import numpy as np
 
 from ..core.params import Params
-from .mesh import honor_platform_env
-
 _INITIALIZED = False
 
 
@@ -70,7 +68,6 @@ def maybe_init_distributed(params: Optional[Params] = None) -> bool:
             "--coordinatorAddress requires --numProcesses and --processId "
             "(or JAX_NUM_PROCESSES / JAX_PROCESS_ID)"
         )
-    honor_platform_env()
     platforms = str(getattr(jax.config, "jax_platforms", None) or "")
     if platforms.split(",")[0] == "cpu":
         # cross-process collectives on plain hosts ride gloo; TPU pods use

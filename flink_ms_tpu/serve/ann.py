@@ -42,7 +42,7 @@ from typing import Optional
 
 import numpy as np
 
-from .topk import _PAD_SCORE, _target_device
+from .topk import _PAD_SCORE, _SCORE_PRECISION, _target_device
 
 # rows per assignment dispatch (one compiled shape).  The distance matrix
 # a dispatch materializes is (chunk, nlist) f32 — 32k rows x 4096 lists is
@@ -112,7 +112,8 @@ def _jits():
         valid = cand >= 0
         safe = jnp.where(valid, cand, 0)
         vecs = matrix[safe]                    # (B, C, d) resident gather
-        scores = jnp.einsum("bcd,bd->bc", vecs, q)
+        scores = jnp.einsum("bcd,bd->bc", vecs, q,
+                            precision=_SCORE_PRECISION)
         scores = jnp.where(valid, scores, _PAD_SCORE)
         s, i = jax.lax.top_k(scores, k)
         idx = jnp.take_along_axis(cand, i, axis=1)
@@ -280,7 +281,9 @@ class IVFIndex:
         q_dev = jax.device_put(q, dev)
         mat = jax.device_put(rows, dev)
         exact = np.asarray(
-            jax.jit(lambda m, x: jax.lax.top_k(x @ m.T, k))(mat, q_dev)[1]
+            jax.jit(lambda m, x: jax.lax.top_k(
+                jnp.matmul(x, m.T, precision=_SCORE_PRECISION), k)
+            )(mat, q_dev)[1]
         )
         _, got = self.search(mat, q_dev, k)
         got = np.asarray(got)

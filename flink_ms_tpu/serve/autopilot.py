@@ -388,9 +388,11 @@ class AutopilotController:
         ``{model_id, journal_dir, tables, heldout, warm}``."""
         from ..eval.mse import rolling_holdout_split
         from ..ops.als import ALSConfig, als_fit, warm_start_factors
-        from ..parallel.mesh import honor_platform_env, make_mesh
+        from ..parallel.mesh import acquire_devices, make_mesh
 
-        honor_platform_env()  # JAX_PLATFORMS pin must precede device work
+        # the trainer owns a device: on a chip host this process must be
+        # the one that holds the chip, or say JAX_PLATFORMS=cpu (ROADMAP D2)
+        acquire_devices()
 
         train_idx, hold_idx = rolling_holdout_split(
             users, items, ratings, fraction=self.holdout_fraction,

@@ -6,7 +6,7 @@ Streams model text files (file or nested directory, matching
 journal topic with fsync'd appends (at-least-once, the analog of
 ``setFlushOnCheckpoint(true)`` — :35-37).
 
-Flush cadence (VERDICT r3 missing #3): the reference flushes its Kafka
+Flush cadence: the reference flushes its Kafka
 producer on EVERY checkpoint (default 60 s), so a crash mid-load loses at
 most one checkpoint interval of buffered rows.  ``--flushInterval`` (ms,
 default 60000 — the reference's checkpoint interval) fsyncs the journal on

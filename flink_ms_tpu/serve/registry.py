@@ -1,5 +1,5 @@
 """Job location registry — the jobId->endpoint resolution the reference
-gets from its JobManager (VERDICT r3 missing #1), grown into the HA
+gets from its JobManager, grown into the HA
 plane's liveness store.
 
 The reference's clients never name a server port: ``QueryClientHelper``

@@ -303,7 +303,16 @@ def spawn_worker_procs(
     ``TPUMS_TOPK_SHARDED`` / ``TPUMS_ANN_NLIST`` / ``TPUMS_ANN_NPROBE``
     set on the launcher reach every shard worker's
     ``DeviceFactorIndex`` (each worker holds only its catalog slice, so
-    its index sizes its own mesh/ANN tiers from its slice)."""
+    its index sizes its own mesh/ANN tiers from its slice).
+
+    A chip belongs to one process, and every ALS worker acquires its
+    index devices at start (``parallel.mesh.acquire_devices``): on a chip
+    host at most one worker may inherit the chip, and the others are
+    spawned with ``env=dict(os.environ, JAX_PLATFORMS="cpu")`` — a caller
+    that itself holds the chip passes that for all of them.  An un-pinned
+    extra worker dies at start rather than serving from the host
+    unannounced (ROADMAP D2 replaces this with one device-owner
+    process)."""
     import subprocess
     import time
 

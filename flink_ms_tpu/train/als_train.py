@@ -32,7 +32,7 @@ from ..core import formats as F
 from ..core.params import Params, field_delimiter_from
 from ..ops.als import ALSConfig, ALSModel, als_fit, rmse
 from ..parallel.distributed import is_primary, maybe_init_distributed
-from ..parallel.mesh import honor_platform_env, mesh_for_blocks
+from ..parallel.mesh import compile_report, mesh_for_blocks
 from ..utils import profiling
 
 
@@ -40,6 +40,15 @@ def run(params: Params) -> ALSModel | None:
     if not params.has("input"):
         print("Use --input to specify file input.")
         return None
+
+    # the mesh first: a host with no chip fails by the device rule before
+    # the ratings file is parsed, not after.
+    # --blocks larger than the device count is legal in the reference (more
+    # blocks than slots).  The blocked-ALS solve is exact per row, so any
+    # logical block count partitions onto the D device blocks without
+    # changing the result; multi-process runs always span every device
+    maybe_init_distributed(params)
+    mesh = mesh_for_blocks(params.get_int("blocks"), params.get_int("devices"))
 
     delim = field_delimiter_from(params)
     users, items, ratings = F.read_ratings(
@@ -56,14 +65,6 @@ def run(params: Params) -> ALSModel | None:
         implicit=params.get_bool("implicit", False),
         alpha=params.get_float("alpha", 40.0),
     )
-
-    honor_platform_env()
-    maybe_init_distributed(params)
-    # --blocks larger than the device count is legal in the reference (more
-    # blocks than slots).  The blocked-ALS solve is exact per row, so any
-    # logical block count partitions onto the D device blocks without
-    # changing the result; multi-process runs always span every device
-    mesh = mesh_for_blocks(params.get_int("blocks"), params.get_int("devices"))
 
     # get_required raises loudly on a present-but-valueless flag
     tmp = (
@@ -92,6 +93,7 @@ def run(params: Params) -> ALSModel | None:
         f"({train_s / max(config.iterations, 1):.3f} s/iter), "
         f"train RMSE={rmse(model, users, items, ratings):.4f}"
     )
+    print(f"[ALS] {compile_report()}")
 
     if not is_primary():  # one process materializes job output
         return model

@@ -23,21 +23,21 @@ from ..core import formats as F
 from ..core.params import Params
 from ..ops.svm import SVMConfig, SVMModel, prepare_svm_blocked, svm_fit
 from ..parallel.distributed import is_primary, maybe_init_distributed
-from ..parallel.mesh import honor_platform_env, mesh_for_blocks
+from ..parallel.mesh import mesh_for_blocks
 from ..utils import profiling
 
 
 def run(params: Params) -> SVMModel:
     training_path = params.get_required("training")
-    data = F.read_libsvm(training_path)
-
-    honor_platform_env()
+    # the mesh first: a host with no chip fails by the device rule before
+    # the training file is parsed, not after
     maybe_init_distributed(params)
     blocks = params.get_int("blocks", 10)
     # blocks = K logical SDCA chains; the mesh spans min(K, devices) (all
     # devices in multi-process runs), and the kernel stacks ceil(K/D)
     # chains per device when K exceeds the device count
     mesh = mesh_for_blocks(blocks, params.get_int("devices"))
+    data = F.read_libsvm(training_path)
 
     iterations = params.get_int("iteration", params.get_int("iterations", 10))
     problem = prepare_svm_blocked(

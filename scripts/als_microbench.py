@@ -20,7 +20,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def run_breakdown(A_mod, problem, cfg, mesh, dev_args, hard_sync):
+def run_breakdown(A_mod, problem, cfg, mesh, dev_args):
     """Time the user half-sweep's phases separately on one device: the
     opposite-factor gather, the full normal-equation assembly, and the
     batched Cholesky solve.  Isolates where a sweep's wall-clock goes so
@@ -89,13 +89,12 @@ def run_breakdown(A_mod, problem, cfg, mesh, dev_args, hard_sync):
     flat_bufs = [a[0] for a in bucket_args]
 
     def timeit(fn, *args_):
-        out = fn(*args_)
-        hard_sync(out if not isinstance(out, tuple) else out[0])
+        jax.block_until_ready(fn(*args_))
         reps = 5
         t0 = time.time()
         for _ in range(reps):
             out = fn(*args_)
-        hard_sync(out if not isinstance(out, tuple) else out[0])
+        jax.block_until_ready(out)
         return (time.time() - t0) / reps
 
     t_gather = timeit(gather_only, y_all, *flat_bufs)
@@ -133,21 +132,13 @@ def main():
     n_items = args.items or (2_000 if small else 26_744)
     rank = args.rank or (16 if small else 50)
 
-    from flink_ms_tpu.parallel.mesh import honor_platform_env
-
-    honor_platform_env()
-
     import jax
     import jax.numpy as jnp
 
     from flink_ms_tpu.ops import als as A
     from flink_ms_tpu.parallel.mesh import make_mesh
-    from flink_ms_tpu.utils.profiling import hard_sync
 
-    devs = jax.devices()
-    accel = [d for d in devs if d.platform != "cpu"] or devs
-    mesh = make_mesh(devices=accel[:1])
-    print(f"backend: {accel[0].platform} ({getattr(accel[0], 'device_kind', '?')})")
+    mesh = make_mesh(1)  # by the device rule: the chip, or JAX_PLATFORMS=cpu
 
     rng = np.random.default_rng(0)
     users = rng.integers(0, n_users, nnz)
@@ -164,7 +155,7 @@ def main():
     _, dev_args = A.compile_fit(problem, base_cfg, mesh)
 
     if args.breakdown:
-        run_breakdown(A, problem, base_cfg, mesh, dev_args, hard_sync)
+        run_breakdown(A, problem, base_cfg, mesh, dev_args)
 
     def steady(cfg):
         fit_fn = A._cached_sweep(problem, cfg, mesh)
@@ -172,7 +163,7 @@ def main():
         def run(trip):
             t = time.time()
             uf, _ = fit_fn(jnp.asarray(trip, jnp.int32), *dev_args)
-            hard_sync(uf)
+            jax.block_until_ready(uf)
             return time.time() - t
 
         run(1), run(4)  # compile + warmup

@@ -37,7 +37,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("TPUMS_TOPK_PLATFORM", "cpu")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

@@ -10,12 +10,13 @@
 # lookup server for Flink queryable state.
 #
 # Usage: scripts/e2e_demo.sh [workdir]    (defaults to a fresh mktemp dir)
-# Runs anywhere: CPU by default (DEMO_PLATFORM=tpu-or-other to override);
-# the ambient JAX_PLATFORMS is ignored so the demo works without a chip.
+# Runs where jax puts it: on the chip when there is one (the trainer exits
+# before the serving job takes the chip; every later step is a JAX-free
+# client), and on a host without one only when asked:
+#   JAX_PLATFORMS=cpu scripts/e2e_demo.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS=${DEMO_PLATFORM:-cpu}
 WORK=${1:-$(mktemp -d /tmp/flink-ms-tpu-demo.XXXXXX)}
 mkdir -p "$WORK"
 PY=${PYTHON:-python}

@@ -5,11 +5,11 @@
 # RangePartitionSVMPredict latency harnesses.
 #
 # Usage: scripts/e2e_demo_svm.sh [workdir]
-# Runs anywhere: CPU by default (DEMO_PLATFORM to override).
+# Runs where jax puts it; on a host without a chip only when asked:
+#   JAX_PLATFORMS=cpu scripts/e2e_demo_svm.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS=${DEMO_PLATFORM:-cpu}
 WORK=${1:-$(mktemp -d /tmp/flink-ms-tpu-svm-demo.XXXXXX)}
 mkdir -p "$WORK"
 PY=${PYTHON:-python}

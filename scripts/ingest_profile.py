@@ -23,7 +23,6 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("TPUMS_TOPK_PLATFORM", "cpu")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from flink_ms_tpu.core import formats as F  # noqa: E402

@@ -11,8 +11,6 @@ import time
 
 sys.path.insert(0, "/root/repo")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from flink_ms_tpu.parallel.mesh import pin_host_backend
-pin_host_backend()
 
 import numpy as np
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Mid-size multi-device rehearsal (VERDICT r3 weak #5): the evidence layer
+"""Mid-size multi-device rehearsal: the evidence layer
 between the toy-shape dryrun and real multi-chip hardware.
 
 One 8-device CPU-mesh run at ~1M-nnz ALS and ~100k-example SVM that PINS,
@@ -41,10 +41,6 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from flink_ms_tpu.parallel.mesh import pin_host_backend  # noqa: E402
-
-pin_host_backend()
 
 import numpy as np  # noqa: E402
 
@@ -249,7 +245,7 @@ def main() -> int:
                 chains_per_device=-(-K // N_DEV))
 
     # -- multi-process DCN rehearsal: 2 procs x 4 devices over gloo --------
-    # (VERDICT r4 #7: the distributed code path — parallel/distributed.py,
+    # (the distributed code path — parallel/distributed.py,
     # gloo collectives, single-writer staging, process-0-authoritative
     # resume — must carry the routed exchange and staging-resume at ~1M
     # nnz, not just the in-process 8-device mesh.)  Stand-in for the
@@ -431,8 +427,7 @@ def main() -> int:
                 ok &= check("mp_svm_matches_inprocess_fit", False,
                             skipped="svm pair failed")
 
-            # N>2 process group (VERDICT r4 held the comm cell at
-            # "partial — never exercised beyond 2 procs"): 4 procs x
+            # N>2 process group: 4 procs x
             # 2 devices over gloo — same 8 global devices, so the
             # blocked layout and the in-process reference fit are
             # unchanged; what varies is process count, per-process

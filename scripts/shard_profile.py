@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Sharded-plane latency attribution (VERDICT r2 weak #6): where do the
+"""Sharded-plane latency attribution: where do the
 3-worker MGET/TOPK percentiles go vs single-worker — client routing, pool
 dispatch, per-worker service time, or merge?
 
@@ -26,7 +26,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("TPUMS_TOPK_PLATFORM", "cpu")
 
 from flink_ms_tpu.core.params import Params  # noqa: E402
 from flink_ms_tpu.gen import als_model_generator  # noqa: E402

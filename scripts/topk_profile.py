@@ -2,11 +2,8 @@
 """Profile the top-k scoring engine on the current backend.
 
 Times the XLA matmul + ``jax.lax.top_k`` path at serving-relevant catalog
-sizes (26k ≈ ML-20M items, 1M ≈ BASELINE scale envelope), and the device
-vs host placement question behind TPUMS_TOPK_PLATFORM.  The Pallas fused
-scorer this script originally A/B'd was removed in round 3 (decision in
-PARITY.md: the serving index is host-pinned in this deployment, and the
-XLA engine already meets the latency envelope).
+sizes (26k ≈ ML-20M items, 1M ≈ BASELINE scale envelope) on whichever
+backend the device rule hands out (the chip, or JAX_PLATFORMS=cpu).
 
   python scripts/topk_profile.py [--items N ...] [--rank K] [--topk T]
 """
@@ -29,17 +26,12 @@ def main():
     ap.add_argument("--reps", type=int, default=20)
     args = ap.parse_args()
 
-    from flink_ms_tpu.parallel.mesh import honor_platform_env
-
-    honor_platform_env()
-
     import jax
     import jax.numpy as jnp
 
-    from flink_ms_tpu.utils.profiling import hard_sync
+    from flink_ms_tpu.parallel.mesh import acquire_devices
 
-    dev = jax.devices()[0]
-    print(f"backend: {dev.platform} ({getattr(dev, 'device_kind', '?')})")
+    acquire_devices()
 
     rng = np.random.default_rng(0)
     for n in args.items:
@@ -55,7 +47,7 @@ def main():
         def run_xla(q):
             t0 = time.time()
             s, _ = xla_topk(md, q)
-            hard_sync(s)
+            jax.block_until_ready(s)
             return time.time() - t0
 
         qs = [jnp.asarray(rng.standard_normal(k).astype(np.float32))

@@ -32,7 +32,6 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("TPUMS_TOPK_PLATFORM", "cpu")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from flink_ms_tpu.core.params import Params  # noqa: E402

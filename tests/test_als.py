@@ -258,10 +258,6 @@ def test_fused_solve_matches_unfused(rng, monkeypatch):
     itf0 = rng.normal(size=(45, k)).astype(np.float32)
     cfg = A.ALSConfig(num_factors=k, iterations=3, lambda_=0.1)
     mesh = make_mesh()
-    # same pin as the implicit sibling below: the fused-solve path can't
-    # use the pallas assembly, so an ambient FLINK_MS_ALS_ASSEMBLY=pallas
-    # would make this a cross-engine comparison
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
     plain = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
     monkeypatch.setenv("FLINK_MS_ALS_FUSED", "1")
     fused = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
@@ -312,11 +308,6 @@ def test_fused_solve_matches_unfused_implicit(rng, monkeypatch):
     cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
                       implicit=True, alpha=10.0)
     mesh = make_mesh(4)
-    # pin one assembly engine for BOTH sides: the fused-solve path cannot
-    # route through the pallas assembly (post-stage), so an ambient
-    # FLINK_MS_ALS_ASSEMBLY=pallas would turn this tight fused-vs-unfused
-    # comparison into a cross-engine one (reassociated arithmetic)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
     plain = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
     monkeypatch.setenv("FLINK_MS_ALS_FUSED", "1")
     fused = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
@@ -638,7 +629,7 @@ def test_implicit_halfsweep_matches_numpy_hkv(rng):
                                rtol=2e-3, atol=2e-4)
 
 def test_bench_default_config_matches_f64_reference_rmse(rng):
-    """VERDICT r3 #3 pinning test: the ALS config the benchmark times (all
+    """pinning test: the ALS config the benchmark times (all
     shipped solver/precision/exchange defaults) must reach the same train
     RMSE as an exact float64 normal-equation solve at equal iterations from
     the same init — the 'identical RMSE' half of the north star."""

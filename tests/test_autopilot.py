@@ -177,9 +177,8 @@ def test_unattended_flywheel_rollout_then_drift_rollback(
     monkeypatch.setenv("TPUMS_HEARTBEAT_S", "0.2")
     monkeypatch.setenv("TPUMS_REPLICA_TTL_S", "30")
     from flink_ms_tpu.ops.als import ALSConfig, als_fit
-    from flink_ms_tpu.parallel.mesh import honor_platform_env, make_mesh
+    from flink_ms_tpu.parallel.mesh import make_mesh
 
-    honor_platform_env()
     rng = np.random.default_rng(0)
     n_u, n_i, k = 20, 15, 3
     U, V = rng.normal(size=(n_u, k)), rng.normal(size=(n_i, k))

@@ -1,7 +1,12 @@
 """Pallas batched Cholesky solve: numerics vs numpy in interpreter mode,
-and end-to-end ALS parity via FLINK_MS_ALS_SOLVER=pallas (SURVEY.md §4:
-kernel unit tests against closed form)."""
+end-to-end ALS parity via FLINK_MS_ALS_SOLVER=pallas (SURVEY.md §4:
+kernel unit tests against closed form), and a TPU cross-lowering of every
+``pallas_call`` under ``flink_ms_tpu/ops/`` — a kernel the installed jax
+cannot lower for the chip fails here, not on the chip."""
 
+import pathlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,7 +20,8 @@ def test_matches_numpy(rng, k, n):
     G = rng.standard_normal((n, k, k)).astype(np.float32)
     A = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
     b = rng.standard_normal((n, k)).astype(np.float32)
-    x = np.asarray(cholesky_solve_batched(jnp.asarray(A), jnp.asarray(b)))
+    x = np.asarray(cholesky_solve_batched(
+        jnp.asarray(A), jnp.asarray(b), interpret=True))
     x_ref = np.linalg.solve(
         A.astype(np.float64), b.astype(np.float64)[..., None]
     )[..., 0]
@@ -33,9 +39,11 @@ def test_batch_major_matches_lane_major(rng, k, n):
     A = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
     b = rng.standard_normal((n, k)).astype(np.float32)
     lane = np.asarray(cholesky_solve_batched(
-        jnp.asarray(A), jnp.asarray(b), layout="lane_major"))
+        jnp.asarray(A), jnp.asarray(b), interpret=True,
+        layout="lane_major"))
     batch = np.asarray(cholesky_solve_batched(
-        jnp.asarray(A), jnp.asarray(b), layout="batch_major"))
+        jnp.asarray(A), jnp.asarray(b), interpret=True,
+        layout="batch_major"))
     np.testing.assert_allclose(batch, lane, rtol=1e-5, atol=1e-6)
     x_ref = np.linalg.solve(
         A.astype(np.float64), b.astype(np.float64)[..., None]
@@ -67,3 +75,37 @@ def test_als_fit_with_pallas_solver_matches_default(rng, monkeypatch):
     np.testing.assert_allclose(
         pallas.item_factors, base.item_factors, rtol=1e-3, atol=1e-5
     )
+
+
+# every pallas_call in ops/ at the shapes the sweeps produce: k=50
+# lane-major is the ML-20M default, k=64 batch-major is the fused
+# (lax.map) solve of the 10M x 1M configuration
+_TPU_LOWERED = {
+    "cholesky_pallas.py": [(50, "lane_major"), (64, "batch_major"),
+                           (50, "batch_major"), (64, "lane_major")],
+}
+
+
+def test_every_ops_pallas_kernel_has_a_lowering_case():
+    import flink_ms_tpu.ops as ops_pkg
+
+    ops = pathlib.Path(ops_pkg.__file__).parent
+    with_kernels = sorted(
+        f.name for f in ops.glob("*.py") if "pallas_call" in f.read_text()
+    )
+    assert with_kernels == sorted(_TPU_LOWERED)
+
+
+@pytest.mark.parametrize("k,layout", _TPU_LOWERED["cholesky_pallas.py"])
+def test_cholesky_kernel_lowers_for_tpu(k, layout):
+    """Mosaic lowering only (no chip here): the compiled, non-interpret
+    kernel must lower to a ``tpu_custom_call``."""
+    n = 4096
+    lowered = jax.jit(
+        lambda A, b: cholesky_solve_batched(
+            A, b, interpret=False, layout=layout)
+    ).trace(
+        jax.ShapeDtypeStruct((n, k, k), jnp.float32),
+        jax.ShapeDtypeStruct((n, k), jnp.float32),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()

@@ -170,7 +170,7 @@ def test_range_payload_malformed_still_raises():
 
 
 def test_float_formatted_index_rejected_like_exact_path():
-    """ADVICE r2: the fast path must agree with the per-token path on what
+    """the fast path must agree with the per-token path on what
     is malformed — a float-shaped index ("3.0:w", "3e0:w") raises, while
     negative/plus-signed integer indices still take the fast path."""
     from flink_ms_tpu.core.formats import parse_svm_range_payload
@@ -185,7 +185,7 @@ def test_float_formatted_index_rejected_like_exact_path():
 
 
 def test_range_cache_duplicate_index_last_wins():
-    """ADVICE r2: duplicate feature ids within one payload resolve to the
+    """duplicate feature ids within one payload resolve to the
     LAST occurrence — the dict-parse semantics the range client had before
     the vectorized cache."""
     from flink_ms_tpu.core.formats import RangePayloadCache

@@ -1,5 +1,4 @@
-"""Shrunken version of scripts/midsize_rehearsal.py's invariants (VERDICT
-r3 weak #5): per-device shard shapes, routed-exchange accounting, and
+"""Shrunken version of scripts/midsize_rehearsal.py's invariants: per-device shard shapes, routed-exchange accounting, and
 staging resume across a simulated restart — fast enough for every test
 run; the committed REHEARSAL_r04.json artifact carries the mid-size
 evidence."""

@@ -1,4 +1,4 @@
-"""Routed factor exchange (VERDICT r3 weak #4 / SURVEY §2.3): need-list
+"""Routed factor exchange (SURVEY §2.3): need-list
 all_to_all replacing the full-table all_gather, equivalence-pinned against
 the gather path on an 8-device CPU mesh, with exchange-volume accounting
 that shrinks as the mesh grows (the property the all_gather lacks)."""
@@ -125,118 +125,3 @@ def test_bad_mode_env_raises(monkeypatch):
     monkeypatch.setenv("FLINK_MS_ALS_EXCHANGE_MODE", "banana")
     with pytest.raises(ValueError, match="banana"):
         als._exchange_mode_choice()
-
-
-def test_fused_gather_assembly_matches_xla(monkeypatch, rng):
-    """FLINK_MS_ALS_ASSEMBLY=pallas (interpret mode off-TPU): the fused
-    gather+contract kernel must reproduce the XLA take+einsum assembly —
-    same fit, same factors (tile boundaries only batch the contraction,
-    per-row arithmetic is untouched)."""
-    users, items, ratings = _ratings(n_users=120, n_items=90, nnz=1_500)
-    mesh = make_mesh(4)
-    problem = prepare_blocked(users, items, ratings, 4)
-    k = 5
-    cfg = ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
-                    exchange_dtype=None)
-    init = _pinned_init(problem, k)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
-    m_xla = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "pallas")
-    m_pal = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    # contraction order differs (batched dot_general vs einsum), so
-    # agreement is to f32 reassociation amplified through the solves
-    np.testing.assert_allclose(m_pal.user_factors, m_xla.user_factors,
-                               rtol=5e-4, atol=1e-6)
-    np.testing.assert_allclose(m_pal.item_factors, m_xla.item_factors,
-                               rtol=5e-4, atol=1e-6)
-
-
-def test_fused_gather_assembly_implicit_matches_xla(monkeypatch, rng):
-    """Implicit/HKV mode through the fused kernel (confidence-weighted
-    lhs + 1+alpha*r rhs) matches the XLA path."""
-    users, items, ratings = _ratings(n_users=100, n_items=70, nnz=1_200)
-    mesh = make_mesh(4)
-    problem = prepare_blocked(users, items, ratings, 4)
-    k = 5
-    cfg = ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
-                    implicit=True, alpha=10.0, exchange_dtype=None)
-    init = _pinned_init(problem, k)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
-    m_xla = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "pallas")
-    m_pal = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    np.testing.assert_allclose(m_pal.user_factors, m_xla.user_factors,
-                               rtol=5e-4, atol=1e-6)
-    np.testing.assert_allclose(m_pal.item_factors, m_xla.item_factors,
-                               rtol=5e-4, atol=1e-6)
-
-def test_fused_gather_assembly_multislice(monkeypatch, rng):
-    """A VMEM budget too small for the whole table forces the sliced
-    multi-pass accumulation — results must match the single-slice path
-    (and the XLA path) over the full fit."""
-    users, items, ratings = _ratings(n_users=150, n_items=110, nnz=1_800)
-    mesh = make_mesh(4)
-    problem = prepare_blocked(users, items, ratings, 4)
-    k = 5
-    cfg = ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
-                    exchange_dtype=None)
-    init = _pinned_init(problem, k)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
-    m_xla = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    # budget small enough that every table (both sides) needs >=2 slices
-    # but few enough to stay under the slice cap
-    from flink_ms_tpu.ops import gather_assembly as ga
-
-    # budget sized so BOTH tables need >=2 slices yet stay under the
-    # slice cap — otherwise one half-sweep silently falls back to XLA and
-    # the comparison is (partly) XLA vs XLA
-    u_shape = (problem.u.per_block * 4, k)
-    i_shape = (problem.i.per_block * 4, k)
-    budget = max(u_shape[0], i_shape[0]) * k * 4 * 2 // 3
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_VMEM_BYTES", str(budget))
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "pallas")
-    for shape in (u_shape, i_shape):
-        n = ga._n_slices(shape, np.float32)
-        assert 2 <= n <= ga._MAX_TABLE_SLICES, (shape, n)
-        assert ga.use_fused_gather(shape, np.float32), shape
-    m_sliced = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                       init=init)
-    np.testing.assert_allclose(m_sliced.user_factors, m_xla.user_factors,
-                               rtol=5e-4, atol=1e-6)
-    np.testing.assert_allclose(m_sliced.item_factors, m_xla.item_factors,
-                               rtol=5e-4, atol=1e-6)
-
-
-def test_fused_gather_assembly_w_chunked(monkeypatch, rng):
-    """Wide rating lists stream through the w-chunk grid axis (a popular
-    catalog entity's bucket width would otherwise blow the VMEM tile);
-    chunked and unchunked results match the XLA path."""
-    # skewed degrees: one hot item collects a wide rating list
-    n = 2_000
-    users = rng.integers(0, 200, n)
-    items = np.where(rng.random(n) < 0.4, 0, rng.integers(0, 80, n))
-    ratings = rng.uniform(1, 5, n)
-    mesh = make_mesh(4)
-    problem = prepare_blocked(users, items, ratings, 4)
-    # the hot item's rating list makes a wide ITEM-side bucket
-    assert max(problem.i.widths) > 64
-    k = 5
-    cfg = ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
-                    exchange_dtype=None)
-    init = _pinned_init(problem, k)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "xla")
-    m_xla = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY", "pallas")
-    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_W_CHUNK", "32")  # force >1
-    m_pal = als_fit(users, items, ratings, cfg, mesh, problem=problem,
-                    init=init)
-    np.testing.assert_allclose(m_pal.user_factors, m_xla.user_factors,
-                               rtol=5e-4, atol=1e-6)
-    np.testing.assert_allclose(m_pal.item_factors, m_xla.item_factors,
-                               rtol=5e-4, atol=1e-6)

@@ -224,7 +224,7 @@ def test_checkpoint_restart_replays_from_offset(tmp_path):
 
 
 def test_latest_restart_without_checkpoint_keeps_seed_offset(tmp_path):
-    """ADVICE r2: a startFrom=latest consumer that fails before its first
+    """a startFrom=latest consumer that fails before its first
     checkpoint must restart from the seeded end-of-journal offset, not 0 —
     resetting to 0 replays the whole backlog the job was configured to
     skip."""

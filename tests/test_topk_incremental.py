@@ -1,7 +1,7 @@
 """Incremental top-k index: streaming row updates are applied in place on
 device (no O(catalog) rebuild on the query path), new items land through a
 background rebuild, and query latency stays flat under a concurrent writer
-(VERDICT r1: one SGD row update must not trigger a multi-second full
+(one SGD row update must not trigger a multi-second full
 re-scan per query at catalog scale)."""
 
 import threading
@@ -134,7 +134,7 @@ def test_p99_flat_under_streaming_writer(rng):
     p99_bound = min(max(0.15, 10 * p99_quiet), 0.3)
     try:
         # three full windows, gate on the MEDIAN of each statistic
-        # (VERDICT r3 weak #6: the old retry-until-pass accepted if ANY
+        # (the old retry-until-pass accepted if ANY
         # window passed, so one clean window could absorb a real
         # regression).  The median still rejects one externally-stalled
         # window — this box has ONE core, and a concurrent process import
@@ -197,3 +197,29 @@ def test_snapshot_first_row_truncated_does_not_poison_width(rng):
     ids, rows, width = index._snapshot_rows()
     assert width == k
     assert len(ids) == 20 and "0" not in ids
+
+
+def test_failed_background_rebuild_keeps_serving_and_is_counted(rng):
+    """A device error inside the background rebuild must not take serving
+    down — but it is counted (``tpums_topk_device_errors_total``), so the
+    chip smoke, which reads the counter as zero, fails on one."""
+    from flink_ms_tpu.obs.metrics import get_registry
+
+    table = ModelTable(4)
+    k = 4
+    _fill(table, 20, k, rng)
+    index = DeviceFactorIndex(table, "-I")
+    q = rng.normal(size=k)
+    before = index.topk(q, 3)
+    errors = get_registry().counter("tpums_topk_device_errors_total")
+    n0 = errors.value
+
+    def device_lost(ids, rows, width):
+        raise RuntimeError("device lost")
+
+    index._assemble = device_lost
+    table.put("new-I", ";".join(repr(float(x)) for x in q * 50.0))
+    assert index.topk(q, 3) == before  # stale index keeps answering
+    index._rebuild_thread.join(timeout=10)
+    assert not index._rebuild_thread.is_alive()
+    assert errors.value == n0 + 1
