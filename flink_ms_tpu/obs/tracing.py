@@ -47,6 +47,7 @@ sample rate, not the request rate.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import random
@@ -118,6 +119,29 @@ def thread_stages() -> Dict[int, str]:
         except IndexError:  # racing pop on the owner thread
             continue
     return out
+
+
+# what ``stage`` hands out where there is no profiler to write to
+_NO_STAGE = contextlib.nullcontext()
+
+
+def stage(name: str, **fields):
+    """``with stage("topk.fetch"):`` — a named interval on the PROFILER's
+    clock: a ``jax.profiler.TraceAnnotation``, so it lands in the same
+    trace, on the same host clock, as the runtime's own events and the
+    device timeline (an operator's ``--profileDir`` trace, the benchmark's
+    traced window).  ``fields`` become the event's stats.  With no profiler
+    session open it costs one constructor and a flag test in C++; in a
+    process that never imported jax (clients, the load generator) it is a
+    shared no-op object, and jax is not imported for its sake.
+
+    Not a ``span``: those are per-request, head-sampled, on ``time.time()``
+    and spill to JSONL; a stage is per-thread wall time for whoever is
+    profiling, and records nothing otherwise."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_STAGE
+    return jax.profiler.TraceAnnotation(name, **fields)
 
 
 _ring_lock = threading.Lock()

@@ -936,38 +936,45 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
 
     def half_sweep(y_shard, flat, routed: bool):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
+        # the three named scopes are how a profile tells the sweep's device
+        # time apart (they write metadata only; the program is the same)
         if routed:
             *bucket_args, counts, send_idx = flat
         else:
             *bucket_args, counts = flat
-        y_send = y_shard[0]
-        if exchange_dtype is not None:
-            # cast BEFORE the collective: the exchange moves half the
-            # bytes over ICI and every downstream gather reads half the
-            # bytes from HBM; accumulation stays in the solve dtype
-            y_send = y_send.astype(exchange_dtype)
-        if routed:
-            # need-list exchange: send each destination only the off-block
-            # rows its ratings reference (pad/diagonal rows are the dummy
-            # slot -> zeros); the received (D, r_max, k) stack plus the
-            # device's OWN shard is the gather table, with idx arrays
-            # pre-remapped (off-block: s*r_max + pos; self: D*r_max + local)
-            picked = jnp.take(y_send, send_idx[0], axis=0)  # (D, r_max, k)
-            recv = jax.lax.all_to_all(
-                picked, BLOCK_AXIS, split_axis=0, concat_axis=0
-            ).reshape(-1, k)
-            y_all = jnp.concatenate([recv, y_send], axis=0)
-        else:
-            y_all = jax.lax.all_gather(y_send, BLOCK_AXIS, axis=0, tiled=True)
+        with jax.named_scope("als.exchange"):
+            y_send = y_shard[0]
+            if exchange_dtype is not None:
+                # cast BEFORE the collective: the exchange moves half the
+                # bytes over ICI and every downstream gather reads half the
+                # bytes from HBM; accumulation stays in the solve dtype
+                y_send = y_send.astype(exchange_dtype)
+            if routed:
+                # need-list exchange: send each destination only the
+                # off-block rows its ratings reference (pad/diagonal rows
+                # are the dummy slot -> zeros); the received (D, r_max, k)
+                # stack plus the device's OWN shard is the gather table,
+                # with idx arrays pre-remapped (off-block: s*r_max + pos;
+                # self: D*r_max + local)
+                picked = jnp.take(y_send, send_idx[0], axis=0)  # (D, r_max, k)
+                recv = jax.lax.all_to_all(
+                    picked, BLOCK_AXIS, split_axis=0, concat_axis=0
+                ).reshape(-1, k)
+                y_all = jnp.concatenate([recv, y_send], axis=0)
+            else:
+                y_all = jax.lax.all_gather(
+                    y_send, BLOCK_AXIS, axis=0, tiled=True)
         buckets = [
             (bucket_args[2 * j][0], bucket_args[2 * j + 1][0])
             for j in range(len(bucket_args) // 2)
         ]
         yty = None
         if implicit:
-            yty = jax.lax.psum(
-                jnp.einsum("nk,nm->km", y_shard[0], y_shard[0]), BLOCK_AXIS
-            )
+            with jax.named_scope("als.assemble"):
+                yty = jax.lax.psum(
+                    jnp.einsum("nk,nm->km", y_shard[0], y_shard[0]),
+                    BLOCK_AXIS,
+                )
         if _fused_solve():
             # per-bucket fused assembly+solve: bucket outputs are
             # contiguous slot ranges, so each bucket's factor rows are
@@ -977,30 +984,34 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             # zero row appended explicitly (the unfused path routes it
             # through a zero system + count mask).
             def solve_chunk(A, bb, cnt, in_scan=False):
-                if yty is not None:
-                    A = A + yty[None, :, :]
-                return _solve_factors(A, bb, cnt, lam, weighted, dtype,
-                                      platform, in_scan=in_scan)
+                with jax.named_scope("als.solve"):
+                    if yty is not None:
+                        A = A + yty[None, :, :]
+                    return _solve_factors(A, bb, cnt, lam, weighted, dtype,
+                                          platform, in_scan=in_scan)
 
             xs = []
             off = 0
             for idx_b, val_b in buckets:
                 rows_j = idx_b.shape[0]
-                xs.append(_bucket_normal_eqs(
-                    y_all, idx_b, val_b, implicit, alpha, dtype,
-                    config.assembly_precision,
-                    post=solve_chunk, extra=counts[0][off:off + rows_j],
-                ))
+                with jax.named_scope("als.assemble"):
+                    xs.append(_bucket_normal_eqs(
+                        y_all, idx_b, val_b, implicit, alpha, dtype,
+                        config.assembly_precision,
+                        post=solve_chunk, extra=counts[0][off:off + rows_j],
+                    ))
                 off += rows_j
             xs.append(jnp.zeros((1, k), dtype))
             return jnp.concatenate(xs, axis=0)[None]
-        A, b = _assemble_normal_eqs(
-            y_all, buckets, implicit, alpha, dtype,
-            precision=config.assembly_precision,
-        )
-        if implicit:
-            A = A + yty[None, :, :]
-        x = _solve_factors(A, b, counts[0], lam, weighted, dtype, platform)
+        with jax.named_scope("als.assemble"):
+            A, b = _assemble_normal_eqs(
+                y_all, buckets, implicit, alpha, dtype,
+                precision=config.assembly_precision,
+            )
+        with jax.named_scope("als.solve"):
+            if implicit:
+                A = A + yty[None, :, :]
+            x = _solve_factors(A, b, counts[0], lam, weighted, dtype, platform)
         return x[None]  # (1, per_block, k)
 
     n_u_args = 2 * n_u_buckets + 1 + (1 if plan["u"] is not None else 0)
@@ -1010,8 +1021,10 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
 
         def one_iter(_, carry):
             uf, itf = carry
-            uf = half_sweep(itf, u_flat, routed=plan["u"] is not None)
-            itf = half_sweep(uf, i_flat, routed=plan["i"] is not None)
+            with jax.named_scope("als.user_half"):
+                uf = half_sweep(itf, u_flat, routed=plan["u"] is not None)
+            with jax.named_scope("als.item_half"):
+                itf = half_sweep(uf, i_flat, routed=plan["i"] is not None)
             return uf, itf
 
         # dynamic trip count (lowers to while_loop): one compiled program

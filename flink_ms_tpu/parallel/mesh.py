@@ -93,7 +93,8 @@ def acquire_devices(host_pinned: bool = False) -> list:
       operator (and ``chip_smoke.py``) learns where a job ran;
     - turns the persistent compile cache on when the backend is ``tpu``:
       at ``$JAX_COMPILATION_CACHE_DIR`` when that is set (jax reads it
-      itself; nothing is set in code), else at ``repo_cache_dir()``.
+      itself; nothing is set in code), else at ``repo_cache_dir()``, with
+      the programs' metadata (named scopes, source locations) in the key.
 
     Call it after ``jax.distributed.initialize`` in multi-process jobs."""
     global _acquired
@@ -114,10 +115,22 @@ def acquire_devices(host_pinned: bool = False) -> list:
         if not _acquired:
             _acquired = True
             _count_compiles()
-            if (backend == "tpu"
-                    and not os.environ.get("JAX_COMPILATION_CACHE_DIR")):
+            if backend == "tpu":
+                if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+                    jax.config.update(
+                        "jax_compilation_cache_dir", repo_cache_dir())
+                # named scopes and source lines live in the metadata that
+                # the cache key leaves out by default, and a hit hands back
+                # the executable with the metadata of whoever compiled it
+                # first.  On a machine that keeps its cache (the chip
+                # tool's does) a profile of today's code then carries
+                # yesterday's names, or none: the TOPK programs of PR 25
+                # came back without their scopes (PERF.md, PR 25).  The
+                # price: an edit that moves a line jax records for a
+                # traced operation, or a checkout at another path,
+                # compiles once more.
                 jax.config.update(
-                    "jax_compilation_cache_dir", repo_cache_dir())
+                    "jax_compilation_cache_include_metadata_in_key", True)
             print(
                 f"[mesh] platform={devices[0].platform} "
                 f"device_kind={devices[0].device_kind} "

@@ -38,6 +38,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from ..obs.tracing import stage
 
 
 def batching_enabled() -> bool:
@@ -49,14 +50,22 @@ class PendingTopK:
     ``wait()`` while the dispatcher scores the coalesced batch and
     scatters results (or the per-group error) back.
 
-    Span fields (filled in by the dispatcher, read by the server's trace
-    epilogue when the request carried a tid): ``queue_wait_s`` — enqueue
-    to dispatch pick-up; ``batch_size`` — queries sharing the dispatch;
-    ``device_s`` — the group's scoring time.  Together they decompose a
-    slow top-k into waiting vs computing vs everything else."""
+    Span fields (filled in by the dispatcher, read by the server's
+    epilogue): ``queue_wait_s`` — enqueue to dispatch pick-up;
+    ``batch_size`` — queries sharing the dispatch; ``device_s`` — the WALL
+    of the group's whole index call (maintenance, stack and pad, enqueue,
+    the device's work, two result copies, ``_format_rows``), not device
+    time: the part that contains the device's work is
+    ``tpums_topk_fetch_seconds``.  The instants behind them, on
+    ``time.perf_counter()``: ``t_enqueue``; ``t_dispatch`` — the group
+    was picked up (``queue_wait_s = t_dispatch - t_enqueue``); ``t_done``
+    — the index call returned (``device_s = t_done - t_dispatch``),
+    stamped once per frame on every member and left None for an inline
+    single, whose reply time is the verb latency less ``device_s``."""
 
     __slots__ = ("vec", "k", "result", "error", "_event",
-                 "t_enqueue", "queue_wait_s", "batch_size", "device_s")
+                 "t_enqueue", "t_dispatch", "t_done",
+                 "queue_wait_s", "batch_size", "device_s")
 
     def __init__(self, vec: np.ndarray, k: int):
         self.vec = vec
@@ -65,6 +74,8 @@ class PendingTopK:
         self.error: Optional[BaseException] = None
         self._event = threading.Event()
         self.t_enqueue = time.perf_counter()
+        self.t_dispatch: Optional[float] = None
+        self.t_done: Optional[float] = None
         self.queue_wait_s: Optional[float] = None
         self.batch_size: Optional[int] = None
         self.device_s: Optional[float] = None
@@ -137,6 +148,16 @@ class TopKBatcher:
         self._obs_batch_size = reg.histogram(
             "tpums_topk_batch_size", bounds=obs_metrics.SIZE_BUCKETS)
         self._obs_device = reg.histogram("tpums_topk_device_seconds")
+        # per frame, from the two instants the index stamps around its
+        # wait for the device: the enqueue returned -> both result arrays
+        # on the host.  device_seconds less this is host work inside the
+        # dispatch.
+        self._obs_fetch = reg.histogram("tpums_topk_fetch_seconds")
+        # per frame that found queries already waiting when the frame
+        # before it came back: that frame's results on the host -> this
+        # frame enqueued, the device starved by the host
+        self._obs_turnaround = reg.histogram("tpums_topk_turnaround_seconds")
+        self._backlog_since: Optional[float] = None
 
     # -- submit side --------------------------------------------------------
 
@@ -170,12 +191,16 @@ class TopKBatcher:
             try:
                 self.inline_singles += 1
                 t0 = time.perf_counter()
-                result = self.index.topk(pending.vec, pending.k)
+                with stage("topk.frame", n=1, b_pad=1, seq=-1):
+                    result = self.index.topk(pending.vec, pending.k)
+                pending.t_dispatch = t0
                 pending.queue_wait_s = 0.0
                 pending.batch_size = 1
+                # the wall of the whole index call, as in _dispatch
+                # (PendingTopK says what that holds); t_done stays None
                 pending.device_s = time.perf_counter() - t0
                 # no registry observation here: an inline single's queue
-                # wait is 0 and its device time is within a constant of
+                # wait is 0 and its dispatch wall is within a constant of
                 # the verb latency the server already histograms, while
                 # even one extra locked observation is measurable on a
                 # ~0.1 ms round trip (README overhead A/B).  The span
@@ -215,8 +240,12 @@ class TopKBatcher:
     # -- dispatcher ---------------------------------------------------------
 
     def _run(self) -> None:
+        # every instant of this thread lies in one named stage (obs/
+        # tracing.stage): ``topk.coalesce`` here, ``topk.frame`` and its
+        # children in _dispatch and in the index, so whoever profiles the
+        # process can name what the host was doing in each device gap
         while True:
-            with self._cond:
+            with stage("topk.coalesce"), self._cond:
                 while not self._queue and not self._closed:
                     self._cond.wait()
                 if not self._queue and self._closed:
@@ -256,42 +285,65 @@ class TopKBatcher:
         for p in batch:
             groups.setdefault((p.k, p.vec.shape), []).append(p)
         for (k, _shape), group in groups.items():
-            t_disp = time.perf_counter()
-            try:
-                if len(group) == 1 and not getattr(
-                    self.index, "prefers_frames", False
-                ):
-                    # a lone query runs the exact single-query program, so
-                    # sequential traffic is BIT-identical to the unbatched
-                    # path (the native plane's byte-parity tests replay
-                    # one-at-a-time queries through here).  Sharded/ANN
-                    # indexes prefer whole frames: there the batched
-                    # program IS the only compiled program, so a lone
-                    # query rides it as a (1, k) frame instead.
-                    results = [self.index.topk(group[0].vec, k)]
-                else:
-                    # the whole frame goes down in ONE stacked dispatch —
-                    # on the sharded tier this is the shard_map program
-                    # (per-device partial top-k + merge) over the frame
-                    results = self.index.topk_many(
-                        np.stack([p.vec for p in group]), k
-                    )
-            except Exception as e:
-                # a bad group (e.g. width mismatch vs the index) fails its
-                # own members; other groups in the batch still score
-                for p in group:
-                    p._finish(error=e)
-                continue
-            device_s = time.perf_counter() - t_disp
+            n = len(group)
+            with stage("topk.frame", n=n,
+                       b_pad=1 << (n - 1).bit_length(), seq=self.dispatches):
+                self._dispatch_group(group, k)
+
+    def _dispatch_group(self, group: List[PendingTopK], k: int) -> None:
+        t_disp = time.perf_counter()
+        try:
+            if len(group) == 1 and not getattr(
+                self.index, "prefers_frames", False
+            ):
+                # a lone query runs the exact single-query program, so
+                # sequential traffic is BIT-identical to the unbatched
+                # path (the native plane's byte-parity tests replay
+                # one-at-a-time queries through here).  Sharded/ANN
+                # indexes prefer whole frames: there the batched
+                # program IS the only compiled program, so a lone
+                # query rides it as a (1, k) frame instead.
+                results = [self.index.topk(group[0].vec, k)]
+            else:
+                # the whole frame goes down in ONE stacked dispatch —
+                # on the sharded tier this is the shard_map program
+                # (per-device partial top-k + merge) over the frame.
+                # The index stacks the vectors, inside its topk.pack
+                results = self.index.topk_many([p.vec for p in group], k)
+        except Exception as e:
+            # a bad group (e.g. width mismatch vs the index) fails its
+            # own members; other groups in the batch still score
+            for p in group:
+                p._finish(error=e)
+            return
+        t_done = time.perf_counter()
+        with stage("topk.scatter"):
+            # the wall of the whole index call: PendingTopK says what
+            # that holds beside the device's work
+            device_s = t_done - t_disp
             self.dispatches += 1
             self.batched_queries += len(group)
             if len(group) > self.max_batch_seen:
                 self.max_batch_seen = len(group)
             metrics_on = obs_metrics.metrics_enabled()
+            # the index stamps its wait for the device, per calling thread;
+            # stamps older than this call belong to an earlier frame, since
+            # an empty index answers without dispatching
+            t_enqueued, t_fetched = self.index.last_fetch() or (None, None)
+            stamped = t_enqueued is not None and t_enqueued >= t_disp
             if metrics_on:
                 self._obs_batch_size.observe(len(group))
                 self._obs_device.observe(device_s)
+                if stamped:
+                    self._obs_fetch.observe(t_fetched - t_enqueued)
+                    if self._backlog_since is not None:
+                        self._obs_turnaround.observe(
+                            t_enqueued - self._backlog_since)
+            self._backlog_since = (
+                t_fetched if stamped and self._queue else None)
             for p, result in zip(group, results):
+                p.t_dispatch = t_disp
+                p.t_done = t_done
                 p.queue_wait_s = t_disp - p.t_enqueue
                 p.batch_size = len(group)
                 p.device_s = device_s

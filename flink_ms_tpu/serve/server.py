@@ -243,6 +243,12 @@ class LookupServer:
         # error counter), created lazily so only verbs actually served
         # appear in the exposition
         self._obs_verbs: Dict[str, tuple] = {}
+        # per batched top-k request: its frame came back from the index
+        # (PendingTopK.t_done) -> the reply rendered.  Handler wake-up,
+        # the wait for the GIL and _format_topk, which neither the queue
+        # wait nor the dispatch wall covers.
+        self._obs_topk_reply = obs_metrics.get_registry().histogram(
+            "tpums_topk_reply_seconds")
         self._obs_burst = obs_metrics.get_registry().histogram(
             "tpums_server_burst_size", bounds=obs_metrics.SIZE_BUCKETS)
         # live persistent connections + their handler threads: clients hold
@@ -670,9 +676,10 @@ class LookupServer:
         traced requests.  ``resolver`` (deferred top-k only) may expose a
         ``pending`` with the microbatcher's span fields — queue wait,
         batch size, device seconds — which join the event AND become
-        synthesized child spans (``mb_queue_wait``/``mb_device``) under
-        the ``server_reply`` span, so one slow traced query shows WHERE
-        its time went.
+        child spans (``mb_queue_wait``/``mb_device``) under the
+        ``server_reply`` span, from the instants the batcher stamped
+        (``t_enqueue``, ``t_dispatch``), so one slow traced query shows
+        WHERE its time went.
 
         ``tid`` is the RAW wire value (possibly ``tid/sid`` — the sid is
         the CLIENT's rpc span, which parents this server's span across
@@ -684,10 +691,14 @@ class LookupServer:
         but NOT a server error — it rides its own counter
         (``tpums_admission_shed_total``), so deliberate shedding never
         reads as the fleet failing."""
-        dt = time.perf_counter() - t0
+        now = time.perf_counter()
+        dt = now - t0
         trace_id, psid = obs_tracing.split_tid(tid) if tid is not None \
             else (None, None)
+        pending = getattr(resolver, "pending", None)
         if obs_metrics.metrics_enabled():
+            if pending is not None and pending.t_done is not None:
+                self._obs_topk_reply.observe(now - pending.t_done)
             # ONE locked observation per request: the per-verb request
             # count is the latency histogram's count, and the
             # ``tpums_server_requests_total`` counter series is
@@ -707,7 +718,6 @@ class LookupServer:
                       "ok": not reply.startswith("E")}
             if shed:
                 fields["shed"] = True
-            pending = getattr(resolver, "pending", None)
             if pending is not None:
                 for name in ("queue_wait_s", "batch_size", "device_s"):
                     v = getattr(pending, name, None)
@@ -717,23 +727,21 @@ class LookupServer:
             obs_tracing.event("server_reply", tid=trace_id, sid=sid,
                               psid=psid, t0=t_end - dt,
                               dur_s=round(dt, 9), **fields)
-            if pending is not None:
-                # synthesize the microbatch stages as child spans — the
-                # batcher records durations, not span ids, so the tree
-                # shape is rebuilt here from the request timeline
-                qw = getattr(pending, "queue_wait_s", None)
-                dev = getattr(pending, "device_s", None)
-                if qw is not None:
-                    obs_tracing.event(
-                        "mb_queue_wait", tid=trace_id,
-                        sid=obs_tracing.new_span_id(), psid=sid,
-                        t0=t_end - dt, dur_s=round(qw, 9))
-                if dev is not None:
-                    obs_tracing.event(
-                        "mb_device", tid=trace_id,
-                        sid=obs_tracing.new_span_id(), psid=sid,
-                        t0=t_end - dev, dur_s=round(dev, 9),
-                        batch_size=getattr(pending, "batch_size", None))
+            if pending is not None and pending.t_dispatch is not None:
+                # the batcher's stamps are perf_counter instants; events
+                # carry wall time
+                wall = time.time() - time.perf_counter()
+                obs_tracing.event(
+                    "mb_queue_wait", tid=trace_id,
+                    sid=obs_tracing.new_span_id(), psid=sid,
+                    t0=pending.t_enqueue + wall,
+                    dur_s=round(pending.queue_wait_s, 9))
+                obs_tracing.event(
+                    "mb_device", tid=trace_id,
+                    sid=obs_tracing.new_span_id(), psid=sid,
+                    t0=pending.t_dispatch + wall,
+                    dur_s=round(pending.device_s, 9),
+                    batch_size=pending.batch_size)
         if stale:
             # staleness rides BEFORE the tid echo: the client strips its
             # exact tid suffix first, then pops the trailing st field

@@ -61,6 +61,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from ..obs.tracing import stage
 from .table import ModelTable
 
 _engine_warn_lock = threading.Lock()
@@ -289,6 +290,8 @@ class DeviceFactorIndex:
         self._k_real = 0  # real factor width
         self._topk_fn = None
         self._topk_many_fn = None
+        # each thread's last dispatch, stamped by _fetch (last_fetch)
+        self._stamps = threading.local()
         self._built_once = False
         # retrieval tiers (module docstring): sharded exact layout +
         # optional IVF ANN shortlist.  Knobs are read once per index; the
@@ -832,28 +835,49 @@ class DeviceFactorIndex:
         otherwise the legacy single-device batched program.  Every branch
         funnels through ``_to_host`` with (B, k)-sized arrays only — the
         catalog never leaves the device."""
-        if self._ann is not None:
-            scores, idx = self._ann.search(self._matrix, q, k_eff)
-            return _to_host(scores), _to_host(idx)
-        if self._is_sharded:
-            fn = _sharded_topk_program(self._mesh)
-            scores, idx = fn(self._matrix, self._bias, q, k_eff)
-            return _to_host(scores), _to_host(idx)
-        if self._topk_many_fn is None:
-            from functools import partial
+        with stage("topk.enqueue"):
+            if self._ann is not None:
+                scores, idx = self._ann.search(self._matrix, q, k_eff)
+            elif self._is_sharded:
+                fn = _sharded_topk_program(self._mesh)
+                scores, idx = fn(self._matrix, self._bias, q, k_eff)
+            else:
+                if self._topk_many_fn is None:
+                    from functools import partial
 
-            import jax
-            import jax.numpy as jnp
+                    import jax
+                    import jax.numpy as jnp
 
-            @partial(jax.jit, static_argnums=2)
-            def topk_many_fn(matrix, qs, k):
-                scores = jnp.matmul(  # (B, n_items)
-                    qs, matrix.T, precision=_SCORE_PRECISION)
-                return jax.lax.top_k(scores, k)
+                    @partial(jax.jit, static_argnums=2)
+                    def topk_many_fn(matrix, qs, k):
+                        with jax.named_scope("topk.score"):
+                            scores = jnp.matmul(  # (B, n_items)
+                                qs, matrix.T, precision=_SCORE_PRECISION)
+                        with jax.named_scope("topk.select"):
+                            return jax.lax.top_k(scores, k)
 
-            self._topk_many_fn = topk_many_fn
-        scores, idx = self._topk_many_fn(self._matrix, q, k_eff)
-        return _to_host(scores), _to_host(idx)
+                    self._topk_many_fn = topk_many_fn
+                scores, idx = self._topk_many_fn(self._matrix, q, k_eff)
+        return self._fetch(scores, idx)
+
+    def _fetch(self, scores, idx):
+        """The wait for the device and the two result copies, between two
+        stamped instants: the one stage of a dispatch that contains the
+        device's work."""
+        t_enqueued = time.perf_counter()
+        with stage("topk.fetch"):
+            out = _to_host(scores), _to_host(idx)
+        self._stamps.last = (t_enqueued, time.perf_counter())
+        return out
+
+    def last_fetch(self) -> Optional[Tuple[float, float]]:
+        """``perf_counter`` instants of the CALLING thread's last dispatch:
+        (the jitted call returned, both result arrays on the host), or
+        None.  Per thread, so that the microbatcher, which reads them into
+        ``tpums_topk_fetch_seconds`` / ``_turnaround_seconds`` once the
+        index call is back and its lock released, never reads the stamps
+        of another caller of the same index (the push plane, a warm-up)."""
+        return getattr(self._stamps, "last", None)
 
     def _format_rows(self, scores, idx, n_rows: int):
         """(B_pad, k) score/index arrays -> B result lists of (id, score).
@@ -872,7 +896,8 @@ class DeviceFactorIndex:
 
     def topk(self, user_factors: np.ndarray, k: int) -> List[Tuple[str, float]]:
         with self._lock:
-            self._maintain_locked()
+            with stage("topk.maintain"):
+                self._maintain_locked()
             if self._matrix is None:
                 return []
             n = self._n_real
@@ -887,25 +912,30 @@ class DeviceFactorIndex:
                 # sharded / ANN tiers only compile the frame program; a
                 # lone query rides it as a (1, k) frame
                 scores, idx = self._dispatch_frame_locked(q[None, :], k_eff)
-                return self._format_rows(scores, idx, 1)[0]
-            if self._topk_fn is None:
-                from functools import partial
+                with stage("topk.format"):
+                    return self._format_rows(scores, idx, 1)[0]
+            with stage("topk.enqueue"):
+                if self._topk_fn is None:
+                    from functools import partial
 
-                import jax
-                import jax.numpy as jnp
+                    import jax
+                    import jax.numpy as jnp
 
-                @partial(jax.jit, static_argnums=2)
-                def topk_fn(matrix, query, k):
-                    scores = jnp.matmul(  # (n_items,)
-                        matrix, query, precision=_SCORE_PRECISION)
-                    return jax.lax.top_k(scores, k)
+                    @partial(jax.jit, static_argnums=2)
+                    def topk_fn(matrix, query, k):
+                        with jax.named_scope("topk.score"):
+                            scores = jnp.matmul(  # (n_items,)
+                                matrix, query, precision=_SCORE_PRECISION)
+                        with jax.named_scope("topk.select"):
+                            return jax.lax.top_k(scores, k)
 
-                self._topk_fn = topk_fn
-            scores, idx = self._topk_fn(self._matrix, q, k_eff)
-            return [
-                (self._ids[int(i)], float(s))
-                for i, s in zip(_to_host(idx), _to_host(scores))
-            ]
+                    self._topk_fn = topk_fn
+                scores, idx = self._topk_fn(self._matrix, q, k_eff)
+            scores, idx = self._fetch(scores, idx)
+            with stage("topk.format"):
+                return [
+                    (self._ids[int(i)], float(s)) for i, s in zip(idx, scores)
+                ]
 
     def topk_many(
         self, queries: np.ndarray, k: int
@@ -928,24 +958,28 @@ class DeviceFactorIndex:
         scatter's fixed shape: XLA compiles a handful of batch shapes,
         not one per in-flight batch size."""
         with self._lock:
-            self._maintain_locked()
-            q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-            n_queries = q.shape[0]
-            if self._matrix is None:
-                return [[] for _ in range(n_queries)]
-            if q.shape[1] != self._k_real:
-                raise ValueError(
-                    f"queries have {q.shape[1]} factors, index has "
-                    f"{self._k_real}"
-                )
-            k_eff = min(k, self._n_real)
-            b_pad = 1 << (n_queries - 1).bit_length() if n_queries > 1 else 1
-            if b_pad != n_queries:
-                q = np.concatenate(
-                    [q, np.broadcast_to(q[:1], (b_pad - n_queries, q.shape[1]))]
-                )
+            with stage("topk.maintain"):
+                self._maintain_locked()
+            with stage("topk.pack"):
+                # a list of vectors (the microbatcher's group) stacks here
+                q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+                n_queries = q.shape[0]
+                if self._matrix is None:
+                    return [[] for _ in range(n_queries)]
+                if q.shape[1] != self._k_real:
+                    raise ValueError(
+                        f"queries have {q.shape[1]} factors, index has "
+                        f"{self._k_real}"
+                    )
+                k_eff = min(k, self._n_real)
+                b_pad = (1 << (n_queries - 1).bit_length()
+                         if n_queries > 1 else 1)
+                if b_pad != n_queries:
+                    q = np.concatenate([q, np.broadcast_to(
+                        q[:1], (b_pad - n_queries, q.shape[1]))])
             scores, idx = self._dispatch_frame_locked(q, k_eff)
-            return self._format_rows(scores, idx, n_queries)
+            with stage("topk.format"):
+                return self._format_rows(scores, idx, n_queries)
 
     def warm_batch_shapes(self, k: int, max_batch: int = 32) -> None:
         """Pre-compile every padded-bucket batched program (power-of-two

@@ -24,9 +24,12 @@ def fresh_rule(monkeypatch):
     armed again, with the listeners it registers removed afterwards."""
     monkeypatch.setattr(M, "_acquired", False)
     monkeypatch.setattr(M, "_count_compiles", lambda: None)
-    saved = jax.config.jax_compilation_cache_dir
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_compilation_cache_include_metadata_in_key")}
     yield
-    jax.config.update("jax_compilation_cache_dir", saved)
+    for name, value in saved.items():
+        jax.config.update(name, value)
 
 
 def _a_chip(monkeypatch):
@@ -89,6 +92,21 @@ def test_cache_at_the_fixed_checkout_path_on_the_chip(
     assert M.acquire_devices()[0].platform == "tpu"
     assert jax.config.jax_compilation_cache_dir == os.path.join(
         ROOT, ".jax_cache")
+
+
+def test_cache_key_holds_the_metadata_on_the_chip_only(
+        fresh_rule, monkeypatch):
+    """A hit otherwise hands back the executable with the named scopes and
+    source lines of whoever compiled it first, and a profile of today's
+    code names yesterday's."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    M.acquire_devices()  # the host: no cache, nothing set
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    monkeypatch.setattr(M, "_acquired", False)
+    _a_chip(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/operator/choice")
+    M.acquire_devices()  # wherever the cache lives
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_cache_path_is_fixed_and_gitignored():

@@ -144,9 +144,11 @@ def test_rocksdb_serving_survives_process_state_loss(tmp_path):
     try:
         journal.append([F.format_als_row(i, "U", [float(i)]) for i in range(30)])
         assert _wait_until(lambda: len(job.table) == 30)
-        # wait for a checkpoint (offset marker) to land
+        # wait for a checkpoint (offset marker) that holds the rows: one can
+        # land at offset 0 before the append is consumed, and stop() then
+        # writes a later one
         assert _wait_until(
-            lambda: job.backend.restore(job.table) is not None, timeout=5
+            lambda: job.backend.restore(job.table) == job.offset, timeout=5
         )
         offset_at_chk = job.backend.restore(job.table)
     finally:
