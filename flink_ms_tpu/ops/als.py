@@ -15,8 +15,14 @@ Ratings are laid out **degree-bucketed**: within each block, entities are
 grouped by degree class (a geometric width ladder, default ratio 1.5 with
 rungs rounded to multiples of 8 — FLINK_MS_ALS_BUCKET_RATIO) and each
 group's rating lists are padded to the class width, so normal-equation
-assembly is a short list of dense batched ``einsum`` contractions — pure
-gather + MXU matmul, no scatter.  (A scatter/``segment_sum`` formulation was measured 8-10x slower
+assembly is a short list of dense batched contractions — a row gather,
+then MXU matmuls, no scatter.  On a TPU, in explicit mode with an f32
+exchange and a rank up to 64, each bucket's gathered rows are contracted
+where the gather left them by one Pallas kernel (``assemble_pallas.py``: A
+and b from a single read, the transpose done in VMEM); every other path
+(bf16 exchange, implicit mode, CPU) is the ``einsum`` pair, which XLA runs
+as a relayout copy, a convolution and a multiply-reduce —
+``resolve_assembly`` decides per sweep.  (A scatter/``segment_sum`` formulation was measured 8-10x slower
 on v5e: TPU scatter serializes per row, and XLA's batched small-matrix
 Cholesky streams the whole (n, k, k) tensor per elimination step.)
 
@@ -571,9 +577,11 @@ def _assembly_chunk_bytes() -> int:
 
 
 def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
-                       precision, post=None, extra=None):
+                       precision, post=None, extra=None, platform=None):
     """One bucket's (A, b): gather the opposite factors for each row's
-    rating list and contract over the rating axis on the MXU.
+    rating list and contract over the rating axis on the MXU — by the
+    Pallas kernel or the einsum pair, as ``resolve_assembly`` answers for
+    ``platform`` (the mesh's; None = einsum).
 
     No mask arrays exist: pad entries gather the opposite side's dummy
     slot, whose factor row is zero by construction, so every pad term
@@ -592,28 +600,47 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     Chunking is over the batch row axis only (the contraction axis w is
     untouched), so chunked and unchunked results are arithmetically
     identical per row."""
+    r, w = idx.shape
+    k = y_all.shape[1]
+    how = resolve_assembly(platform, y_all.dtype, dtype, implicit, k,
+                           precision)
+
     def compute(idx_c, val_c, extra_c, in_scan=False):
-        y = jnp.take(y_all, idx_c, axis=0)                   # (r, w, k)
+        # the two scopes split als.assemble in a profile: the gather is
+        # XLA's either way, the contraction is what `how` chose
+        with jax.named_scope("als.gather"):
+            # kernel path: out-of-range indices clip (there are none: pads
+            # point at a real dummy slot).  The default mode's fill is a
+            # select over all of y, which XLA folds into the einsum's
+            # operands but would run as a pass of its own before a kernel
+            y = jnp.take(y_all, idx_c, axis=0,              # (r, w, k)
+                         mode="clip" if how == "kernel" else None)
         # HIGHEST keeps f32 products (bf16 single-pass shifts the normal
         # equations enough to slow convergence at small lambda)
-        if implicit:
-            w = (alpha * val_c).astype(dtype)       # pads: val 0 -> w 0
-            t = (1.0 + alpha * val_c).astype(dtype)  # pads: y row is zero
-            yw = y * w[..., None]
-            A = jnp.einsum("rwk,rwl->rkl", yw, y, precision=precision,
-                           preferred_element_type=dtype)
-        else:
-            A = jnp.einsum("rwk,rwl->rkl", y, y, precision=precision,
-                           preferred_element_type=dtype)
-            t = val_c.astype(dtype)                  # pads: val 0
-        b = jnp.einsum("rwk,rw->rk", y, t, precision=precision,
-                       preferred_element_type=dtype)
+        with jax.named_scope("als.contract"):
+            if how == "kernel":
+                from .assemble_pallas import assemble_bucket
+
+                A, b = assemble_bucket(y, val_c, precision=precision,
+                                       interpret=platform != "tpu")
+            else:
+                if implicit:
+                    wgt = (alpha * val_c).astype(dtype)  # pads: val 0 -> 0
+                    t = (1.0 + alpha * val_c).astype(dtype)  # pads: y is 0
+                    yw = y * wgt[..., None]
+                    A = jnp.einsum("rwk,rwl->rkl", yw, y,
+                                   precision=precision,
+                                   preferred_element_type=dtype)
+                else:
+                    A = jnp.einsum("rwk,rwl->rkl", y, y, precision=precision,
+                                   preferred_element_type=dtype)
+                    t = val_c.astype(dtype)              # pads: val 0
+                b = jnp.einsum("rwk,rw->rk", y, t, precision=precision,
+                               preferred_element_type=dtype)
         if post is None:
             return A, b
         return post(A, b, extra_c, in_scan=in_scan)
 
-    r, w = idx.shape
-    k = y_all.shape[1]
     # peak transient: the gather itself (at the EXCHANGE dtype's width),
     # plus the same-size solve-dtype yw intermediate in implicit mode
     # (TPU dots don't fuse elementwise producers into operands)
@@ -666,7 +693,7 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
 
 
 def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
-                         precision="highest"):
+                         precision="highest", platform=None):
     """A_u = Σ w·y yᵀ and b_u = Σ t·y per slot, as batched MXU matmuls.
 
     y_all:   (n_slots_global, k) gathered opposite-side factor table
@@ -685,6 +712,7 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     for idx, val in buckets:
         A, b = _bucket_normal_eqs(
             y_all, idx, val, implicit, alpha, dtype, precision,
+            platform=platform,
         )
         As.append(A)
         bs.append(b)
@@ -858,6 +886,43 @@ def resolve_exchange(exchange_dtype: Optional[str],
     return exchange_dtype
 
 
+def resolve_assembly(platform: Optional[str], y_dtype, dtype, implicit: bool,
+                     k: int, precision: str = "highest") -> str:
+    """How a sweep's buckets contract their gathered rows: "kernel"
+    (``assemble_pallas.assemble_bucket``: A and b from one read of y, no
+    relayout copy) or "einsum" (the pair XLA schedules itself).  The kernel
+    engages where a chip run priced it — a TPU, explicit mode, an f32
+    exchange and solve, full-f32 or one-pass products, rank up to 64 (the
+    cell's is 50; the widest tiles compile and agree with float64 on the
+    chip up to k = 128, but the MXU work grows with k squared where the
+    bytes do not, and nothing has timed that against the einsum pair:
+    PERF.md section 6, PR 26) — and everything else keeps the einsum pair
+    unchanged: the bf16 exchange (``als_train``'s default on a TPU),
+    implicit mode's weighted operand, three-pass products and every CPU
+    fit.  It holds no width: on the chip the kernel is ahead at every
+    width of the ML-20M ladder, w = 24 included."""
+    if platform != "tpu" or implicit:
+        return "einsum"
+    if jnp.dtype(y_dtype) != jnp.float32 or jnp.dtype(dtype) != jnp.float32:
+        return "einsum"
+    if precision == "high":
+        return "einsum"  # Mosaic's matmul has no three-pass mode
+    return "kernel" if k <= 64 else "einsum"
+
+
+def _log_assembly(problem: "BlockedProblem", how: str) -> None:
+    """The static choice of one compiled sweep, per side: how many buckets
+    the kernel takes and their share of the padded ratings."""
+    parts = []
+    for name, side in (("u", problem.u), ("i", problem.i)):
+        padded = sum(w * r for w, r in zip(side.widths, side.rows))
+        on = len(side.widths) if how == "kernel" else 0
+        parts.append(f"{name}-sweep kernel on {on} of {len(side.widths)} "
+                     f"buckets ({100.0 if on else 0.0:.1f}% of {padded} "
+                     "padded ratings)")
+    print("[als] assembly: " + ", ".join(parts) + "; einsum pair elsewhere")
+
+
 def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
     k = A.shape[-1]
     choice = resolve_solver(platform)
@@ -933,6 +998,10 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     exchange_dtype = (
         jnp.dtype(resolved_exchange) if resolved_exchange else None
     )
+    if platform == "tpu":
+        _log_assembly(problem, resolve_assembly(
+            platform, exchange_dtype or dtype, dtype, implicit, k,
+            config.assembly_precision))
 
     def half_sweep(y_shard, flat, routed: bool):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
@@ -999,6 +1068,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                         y_all, idx_b, val_b, implicit, alpha, dtype,
                         config.assembly_precision,
                         post=solve_chunk, extra=counts[0][off:off + rows_j],
+                        platform=platform,
                     ))
                 off += rows_j
             xs.append(jnp.zeros((1, k), dtype))
@@ -1006,7 +1076,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         with jax.named_scope("als.assemble"):
             A, b = _assemble_normal_eqs(
                 y_all, buckets, implicit, alpha, dtype,
-                precision=config.assembly_precision,
+                precision=config.assembly_precision, platform=platform,
             )
         with jax.named_scope("als.solve"):
             if implicit:

@@ -83,6 +83,10 @@ def test_als_fit_with_pallas_solver_matches_default(rng, monkeypatch):
 _TPU_LOWERED = {
     "cholesky_pallas.py": [(50, "lane_major"), (64, "batch_major"),
                            (50, "batch_major"), (64, "lane_major")],
+    # (w, k) of the assembly kernel: the user side's narrowest and a middle
+    # bucket of ML-20M, rank 64, and the item side's 64,728-wide bucket,
+    # which goes through the tiled-w path with a ragged last tile
+    "assemble_pallas.py": [(24, 50), (144, 50), (328, 64), (64728, 50)],
 }
 
 
@@ -107,5 +111,21 @@ def test_cholesky_kernel_lowers_for_tpu(k, layout):
     ).trace(
         jax.ShapeDtypeStruct((n, k, k), jnp.float32),
         jax.ShapeDtypeStruct((n, k), jnp.float32),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("w,k", _TPU_LOWERED["assemble_pallas.py"])
+def test_assembly_kernel_lowers_for_tpu(w, k):
+    from flink_ms_tpu.ops.assemble_pallas import assemble_bucket, tile_sizes
+
+    r = 24
+    assert (tile_sizes(w, k)[1] < w) == (w == 64728)
+    lowered = jax.jit(
+        lambda y, t: assemble_bucket(
+            y, t, precision="highest", interpret=False)
+    ).trace(
+        jax.ShapeDtypeStruct((r, w, k), jnp.float32),
+        jax.ShapeDtypeStruct((r, w), jnp.float32),
     ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()

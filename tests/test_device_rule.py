@@ -126,9 +126,13 @@ def test_cache_path_is_fixed_and_gitignored():
     assert ".jax_cache/" in open(os.path.join(ROOT, ".gitignore")).read()
 
 
-def test_compile_counters_feed_the_report():
+def test_compile_counters_feed_the_report(monkeypatch):
     from flink_ms_tpu.obs.metrics import get_registry
 
+    # hook the listeners up again: they hold the counters of the registry
+    # as it was when this process first acquired, and an earlier test file
+    # in the same worker may have reset it since (test_native_protocol does)
+    monkeypatch.setattr(M, "_acquired", False)
     M.acquire_devices()
     secs = get_registry().counter("tpums_jax_compile_seconds_total")
     before = secs.value

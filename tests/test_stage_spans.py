@@ -126,16 +126,22 @@ def test_one_frame_span_per_dispatch_with_its_fields(traced_frames):
 def test_six_children_tile_each_frame(traced_frames):
     events, _ = traced_frames
     frames = [e for e in events if e[2] == "topk.frame"]
-    gaps = total = 0.0
+    gaps, spans = [], []
     for f_start, f_end, _, _ in frames:
         inside = [e for e in events
                   if e[2] != "topk.frame" and f_start <= e[0] and e[1] <= f_end]
         assert [e[2] for e in inside] == CHILDREN
         edges = [f_start] + [t for e in inside for t in e[:2]] + [f_end]
         assert edges == sorted(edges), "children overlap"
-        gaps += sum(b - a for a, b in zip(edges[::2], edges[1::2]))
-        total += f_end - f_start
-    assert gaps < 0.05 * total, (gaps, total)
+        gaps.append(sum(b - a for a, b in zip(edges[::2], edges[1::2])))
+        spans.append(f_end - f_start)
+    # the sum over the frames, less the one frame with the widest gap: a
+    # thread the scheduler took away between two children (six test workers
+    # on eight cores) is not a hole in the tiling, and a hole is in every
+    # frame
+    worst = gaps.index(max(gaps))
+    del gaps[worst], spans[worst]
+    assert sum(gaps) < 0.05 * sum(spans), (gaps, spans)
 
 
 def test_one_coalesce_span_between_frames(traced_frames):
