@@ -251,20 +251,22 @@ def _sharded_topk_program(mesh):
             check_vma=False,
         )
         def run(m, b, q):
-            # (B, n/D) scores of this shard's rows
-            scores = jnp.matmul(
-                q, m.T, precision=_SCORE_PRECISION) + b[None, :]
-            k_local = min(k, m.shape[0])
-            s, i = jax.lax.top_k(scores, k_local)
-            gi = (i + jax.lax.axis_index(BLOCK_AXIS) * m.shape[0]).astype(
-                jnp.int32
-            )
-            s_all = jax.lax.all_gather(s, BLOCK_AXIS)   # (D, B, k_local)
-            g_all = jax.lax.all_gather(gi, BLOCK_AXIS)
-            s_cat = jnp.moveaxis(s_all, 0, 1).reshape(q.shape[0], -1)
-            g_cat = jnp.moveaxis(g_all, 0, 1).reshape(q.shape[0], -1)
-            ms, mi = jax.lax.top_k(s_cat, k)  # k <= D*k_local == n_pad
-            return ms, jnp.take_along_axis(g_cat, mi, axis=1)
+            with jax.named_scope("topk.shard_score"):
+                # (B, n/D) scores of this shard's rows
+                scores = jnp.matmul(
+                    q, m.T, precision=_SCORE_PRECISION) + b[None, :]
+            with jax.named_scope("topk.shard_select"):
+                k_local = min(k, m.shape[0])
+                s, i = jax.lax.top_k(scores, k_local)
+                gi = (i + jax.lax.axis_index(BLOCK_AXIS)
+                      * m.shape[0]).astype(jnp.int32)
+            with jax.named_scope("topk.merge"):
+                s_all = jax.lax.all_gather(s, BLOCK_AXIS)  # (D, B, k_local)
+                g_all = jax.lax.all_gather(gi, BLOCK_AXIS)
+                s_cat = jnp.moveaxis(s_all, 0, 1).reshape(q.shape[0], -1)
+                g_cat = jnp.moveaxis(g_all, 0, 1).reshape(q.shape[0], -1)
+                ms, mi = jax.lax.top_k(s_cat, k)  # k <= D*k_local == n_pad
+                return ms, jnp.take_along_axis(g_cat, mi, axis=1)
 
         return run(matrix, bias, qs)
 
@@ -323,6 +325,14 @@ class DeviceFactorIndex:
             "tpums_topk_index_staleness_seconds", pid=str(os.getpid()))
         self._obs_ann_recall = reg.gauge(
             "tpums_ann_recall_probe", pid=str(os.getpid()))
+        # the installed layout (set at every swap): devices the rows are
+        # split over (1 = the single-device layout), rows a device holds,
+        # pad rows among them; and the frames the shard_map program ran
+        self._obs_shards = reg.gauge("tpums_topk_shards")
+        self._obs_shard_rows = reg.gauge("tpums_topk_shard_rows")
+        self._obs_pad_rows = reg.gauge("tpums_topk_pad_rows")
+        self._obs_sharded_frames = reg.counter(
+            "tpums_topk_sharded_frames_total")
         self._oldest_dirty_ts: Optional[float] = None
         # dirty-key plumbing: the table's writer thread appends, the query
         # path drains.  Tables without listener support (none in-tree) fall
@@ -594,6 +604,10 @@ class DeviceFactorIndex:
         self._n_pad = a["n_pad"]
         self._is_sharded = a["sharded"]
         self._ann = a["ann"]
+        n_shards = a["matrix"].sharding.num_devices if a["sharded"] else 1
+        self._obs_shards.set(n_shards)
+        self._obs_shard_rows.set(a["n_pad"] // n_shards)
+        self._obs_pad_rows.set(a["n_pad"] - a["n_real"])
         self._built_once = True
         self.full_builds += 1
         self._obs_rebuilds.inc()
@@ -841,6 +855,7 @@ class DeviceFactorIndex:
             elif self._is_sharded:
                 fn = _sharded_topk_program(self._mesh)
                 scores, idx = fn(self._matrix, self._bias, q, k_eff)
+                self._obs_sharded_frames.inc()
             else:
                 if self._topk_many_fn is None:
                     from functools import partial
