@@ -42,7 +42,10 @@ from typing import Optional
 
 import numpy as np
 
-from .topk import _PAD_SCORE, _SCORE_PRECISION, _target_device
+from .topk import (
+    _PAD_SCORE, _SCORE_PRECISION, _pack_results, _target_device,
+    _unpack_results,
+)
 
 # rows per assignment dispatch (one compiled shape).  The distance matrix
 # a dispatch materializes is (chunk, nlist) f32 — 32k rows x 4096 lists is
@@ -120,7 +123,7 @@ def _jits():
         # a slot that still scores at the pad floor is an empty shortlist
         # slot, not a real row — surface it as -1 for the formatter
         idx = jnp.where(s > _PAD_SCORE * 0.5, idx, -1)
-        return s, idx
+        return _pack_results(s, idx)
 
     _partial_stats, _recenter, _assign, _search = (
         partial_stats, recenter, assign_only, search
@@ -285,8 +288,7 @@ class IVFIndex:
                 jnp.matmul(x, m.T, precision=_SCORE_PRECISION), k)
             )(mat, q_dev)[1]
         )
-        _, got = self.search(mat, q_dev, k)
-        got = np.asarray(got)
+        _, got = _unpack_results(np.asarray(self.search(mat, q_dev, k)))
         hits = 0
         for r in range(nq):
             hits += len(np.intersect1d(exact[r], got[r][got[r] >= 0]))
@@ -308,11 +310,11 @@ class IVFIndex:
     # -- querying -----------------------------------------------------------
 
     def search(self, matrix, q, k: int):
-        """(B, d) query frame -> (scores, idx) device arrays.  ``matrix``
-        is the resident factor matrix (single-device or mesh-sharded —
-        the gather works against either layout); returned width is
-        ``min(k, nprobe*list_len)`` and empty shortlist slots carry
-        ``idx == -1``."""
+        """(B, d) query frame -> one device array, ``topk._pack_results``
+        of (scores, idx).  ``matrix`` is the resident factor matrix
+        (single-device or mesh-sharded — the gather works against either
+        layout); each half's width is ``min(k, nprobe*list_len)`` and
+        empty shortlist slots carry ``idx == -1``."""
         search = _jits()[3]
         k_eff = min(k, self.nprobe * self.list_len)
         return search(

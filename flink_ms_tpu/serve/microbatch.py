@@ -20,11 +20,13 @@ batcher at construction:
 - ``TPUMS_TOPK_BATCH``          "1" (default) enable, "0" disable
 - ``TPUMS_TOPK_BATCH_MAX``      max queries per device dispatch (default 32)
 - ``TPUMS_TOPK_BATCH_WAIT_US``  coalescing window in microseconds
-                                (default 200) — the worst-case latency a
+                                (default 200), counted from the ARRIVAL of
+                                the queue's head — the worst-case latency a
                                 lone request pays for the chance to share
                                 a dispatch.  While a dispatch executes,
-                                new arrivals queue up naturally, so under
-                                saturation batches fill without waiting.
+                                new arrivals queue up naturally and their
+                                window runs out meanwhile, so a backlog is
+                                picked up as it stands, without waiting.
 """
 
 from __future__ import annotations
@@ -54,7 +56,7 @@ class PendingTopK:
     epilogue): ``queue_wait_s`` — enqueue to dispatch pick-up;
     ``batch_size`` — queries sharing the dispatch; ``device_s`` — the WALL
     of the group's whole index call (maintenance, stack and pad, enqueue,
-    the device's work, two result copies, ``_format_rows``), not device
+    the device's work, the result copy, ``_format_rows``), not device
     time: the part that contains the device's work is
     ``tpums_topk_fetch_seconds``.  The instants behind them, on
     ``time.perf_counter()``: ``t_enqueue``; ``t_dispatch`` — the group
@@ -149,14 +151,22 @@ class TopKBatcher:
             "tpums_topk_batch_size", bounds=obs_metrics.SIZE_BUCKETS)
         self._obs_device = reg.histogram("tpums_topk_device_seconds")
         # per frame, from the two instants the index stamps around its
-        # wait for the device: the enqueue returned -> both result arrays
-        # on the host.  device_seconds less this is host work inside the
+        # wait for the device: the enqueue returned -> the results on the
+        # host.  device_seconds less this is host work inside the
         # dispatch.
         self._obs_fetch = reg.histogram("tpums_topk_fetch_seconds")
         # per frame that found queries already waiting when the frame
         # before it came back: that frame's results on the host -> this
         # frame enqueued, the device starved by the host
         self._obs_turnaround = reg.histogram("tpums_topk_turnaround_seconds")
+        # per frame: the group picked up -> the jitted call returned
+        # (maintain, stack and pad, the frame's transfer, the launch): the
+        # host's serial work before the device can start
+        self._obs_enqueue = reg.histogram("tpums_topk_enqueue_seconds")
+        # per frame, 0 or 1: 1 when the dispatcher waited on the coalescing
+        # window before taking the frame's batch, 0 when the head's age had
+        # used the window up; sum / count is the share of frames that paid it
+        self._obs_window_held = reg.histogram("tpums_topk_window_held")
         self._backlog_since: Optional[float] = None
 
     # -- submit side --------------------------------------------------------
@@ -250,17 +260,21 @@ class TopKBatcher:
                     self._cond.wait()
                 if not self._queue and self._closed:
                     return
-                # coalescing window: give concurrent arrivals max_wait_s
-                # to share this dispatch, but never hold a full batch
-                if (len(self._queue) < self.max_batch
-                        and self.max_wait_s > 0 and not self._flush):
-                    deadline = time.monotonic() + self.max_wait_s
-                    while (len(self._queue) < self.max_batch
-                           and not self._closed and not self._flush):
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            break
-                        self._cond.wait(remaining)
+                # coalescing window: an arrival gets max_wait_s, counted
+                # from ITS OWN arrival, for companions to share its
+                # dispatch.  A head that queued while the frame before it
+                # ran has used the window up, so a backlog is taken as it
+                # stands; an arrival at an idle batcher gets all of it.
+                # Never holds a full batch.  (t_enqueue is perf_counter.)
+                held = 0
+                while (len(self._queue) < self.max_batch
+                       and not self._closed and not self._flush):
+                    remaining = (self._queue[0].t_enqueue + self.max_wait_s
+                                 - time.perf_counter())
+                    if remaining <= 0:
+                        break
+                    held = 1
+                    self._cond.wait(remaining)
                 self._flush = False
                 batch = [
                     self._queue.popleft()
@@ -270,7 +284,7 @@ class TopKBatcher:
                 # into the NEXT batch), not take the idle fast path
                 self._executing += 1
             try:
-                self._dispatch(batch)
+                self._dispatch(batch, held)
             except BaseException as e:  # the loop must survive anything —
                 # a dead dispatcher would park every future submitter
                 for p in batch:
@@ -280,7 +294,7 @@ class TopKBatcher:
                 with self._cond:
                     self._executing -= 1
 
-    def _dispatch(self, batch: List[PendingTopK]) -> None:
+    def _dispatch(self, batch: List[PendingTopK], held: int) -> None:
         groups: dict = {}
         for p in batch:
             groups.setdefault((p.k, p.vec.shape), []).append(p)
@@ -288,9 +302,10 @@ class TopKBatcher:
             n = len(group)
             with stage("topk.frame", n=n,
                        b_pad=1 << (n - 1).bit_length(), seq=self.dispatches):
-                self._dispatch_group(group, k)
+                self._dispatch_group(group, k, held)
 
-    def _dispatch_group(self, group: List[PendingTopK], k: int) -> None:
+    def _dispatch_group(self, group: List[PendingTopK], k: int,
+                        held: int) -> None:
         t_disp = time.perf_counter()
         try:
             if len(group) == 1 and not getattr(
@@ -334,7 +349,9 @@ class TopKBatcher:
             if metrics_on:
                 self._obs_batch_size.observe(len(group))
                 self._obs_device.observe(device_s)
+                self._obs_window_held.observe(held)
                 if stamped:
+                    self._obs_enqueue.observe(t_enqueued - t_disp)
                     self._obs_fetch.observe(t_fetched - t_enqueued)
                     if self._backlog_since is not None:
                         self._obs_turnaround.observe(

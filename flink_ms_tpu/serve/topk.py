@@ -200,10 +200,32 @@ def _index_mesh():
 
 def _to_host(x) -> np.ndarray:
     """The ONE funnel through which query results reach the host.  On the
-    steady sharded path exactly two (B, k) arrays pass through per
-    dispatch — the zero-host-copy test monkeypatches this to prove no
-    catalog-sized array ever does."""
+    steady path exactly one (B, 2k) array passes through per dispatch —
+    the zero-host-copy test monkeypatches this to prove no catalog-sized
+    array ever does."""
     return np.asarray(x)
+
+
+def _pack_results(scores, idx):
+    """The last step of every query program (traced): ``(..., k)`` f32
+    scores and int32 row indices -> ONE ``(..., 2k)`` int32 array, the
+    scores bit-cast beside the indices.  Two outputs are two buffers to
+    allocate at every launch and two device->host copies, the second
+    issued only when the first is back (0.6 ms of a blocked dispatcher a
+    frame on a TPU v5e, PERF.md §6); one output is one of each.  Integers,
+    so no bit pattern is a NaN or a denormal anywhere on the way."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(scores, jnp.int32), idx], axis=-1)
+
+
+def _unpack_results(packed: np.ndarray):
+    """Host side of ``_pack_results`` -> ``(scores, idx)`` views of the
+    one host array, bit for bit what the program computed."""
+    k = packed.shape[-1] // 2
+    return packed[..., :k].view(np.float32), packed[..., k:]
 
 
 # Scores are full-f32 products on every backend.  The TPU's default matmul
@@ -229,7 +251,7 @@ def _sharded_topk_program(mesh):
     (D, B, k_local) partials feeds the final merge ``top_k`` — O(D*k)
     work replicated on every shard, tiny next to the O(n/D) scan.  The
     catalog never moves: only the merged (B, k) winners leave the
-    program."""
+    program, as one ``_pack_results`` array."""
     fn = _sharded_program_cache.get(mesh)
     if fn is not None:
         return fn
@@ -247,7 +269,7 @@ def _sharded_topk_program(mesh):
         @partial(
             shard_map, mesh=mesh,
             in_specs=(P(BLOCK_AXIS, None), P(BLOCK_AXIS), P(None, None)),
-            out_specs=(P(None, None), P(None, None)),
+            out_specs=P(None, None),
             check_vma=False,
         )
         def run(m, b, q):
@@ -266,7 +288,8 @@ def _sharded_topk_program(mesh):
                 s_cat = jnp.moveaxis(s_all, 0, 1).reshape(q.shape[0], -1)
                 g_cat = jnp.moveaxis(g_all, 0, 1).reshape(q.shape[0], -1)
                 ms, mi = jax.lax.top_k(s_cat, k)  # k <= D*k_local == n_pad
-                return ms, jnp.take_along_axis(g_cat, mi, axis=1)
+                return _pack_results(
+                    ms, jnp.take_along_axis(g_cat, mi, axis=1))
 
         return run(matrix, bias, qs)
 
@@ -847,14 +870,14 @@ class DeviceFactorIndex:
         exactly re-ranks the shortlist against the SAME resident matrix;
         the sharded exact tier runs the shard_map partial-top-k + merge;
         otherwise the legacy single-device batched program.  Every branch
-        funnels through ``_to_host`` with (B, k)-sized arrays only — the
+        funnels through ``_to_host`` with one (B, 2k) array only — the
         catalog never leaves the device."""
         with stage("topk.enqueue"):
             if self._ann is not None:
-                scores, idx = self._ann.search(self._matrix, q, k_eff)
+                packed = self._ann.search(self._matrix, q, k_eff)
             elif self._is_sharded:
                 fn = _sharded_topk_program(self._mesh)
-                scores, idx = fn(self._matrix, self._bias, q, k_eff)
+                packed = fn(self._matrix, self._bias, q, k_eff)
                 self._obs_sharded_frames.inc()
             else:
                 if self._topk_many_fn is None:
@@ -869,25 +892,25 @@ class DeviceFactorIndex:
                             scores = jnp.matmul(  # (B, n_items)
                                 qs, matrix.T, precision=_SCORE_PRECISION)
                         with jax.named_scope("topk.select"):
-                            return jax.lax.top_k(scores, k)
+                            return _pack_results(*jax.lax.top_k(scores, k))
 
                     self._topk_many_fn = topk_many_fn
-                scores, idx = self._topk_many_fn(self._matrix, q, k_eff)
-        return self._fetch(scores, idx)
+                packed = self._topk_many_fn(self._matrix, q, k_eff)
+        return self._fetch(packed)
 
-    def _fetch(self, scores, idx):
-        """The wait for the device and the two result copies, between two
+    def _fetch(self, packed):
+        """The wait for the device and the one result copy, between two
         stamped instants: the one stage of a dispatch that contains the
-        device's work."""
+        device's work.  -> ``(scores, idx)`` host arrays."""
         t_enqueued = time.perf_counter()
         with stage("topk.fetch"):
-            out = _to_host(scores), _to_host(idx)
+            out = _unpack_results(_to_host(packed))
         self._stamps.last = (t_enqueued, time.perf_counter())
         return out
 
     def last_fetch(self) -> Optional[Tuple[float, float]]:
         """``perf_counter`` instants of the CALLING thread's last dispatch:
-        (the jitted call returned, both result arrays on the host), or
+        (the jitted call returned, the results on the host), or
         None.  Per thread, so that the microbatcher, which reads them into
         ``tpums_topk_fetch_seconds`` / ``_turnaround_seconds`` once the
         index call is back and its lock released, never reads the stamps
@@ -942,11 +965,11 @@ class DeviceFactorIndex:
                             scores = jnp.matmul(  # (n_items,)
                                 matrix, query, precision=_SCORE_PRECISION)
                         with jax.named_scope("topk.select"):
-                            return jax.lax.top_k(scores, k)
+                            return _pack_results(*jax.lax.top_k(scores, k))
 
                     self._topk_fn = topk_fn
-                scores, idx = self._topk_fn(self._matrix, q, k_eff)
-            scores, idx = self._fetch(scores, idx)
+                packed = self._topk_fn(self._matrix, q, k_eff)
+            scores, idx = self._fetch(packed)
             with stage("topk.format"):
                 return [
                     (self._ids[int(i)], float(s)) for i, s in zip(idx, scores)

@@ -216,10 +216,10 @@ def main(argv=None) -> int:
 
     mat = ann_idx._matrix
     q_dev = jax.device_put(queries[:frame])
-    ann.search(mat, q_dev, topk)[0].block_until_ready()  # warm
+    ann.search(mat, q_dev, topk).block_until_ready()  # warm
     t0 = time.perf_counter()
     for _ in range(trials):
-        ann.search(mat, q_dev, topk)[0].block_until_ready()
+        ann.search(mat, q_dev, topk).block_until_ready()
     rr = (time.perf_counter() - t0) / trials
     result["ivf_search_kernel_ms"] = rr * 1e3
     say(f"[ann-profile] ivf kernel:    {rr * 1e3:.2f}ms/frame "

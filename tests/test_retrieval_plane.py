@@ -198,12 +198,12 @@ def test_sharded_steady_path_zero_catalog_copies(catalog, monkeypatch):
     for _ in range(5):
         shard.topk_many(q, 10)
     # _to_host is the ONE device->host funnel on the query path: only
-    # the merged (B, k) winners may cross, never a catalog-sized array
-    assert seen, "query path no longer routes through _to_host"
-    assert all(len(s) == 2 and s[0] == 8 and s[1] == 10 for s in seen), seen
+    # the merged (B, k) winners may cross, never a catalog-sized array —
+    # ONE (B, 2k) array a dispatch, scores and indices side by side
+    assert seen == [(8, 20)] * 5, seen
     # and the resident matrix was not re-placed or rebuilt per query
     assert shard._matrix is matrix_before
-    # jit-trace check: the compiled program's outputs are (B, k) only —
+    # jit-trace check: the compiled program's one output is (B, 2k) —
     # the catalog stays an input, it never flows back out
     import jax
 
@@ -211,7 +211,57 @@ def test_sharded_steady_path_zero_catalog_copies(catalog, monkeypatch):
     traced = jax.make_jaxpr(lambda m, b, qs: fn(m, b, qs, 10))(
         shard._matrix, shard._bias, q)
     out_shapes = [tuple(v.aval.shape) for v in traced.jaxpr.outvars]
-    assert out_shapes == [(8, 10), (8, 10)]
+    assert out_shapes == [(8, 20)]
+
+
+# -- the one-copy fetch --------------------------------------------------
+
+
+def test_pack_and_unpack_are_a_bit_cast():
+    """Scores travel as int32 bit patterns beside the indices: every
+    float32 pattern (a NaN's payload, a denormal, -0.0, the pad score)
+    and every index (-1 is a masked ANN slot) comes back bit for bit."""
+    import jax
+
+    scores = np.array(
+        [[1.5, -0.0, np.nan, 1e-45, -np.inf, topk_mod._PAD_SCORE]],
+        dtype=np.float32)
+    scores.view(np.uint32)[0, 2] = 0x7FC12345  # a NaN with a payload
+    idx = np.array([[0, 2**31 - 1, -1, 7, -2**31, 123456789]], np.int32)
+    packed = topk_mod._to_host(jax.jit(topk_mod._pack_results)(scores, idx))
+    assert packed.shape == (1, 12) and packed.dtype == np.int32
+    got_scores, got_idx = topk_mod._unpack_results(packed)
+    assert got_scores.dtype == np.float32
+    assert got_scores.tobytes() == scores.tobytes()
+    assert got_idx.tobytes() == idx.tobytes()
+
+
+@pytest.mark.parametrize("sharded", ["0", "1"])
+def test_one_copy_fetch_returns_the_two_array_forms_bits(
+        catalog, monkeypatch, sharded):
+    """The packed output is the programs' former pair of outputs, bit for
+    bit: the same index answers frames and singles once through the
+    one-copy fetch and once with the programs returning (scores, idx) as
+    they did, and the (id, float score) rows compare with ``==``.  On the
+    sharded tier, where a single rides the frame program, a frame's rows
+    are the singles' rows too."""
+    table, rows = catalog
+    idx = _index(table, monkeypatch, sharded=sharded, tier="exact")
+    q = np.random.default_rng(11).normal(size=(8, rows.shape[1]))
+    q = q.astype(np.float32)
+    many, singles = idx.topk_many(q, 10), [idx.topk(v, 10) for v in q]
+    assert idx._is_sharded == (sharded == "1")
+    assert all(len(row) == 10 for row in many)
+    if idx._is_sharded:
+        assert many == singles
+    monkeypatch.setattr(topk_mod, "_pack_results", lambda s, i: (s, i))
+    monkeypatch.setattr(topk_mod, "_unpack_results", lambda pair: pair)
+    monkeypatch.setattr(topk_mod, "_to_host",
+                        lambda pair: tuple(np.asarray(a) for a in pair))
+    monkeypatch.setattr(topk_mod, "_sharded_program_cache", {})
+    idx._topk_fn = idx._topk_many_fn = None  # traced again, unpacked
+    assert idx.topk_many(q, 10) == many
+    assert [idx.topk(v, 10) for v in q] == singles
 
 
 # -- observability -------------------------------------------------------

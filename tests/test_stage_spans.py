@@ -155,17 +155,27 @@ def test_one_coalesce_span_between_frames(traced_frames):
 # -- the three counters -----------------------------------------------------
 
 def test_fetch_is_observed_once_per_frame_inside_the_dispatch_wall(rng):
+    """And so is enqueue: picked up -> the jitted call returned, then that
+    -> the results on the host, two pieces of one dispatch wall, in the
+    batched frames only."""
     batcher = TopKBatcher(_index(rng, 5_000), max_batch=8,
                           max_wait_us=200_000)
-    fetch0, device0 = _hist("tpums_topk_fetch_seconds"), \
-        _hist("tpums_topk_device_seconds")
+    names = ("tpums_topk_enqueue_seconds", "tpums_topk_fetch_seconds",
+             "tpums_topk_device_seconds")
+    before = [_hist(name) for name in names]
     for n in (3, 1, 8):  # a lone query rides the single-query program
         _burst(batcher, rng, n)
+    (enq_n, enq_s), (fetch_n, fetch_s), (wall_n, wall_s) = [
+        (n1 - n0, s1 - s0)
+        for (n0, s0), (n1, s1) in zip(before, map(_hist, names))]
+    assert enq_n == fetch_n == wall_n == 3
+    assert 0 < enq_s and 0 < fetch_s and enq_s + fetch_s <= wall_s
+    # an arrival at the idle batcher scores inline: no frame, no observation
+    batcher.score(rng.normal(size=32).astype(np.float32), 5, timeout=60)
     batcher.close()
-    fetch1, device1 = _hist("tpums_topk_fetch_seconds"), \
-        _hist("tpums_topk_device_seconds")
-    assert fetch1[0] - fetch0[0] == device1[0] - device0[0] == 3
-    assert 0 < fetch1[1] - fetch0[1] <= device1[1] - device0[1]
+    assert batcher.inline_singles == 1
+    assert [_hist(name)[0] - n0 for name, (n0, _) in zip(names, before)] \
+        == [3, 3, 3]
 
 
 def test_fetch_stamps_are_the_calling_threads_own(rng):

@@ -9,6 +9,7 @@ import time
 import numpy as np
 import pytest
 
+from flink_ms_tpu.obs import metrics as obs_metrics
 from flink_ms_tpu.serve.client import QueryClient
 from flink_ms_tpu.serve.microbatch import TopKBatcher
 from flink_ms_tpu.serve.server import LookupServer
@@ -190,6 +191,80 @@ def test_lone_query_latency_bounded_by_wait_window(rng):
     # generous absolute slack for a loaded single-core CI box; the bound
     # still rejects any design that waits a multiple of the window
     assert batched <= single + max_wait_s + 0.25, (single, batched)
+
+
+# -- the window's origin ----------------------------------------------------
+
+class _SleepyIndex:
+    """A stub whose frame takes ``sleep_s``: long enough in flight for the
+    next frame's head to outlive the coalescing window behind it."""
+
+    prefers_frames = True  # a lone query rides topk_many too
+
+    def __init__(self, sleep_s):
+        self.sleep_s = sleep_s
+
+    def topk_many(self, vecs, k):
+        time.sleep(self.sleep_s)
+        return [[("0", 0.0)] for _ in vecs]
+
+    def last_fetch(self):
+        return None
+
+
+def _window_held():
+    h = obs_metrics.get_registry().histogram("tpums_topk_window_held")
+    return h.count, h.sum
+
+
+@pytest.mark.parametrize("case", ["backlog", "idle_arrival", "flush"])
+def test_window_counts_from_the_heads_arrival(case):
+    """The coalescing window is an arrival's chance to find companions,
+    counted from ITS arrival: a head that queued behind a running frame
+    has used it up; an arrival at an idle batcher gets all of it, and a
+    later companion does not re-arm it; ``flush()`` still ends it."""
+    wait_s = {"backlog": 0.8, "idle_arrival": 0.4, "flush": 5.0}[case]
+    batcher = TopKBatcher(_SleepyIndex(1.0 if case == "backlog" else 0.0),
+                          max_batch=8, max_wait_us=wait_s * 1e6)
+    q = np.zeros(4, np.float32)
+
+    def frame_of_one():
+        p = batcher.submit(q, 1, allow_inline=False)
+        batcher.flush()
+        return p
+
+    try:
+        if case == "backlog":
+            first = frame_of_one()
+            time.sleep(0.05)  # the frame is in flight for 1.0 s
+            second = batcher.submit(q, 1, allow_inline=False)
+            first.wait(timeout=60)
+            n0, held0 = _window_held()
+            second.wait(timeout=60)
+            # picked up when the first frame came back, not wait_s later
+            assert second.queue_wait_s >= wait_s
+            assert second.t_dispatch - first.t_done < wait_s / 2
+            assert _window_held() == (n0 + 1, held0)
+        elif case == "idle_arrival":
+            frame_of_one().wait(timeout=60)  # the dispatcher is up and idle
+            n0, held0 = _window_held()
+            head = batcher.submit(q, 1, allow_inline=False)
+            time.sleep(0.1)
+            companion = batcher.submit(q, 1, allow_inline=False)
+            head.wait(timeout=60)
+            companion.wait(timeout=60)
+            assert head.batch_size == companion.batch_size == 2
+            assert wait_s <= head.queue_wait_s < wait_s + 0.5
+            assert companion.queue_wait_s < head.queue_wait_s - 0.05
+            assert _window_held() == (n0 + 1, held0 + 1)
+        else:
+            n0, _ = _window_held()
+            lone = frame_of_one()
+            lone.wait(timeout=60)
+            assert lone.queue_wait_s < wait_s / 2
+            assert _window_held()[0] == n0 + 1
+    finally:
+        batcher.close()
 
 
 # -- client pipelining ------------------------------------------------------
