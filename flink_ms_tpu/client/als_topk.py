@@ -1,4 +1,4 @@
-"""Top-k recommendation client (TPU-native extension; BASELINE.md config
+"""Top-k recommendation client (TPU-native extension; BASELINE.json config
 "flink-queryable-client top-k recommendation serving from ALS factors").
 
 Interactive: enter a user id per line, get the top-k items with scores from

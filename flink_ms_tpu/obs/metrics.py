@@ -70,9 +70,9 @@ _EXEMPLARS = os.environ.get("TPUMS_EXEMPLARS", "0") != "0"
 # sites that can link an observation to a trace (serve/server.py request
 # epilogue) already hold the wire tid, and an untraced observation then
 # pays literally nothing for the feature — no thread-local read, no
-# provider call.  (A provider indirection was tried first and its ~0.1us
-# per-observe read alone threatened the 3% hot-path bar that
-# scripts/obs_overhead_ab.py enforces.)
+# provider call.  (A provider indirection was tried first: its read alone
+# cost ~0.1us per observe — 2026-07-31, earlier installation, not
+# reproduced — against a GET served in tens of microseconds.)
 
 
 def exemplars_enabled() -> bool:
@@ -104,10 +104,10 @@ def log_buckets(lo: float, hi: float, per_decade: int = 16) -> Tuple[float, ...]
 
 
 # One ladder for every latency series in the repo — serving verbs, queue
-# waits, ingest applies, AND the bench harness percentiles
-# (bench_sections._pcts / StepTimer route through these same bounds, so a
-# bench p50 and a scraped serving p50 are estimates over the identical
-# bucketization).  1 µs .. 100 s at 16 buckets/decade: interpolated
+# waits, ingest applies, and offline percentiles (``bucketed_quantiles``,
+# ``utils/profiling.StepTimer``), so a client-side p50 and a scraped
+# serving p50 are estimates over the identical bucketization.
+# 1 µs .. 100 s at 16 buckets/decade: interpolated
 # quantiles land within ~7% of the exact rank statistic.
 LATENCY_BUCKETS_S: Tuple[float, ...] = log_buckets(1e-6, 100.0, 16)
 
@@ -508,8 +508,8 @@ def bucketed_quantiles(values: Sequence[float], qs: Sequence[float],
                        ) -> List[float]:
     """Interpolated quantiles of ``values`` computed THROUGH the shared
     bucket ladder — the same estimate a scraped serving histogram yields
-    for the same data.  The bench harness routes its percentiles through
-    this so a bench p50 and a fleet-scrape p50 are the identical
+    for the same data.  Client-side recorders route their percentiles
+    through this so their p50 and a fleet-scrape p50 are the identical
     statistic, not an exact-rank number compared against a bucket
     interpolation.  Pure computation: unaffected by the enable switch."""
     h = Histogram("_bucketed", bounds=bounds).fill(values)
@@ -527,7 +527,7 @@ def snapshot_quantile(hist_entry: dict, q: float) -> float:
 
 
 def diff_snapshots(before: dict, after: dict) -> dict:
-    """Compact before/after delta for bench detail records: counters that
+    """Compact before/after delta of two snapshots: counters that
     moved, histogram count/sum deltas, and gauges at their AFTER value
     (gauges are levels, not flows)."""
     def index(snap, kind):

@@ -49,9 +49,7 @@ Artifacts and scrapes:
   registry, which is what the watch plane's CPU rules alert on (and the
   alert page then carries ``profdiff``'s top-delta frames).
 
-``TPUMS_PROF=0`` is the kill switch; the enforced hot-path bar is the
-profiler arm of ``scripts/obs_overhead_ab.py`` (GET p50 overhead <= 3%,
-ABAB).
+``TPUMS_PROF=0`` is the kill switch.
 
 CLI::
 

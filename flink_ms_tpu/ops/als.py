@@ -26,7 +26,7 @@ as a relayout copy, a convolution and a multiply-reduce —
 on v5e: TPU scatter serializes per row, and XLA's batched small-matrix
 Cholesky streams the whole (n, k, k) tensor per elimination step.)
 
-Supports the two training modes named in BASELINE.md:
+Supports the two training modes named in BASELINE.json:
 
 - explicit feedback (FlinkML parity): weighted-λ regularization
   (reg_u = n_u, Zhou et al. ALS-WR) or plain λ;
@@ -69,7 +69,7 @@ from ..parallel.mesh import (
 @dataclasses.dataclass(frozen=True)
 class ALSConfig:
     """Mirrors the reference's surfaced parameters (ALSImpl.scala:35-49) plus
-    the implicit-feedback mode required by BASELINE.md."""
+    the implicit-feedback mode required by BASELINE.json."""
 
     num_factors: int = 10
     iterations: int = 10
@@ -89,12 +89,12 @@ class ALSConfig:
     # changes the bytes moved).  Normal equations still accumulate in the
     # solve dtype via preferred_element_type.  None = full precision;
     # "auto" (the default) resolves per backend in resolve_exchange():
-    # bfloat16 on TPU — chip-measured +20% (50.2 vs 62.7 ms/iter at the
-    # 5M-nnz probe under the pallas solver) at a +1.4e-5 relative train-
-    # RMSE delta vs an f64 reference at the bench anchor scale — and full
-    # precision elsewhere.  Every accelerator bench artifact re-witnesses
-    # the quality side (als_rmse_at_iters / als_rmse_ref_delta inherit
-    # the resolved config).
+    # bfloat16 on TPU — 0.1786 against 0.2287 s/iter at the ML-20M shape
+    # with both on the einsum pair (PERF.md section 7 row 2, PR 24; since
+    # PR 26 the f32 exchange runs the assembly kernel and is ahead) at a
+    # +1.4e-5 relative train-RMSE delta vs an f64 reference (2026-07-31,
+    # earlier installation, not reproduced) — and full precision
+    # elsewhere.
     exchange_dtype: Optional[str] = "auto"
 
 
@@ -725,160 +725,48 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     return jnp.concatenate(As, axis=0), jnp.concatenate(bs, axis=0)
 
 
-def _chol_solve_unrolled(A, b):
-    """Batched SPD solve by unrolled right-looking Cholesky + substitutions.
-
-    XLA's ``lax.linalg.cholesky``/``triangular_solve`` lower to a device
-    while-loop of dynamic slices that is latency-bound for large batches of
-    tiny matrices (measured ~35 ms for (20k, 16, 16) on v5e).  This variant
-    unrolls the k elimination steps as vectorized rank-1 downdates over the
-    whole batch — pure VPU elementwise work that XLA fuses.  k is small
-    (10-64 per the reference's numFactors surface) so the unroll is cheap
-    to compile.  A (n, k, k), b (n, k) -> x (n, k).
-    """
-    n, k = b.shape
-    M = A
-    cols = []  # cols[j][:, i] = L[:, i, j] (column j of L; rows < j zero)
-    upper = jnp.cumsum(jnp.eye(k, dtype=A.dtype), axis=0)  # lower-tri ones
-    for j in range(k):
-        d = jax.lax.rsqrt(M[:, j, j])
-        col = M[:, :, j] * d[:, None] * upper[:, j][None, :]
-        cols.append(col)
-        M = M - col[:, :, None] * col[:, None, :]
-    # forward solve L z = b, running accumulator acc = Σ_p cols[p]·z_p
-    acc = jnp.zeros_like(b)
-    zs = []
-    for j in range(k):
-        z = (b[:, j] - acc[:, j]) / cols[j][:, j]
-        zs.append(z)
-        acc = acc + cols[j] * z[:, None]
-    # back solve Lᵀ x = z; row j of L (= column j of Lᵀ) needs L as a matrix
-    Lmat = jnp.stack(cols, axis=-1)  # (n, k, k) lower-triangular
-    acc = jnp.zeros_like(b)
-    xs = [None] * k
-    for j in reversed(range(k)):
-        x = (zs[j] - acc[:, j]) / Lmat[:, j, j]
-        xs[j] = x
-        acc = acc + Lmat[:, j, :] * x[:, None]
-    return jnp.stack(xs, axis=-1)
-
-
-def _chol_solve_panel(A, b, P: int = 8):
-    """Batched SPD solve by PANEL-blocked right-looking Cholesky.
-
-    The fully unrolled variant's k rank-1 downdates each stream the whole
-    (n, k, k) tensor — ~k full HBM passes.  Blocking the elimination into
-    panels of P columns keeps the rank-1 work inside an (n, k-p0, P) slab
-    and applies ONE rank-P downdate of the trailing submatrix per panel
-    (a batched matmul — MXU work), so the big tensor is streamed ~k/P
-    times instead of k.  Same numerics, reassociated.  A (n, k, k),
-    b (n, k) -> x (n, k)."""
-    n, k = b.shape
-    T = A
-    col_blocks = []  # per panel: L rows [p0:k), cols [p0:p0+pw)
-    for p0 in range(0, k, P):
-        pw = min(P, k - p0)
-        kr = k - p0
-        panel = T[:, :, :pw]                       # (n, kr, pw)
-        row_idx = jnp.arange(kr)
-        cols = []
-        for j in range(pw):
-            d = jax.lax.rsqrt(panel[:, j, j])
-            col = panel[:, :, j] * d[:, None] * (row_idx >= j)[None, :]
-            cols.append(col)
-            panel = panel - col[:, :, None] * col[:, None, :pw]
-        Lp = jnp.stack(cols, axis=-1)              # (n, kr, pw)
-        col_blocks.append(Lp)
-        if pw < kr:
-            Lt = Lp[:, pw:, :]                     # (n, kr-pw, pw)
-            # HIGHEST: the downdate must not lose mantissa on the MXU —
-            # errors compound across the k/P panels (same reasoning as
-            # the assembly einsums)
-            T = T[:, pw:, pw:] - jnp.einsum(
-                "nip,njp->nij", Lt, Lt, precision="highest"
-            )
-    # forward solve L z = b (block column sweep)
-    rhs = b
-    z_parts = []
-    for Lp in col_blocks:
-        pw = Lp.shape[2]
-        r = rhs                                    # (n, kr)
-        zb = []
-        for j in range(pw):
-            zj = r[:, j] / Lp[:, j, j]
-            zb.append(zj)
-            r = r - Lp[:, :, j] * zj[:, None]
-        z_parts.append(jnp.stack(zb, axis=-1))
-        rhs = r[:, pw:]
-    # back solve Lᵀ x = z (reverse block sweep)
-    x_parts: list = [None] * len(col_blocks)
-    x_below = jnp.zeros((n, 0), dtype=b.dtype)
-    for bi in reversed(range(len(col_blocks))):
-        Lp = col_blocks[bi]
-        pw = Lp.shape[2]
-        zb = z_parts[bi]
-        if x_below.shape[1]:
-            zb = zb - jnp.einsum(
-                "nrp,nr->np", Lp[:, pw:, :], x_below, precision="highest"
-            )
-        xb = [None] * pw
-        for j in reversed(range(pw)):
-            acc = zb[:, j]
-            for jj in range(j + 1, pw):
-                acc = acc - Lp[:, jj, j] * xb[jj]
-            xb[j] = acc / Lp[:, j, j]
-        x_parts[bi] = jnp.stack(xb, axis=-1)
-        x_below = jnp.concatenate([x_parts[bi], x_below], axis=-1)
-    return jnp.concatenate(x_parts, axis=-1)
-
-
-# solver selection: "auto" picks per backend — "pallas" on TPU (the
-# round-3 on-chip matrix at 5M nnz / k=50 measured 62.7 ms/iter vs 444.9
-# unrolled / 103.3 panel / 492.6 lax: the VMEM-resident one-pass solve is
-# 7.1x the streaming unroll, and the phase breakdown attributed 76% of the
-# unrolled iteration to the solve), "lax" on CPU (LAPACK-backed, compiles
-# orders of magnitude faster than the rank-50 unroll graph).  Explicit
-# overrides: "unrolled", "panel", "pallas", "lax" via FLINK_MS_ALS_SOLVER.
-_UNROLL_MAX_K = 64
-
-
-def _solver_choice() -> str:
-    return os.environ.get("FLINK_MS_ALS_SOLVER", "auto")
-
-
 def _fused_solve() -> bool:
     """FLINK_MS_ALS_FUSED=1: solve each bucket chunk inside the assembly
     lax.map, so the (per_block, k, k) normal-equation tensor never
-    materializes (the roofline's dominant HBM term, BASELINE.md) and the
-    half-sweep's peak transient stops scaling with the catalog size —
-    required for the 10M-user scale envelope, opt-in until chip-validated."""
+    materializes (its concatenation and copies are 12 ms of the 154.8 ms
+    iteration, train_iter_s 0.142549 with the knob set: PERF.md section 7,
+    PR 26) and the half-sweep's peak transient stops scaling with the
+    catalog size — required for the 10M-user scale envelope, opt-in until
+    that shape has run on the chip."""
     return os.environ.get("FLINK_MS_ALS_FUSED", "0") == "1"
 
 
+# Two solvers.  "pallas" on a TPU: one VMEM-resident pass per tile, 7.15 ms
+# of the 154.8 ms iteration at the ML-20M shape (PERF.md section 5), where
+# XLA's own lax.linalg lowering is a device while-loop of dynamic slices
+# that streams the whole (n, k, k) tensor per elimination step (492.6
+# against 62.7 ms/iter at a 5M-nnz, k=50 probe; 2026-07-31, earlier
+# installation, not reproduced).  "lax" everywhere else: LAPACK-backed on
+# the host.  Naming "pallas" on a CPU runs the chip's kernel interpreted
+# (chip_smoke.py --tiny, tests/test_cholesky_pallas.py).
+_SOLVERS = ("auto", "pallas", "lax")
+
+
 def resolve_solver(platform: Optional[str]) -> str:
-    """The solver an "auto" choice resolves to on `platform` (the explicit
-    FLINK_MS_ALS_SOLVER override passes through untouched)."""
-    choice = _solver_choice()
+    """The solver a fit on `platform` runs: FLINK_MS_ALS_SOLVER when it
+    names one, else "pallas" on a TPU and "lax" everywhere else."""
+    choice = os.environ.get("FLINK_MS_ALS_SOLVER", "auto")
+    if choice not in _SOLVERS:
+        raise ValueError(
+            f"FLINK_MS_ALS_SOLVER={choice!r}: expected one of "
+            + " | ".join(_SOLVERS)
+        )
     if choice == "auto":
-        if platform == "cpu":
-            # LAPACK-backed lax.linalg: on the host backend it both compiles
-            # orders of magnitude faster than the k-step unroll (whose
-            # rank-50 graph takes minutes in XLA:CPU) and runs faster
-            return "lax"
-        if platform == "tpu":
-            # chip-measured winner (see the selection note above); non-TPU
-            # accelerators keep the unrolled fallback — the Pallas kernel's
-            # compiled path is TPU-only
-            return "pallas"
+        return "pallas" if platform == "tpu" else "lax"
     return choice
 
 
 def resolve_exchange(exchange_dtype: Optional[str],
                      platform: Optional[str]) -> Optional[str]:
     """The factor-exchange dtype an "auto" config resolves to on
-    `platform` (explicit values and None pass through).  bfloat16 on TPU:
-    chip-measured +20% iteration speed at a +1.4e-5 relative RMSE delta
-    vs an f64 reference (ALSConfig.exchange_dtype docstring); full
+    `platform` (explicit values and None pass through).  bfloat16 on TPU
+    (half the bytes moved; its speed and RMSE delta are in the comment on
+    ALSConfig.exchange_dtype); full
     precision everywhere else — the CPU baseline/reference paths must
     not silently change numerics."""
     if exchange_dtype == "auto":
@@ -924,9 +812,7 @@ def _log_assembly(problem: "BlockedProblem", how: str) -> None:
 
 
 def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
-    k = A.shape[-1]
-    choice = resolve_solver(platform)
-    if choice == "pallas":
+    if resolve_solver(platform) == "pallas":
         from .cholesky_pallas import cholesky_solve_batched
 
         # in_scan (the fused per-chunk solve inside lax.map): the kernel's
@@ -936,10 +822,6 @@ def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
         return cholesky_solve_batched(
             A, b, interpret=platform != "tpu", layout=layout
         ).astype(A.dtype)
-    if choice == "panel":
-        return _chol_solve_panel(A, b)
-    if choice == "unrolled" or (choice == "auto" and k <= _UNROLL_MAX_K):
-        return _chol_solve_unrolled(A, b)
     L = jax.lax.linalg.cholesky(A)
     x = jax.lax.linalg.triangular_solve(
         L, b[..., None], left_side=True, lower=True
@@ -1152,8 +1034,10 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                 _exchange_plan(problem, num_blocks(mesh)).items()
             )
         ),
-        _solver_choice(),          # env overrides are baked in at trace
-        _assembly_chunk_bytes(),   # time, so they key the executable
+        # env overrides are baked in at trace time, so they key the
+        # executable; an unknown solver name raises here, before any trace
+        resolve_solver(mesh.devices.flat[0].platform),
+        _assembly_chunk_bytes(),
         _fused_solve(),
         # the Pallas solver reads its layout knob at trace time too (when
         # layout=None inside cholesky_solve_batched) — omitting it here
@@ -1577,7 +1461,7 @@ def _predict_dense(uf, itf, u_idx, i_idx):
 def _predict_chunk_rows() -> int:
     # bound the two (chunk, k) gather transients: an unchunked 20M-pair
     # predict at k=50 compiled to a 19 GB program and OOM'd 16 GB HBM
-    # (round-3 bench quality anchor); 4M rows keeps transients ~2-3 GB
+    # (2026-07-31, earlier installation); 4M rows keeps transients ~2-3 GB
     return int(os.environ.get("FLINK_MS_PREDICT_CHUNK", 4_000_000))
 
 

@@ -195,14 +195,15 @@ def _dw_choice() -> str:
     segment-sum.  "presorted": store val ALREADY feature-sorted at prepare
     time, so the round end multiplies the streamed sorted values by a
     gather from only the tiny (C·H) Δα table and segment-sums — no
-    runtime permutation of the big array.  Round-3 chip A/B at RCV1 scale
-    (49M nnz): direct 0.80 s/round, presorted 1.33, sorted 1.60 — XLA
+    runtime permutation of the big array.  At RCV1 scale (49M nnz;
+    2026-07-31, earlier installation, not reproduced): direct 0.80
+    s/round, presorted 1.33, sorted 1.60 — XLA
     lowers even a sorted segment-sum to the same serialized scatter, so
     the rewrites only add gather cost.  "auto" (default) = direct
     everywhere; the alternatives remain selectable for future
-    lowering/hardware changes.  (BASELINE.md carries the piecewise
-    attribution: the boundary cost is two 49M-scalar irregular ops that
-    shrink linearly with device count on a real mesh.)"""
+    lowering/hardware changes.  (The boundary cost is two 49M-scalar
+    irregular ops that shrink linearly with device count on a real
+    mesh.)"""
     choice = os.environ.get("FLINK_MS_SVM_DW", "auto")
     if choice not in ("auto", "direct", "sorted", "presorted"):
         # a typo'd knob must not silently fall through to the direct
@@ -224,9 +225,10 @@ def _step_choice() -> str:
     TPU fori_loop (round 3 measured 9.3 ms/step on v5e for ~µs of math).
     "onehot": hoist the (C, H) step-index draw out of the loop and express
     every read/write as a dense mask/one-hot contraction — pure VPU/MXU
-    work, bit-identical results (products are exact 0s and 1s).  Round-3
-    chip A/B: neutral at RCV1 scale (0.804 vs 0.799 s/round — the round
-    BOUNDARY dominates single-chip, see _dw_choice), so "auto" = dynamic
+    work, bit-identical results (products are exact 0s and 1s).  Neutral
+    at RCV1 scale (0.804 vs 0.799 s/round, 2026-07-31, earlier
+    installation, not reproduced: the round BOUNDARY dominates
+    single-chip, see _dw_choice), so "auto" = dynamic
     everywhere; onehot stays selectable for meshes where the boundary
     shrinks and per-step latency resurfaces."""
     choice = os.environ.get("FLINK_MS_SVM_STEP", "auto")

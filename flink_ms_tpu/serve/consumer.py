@@ -269,8 +269,8 @@ class ServingJob:
         self.parse_errors = 0
         # ingest-plane observability: which path ran last, how many rows /
         # chunks it applied, and the wall time spent inside state
-        # application (parse + put + listener fan-out) — the bench's
-        # cold-start rows/sec and the ingest_profile tool read these
+        # application (parse + put + listener fan-out); ingest_stats()
+        # reports them
         self.ingest_path = "idle"
         self.ingest_rows = 0
         self.ingest_batches = 0
@@ -985,8 +985,7 @@ class ServingJob:
                 self._ready.set()
                 if self._bootstrap_t0 is not None:
                     # cold-path bookkeeping, once per process lifetime: how
-                    # long start()->ready took and which source fed it —
-                    # the flatness the serving_bootstrap bench tracks
+                    # long start()->ready took and which source fed it
                     self.bootstrap_seconds = (
                         time.monotonic() - self._bootstrap_t0
                     )

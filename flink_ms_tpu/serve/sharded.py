@@ -154,9 +154,10 @@ class ShardedQueryClient:
         if len(by_owner) == 1 or len(keys) < self.seq_fanout_keys:
             # single owner, or a tiny request: pool dispatch overhead
             # exceeds the worker service time it would parallelize
-            # (profiled, scripts/shard_profile.py: 2-key MGET p50 0.104 ms
-            # pooled vs 0.041 ms sequential — per-worker service is
-            # ~0.02 ms) — issue the sub-MGETs serially on this thread
+            # (2-key MGET p50 0.104 ms pooled vs 0.041 ms sequential,
+            # per-worker service ~0.02 ms; 2026-07-31, earlier
+            # installation, not reproduced) — issue the sub-MGETs
+            # serially on this thread
             try:
                 for w, positions in by_owner.items():
                     for p, v in zip(positions,

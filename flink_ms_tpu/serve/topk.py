@@ -3,7 +3,7 @@
 The reference serves only point lookups; top-k over a 26k..1M-item catalog
 would need one RPC per item.  TPU-native serving instead keeps a
 device-resident mirror of the item-factor matrix and answers top-k with one
-jitted matmul + ``lax.top_k`` — the BASELINE.md config
+jitted matmul + ``lax.top_k`` — the BASELINE.json config
 "flink-queryable-client top-k recommendation serving from ALS factors".
 
 Index maintenance is INCREMENTAL: the table pushes changed keys into the
@@ -16,8 +16,8 @@ index's dirty set (``add_change_listener``), and at query time
   queries keep answering from the current (briefly stale) index — the
   rebuild swaps in atomically when ready.
 
-The first query after startup pays the initial build (reported by the
-serving benchmark as ``serving_topk_build_s``).
+The first query after startup pays the initial build (the benchmark's
+``index_build_s``).
 
 RETRIEVAL TIERS (round 11).  Two levers lift the catalog ceiling from the
 ~1M rows the single-array exact scan tops out at:
@@ -63,32 +63,6 @@ import numpy as np
 from ..obs import metrics as obs_metrics
 from ..obs.tracing import stage
 from .table import ModelTable
-
-_engine_warn_lock = threading.Lock()
-_engine_warned = False
-
-
-def _default_engine() -> str:
-    """TPUMS_TOPK_ENGINE: only ``xla`` remains.  The fused Pallas scorer
-    was removed in round 3 (decision in PARITY.md).  A stale ``pallas``
-    setting degrades loudly to xla — ONCE per process: this runs on every
-    index construction (sharded serving builds one per state, rebuilds
-    included), and repeating the same warning per call buried real log
-    lines."""
-    global _engine_warned
-    engine = os.environ.get("TPUMS_TOPK_ENGINE", "xla")
-    if engine != "xla":
-        with _engine_warn_lock:
-            if not _engine_warned:
-                _engine_warned = True
-                print(
-                    f"[topk] TPUMS_TOPK_ENGINE={engine!r} is no longer "
-                    "available (Pallas scorer removed in round 3 — see "
-                    "PARITY.md); using xla",
-                    file=sys.stderr,
-                )
-        engine = "xla"
-    return engine
 
 
 def _tier_mode() -> str:
@@ -298,11 +272,9 @@ def _sharded_topk_program(mesh):
 
 
 class DeviceFactorIndex:
-    def __init__(self, table: ModelTable, factor_suffix: str = "-I",
-                 engine: Optional[str] = None):
+    def __init__(self, table: ModelTable, factor_suffix: str = "-I"):
         self.table = table
         self.suffix = factor_suffix
-        self.engine = engine or _default_engine()
         # acquires the index's devices on THIS thread: a process that
         # expected the chip and got the host dies here, at construction
         _warm_jit_async()
@@ -650,9 +622,9 @@ class DeviceFactorIndex:
     def bulk_load(self, ids, rows) -> None:
         """Install a pre-parsed catalog directly — semantically a full
         build whose table snapshot parsed to exactly ``(ids, rows)``.
-        The bench harness and ``scripts/ann_profile.py`` use it to stand
-        up 1M–10M-row catalogs without materializing 10M payload strings
-        through the table; later updates via the table flow through the
+        ``benchmark/drivers/topk_open.py`` uses it to stand up 5M–17M-row
+        catalogs without materializing as many payload strings through
+        the table; later updates via the table flow through the
         normal dirty-set maintenance (unknown ids trigger a rebuild whose
         snapshot reads the TABLE, so a bulk-loaded catalog absent from
         the table reverts — this is a load ramp, not a second source of
@@ -1050,8 +1022,7 @@ class ALSTopkHandler:
     Scoring routes through the cross-request microbatcher
     (``microbatch.TopKBatcher``) unless ``TPUMS_TOPK_BATCH=0``: concurrent
     TOPK/TOPKV requests coalesce into one batched device dispatch instead
-    of serializing on the index lock.  ``batching`` can be flipped live
-    (the bench harness A/Bs both paths on one warm index)."""
+    of serializing on the index lock.  ``batching`` can be flipped live."""
 
     def __init__(self, table: ModelTable, batcher=None):
         self.table = table

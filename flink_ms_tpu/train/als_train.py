@@ -6,7 +6,7 @@ same ``id,U|I,f1;f2;...`` model rows, so downstream tools (mean-vector job,
 producer/consumer, clients) interoperate with files from either framework.
 
 Flags beyond the reference (TPU-native surface):
-  --implicit true      confidence-weighted implicit-feedback ALS (BASELINE.md)
+  --implicit true      confidence-weighted implicit-feedback ALS (BASELINE.json)
   --alpha 40.0         implicit confidence scale
   --devices N          mesh size (defaults to all visible devices; the
                        reference's --blocks maps to Flink's internal blocking

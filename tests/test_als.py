@@ -89,28 +89,18 @@ def test_assembly_matches_numpy(rng):
 
 
 @pytest.mark.parametrize("k", [3, 8, 16, 50])
-def test_chol_solve_unrolled_matches_numpy(rng, k):
+@pytest.mark.parametrize("solver", ["lax", "pallas"])
+def test_chol_solve_matches_numpy(rng, monkeypatch, solver, k):
+    """Both solvers through the seam a sweep calls, selected by the knob:
+    on the CPU ``pallas`` is the chip's kernel interpreted."""
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", solver)
     n = 257
     G = rng.standard_normal((n, k, k)).astype(np.float32)
     A_ = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
     b = rng.standard_normal((n, k)).astype(np.float32)
     x = np.asarray(
-        jax.jit(A._chol_solve_unrolled)(jnp.asarray(A_), jnp.asarray(b))
-    )
-    x_ref = np.linalg.solve(
-        A_.astype(np.float64), b.astype(np.float64)[..., None]
-    )[..., 0]
-    np.testing.assert_allclose(x, x_ref, rtol=2e-3, atol=2e-4)
-
-
-@pytest.mark.parametrize("k", [3, 8, 16, 50])
-def test_chol_solve_panel_matches_numpy(rng, k):
-    n = 257
-    G = rng.standard_normal((n, k, k)).astype(np.float32)
-    A_ = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
-    b = rng.standard_normal((n, k)).astype(np.float32)
-    x = np.asarray(
-        jax.jit(A._chol_solve_panel)(jnp.asarray(A_), jnp.asarray(b))
+        jax.jit(lambda a, c: A._chol_solve(a, c, "cpu"))(
+            jnp.asarray(A_), jnp.asarray(b))
     )
     x_ref = np.linalg.solve(
         A_.astype(np.float64), b.astype(np.float64)[..., None]
@@ -121,8 +111,7 @@ def test_chol_solve_panel_matches_numpy(rng, k):
 def test_predict_chunked_equals_unchunked(rng, monkeypatch):
     """Chunked prediction (padded-tail fixed-shape device calls) is
     element-equal to the single-call path — the chunking exists because an
-    unchunked 20M-pair predict OOM'd 16 GB HBM in the round-3 bench
-    quality anchor."""
+    unchunked 20M-pair predict OOM'd 16 GB HBM."""
     m = A.ALSModel(
         user_ids=np.arange(80), item_ids=np.arange(50),
         user_factors=rng.normal(size=(80, 6)).astype(np.float32),
@@ -136,15 +125,35 @@ def test_predict_chunked_equals_unchunked(rng, monkeypatch):
 
 
 def test_auto_solver_resolution(monkeypatch):
-    """"auto" resolves per backend: the round-3 on-chip matrix made pallas
-    the TPU default (62.7 vs 444.9 ms/iter unrolled at 5M nnz / k=50); CPU
-    keeps LAPACK-backed lax; explicit overrides pass through."""
+    """"auto" resolves per platform: the Pallas kernel on a TPU, LAPACK-
+    backed lax everywhere else; a solver named by the knob passes through."""
     monkeypatch.delenv("FLINK_MS_ALS_SOLVER", raising=False)
     assert A.resolve_solver("tpu") == "pallas"
     assert A.resolve_solver("cpu") == "lax"
-    assert A.resolve_solver(None) == "auto"  # unknown backend: k-heuristic
-    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "panel")
-    assert A.resolve_solver("tpu") == "panel"
+    assert A.resolve_solver(None) == "lax"
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "auto")
+    assert A.resolve_solver("tpu") == "pallas"
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "lax")
+    assert A.resolve_solver("tpu") == "lax"
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
+    assert A.resolve_solver("cpu") == "pallas"
+
+
+@pytest.mark.parametrize("value", ["banana", "panel", "unrolled", ""])
+def test_unknown_solver_value_raises(rng, monkeypatch, value):
+    """A name that is no solver stops the fit before anything is traced,
+    with the three that are in the message."""
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", value)
+    with pytest.raises(ValueError, match=r"auto \| pallas \| lax"):
+        A.resolve_solver("cpu")
+    u, i, r = _synthetic(rng, n_users=12, n_items=9)
+    traced = []
+    monkeypatch.setattr(A, "_make_sweep",
+                        lambda *a, **kw: traced.append(1))
+    with pytest.raises(ValueError, match="FLINK_MS_ALS_SOLVER"):
+        A.als_fit(u, i, r, A.ALSConfig(num_factors=3, iterations=1),
+                  make_mesh(1))
+    assert not traced
 
 
 def test_auto_exchange_resolution():
@@ -157,24 +166,6 @@ def test_auto_exchange_resolution():
     assert A.resolve_exchange(None, "tpu") is None
     assert A.resolve_exchange("bfloat16", "cpu") == "bfloat16"
     assert A.ALSConfig().exchange_dtype == "auto"
-
-
-def test_fit_with_panel_solver_matches_default(rng, monkeypatch):
-    u, i, r = _synthetic(rng, n_users=30, n_items=20)
-    k = 5
-    uf0 = rng.normal(size=(30, k)).astype(np.float32)
-    itf0 = rng.normal(size=(20, k)).astype(np.float32)
-    cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1)
-    mesh = make_mesh(1)
-    base = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
-    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "panel")
-    panel = A.als_fit(u, i, r, cfg, mesh, init=(uf0, itf0))
-    np.testing.assert_allclose(
-        panel.user_factors, base.user_factors, rtol=1e-3, atol=1e-5
-    )
-    np.testing.assert_allclose(
-        panel.item_factors, base.item_factors, rtol=1e-3, atol=1e-5
-    )
 
 
 @pytest.mark.parametrize("weighted", [True, False])

@@ -1,7 +1,9 @@
-"""Shrunken version of scripts/midsize_rehearsal.py's invariants: per-device shard shapes, routed-exchange accounting, and
-staging resume across a simulated restart — fast enough for every test
-run; the committed REHEARSAL_r04.json artifact carries the mid-size
-evidence."""
+"""The sharded ALS fit's invariants at a size every test run affords:
+each device holds one ``(1, per_block, k)`` block of a factor table and no
+more; a routed exchange's plan accounts for its rows consistently (``D - 1``
+peers send ``r_max`` rows each, the received table is ``D * r_max`` plus the
+local block, the send plan is ``(D, D, r_max)``); and a staged fit resumed
+after a simulated restart ends where an uninterrupted one does."""
 
 import os
 

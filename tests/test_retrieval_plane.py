@@ -342,15 +342,6 @@ def test_fleet_signals_surfaces_retrieval_health():
     assert fleet_signals(empty, empty, dt_s=1.0)["ann_recall"] is None
 
 
-def test_engine_warning_prints_once(monkeypatch, capsys):
-    monkeypatch.setenv("TPUMS_TOPK_ENGINE", "pallas")
-    monkeypatch.setattr(topk_mod, "_engine_warned", False)
-    assert topk_mod._default_engine() == "xla"
-    assert topk_mod._default_engine() == "xla"
-    err = capsys.readouterr().err
-    assert err.count("no longer available") == 1
-
-
 # -- microbatcher frame handoff ------------------------------------------
 
 
