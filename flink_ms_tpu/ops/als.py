@@ -19,12 +19,15 @@ assembly is a short list of dense batched contractions — a row gather,
 then MXU matmuls, no scatter.  On a TPU, in explicit mode with an f32
 exchange and a rank up to 64, each bucket's gathered rows are contracted
 where the gather left them by one Pallas kernel (``assemble_pallas.py``: A
-and b from a single read, the transpose done in VMEM); every other path
-(bf16 exchange, implicit mode, CPU) is the ``einsum`` pair, which XLA runs
-as a relayout copy, a convolution and a multiply-reduce —
-``resolve_assembly`` decides per sweep.  (A scatter/``segment_sum`` formulation was measured 8-10x slower
-on v5e: TPU scatter serializes per row, and XLA's batched small-matrix
-Cholesky streams the whole (n, k, k) tensor per elimination step.)
+and b from a single read, the transpose done in VMEM, written batch-minor
+as the Pallas solver reads them, which adds λ·reg to the diagonal itself:
+between the two kernels A is joined along the lanes and nothing else);
+every other path (bf16 exchange, implicit mode, CPU) is the ``einsum``
+pair, which XLA runs as a relayout copy, a convolution and a
+multiply-reduce — ``resolve_assembly`` decides per sweep.  (A
+scatter/``segment_sum`` formulation was measured 8-10x slower on v5e: TPU
+scatter serializes per row, and XLA's batched small-matrix Cholesky
+streams the whole (n, k, k) tensor per elimination step.)
 
 Supports the two training modes named in BASELINE.json:
 
@@ -577,7 +580,8 @@ def _assembly_chunk_bytes() -> int:
 
 
 def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
-                       precision, post=None, extra=None, platform=None):
+                       precision, post=None, extra=None, platform=None,
+                       lanes=False):
     """One bucket's (A, b): gather the opposite factors for each row's
     rating list and contract over the rating axis on the MXU — by the
     Pallas kernel or the einsum pair, as ``resolve_assembly`` answers for
@@ -597,6 +601,12 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     batch-major layout) or straight-line (lane-major compiles and is ~9%
     faster).  ``extra`` is an optional (rows, ...) operand sliced
     alongside idx/val (the per-slot counts).
+    ``lanes`` (kernel path only): return At (k, k, n), bt (k, n), the batch
+    on the lanes and zero-padded to whole lane tiles as the Pallas solver
+    reads it, instead of A (r, k, k), b (r, k).  A straight-line bucket's
+    kernel writes it itself; inside the lax.map chunks the batch-major
+    kernel stays (no lane-major operand may be laid out there, see
+    ``_chol_solve``) and the bucket is transposed once after the map.
     Chunking is over the batch row axis only (the contraction axis w is
     untouched), so chunked and unchunked results are arithmetically
     identical per row."""
@@ -619,10 +629,13 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
         # equations enough to slow convergence at small lambda)
         with jax.named_scope("als.contract"):
             if how == "kernel":
-                from .assemble_pallas import assemble_bucket
+                from . import assemble_pallas
 
-                A, b = assemble_bucket(y, val_c, precision=precision,
-                                       interpret=platform != "tpu")
+                assemble = (assemble_pallas.assemble_bucket_lanes
+                            if lanes and not in_scan
+                            else assemble_pallas.assemble_bucket)
+                A, b = assemble(y, val_c, precision=precision,
+                                interpret=platform != "tpu")
             else:
                 if implicit:
                     wgt = (alpha * val_c).astype(dtype)  # pads: val 0 -> 0
@@ -686,14 +699,19 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
         return compute(args[0], args[1], args[2], in_scan=True)
 
     operands = (idx_c, val_c) if extra is None else (idx_c, val_c, extra_c)
-    out = jax.lax.map(one_chunk, operands)
-    return jax.tree.map(
-        lambda t: t.reshape((r_pad,) + t.shape[2:])[:r], out
+    out = jax.tree.map(
+        lambda t: t.reshape((r_pad,) + t.shape[2:])[:r],
+        jax.lax.map(one_chunk, operands),
     )
+    if lanes:
+        from .assemble_pallas import to_lanes
+
+        return to_lanes(*out)
+    return out
 
 
 def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
-                         precision="highest", platform=None):
+                         precision="highest", platform=None, lanes=False):
     """A_u = Σ w·y yᵀ and b_u = Σ t·y per slot, as batched MXU matmuls.
 
     y_all:   (n_slots_global, k) gathered opposite-side factor table
@@ -707,15 +725,22 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
 
     Pad entries have val 0 and idx = the opposite side's dummy slot, whose
     factor row is zero — every pad term vanishes through y or val.
+
+    ``lanes``: the hand-off to the Pallas solver with nothing in between —
+    At (k, k, n), bt (k, n), each bucket's entities on the lanes followed by
+    its zero pad up to a whole lane tile, joined along the lanes (one copy;
+    no relayout, no pad pass, no dummy system: ``_solve_factors_lanes``).
     """
     As, bs = [], []
     for idx, val in buckets:
         A, b = _bucket_normal_eqs(
             y_all, idx, val, implicit, alpha, dtype, precision,
-            platform=platform,
+            platform=platform, lanes=lanes,
         )
         As.append(A)
         bs.append(b)
+    if lanes:
+        return jnp.concatenate(As, axis=2), jnp.concatenate(bs, axis=1)
     k = y_all.shape[1]
     # one zero system for the block's guaranteed dummy last slot (no bucket
     # row covers it); count==0 regularization keeps it PD and the solve
@@ -728,16 +753,18 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
 def _fused_solve() -> bool:
     """FLINK_MS_ALS_FUSED=1: solve each bucket chunk inside the assembly
     lax.map, so the (per_block, k, k) normal-equation tensor never
-    materializes (its concatenation and copies are 12 ms of the 154.8 ms
-    iteration, train_iter_s 0.142549 with the knob set: PERF.md section 7,
-    PR 26) and the half-sweep's peak transient stops scaling with the
-    catalog size — required for the 10M-user scale envelope, opt-in until
-    that shape has run on the chip."""
+    materializes and the half-sweep's peak transient stops scaling with
+    the catalog size — required for the 10M-user scale envelope, opt-in
+    until that shape has run on the chip.  It keeps the batch-major
+    hand-off (A (r, k, k) out of the kernel, laid out for the solver by
+    XLA, one solver body a bucket): at the ML-20M shape that was ahead of
+    the default route until PR 30 (0.142549 against 0.154758 s/iter, PR 26)
+    and is behind it since (PERF.md section 7 has the reading)."""
     return os.environ.get("FLINK_MS_ALS_FUSED", "0") == "1"
 
 
-# Two solvers.  "pallas" on a TPU: one VMEM-resident pass per tile, 7.15 ms
-# of the 154.8 ms iteration at the ML-20M shape (PERF.md section 5), where
+# Two solvers.  "pallas" on a TPU: one VMEM-resident pass per tile, 7.2 ms
+# of the 129.9 ms iteration at the ML-20M shape (PERF.md section 5), where
 # XLA's own lax.linalg lowering is a device while-loop of dynamic slices
 # that streams the whole (n, k, k) tensor per elimination step (492.6
 # against 62.7 ms/iter at a 5M-nnz, k=50 probe; 2026-07-31, earlier
@@ -798,16 +825,24 @@ def resolve_assembly(platform: Optional[str], y_dtype, dtype, implicit: bool,
     return "kernel" if k <= 64 else "einsum"
 
 
-def _log_assembly(problem: "BlockedProblem", how: str) -> None:
+def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
+                  k: int) -> None:
     """The static choice of one compiled sweep, per side: how many buckets
-    the kernel takes and their share of the padded ratings."""
+    the kernel takes and their share of the padded ratings, and how many of
+    them hand A to the solver lane-major from the kernel itself (with
+    ``lanes``, every bucket whose gather fits one chunk; the others are
+    transposed after their lax.map) and their share of the entities."""
     parts = []
     for name, side in (("u", problem.u), ("i", problem.i)):
         padded = sum(w * r for w, r in zip(side.widths, side.rows))
         on = len(side.widths) if how == "kernel" else 0
+        direct = [r for w, r in zip(side.widths, side.rows)
+                  if lanes and r * w * k * 4 <= _assembly_chunk_bytes()]
         parts.append(f"{name}-sweep kernel on {on} of {len(side.widths)} "
                      f"buckets ({100.0 if on else 0.0:.1f}% of {padded} "
-                     "padded ratings)")
+                     f"padded ratings), lane-major hand-off on {len(direct)} "
+                     f"({100.0 * sum(direct) / sum(side.rows):.1f}% of "
+                     f"{sum(side.rows)} entities)")
     print("[als] assembly: " + ", ".join(parts) + "; einsum pair elsewhere")
 
 
@@ -831,17 +866,46 @@ def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
     )[..., 0]
 
 
+def _reg_diagonal(counts, lam, weighted_reg):
+    """λ·reg, and 1 for an empty row (a padding entity, an id with no
+    ratings): the identity system keeps Cholesky PD, the caller zeroes x."""
+    reg = counts if weighted_reg else jnp.ones_like(counts)
+    return lam * reg + jnp.where(counts > 0, 0.0, 1.0)
+
+
 def _solve_factors(A, b, counts, lam, weighted_reg, dtype,
                    platform: Optional[str] = None, in_scan=False):
     """Batched Cholesky solve of (A + λ·reg·I) x = b with empty rows masked."""
     k = A.shape[-1]
-    reg = counts if weighted_reg else jnp.ones_like(counts)
-    # empty rows (padding entities / ids with no ratings): force identity
-    # system so Cholesky stays PD, then zero the result
-    diag = lam * reg + jnp.where(counts > 0, 0.0, 1.0)
+    diag = _reg_diagonal(counts, lam, weighted_reg)
     A = A + diag[:, None, None] * jnp.eye(k, dtype=dtype)
     x = _chol_solve(A, b, platform, in_scan=in_scan)
     return jnp.where((counts > 0)[:, None], x, 0.0)
+
+
+def _solve_factors_lanes(At, bt, counts, rows, lam, weighted_reg,
+                         platform: Optional[str]):
+    """``_solve_factors`` for ``_assemble_normal_eqs(lanes=True)``' output,
+    ``rows`` the buckets' entity counts: the same system per entity, with
+    the diagonal added inside the solver's tile.  Only the (n,) diagonal
+    goes out to the kernels' lane layout and only x (k, n) comes back from
+    it; a bucket's pad lanes are identity systems, and the block's dummy
+    last slot, which no bucket covers, is the zero row appended here."""
+    from .assemble_pallas import LANES
+    from .cholesky_pallas import cholesky_solve_lanes
+
+    diag = _reg_diagonal(counts, lam, weighted_reg)
+    d, picks, slot, lane = [], [], 0, 0
+    for r in rows:
+        pad = -r % LANES
+        d.append(jnp.pad(diag[slot:slot + r], (0, pad), constant_values=1.0))
+        picks.append(slice(lane, lane + r))
+        slot, lane = slot + r, lane + r + pad
+    x = cholesky_solve_lanes(At, bt, jnp.concatenate(d),
+                             interpret=platform != "tpu")
+    x = jnp.concatenate([x[:, p] for p in picks]
+                        + [jnp.zeros((x.shape[0], 1), x.dtype)], axis=1)
+    return jnp.where((counts > 0)[:, None], x.T, 0.0)
 
 
 def _flat_side_args(side: SideLayout, dtype, routed=None):
@@ -880,10 +944,13 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     exchange_dtype = (
         jnp.dtype(resolved_exchange) if resolved_exchange else None
     )
+    how = resolve_assembly(platform, exchange_dtype or dtype, dtype, implicit,
+                           k, config.assembly_precision)
+    # the kernel path hands A to the Pallas solver in the solver's own
+    # layout (the fused route below solves per chunk, batch-major)
+    lanes = how == "kernel" and resolve_solver(platform) == "pallas"
     if platform == "tpu":
-        _log_assembly(problem, resolve_assembly(
-            platform, exchange_dtype or dtype, dtype, implicit, k,
-            config.assembly_precision))
+        _log_assembly(problem, how, lanes and not _fused_solve(), k)
 
     def half_sweep(y_shard, flat, routed: bool):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
@@ -959,11 +1026,18 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             A, b = _assemble_normal_eqs(
                 y_all, buckets, implicit, alpha, dtype,
                 precision=config.assembly_precision, platform=platform,
+                lanes=lanes,
             )
         with jax.named_scope("als.solve"):
-            if implicit:
-                A = A + yty[None, :, :]
-            x = _solve_factors(A, b, counts[0], lam, weighted, dtype, platform)
+            if lanes:
+                x = _solve_factors_lanes(
+                    A, b, counts[0], [idx_b.shape[0] for idx_b, _ in buckets],
+                    lam, weighted, platform)
+            else:
+                if implicit:
+                    A = A + yty[None, :, :]
+                x = _solve_factors(A, b, counts[0], lam, weighted, dtype,
+                                   platform)
         return x[None]  # (1, per_block, k)
 
     n_u_args = 2 * n_u_buckets + 1 + (1 if plan["u"] is not None else 0)

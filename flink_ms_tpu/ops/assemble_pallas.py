@@ -26,6 +26,35 @@ left it:
 - pad entries stay exact zeros through ``y`` itself; only the ragged last
   w tile is masked (an out-of-bounds block read is not zeros).
 
+Two output layouts, one contraction.  ``assemble_bucket`` writes A
+``(r, k, k)``, b ``(r, k)``: batch-major, each 50x50 tile padded to 56x128
+in HBM (28.7 KB an entity where 10 KB are data), which the Pallas solver
+can only read after XLA has concatenated, padded and transposed it (32 ms
+of the 154.8 ms ML-20M iteration, PERF.md section 5, PR 26).
+``assemble_bucket_lanes`` writes what that solver reads, At ``(k, k, n)``
+and bt ``(k, n)`` with the batch on the lanes and n = r rounded up to 128:
+
+- grid ``(n / 128, S, cdiv(w, Wt))``: a lane tile of 128 entities is fed in
+  S sub-blocks of Cs entities (``lane_tile_sizes``: as many as the same
+  VMEM budget holds, 128 at w <= 64, 8 from w = 744) into a resident
+  ``(128, k+1, k)`` accumulator; the tile's last step transposes it in
+  VMEM, ``(1, 2, 0)`` as the batch-major solver kernel does, and writes one
+  lane-dense ``(k, k, 128)`` block: 1.88 GB a sweep instead of 4.74;
+- a sub-block wholly past r is skipped and fetches nothing (its block
+  index is held at the last real one); lanes past r are written as zeros,
+  which the solver's diagonal operand turns into identity systems.
+
+On the chip the transposition costs 2.9 us a lane tile: hidden behind the
+next block's DMA from w = 496 up, on top of the contraction's 9 us in the
+narrow buckets, which are bound by the MXU's many small products, not by
+HBM (w = 24: 3.05 ms against 2.38 batch-major and 0.84 for its bytes; all
+33 buckets 44.3 ms against 43.1; behind them the 9.2 ms relayout copy and
+the 11.1 ms reg add + pad are gone and the 12 ms concatenation is a 5.3 ms
+join along the lanes: PERF.md sections 5 and 6, PR 30).
+``ops/als._bucket_normal_eqs`` picks the layout: lane-major whenever the
+Pallas solver takes the result and the bucket runs straight-line,
+batch-major inside ``lax.map`` chunks and on the fused route.
+
 ``precision`` is the caller's: "highest" contracts f32 products (six bf16
 passes, as the einsum it replaces), "default" rounds both operands to
 bf16 first, which is what one MXU pass means.  ``interpret`` comes from
@@ -45,8 +74,29 @@ from jax.experimental.pallas import tpu as pltpu
 from .cholesky_pallas import _round_up
 
 _GROUP = 8                 # entities per batched step: one f32 sublane tile
+LANES = 128               # entities per lane-major output block: one lane tile
 _MAX_WT = 1024             # rating rows per step once w is tiled (lane tiles)
 _VMEM_BUDGET = 10 << 20    # of the 16 MB scoped VMEM, for the pipelined blocks
+# the lane-major form holds, beside the same pipelined input blocks, the
+# resident (128, k+1, k) accumulator, its transpose and two (k, k, 128)
+# output blocks (13 MB at k = 64): more than the default scoped 16 MB
+_LANES_VMEM_LIMIT = 40 << 20
+
+
+def _input_bytes(w: int, k: int) -> int:
+    """VMEM of one entity's double-buffered y and t rows, lane-padded."""
+    return 2 * (w * _round_up(k, 128) * 4 + _round_up(w, 128) * 4)
+
+
+def _split_w(w: int) -> int:
+    """Wt for a bucket wider than ``_MAX_WT``: equal tiles of whole lane
+    tiles, at least half ``_MAX_WT`` each.  The last tile's overhang is
+    computed on masked zeros (chip: 1120 as 1024 + 96 ran at 1.8x the read's
+    time, evenly tiled widths at 1.1x), so the split that overhangs least
+    wins: 1120 -> 3 x 384."""
+    n = -(-w // _MAX_WT)
+    return min((_round_up(-(-w // m), 128) for m in range(n, 2 * n + 1)),
+               key=lambda t: (t * -(-w // t), -t))
 
 
 def tile_sizes(w: int, k: int):
@@ -54,46 +104,43 @@ def tile_sizes(w: int, k: int):
     ``_MAX_WT`` wide is contracted whole, and C fills the VMEM budget with
     double-buffered ``(C, Wt, k)`` input and ``(C, k, k)`` output blocks,
     both lane-padded to 128.  A wider bucket (few entities, long lists)
-    takes one group of entities and equal tiles of whole lane tiles, at
-    least half ``_MAX_WT`` each.  The last tile's overhang is computed on
-    masked zeros (chip: 1120 as 1024 + 96 ran at 1.8x the read's time,
-    evenly tiled widths at 1.1x), so the split that overhangs least wins:
-    1120 -> 3 x 384.  The budget counts the pipelined blocks alone; a
-    group's temporaries (the 128-lane slab of 8 x Wt rows, its transpose,
-    the masked copy of a ragged tile) live in the rest of the scoped 16 MB:
-    the widest case, w = 3000 as 1024-row tiles with a ragged last one,
-    compiles and agrees with float64 on the chip at k = 50, 64, 100, 128."""
+    takes one group of entities and ``_split_w``'s tiles.  The budget counts
+    the pipelined blocks alone; a group's temporaries (the 128-lane slab of
+    8 x Wt rows, its transpose, the masked copy of a ragged tile) live in
+    the rest of the scoped 16 MB: the widest case, w = 3000 as 1024-row
+    tiles with a ragged last one, compiles and agrees with float64 on the
+    chip at k = 50, 64, 100, 128."""
     if w > _MAX_WT:
-        n = -(-w // _MAX_WT)
-        wt = min((_round_up(-(-w // m), 128) for m in range(n, 2 * n + 1)),
-                 key=lambda t: (t * -(-w // t), -t))
-        return _GROUP, wt
-    row = _round_up(k, 128) * 4
-    per_entity = 2 * ((w + _round_up(k, 8)) * row + _round_up(w, 128) * 4)
+        return _GROUP, _split_w(w)
+    per_entity = _input_bytes(w, k) + 2 * _round_up(k, 8) * _round_up(k, 128) * 4
     return max(_VMEM_BUDGET // per_entity // _GROUP * _GROUP, _GROUP), w
 
 
-def _assemble_kernel(y_ref, t_ref, a_ref, b_ref, *, w: int, k: int,
-                     one_pass: bool):
-    """One grid step: y (C, Wt, k), t (C, Wt) -> A (C, k, k), b (C, k)."""
+def lane_tile_sizes(w: int, k: int):
+    """-> (Cs, Wt) of the lane-major form, whose output block is always one
+    lane tile of 128 entities: the input arrives in sub-blocks of Cs
+    entities, the largest power of two whose double-buffered rows fit the
+    same budget (128 at w <= 64, 8 from w = 744), and a bucket wider than
+    ``_MAX_WT`` is tiled over w as in ``tile_sizes``."""
+    if w > _MAX_WT:
+        return _GROUP, _split_w(w)
+    cs = LANES
+    while cs > _GROUP and cs * _input_bytes(w, k) > _VMEM_BUDGET:
+        cs //= 2
+    return cs, w
+
+
+def _contract_groups(y_ref, t_ref, j, put, *, w: int, k: int, one_pass: bool):
+    """One grid step's contraction of y (C, Wt, k), t (C, Wt), w tile j: for
+    each sublane tile g of 8 entities, ``put(g, res)`` receives
+    ``res (8, k+1, k)``, A in rows 0..k-1 and b in row k."""
     C, wt, _ = y_ref.shape
-    tiled = wt < w
     ragged = w % wt != 0
-    j = pl.program_id(1)
-    if tiled:
-        @pl.when(j == 0)
-        def _():
-            a_ref[...] = jnp.zeros_like(a_ref)
-            b_ref[...] = jnp.zeros_like(b_ref)
     if ragged:
         left = w - j * wt        # rating rows of this tile inside the array
         y_keep = jax.lax.broadcasted_iota(jnp.int32, (wt, k), 0) < left
         t_keep = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, wt), 1) < left
     precision = None if one_pass else jax.lax.Precision.HIGHEST
-
-    def put(ref, at, value):
-        # a tiled bucket accumulates over its w tiles; a whole one is written
-        ref[at] = ref[at] + value if tiled else value
 
     def group(g, carry):
         # one batched transpose and one batched contraction for a sublane
@@ -108,26 +155,85 @@ def _assemble_kernel(y_ref, t_ref, a_ref, b_ref, *, w: int, k: int,
             [jnp.swapaxes(y8, 1, 2), t8[:, None, :]], axis=1)   # (8, k+1, Wt)
         if one_pass:
             lhs, y8 = lhs.astype(jnp.bfloat16), y8.astype(jnp.bfloat16)
-        res = jax.lax.dot_general(
+        put(g, jax.lax.dot_general(
             lhs, y8, (((2,), (1,)), ((0,), (0,))), precision=precision,
-            preferred_element_type=jnp.float32)             # (8, k+1, k)
-        put(a_ref, rows, res[:, :k])
-        put(b_ref, rows, res[:, k])
+            preferred_element_type=jnp.float32))            # (8, k+1, k)
         return carry
 
     jax.lax.fori_loop(0, C // _GROUP, group, 0)
+
+
+def _assemble_kernel(y_ref, t_ref, a_ref, b_ref, *, w: int, k: int,
+                     one_pass: bool):
+    """One grid step: y (C, Wt, k), t (C, Wt) -> A (C, k, k), b (C, k)."""
+    tiled = y_ref.shape[1] < w
+    j = pl.program_id(1)
+    if tiled:
+        @pl.when(j == 0)
+        def _():
+            a_ref[...] = jnp.zeros_like(a_ref)
+            b_ref[...] = jnp.zeros_like(b_ref)
+
+    def put(g, res):
+        # a tiled bucket accumulates over its w tiles; a whole one is written
+        rows = pl.ds(pl.multiple_of(g * _GROUP, _GROUP), _GROUP)
+        a_ref[rows] = a_ref[rows] + res[:, :k] if tiled else res[:, :k]
+        b_ref[rows] = b_ref[rows] + res[:, k] if tiled else res[:, k]
+
+    _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass)
+
+
+def _assemble_kernel_lanes(y_ref, t_ref, a_ref, b_ref, acc_ref, *, r: int,
+                           w: int, k: int, one_pass: bool):
+    """Grid step (i, s, j): entity sub-block s of lane tile i, w tile j.
+    y (Cs, Wt, k), t (Cs, Wt) land in rows s*Cs.. of the resident
+    ``acc (128, k+1, k)``; the lane tile's last step transposes it in VMEM
+    and writes At (k, k, 128), bt (k, 128), lanes past r as exact zeros."""
+    cs, wt, _ = y_ref.shape
+    tiled = wt < w
+    i, s, j = (pl.program_id(d) for d in range(3))
+    base = pl.multiple_of(s * cs, _GROUP)
+
+    # a sub-block wholly past r (the index map held its block at the last
+    # real one, so nothing was fetched for it) is skipped; its acc rows and
+    # a partial sub-block's rows past r hold leftovers, masked below
+    @pl.when(i * LANES + base < r)
+    def _():
+        if tiled:
+            @pl.when(j == 0)
+            def _():
+                acc_ref[pl.ds(base, cs)] = jnp.zeros((cs, k + 1, k),
+                                                     jnp.float32)
+
+        def put(g, res):
+            rows = pl.ds(pl.multiple_of(base + g * _GROUP, _GROUP), _GROUP)
+            acc_ref[rows] = acc_ref[rows] + res if tiled else res
+
+        _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass)
+
+    @pl.when((s == pl.num_programs(1) - 1) & (j == pl.num_programs(2) - 1))
+    def _():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
+        out = jnp.where(lane < r - i * LANES,
+                        jnp.transpose(acc_ref[:], (1, 2, 0)), 0.0)
+        a_ref[:] = out[:k]
+        b_ref[:] = out[k]
+
+
+def _one_pass(precision: str) -> bool:
+    if precision not in ("highest", "default"):
+        raise ValueError(f"assembly kernel precision {precision!r}")
+    return precision == "default"
 
 
 def assemble_bucket(y, t, *, precision: str, interpret: bool):
     """A = einsum("rwk,rwl->rkl", y, y), b = einsum("rwk,rw->rk", y, t) from
     one read of y.  y (r, w, k) float32 with w a multiple of 8, t (r, w);
     ``precision`` "highest" or "default"."""
-    if precision not in ("highest", "default"):
-        raise ValueError(f"assembly kernel precision {precision!r}")
     r, w, k = y.shape
     c, wt = tile_sizes(w, k)
     kernel = functools.partial(_assemble_kernel, w=w, k=k,
-                               one_pass=precision == "default")
+                               one_pass=_one_pass(precision))
     return pl.pallas_call(
         kernel,
         grid=(pl.cdiv(r, c), pl.cdiv(w, wt)),
@@ -147,3 +253,52 @@ def assemble_bucket(y, t, *, precision: str, interpret: bool):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(y, t.astype(jnp.float32))
+
+
+def assemble_bucket_lanes(y, t, *, precision: str, interpret: bool):
+    """The same sums with the batch on the lanes, as the Pallas solver reads
+    them: At (k, k, n), bt (k, n) with n = r rounded up to 128 and the lanes
+    past r exact zeros."""
+    r, w, k = y.shape
+    cs, wt = lane_tile_sizes(w, k)
+    n_sub = min(LANES // cs, pl.cdiv(r, cs))
+    n_w = pl.cdiv(w, wt)
+    last = pl.cdiv(r, cs) - 1     # the last entity block inside the array
+
+    def y_block(i, s, j):
+        e = i * n_sub + s
+        return jnp.minimum(e, last), jnp.where(e > last, n_w - 1, j)
+
+    n = _round_up(r, LANES)
+    kernel = functools.partial(_assemble_kernel_lanes, r=r, w=w, k=k,
+                               one_pass=_one_pass(precision))
+    return pl.pallas_call(
+        kernel,
+        grid=(n // LANES, n_sub, n_w),
+        in_specs=[
+            pl.BlockSpec((cs, wt, k), lambda i, s, j: (*y_block(i, s, j), 0)),
+            pl.BlockSpec((cs, wt), y_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((k, k, LANES), lambda i, s, j: (0, 0, i)),
+            pl.BlockSpec((k, LANES), lambda i, s, j: (0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((k, k, n), jnp.float32),
+            jax.ShapeDtypeStruct((k, n), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((LANES, k + 1, k), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_LANES_VMEM_LIMIT),
+        interpret=interpret,
+    )(y, t.astype(jnp.float32))
+
+
+def to_lanes(A, b):
+    """A (r, k, k), b (r, k) -> ``assemble_bucket_lanes``' layout by an XLA
+    transpose and zero pad: for a bucket whose kernel had to stay
+    batch-major (inside ``lax.map`` chunks)."""
+    pad = -A.shape[0] % LANES
+    return (jnp.pad(jnp.transpose(A, (1, 2, 0)), ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(jnp.transpose(b, (1, 0)), ((0, 0), (0, pad))))

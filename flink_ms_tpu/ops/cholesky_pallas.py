@@ -15,6 +15,16 @@ Pallas kernel:
 - the k-step Cholesky, forward- and back-substitution all run on the tile
   while it lives in VMEM; HBM sees one read of A/b and one write of x.
 
+Two ways in.  ``cholesky_solve_batched`` takes A ``(n, k, k)`` as the einsum
+paths assemble it and lays it out for the kernel in XLA (a transpose and a
+pad over the whole tensor) or, ``layout="batch_major"``, per tile in VMEM.
+``cholesky_solve_lanes`` takes At ``(k, k, n)`` as the assembly kernel
+writes it (``assemble_pallas.assemble_bucket_lanes``) and the
+regularisation as a per-lane operand that the kernel adds to the diagonal
+in VMEM, so that A is read from HBM once and nothing rewrites it on the
+way: at the ML-20M shape 7.2 ms an iteration in ``als.solve`` where the
+reg add, the pad and the kernel took 18.3 (PERF.md section 5, PR 30).
+
 The caller says where it runs: ``interpret=True`` is the interpreter-mode
 path CPU tests pin numerics with, ``interpret=False`` compiles for the TPU.
 ``ops/als._chol_solve`` derives it from its mesh's platform; selection of
@@ -38,14 +48,13 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _solve_kernel(a_ref, b_ref, x_ref, *, k: int):
-    """One tile: A (k, k, T) SPD, b (k, T) -> x (k, T).
+def _solve_tile(M, b, k: int):
+    """A (k, k, T) SPD, b (k, T) -> x (k, T), T systems on the lanes.
 
     Right-looking Cholesky by rank-1 downdates, then the two triangular
     substitutions, fully unrolled over the static k — every op is
     vectorized over the T lanes.
     """
-    M = a_ref[:]                                  # (k, k, T)
     rows = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
     cols = []                                     # cols[j]: (k, T), >=2D ops
     for j in range(k):
@@ -57,7 +66,6 @@ def _solve_kernel(a_ref, b_ref, x_ref, *, k: int):
     # L[i, j] = cols[j][i]; diag entries as a (k, T) stack for the solves
     diag = jnp.concatenate([c[j:j + 1, :] for j, c in enumerate(cols)], axis=0)
 
-    b = b_ref[:]                                  # (k, T)
     # forward solve L z = b with a running accumulator acc = Σ_p L[:,p]·z_p
     acc = jnp.zeros_like(b)
     zs = []                                       # zs[j]: (1, T)
@@ -74,25 +82,36 @@ def _solve_kernel(a_ref, b_ref, x_ref, *, k: int):
         x = (zs[j] - acc[j:j + 1, :]) / diag[j:j + 1, :]
         xs[j] = x
         acc = acc + Lrows[j, :, :] * x            # row j of L, (k, T)
-    x_ref[:] = jnp.concatenate(xs, axis=0)        # (k, T)
+    return jnp.concatenate(xs, axis=0)            # (k, T)
+
+
+def _solve_kernel(a_ref, b_ref, *rest, k: int):
+    """One tile: A (k, k, T), b (k, T) [, d (1, T)] -> x (k, T).  With d the
+    system solved is A + d·I: the regularisation reaches the diagonal here,
+    in VMEM, and no pass over A in HBM adds it."""
+    *d_ref, x_ref = rest
+    M = a_ref[:]
+    if d_ref:
+        on_diag = (jax.lax.broadcasted_iota(jnp.int32, (k, k, 1), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (k, k, 1), 1))
+        M = M + jnp.where(on_diag, d_ref[0][:][None], 0.0)
+    x_ref[:] = _solve_tile(M, b_ref[:], k)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _solve_padded(At, bt, tile: int, interpret: bool):
+def _solve_padded(At, bt, tile: int, interpret: bool, d=None):
     k = At.shape[0]
     n_pad = At.shape[2]
-    kernel = functools.partial(_solve_kernel, k=k)
+    lanes = pl.BlockSpec((k, tile), lambda i: (0, i))
     return pl.pallas_call(
-        kernel,
+        functools.partial(_solve_kernel, k=k),
         grid=(n_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((k, k, tile), lambda i: (0, 0, i)),
-            pl.BlockSpec((k, tile), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((k, tile), lambda i: (0, i)),
+        in_specs=[pl.BlockSpec((k, k, tile), lambda i: (0, 0, i)), lanes]
+        + ([] if d is None else [pl.BlockSpec((1, tile), lambda i: (0, i))]),
+        out_specs=lanes,
         out_shape=jax.ShapeDtypeStruct((k, n_pad), At.dtype),
         interpret=interpret,
-    )(At, bt)
+    )(At, bt, *(() if d is None else (d,)))
 
 
 def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
@@ -104,29 +123,7 @@ def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
     round-3 fused-mode AOT OOM)."""
     M = jnp.transpose(a_ref[:], (1, 2, 0))        # (k, k, T) in VMEM
     b = jnp.transpose(b_ref[:], (1, 0))           # (k, T)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
-    cols = []
-    for j in range(k):
-        d = jax.lax.rsqrt(M[j, j:j + 1, :])
-        col = M[:, j, :] * d
-        col = jnp.where(rows >= j, col, 0.0)
-        cols.append(col)
-        M = M - col[:, None, :] * col[None, :, :]
-    diag = jnp.concatenate([c[j:j + 1, :] for j, c in enumerate(cols)], axis=0)
-    acc = jnp.zeros_like(b)
-    zs = []
-    for j in range(k):
-        z = (b[j:j + 1, :] - acc[j:j + 1, :]) / diag[j:j + 1, :]
-        zs.append(z)
-        acc = acc + cols[j] * z
-    Lrows = jnp.stack([c for c in cols], axis=1)  # (k, k, T)
-    acc = jnp.zeros_like(b)
-    xs = [None] * k
-    for j in reversed(range(k)):
-        x = (zs[j] - acc[j:j + 1, :]) / diag[j:j + 1, :]
-        xs[j] = x
-        acc = acc + Lrows[j, :, :] * x
-    x_ref[:] = jnp.transpose(jnp.concatenate(xs, axis=0), (1, 0))
+    x_ref[:] = jnp.transpose(_solve_tile(M, b, k), (1, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -144,6 +141,14 @@ def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((n_pad, k), Ab.dtype),
         interpret=interpret,
     )(Ab, bb)
+
+
+def cholesky_solve_lanes(At, bt, d, *, interpret: bool, tile: int = 128):
+    """(A + d·I) x = b for systems that already lie batch-minor, as
+    ``assemble_pallas.assemble_bucket_lanes`` writes them: At (k, k, n),
+    bt (k, n), d (n,) -> x (k, n), n a multiple of ``tile``.  A pad lane
+    carries A = 0, b = 0, d = 1: the identity system, x = 0."""
+    return _solve_padded(At, bt, tile, bool(interpret), d[None, :])
 
 
 def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,

@@ -1,9 +1,12 @@
 """Pallas ALS assembly kernel: numerics against a float64 einsum in
-interpreter mode, the precision it is asked for, end-to-end ALS parity
-with the resolver patched onto the kernel (unfused, fused, chunked), and
-the resolver's answers — the bf16-exchange, implicit and CPU paths must
-keep the einsum pair.  The TPU cross-lowering cases are beside the
-Cholesky kernel's in ``test_cholesky_pallas.py``."""
+interpreter mode, in both output layouts (batch-major, and lane-major as
+the Pallas solver reads it), the precision it is asked for, end-to-end ALS
+parity with the resolver patched onto the kernel (unfused, fused, chunked,
+and the lane-major hand-off to the solver), which buckets take that
+hand-off, and the resolver's answers — the bf16-exchange, implicit and CPU
+paths must keep the einsum pair and trace no new kernel.  The TPU
+cross-lowering cases are beside the Cholesky kernel's in
+``test_cholesky_pallas.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +14,9 @@ import numpy as np
 import pytest
 
 from flink_ms_tpu.ops import als as A
-from flink_ms_tpu.ops.assemble_pallas import assemble_bucket, tile_sizes
+from flink_ms_tpu.ops import assemble_pallas
+from flink_ms_tpu.ops.assemble_pallas import (
+    assemble_bucket, assemble_bucket_lanes, lane_tile_sizes, tile_sizes)
 from flink_ms_tpu.parallel.mesh import make_mesh
 
 # rows per width: never a multiple of the kernel's entity tile C, and more
@@ -49,6 +54,109 @@ def test_kernel_matches_float64_einsum(rng, w, k):
     np.testing.assert_allclose(got_b, want_b, rtol=1e-5,
                                atol=1e-6 * np.abs(want_b).max())
     assert not np.asarray(got_a)[1].any() and not np.asarray(got_b)[1].any()
+
+
+@pytest.mark.parametrize("k", [10, 50, 64])
+@pytest.mark.parametrize("w", [24, 144, 1032])
+@pytest.mark.parametrize("r", [5, 130, 257])
+def test_lane_major_kernel_matches_float64_einsum(rng, r, w, k):
+    """The same sums with the batch on the lanes: r under one lane tile,
+    ragged over two, and over three; w = 24 whole in one sub-block of 128
+    entities, 144 in sub-blocks of 64 (at r = 5 the second one lies wholly
+    past the array and is skipped), 1032 tiled over w with a ragged last
+    tile in sub-blocks of 8.  Lanes past r, and the all-pad entity, are
+    exact zeros: the solver's diagonal operand makes them identity systems."""
+    cs, wt = lane_tile_sizes(w, k)
+    assert 128 % cs == 0 and (w <= wt or w % wt)
+    y, t = _bucket(rng, r, w, k)
+    got_a, got_b = (np.asarray(x) for x in assemble_bucket_lanes(
+        jnp.asarray(y), jnp.asarray(t), precision="highest", interpret=True))
+    n = -(-r // 128) * 128
+    assert got_a.shape == (k, k, n) and got_b.shape == (k, n)
+    want_a, want_b = _einsum64(y, t)
+    np.testing.assert_allclose(got_a[:, :, :r].transpose(2, 0, 1), want_a,
+                               rtol=1e-5, atol=1e-6 * np.abs(want_a).max())
+    np.testing.assert_allclose(got_b[:, :r].T, want_b, rtol=1e-5,
+                               atol=1e-6 * np.abs(want_b).max())
+    assert not got_a[:, :, r:].any() and not got_b[:, r:].any()
+    assert not got_a[:, :, 1].any() and not got_b[:, 1].any()
+
+
+def test_lane_major_kernel_sums_what_the_batch_major_one_sums(rng):
+    """Same contraction per entity, same order over the w tiles: the two
+    layouts hold the same floats."""
+    y, t = _bucket(rng, 21, 1032, 50)
+    a, b = assemble_bucket(jnp.asarray(y), jnp.asarray(t),
+                           precision="highest", interpret=True)
+    at, bt = assemble_bucket_lanes(jnp.asarray(y), jnp.asarray(t),
+                                   precision="highest", interpret=True)
+    np.testing.assert_array_equal(np.asarray(at)[:, :, :21].transpose(2, 0, 1), a)
+    np.testing.assert_array_equal(np.asarray(bt)[:, :21].T, b)
+
+
+# (w, rows) of the two sides of als-ml20m.retrain (benchmark/synth.py's
+# degree sequences through `_side_order`), widest first
+_ML20M = {
+    "u": [(12784, 1), (8520, 16), (5680, 51), (3784, 151), (2520, 429),
+          (1680, 886), (1120, 1935), (744, 3695), (496, 6535), (328, 10033),
+          (216, 13236), (144, 16549), (96, 18001), (64, 19890), (40, 18580),
+          (24, 28505)],
+    "i": [(97096, 7), (64728, 24), (43152, 36), (28768, 53), (19176, 80),
+          (12784, 121), (8520, 181), (5680, 271), (3784, 408), (2520, 611),
+          (1680, 916), (1120, 1388), (744, 2066), (496, 3172), (328, 4850),
+          (216, 7088), (144, 5472)],
+}
+
+
+@pytest.mark.parametrize("w", sorted({w for side in _ML20M.values()
+                                      for w, _ in side}))
+def test_lane_tiles_on_the_ml20m_ladder(w):
+    """Every width of the cell's ladder has a lane-major tiling: sub-blocks
+    that divide the lane tile, whole in w up to 1024 and inside the same
+    VMEM budget as the batch-major form's input blocks, the batch-major
+    form's w tiles beyond."""
+    cs, wt = lane_tile_sizes(w, 50)
+    assert cs in (8, 16, 32, 64, 128)
+    if w <= 1024:
+        assert wt == w
+        assert cs * assemble_pallas._input_bytes(w, 50) <= 10 << 20
+        assert cs == 128 or 2 * cs * assemble_pallas._input_bytes(w, 50) > 10 << 20
+    else:
+        assert (cs, wt) == tile_sizes(w, 50)
+
+
+def _ladder_problem():
+    import types
+
+    side = {n: types.SimpleNamespace(widths=tuple(w for w, _ in b),
+                                     rows=tuple(r for _, r in b))
+            for n, b in _ML20M.items()}
+    return types.SimpleNamespace(u=side["u"], i=side["i"])
+
+
+@pytest.mark.parametrize("limit,want", [
+    # the cell: the largest gather is 658 MB of the 2 GiB chunk, so all 33
+    # buckets are written lane-major by the kernel itself
+    (None, ("on 16 (100.0% of 138493 entities)",
+            "on 17 (100.0% of 26744 entities)")),
+    # a 512 MB chunk: the user side's four largest gathers (w = 216..744)
+    # run in lax.map chunks, batch-major, and are transposed after
+    (512 << 20, ("on 12 (75.8% of 138493 entities)",
+                 "on 17 (100.0% of 26744 entities)")),
+])
+def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
+    """The rule holds no width and no row count: a bucket's kernel writes
+    the solver's layout whenever it runs straight-line, and `_log_assembly`
+    prints the share per side."""
+    if limit:
+        monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", str(limit))
+    A._log_assembly(_ladder_problem(), "kernel", True, 50)
+    line = capsys.readouterr().out
+    u, i = line.split("i-sweep")
+    assert "lane-major hand-off " + want[0] in u
+    assert "lane-major hand-off " + want[1] in i
+    A._log_assembly(_ladder_problem(), "kernel", False, 50)   # lax solver, fused
+    assert "hand-off on 0 (0.0%" in capsys.readouterr().out
 
 
 @pytest.mark.parametrize("w", [24, 1032])
@@ -111,6 +219,108 @@ def test_als_fit_with_kernel_matches_einsum(rng, monkeypatch, mode):
         kernel.user_factors, base.user_factors, rtol=1e-3, atol=1e-5)
     np.testing.assert_allclose(
         kernel.item_factors, base.item_factors, rtol=1e-3, atol=1e-5)
+
+
+def _spy(monkeypatch, module, name, note):
+    """`module.name` still runs; `note(args)` sees each call first."""
+    fn = getattr(module, name)
+
+    def spy(*a, **kw):
+        note(a)
+        return fn(*a, **kw)
+
+    monkeypatch.setattr(module, name, spy)
+
+
+def _count_lane_calls(monkeypatch):
+    seen = []
+    _spy(monkeypatch, assemble_pallas, "assemble_bucket_lanes",
+         lambda a: seen.append(a[0].shape))
+    return seen
+
+
+@pytest.mark.parametrize("mode", ["lanes", "lanes_chunked", "lax_solver"])
+def test_als_fit_hands_off_lane_major(rng, monkeypatch, mode):
+    """One device, a user bucket of more than 128 rows, the Pallas solver:
+    the kernel writes A batch-minor, the solver adds the diagonal, and the
+    factors are the einsum path's.  A bucket that chunks keeps the
+    batch-major kernel inside its lax.map and is transposed after; with
+    the lax solver nothing is lane-major."""
+    n_users, n_items, k = 300, 30, 4
+    full = rng.normal(size=(n_users, k)) @ rng.normal(size=(n_items, k)).T
+    u, i = np.nonzero(rng.uniform(size=full.shape) < 0.6)
+    r = full[u, i]
+    init = (rng.normal(size=(n_users, k)).astype(np.float32),
+            rng.normal(size=(n_items, k)).astype(np.float32))
+    cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1)
+    mesh = make_mesh(1)
+    base = A.als_fit(u, i, r, cfg, mesh, init=init)
+    if mode != "lax_solver":
+        monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
+    if mode == "lanes_chunked":
+        monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", "4096")
+    A._SWEEP_CACHE.clear()
+    monkeypatch.setattr(A, "resolve_assembly", _kernel_everywhere)
+    seen = _count_lane_calls(monkeypatch)
+    try:
+        kernel = A.als_fit(u, i, r, cfg, mesh, init=init)
+    finally:
+        A._SWEEP_CACHE.clear()
+    rows = sorted(shape[0] for shape in seen)
+    if mode == "lanes":
+        assert len(rows) >= 3 and rows[-1] > 128
+    elif mode == "lanes_chunked":
+        assert rows and rows[-1] * 16 * k * 4 <= 4096 < 205 * 24 * k * 4
+    else:
+        assert not rows
+    np.testing.assert_allclose(
+        kernel.user_factors, base.user_factors, rtol=1e-3, atol=1e-5)
+    np.testing.assert_allclose(
+        kernel.item_factors, base.item_factors, rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("exchange,implicit,on_kernel", [
+    ("bfloat16", False, False),   # `als_train`'s default on a TPU
+    (None, True, False),          # implicit mode
+    (None, False, True),          # explicit f32: one kernel a bucket
+])
+def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
+                                           implicit, on_kernel):
+    """A sweep on the Pallas solver with the resolver as a TPU would answer
+    it: the bf16 exchange and implicit mode call no assembly kernel and
+    hand `(n, k, k)` to `cholesky_solve_batched` as before; only the kernel
+    path reaches the solver through `cholesky_solve_lanes`."""
+    from flink_ms_tpu.ops import cholesky_pallas
+
+    n_users, n_items, k = 40, 30, 4
+    u, i = np.nonzero(rng.uniform(size=(n_users, n_items)) < 0.6)
+    problem = A.prepare_blocked(u, i, np.ones(len(u), np.float32), 1)
+    cfg = A.ALSConfig(num_factors=k, iterations=1, implicit=implicit,
+                      exchange_dtype=exchange)
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
+    real = A.resolve_assembly
+    monkeypatch.setattr(
+        A, "resolve_assembly",
+        lambda platform, *a, **kw: real("tpu", *a, **kw))
+    calls = {"kernel": [], "batched": [], "lanes": []}
+    for module, name, key in [
+            (assemble_pallas, "assemble_bucket", "kernel"),
+            (assemble_pallas, "assemble_bucket_lanes", "kernel"),
+            (cholesky_pallas, "cholesky_solve_batched", "batched"),
+            (cholesky_pallas, "cholesky_solve_lanes", "lanes")]:
+        _spy(monkeypatch, module, name,
+             lambda a, key=key: calls[key].append(a[0].shape))
+    fit_fn, dev_args = A.compile_fit(problem, cfg, make_mesh(1))
+    A._SWEEP_CACHE.clear()
+    jax.make_jaxpr(fit_fn)(jnp.asarray(1, jnp.int32), *dev_args)
+    if on_kernel:
+        assert len(calls["kernel"]) == (len(problem.u.widths)
+                                        + len(problem.i.widths))
+        assert len(calls["lanes"]) == 2 and not calls["batched"]
+    else:
+        assert not calls["kernel"] and not calls["lanes"]
+        assert calls["batched"] == [(problem.u.per_block, k, k),
+                                    (problem.i.per_block, k, k)]
 
 
 @pytest.mark.parametrize("platform,y_dtype,implicit,w,k,precision,want", [

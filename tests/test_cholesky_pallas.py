@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flink_ms_tpu.ops.cholesky_pallas import cholesky_solve_batched
+from flink_ms_tpu.ops.cholesky_pallas import (
+    cholesky_solve_batched, cholesky_solve_lanes)
 
 
 @pytest.mark.parametrize("k", [3, 8, 16, 50])
@@ -51,6 +52,52 @@ def test_batch_major_matches_lane_major(rng, k, n):
     np.testing.assert_allclose(batch, x_ref, rtol=2e-3, atol=2e-4)
 
 
+# rank 50 once: every new n retraces its three unrolled k-loops
+@pytest.mark.parametrize("n,k", [(n, k) for k in (3, 8, 16)
+                                 for n in (1, 100, 257)] + [(257, 50)])
+def test_lanes_with_diagonal_matches_numpy(rng, k, n):
+    """The solver as the assembly kernel feeds it: A already batch-minor,
+    padded to whole lane tiles, and the regularisation as a per-lane
+    operand added to the diagonal in VMEM.  Against float64
+    solve(A + d·I, b); a `count = 0` lane (A = 0, b = 0, d = 1) and every
+    pad lane are identity systems, x = 0 exactly."""
+    G = rng.standard_normal((n, k, k)).astype(np.float32)
+    A = G @ G.transpose(0, 2, 1)
+    b = rng.standard_normal((n, k)).astype(np.float32)
+    d = rng.uniform(0.5, 8.0, n).astype(np.float32)
+    A[n // 2], b[n // 2], d[n // 2] = 0.0, 0.0, 1.0      # an empty row
+    pad = -n % 128
+    At = np.pad(A.transpose(1, 2, 0), ((0, 0), (0, 0), (0, pad)))
+    bt = np.pad(b.T, ((0, 0), (0, pad)))
+    x = np.asarray(cholesky_solve_lanes(
+        jnp.asarray(At), jnp.asarray(bt),
+        jnp.asarray(np.pad(d, (0, pad), constant_values=1.0)),
+        interpret=True))
+    assert x.shape == (k, n + pad)
+    x_ref = np.linalg.solve(
+        A.astype(np.float64) + d[:, None, None] * np.eye(k),
+        b.astype(np.float64)[..., None])[..., 0]
+    np.testing.assert_allclose(x[:, :n].T, x_ref, rtol=2e-3, atol=2e-4)
+    assert not x[:, n:].any() and not x[:, n // 2].any()
+
+
+def test_diagonal_operand_is_the_xla_add(rng):
+    """Same elimination on the same numbers: adding d in the tile gives the
+    floats that `A + d·I` in XLA and the operand-free kernel give."""
+    n, k = 128, 16
+    G = rng.standard_normal((n, k, k)).astype(np.float32)
+    A = G @ G.transpose(0, 2, 1)
+    b = rng.standard_normal((n, k)).astype(np.float32)
+    d = rng.uniform(0.5, 8.0, n).astype(np.float32)
+    inside = cholesky_solve_lanes(
+        jnp.asarray(A.transpose(1, 2, 0)), jnp.asarray(b.T), jnp.asarray(d),
+        interpret=True)
+    outside = cholesky_solve_batched(
+        jnp.asarray(A) + jnp.asarray(d)[:, None, None] * jnp.eye(k),
+        jnp.asarray(b), interpret=True, layout="lane_major")
+    np.testing.assert_array_equal(np.asarray(inside).T, np.asarray(outside))
+
+
 def test_als_fit_with_pallas_solver_matches_default(rng, monkeypatch):
     from flink_ms_tpu.ops import als as A
     from flink_ms_tpu.parallel.mesh import make_mesh
@@ -88,6 +135,12 @@ _TPU_LOWERED = {
     # which goes through the tiled-w path with a ragged last tile
     "assemble_pallas.py": [(24, 50), (144, 50), (328, 64), (64728, 50)],
 }
+# (r, w, k) of the lane-major form at the extremes of als-ml20m.retrain:
+# the most rows, the middle of the user side, the widest whole bucket
+# (sub-blocks of 8), the first tiled one, and seven entities of 97,096
+# ratings (one lane tile, 95 w tiles); and rank 64
+_TPU_LOWERED_LANES = [(28505, 24, 50), (13236, 216, 50), (3695, 744, 50),
+                      (1935, 1120, 50), (7, 97096, 50), (4850, 328, 64)]
 
 
 def test_every_ops_pallas_kernel_has_a_lowering_case():
@@ -127,5 +180,36 @@ def test_assembly_kernel_lowers_for_tpu(w, k):
     ).trace(
         jax.ShapeDtypeStruct((r, w, k), jnp.float32),
         jax.ShapeDtypeStruct((r, w), jnp.float32),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("r,w,k", _TPU_LOWERED_LANES)
+def test_lane_major_assembly_kernel_lowers_for_tpu(r, w, k):
+    from flink_ms_tpu.ops.assemble_pallas import (
+        assemble_bucket_lanes, lane_tile_sizes)
+
+    assert (lane_tile_sizes(w, k)[1] < w) == (w > 1024)
+    lowered = jax.jit(
+        lambda y, t: assemble_bucket_lanes(
+            y, t, precision="highest", interpret=False)
+    ).trace(
+        jax.ShapeDtypeStruct((r, w, k), jnp.float32),
+        jax.ShapeDtypeStruct((r, w), jnp.float32),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    n = -(-r // 128) * 128
+    assert [tuple(o.shape) for o in lowered.out_info] == [(k, k, n), (k, n)]
+
+
+@pytest.mark.parametrize("k,n", [(50, 138496), (64, 4096)])
+def test_cholesky_kernel_with_diagonal_lowers_for_tpu(k, n):
+    """(50, 50, 138496): the user side of als-ml20m.retrain, padded."""
+    lowered = jax.jit(
+        lambda At, bt, d: cholesky_solve_lanes(At, bt, d, interpret=False)
+    ).trace(
+        jax.ShapeDtypeStruct((k, k, n), jnp.float32),
+        jax.ShapeDtypeStruct((k, n), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
     ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()

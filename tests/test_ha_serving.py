@@ -283,10 +283,13 @@ def test_failover_absorbs_dead_replica_with_zero_errors(tmp_path):
             keys = [f"{u}-U" for u in range(len(uf))]
             for key in keys:  # warm: stick to one replica
                 assert client.query_state(ALS_MODEL := ALS_STATE, key)
-            # crash replica 0's data plane only: its registry entry stays
-            # (pid is alive), so the client must discover deadness the
-            # hard way — refused connects — and fail over anyway
-            jobs[0].server.stop()
+            # crash the data plane of the replica the client sticks to
+            # (replica 0, unless the warm-up already failed over under
+            # load): its registry entry stays (pid is alive), so the client
+            # must discover deadness the hard way — refused connects — and
+            # fail over anyway
+            stuck_to = client._shards[0].prefer
+            next(j for j in jobs if j.port == stuck_to[1]).server.stop()
             errors = []
             for _ in range(3):
                 for key in keys:

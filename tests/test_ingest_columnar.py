@@ -324,9 +324,11 @@ def test_checkpoints_deferred_while_replaying(tmp_path, monkeypatch):
     try:
         assert _wait_until(lambda: job.ingest_rows >= 2000)
         assert job.checkpoints_deferred >= 1
-        # once drained, the wall-clock checkpoint goes through again
-        assert _wait_until(lambda: backend._snap is not None)
-        assert backend._snap[0] == journal.end_offset()
+        # once drained, the wall-clock checkpoint goes through again (an
+        # earlier one may have gone through mid-replay on a loaded host:
+        # deferral is bounded by CHECKPOINT_MAX_DEFER_INTERVALS)
+        assert _wait_until(lambda: backend._snap is not None
+                           and backend._snap[0] == journal.end_offset())
     finally:
         job.stop()
 
