@@ -54,6 +54,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.formats import SparseData
+from ..obs import metrics as obs_metrics
+from ..obs import tracing
 from ..parallel.mesh import BLOCK_AXIS, block_sharding, num_blocks
 
 
@@ -148,39 +150,41 @@ def prepare_svm_blocked(
 ) -> BlockedSVMProblem:
     """Vectorized re-layout: shuffle examples across K blocks, pad each row
     to the max nnz (static shapes for XLA)."""
-    n = data.n_examples
-    rows_pb = -(-n // n_blocks) if n else 1
-    lens = (data.indptr[1:] - data.indptr[:-1]).astype(np.int64)
-    L = max(int(lens.max()) if n else 1, 1)
+    with tracing.stage("svm.prepare"):
+        n = data.n_examples
+        rows_pb = -(-n // n_blocks) if n else 1
+        lens = (data.indptr[1:] - data.indptr[:-1]).astype(np.int64)
+        L = max(int(lens.max()) if n else 1, 1)
 
-    # padded row-major staging in original example order
-    mask = np.arange(L)[None, :] < lens[:, None]           # (n, L)
-    idx_rows = np.zeros((n, L), dtype=np.int32)
-    val_rows = np.zeros((n, L), dtype=dtype)
-    idx_rows[mask] = data.indices                          # CSR order
-    val_rows[mask] = data.values.astype(dtype)
+        # padded row-major staging in original example order
+        mask = np.arange(L)[None, :] < lens[:, None]           # (n, L)
+        idx_rows = np.zeros((n, L), dtype=np.int32)
+        val_rows = np.zeros((n, L), dtype=dtype)
+        idx_rows[mask] = data.indices                          # CSR order
+        val_rows[mask] = data.values.astype(dtype)
 
-    order = np.random.default_rng(seed).permutation(n)     # slot s <- example
-    idx = np.zeros((n_blocks * rows_pb, L), dtype=np.int32)
-    val = np.zeros((n_blocks * rows_pb, L), dtype=dtype)
-    label = np.zeros((n_blocks * rows_pb,), dtype=dtype)
-    idx[:n] = idx_rows[order]
-    val[:n] = val_rows[order]
-    signs = np.sign(data.labels[order]).astype(dtype)
-    label[:n] = np.where(signs == 0, 1.0, signs)           # labels must be +-1
-    sq_norm = np.sum(val.astype(np.float64) ** 2, axis=-1).astype(dtype)
-    # slot s -> (block s // rows_pb, row s % rows_pb): contiguous rows per
-    # block, matching the reference's partition-then-iterate layout
-    return BlockedSVMProblem(
-        n_blocks=n_blocks,
-        n_examples=n,
-        n_features=data.n_features,
-        rows_per_block=rows_pb,
-        idx=idx.reshape(n_blocks, rows_pb, L),
-        val=val.reshape(n_blocks, rows_pb, L),
-        label=label.reshape(n_blocks, rows_pb),
-        sq_norm=sq_norm.reshape(n_blocks, rows_pb),
-    )
+        # slot s <- example order[s]
+        order = np.random.default_rng(seed).permutation(n)
+        idx = np.zeros((n_blocks * rows_pb, L), dtype=np.int32)
+        val = np.zeros((n_blocks * rows_pb, L), dtype=dtype)
+        label = np.zeros((n_blocks * rows_pb,), dtype=dtype)
+        idx[:n] = idx_rows[order]
+        val[:n] = val_rows[order]
+        signs = np.sign(data.labels[order]).astype(dtype)
+        label[:n] = np.where(signs == 0, 1.0, signs)  # labels must be +-1
+        sq_norm = np.sum(val.astype(np.float64) ** 2, axis=-1).astype(dtype)
+        # slot s -> (block s // rows_pb, row s % rows_pb): contiguous rows per
+        # block, matching the reference's partition-then-iterate layout
+        return BlockedSVMProblem(
+            n_blocks=n_blocks,
+            n_examples=n,
+            n_features=data.n_features,
+            rows_per_block=rows_pb,
+            idx=idx.reshape(n_blocks, rows_pb, L),
+            val=val.reshape(n_blocks, rows_pb, L),
+            label=label.reshape(n_blocks, rows_pb),
+            sq_norm=sq_norm.reshape(n_blocks, rows_pb),
+        )
 
 
 def _round_up(x: int, m: int) -> int:
@@ -195,15 +199,14 @@ def _dw_choice() -> str:
     segment-sum.  "presorted": store val ALREADY feature-sorted at prepare
     time, so the round end multiplies the streamed sorted values by a
     gather from only the tiny (C·H) Δα table and segment-sums — no
-    runtime permutation of the big array.  At RCV1 scale (49M nnz;
-    2026-07-31, earlier installation, not reproduced): direct 0.80
-    s/round, presorted 1.33, sorted 1.60 — XLA
-    lowers even a sorted segment-sum to the same serialized scatter, so
-    the rewrites only add gather cost.  "auto" (default) = direct
-    everywhere; the alternatives remain selectable for future
-    lowering/hardware changes.  (The boundary cost is two 49M-scalar
-    irregular ops that shrink linearly with device count on a real
-    mesh.)"""
+    runtime permutation of the big array.  "auto" (default) = direct
+    everywhere.  Measured on one TPU v5e in the benchmark cell
+    ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5; 8192 chains x 83 rows padded
+    to 256, 174M entries of which 49.6M are real): the direct scatter-add
+    takes 1.42 s of a 2.78 s round (8.2 ns an entry, pads included) and
+    the round-start gather ``take(w, idx)`` 1.31 s; the steps between them
+    0.04 s.  "sorted" and "presorted" have no reading on that cell: it is
+    where they are to be judged (ROADMAP S5, D5)."""
     choice = os.environ.get("FLINK_MS_SVM_DW", "auto")
     if choice not in ("auto", "direct", "sorted", "presorted"):
         # a typo'd knob must not silently fall through to the direct
@@ -220,18 +223,22 @@ def _dw_choice() -> str:
 def _step_choice() -> str:
     """FLINK_MS_SVM_STEP: how the Gram engine's SDCA step touches chain
     state.  "dynamic": per-chain dynamic gather of the Gram row + scatter-
-    add into alpha — O(1) memory touched per step, but batched per-chain
-    gathers/scatters and a per-step threefry chain serialize inside the
-    TPU fori_loop (round 3 measured 9.3 ms/step on v5e for ~µs of math).
-    "onehot": hoist the (C, H) step-index draw out of the loop and express
-    every read/write as a dense mask/one-hot contraction — pure VPU/MXU
-    work, bit-identical results (products are exact 0s and 1s).  Neutral
-    at RCV1 scale (0.804 vs 0.799 s/round, 2026-07-31, earlier
-    installation, not reproduced: the round BOUNDARY dominates
-    single-chip, see _dw_choice), so "auto" = dynamic
-    everywhere; onehot stays selectable for meshes where the boundary
-    shrinks and per-step latency resurfaces."""
+    add into alpha — O(1) memory touched per step, with a threefry draw
+    inside the fori_loop.  "onehot": hoist the (C, H) step-index draw out
+    of the loop and express every read/write as a dense mask/one-hot
+    contraction — pure VPU/MXU work, bit-identical results (products are
+    exact 0s and 1s).  "auto" = dynamic everywhere.  Measured on one TPU
+    v5e in ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5): the 83 dynamic steps
+    of 8192 chains take 37 ms of a 2.78 s round, 0.45 ms a step; the
+    round's boundary (gather and scatter-add, see _dw_choice) is the other
+    98.5%.  "onehot" has no reading on that cell; it stays selectable for
+    meshes where the boundary shrinks (ROADMAP D5)."""
     choice = os.environ.get("FLINK_MS_SVM_STEP", "auto")
+    if choice not in ("auto", "dynamic", "onehot"):
+        # as _dw_choice: a typo must not run the dynamic step in silence
+        raise ValueError(
+            f"FLINK_MS_SVM_STEP={choice!r} must be auto|dynamic|onehot"
+        )
     if choice == "auto":
         return "dynamic"
     return choice
@@ -402,7 +409,8 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                               precision="highest",
                               preferred_element_type=dtype)
 
-        return jax.lax.map(one, (idx_s, val_s), batch_size=B)
+        with jax.named_scope("svm.gram"):
+            return jax.lax.map(one, (idx_s, val_s), batch_size=B)
 
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
                   gram=None, dw_a=None, dw_b=None, dw_c=None):
@@ -426,53 +434,66 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                 )
             )(chain_ids)
 
+        # The round's parts carry named scopes (svm.margins, svm.steps,
+        # svm.dw, svm.combine) so that a profile splits a round by them;
+        # the scatter engine computes its margins inside each step, so its
+        # rounds have no svm.margins.
         def outer(it, carry):
             w, alpha = carry
-            keys = chain_keys(it)
-            dw, dalpha = jax.vmap(
-                chain_sdca, in_axes=(None, 0, 0, 0, 0, 0, 0)
-            )(w, idx, val, label, sq_norm, alpha, keys)
-            w = w + gamma * jax.lax.psum(jnp.sum(dw, axis=0), BLOCK_AXIS)
-            alpha = alpha + gamma * dalpha
+            with jax.named_scope("svm.steps"):
+                keys = chain_keys(it)
+                dw, dalpha = jax.vmap(
+                    chain_sdca, in_axes=(None, 0, 0, 0, 0, 0, 0)
+                )(w, idx, val, label, sq_norm, alpha, keys)
+            with jax.named_scope("svm.dw"):
+                dw = jnp.sum(dw, axis=0)
+            with jax.named_scope("svm.combine"):
+                w = w + gamma * jax.lax.psum(dw, BLOCK_AXIS)
+                alpha = alpha + gamma * dalpha
             return w, alpha
 
         def outer_gram(it, carry):
             w, alpha = carry
-            keys = chain_keys(it)
             # round-start margins for every row: ONE (C, H, L) gather of w
             # HIGHEST: the scatter path computes these margins as full-f32
             # elementwise work; a default-precision (bf16-pass) contraction
             # here would seed every SDCA step with ~1e-3 relative error and
             # break the documented cross-engine equivalence on TPU.
-            wx0 = jnp.einsum("chl,chl->ch", jnp.take(w, idx, axis=0),
-                             val, precision="highest",
-                             preferred_element_type=dtype)
-            dalpha = jax.vmap(sdca_gram)(
-                wx0, gram, label, sq_norm, alpha, keys
-            )
+            with jax.named_scope("svm.margins"):
+                wx0 = jnp.einsum("chl,chl->ch", jnp.take(w, idx, axis=0),
+                                 val, precision="highest",
+                                 preferred_element_type=dtype)
+            with jax.named_scope("svm.steps"):
+                keys = chain_keys(it)
+                dalpha = jax.vmap(sdca_gram)(
+                    wx0, gram, label, sq_norm, alpha, keys
+                )
             # this device's Δw = Σ_chains X_cᵀ Δα_c / λn: ONE reduction
             # per round (the scatter engine pays one per STEP per chain).
             # Mode trade-offs in _dw_choice's docstring.
-            if dw_mode == "presorted":
-                # val is stored feature-sorted (dw_a) at prepare time, so
-                # the only runtime gather reads the tiny (C·H) Δα table
-                dw = jax.ops.segment_sum(
-                    dw_a[0] * dalpha.reshape(-1)[dw_c[0]], dw_b[0],
-                    num_segments=d, indices_are_sorted=True,
-                ) / lam_n
-            elif dw_mode == "sorted":
-                contrib = (val * dalpha[:, :, None]).reshape(-1)
-                dw = jax.ops.segment_sum(
-                    contrib[dw_a[0]], dw_b[0], num_segments=d,
-                    indices_are_sorted=True,
-                ) / lam_n
-            else:
-                contrib = (val * dalpha[:, :, None]).reshape(-1)
-                dw = jnp.zeros((d,), dtype).at[idx.reshape(-1)].add(
-                    contrib
-                ) / lam_n
-            w = w + gamma * jax.lax.psum(dw, BLOCK_AXIS)
-            alpha = alpha + gamma * dalpha
+            with jax.named_scope("svm.dw"):
+                if dw_mode == "presorted":
+                    # val is stored feature-sorted (dw_a) at prepare time,
+                    # so the only runtime gather reads the tiny (C·H) Δα
+                    # table
+                    dw = jax.ops.segment_sum(
+                        dw_a[0] * dalpha.reshape(-1)[dw_c[0]], dw_b[0],
+                        num_segments=d, indices_are_sorted=True,
+                    ) / lam_n
+                elif dw_mode == "sorted":
+                    contrib = (val * dalpha[:, :, None]).reshape(-1)
+                    dw = jax.ops.segment_sum(
+                        contrib[dw_a[0]], dw_b[0], num_segments=d,
+                        indices_are_sorted=True,
+                    ) / lam_n
+                else:
+                    contrib = (val * dalpha[:, :, None]).reshape(-1)
+                    dw = jnp.zeros((d,), dtype).at[idx.reshape(-1)].add(
+                        contrib
+                    ) / lam_n
+            with jax.named_scope("svm.combine"):
+                w = w + gamma * jax.lax.psum(dw, BLOCK_AXIS)
+                alpha = alpha + gamma * dalpha
             return w, alpha
 
         body = outer_gram if inner == "gram" else outer
@@ -501,9 +522,16 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         as args[0]/args[5]) to continue EXACTLY where a prior call
         stopped — absolute-round RNG makes chained segments bit-identical
         to one long fit."""
-        lo = jnp.asarray(start, jnp.int32)
-        span = jnp.stack([lo, lo + jnp.asarray(rounds, jnp.int32)])
-        return jfit(span, *args)
+        # the stage is the host's side of the call: the rounds are
+        # enqueued, not awaited (their device time lies under the svm.*
+        # scopes of the same profile)
+        with tracing.stage("svm.fit"):
+            lo = jnp.asarray(start, jnp.int32)
+            span = jnp.stack([lo, lo + jnp.asarray(rounds, jnp.int32)])
+            out = jfit(span, *args)
+        obs_metrics.get_registry().counter("tpums_svm_rounds_total").inc(
+            int(rounds))
+        return out
     # the Gram build is hoisted out of the fit: compile_svm_fit runs it
     # once and ships the (Kp, H, H) tensor as a device arg, so repeat fit
     # calls (benchmark loops, retrain cycles) don't pay it again
@@ -550,6 +578,23 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     return fn
 
 
+def _set_layout_gauges(problem: BlockedSVMProblem, Kp: int, D: int,
+                       gram_bytes: int) -> None:
+    """What the installed layout holds, for whoever reads the registry:
+    row slots (pad rows and pad blocks included), the width every row is
+    padded to, the entries of the padded arrays that carry no value, the
+    Gram tensor's bytes (0 on the scatter engine), chains per device."""
+    reg = obs_metrics.get_registry()
+    slots = Kp * problem.rows_per_block
+    width = problem.idx.shape[-1]
+    reg.gauge("tpums_svm_rows").set(slots)
+    reg.gauge("tpums_svm_row_width").set(width)
+    reg.gauge("tpums_svm_pad_entries").set(
+        slots * width - int(np.count_nonzero(problem.val)))
+    reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
+    reg.gauge("tpums_svm_chains_per_device").set(Kp // D)
+
+
 def compile_svm_fit(
     problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh
 ):
@@ -574,24 +619,34 @@ def compile_svm_fit(
     shard3 = block_sharding(mesh, rank=3)
     shard2 = block_sharding(mesh, rank=2)
     rep = NamedSharding(mesh, P())
-    dev_args = [
-        jax.device_put(w0, rep),
-        jax.device_put(jnp.asarray(pad_blocks(problem.idx)), shard3),
-        jax.device_put(
-            jnp.asarray(pad_blocks(problem.val).astype(dtype)), shard3
-        ),
-        jax.device_put(
-            jnp.asarray(pad_blocks(problem.label).astype(dtype)), shard2
-        ),
-        jax.device_put(
-            jnp.asarray(pad_blocks(problem.sq_norm).astype(dtype)), shard2
-        ),
-        jax.device_put(alpha0, shard2),
-        jax.device_put(jnp.asarray([config.seed], dtype=jnp.uint32), rep),
-    ]
+    # the two stages end when the device has what they made, so that a
+    # profile shows the transfer and the Gram build, not their dispatch
+    with tracing.stage("svm.place"):
+        dev_args = jax.block_until_ready([
+            jax.device_put(w0, rep),
+            jax.device_put(jnp.asarray(pad_blocks(problem.idx)), shard3),
+            jax.device_put(
+                jnp.asarray(pad_blocks(problem.val).astype(dtype)), shard3
+            ),
+            jax.device_put(
+                jnp.asarray(pad_blocks(problem.label).astype(dtype)), shard2
+            ),
+            jax.device_put(
+                jnp.asarray(pad_blocks(problem.sq_norm).astype(dtype)),
+                shard2,
+            ),
+            jax.device_put(alpha0, shard2),
+            jax.device_put(
+                jnp.asarray([config.seed], dtype=jnp.uint32), rep
+            ),
+        ])
     fit, gram_fn, dw_mode = _cached_fit(problem, config, mesh)
     if gram_fn is not None:
-        dev_args.append(gram_fn(dev_args[1], dev_args[2]))
+        with tracing.stage("svm.gram_build"):
+            dev_args.append(jax.block_until_ready(
+                gram_fn(dev_args[1], dev_args[2])
+            ))
+    _set_layout_gauges(problem, Kp, D, dev_args[7].nbytes if gram_fn else 0)
     if dw_mode in ("sorted", "presorted"):
         # per-device feature-sorted layout of the flattened (C, H, L)
         # entries (host-side, once per layout).  sorted ships (perm, ids):
@@ -642,7 +697,7 @@ def svm_fit(
     if problem is None:
         problem = prepare_svm_blocked(data, D, seed=config.seed)
     fit, dev_args = compile_svm_fit(problem, config, mesh)
-    w, _alpha = fit(jnp.asarray(config.iterations, jnp.int32), *dev_args)
+    w, _alpha = fit(config.iterations, *dev_args)
     from ..parallel.distributed import to_host_array
 
     return SVMModel(weights=to_host_array(w).astype(np.float64))

@@ -1,0 +1,252 @@
+"""The CoCoA program against the benchmark's plain reference
+(`benchmark/reference_cocoa.py`: numpy, float64, CSR, nothing of the padded
+arrays, the Gram matrix or the dw modes), on rows of unequal length; the
+synthetic documents of `benchmark/synth_cocoa.py`; and what PR 31 added to
+`ops/svm.py` for whoever profiles it: named scopes, gauges, a counter."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_cocoa as ref
+from benchmark import synth_cocoa
+from benchmark.drivers.cocoa_rounds import by_example, slots_of, step_draws
+from flink_ms_tpu.core.formats import SparseData
+from flink_ms_tpu.obs import metrics as obs_metrics
+from flink_ms_tpu.ops import svm
+from flink_ms_tpu.ops.svm import SVMConfig, compile_svm_fit, prepare_svm_blocked
+from flink_ms_tpu.parallel.mesh import make_mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEED = 11
+LAM = 1e-3
+# float32 state against float64: sums of at most a few hundred products a
+# row and three rounds of steps; bfloat16 (8 bits of mantissa) misses by 100x
+TOL = 2e-5
+ROUND_SCOPES = ("svm.margins", "svm.steps", "svm.dw", "svm.combine")
+
+
+def uneven_documents(n=61, d=300, long_row=120, seed=3):
+    """Unit-norm rows of 1..9 distinct features, one of `long_row`, one
+    empty: what `prepare_svm_blocked` pads to the longest."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(1, 10, n)
+    lens[7], lens[20] = long_row, 0
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    indices = np.concatenate([rng.choice(d, l, replace=False) for l in lens])
+    values = 0.1 + rng.random(len(indices))
+    norms = np.sqrt(np.add.reduceat(values ** 2, indptr[:-1][lens > 0]))
+    values /= np.repeat(norms, lens[lens > 0])
+    labels = np.where(rng.random(n) < 0.47, 1.0, -1.0)
+    return SparseData(labels=labels, indptr=indptr, indices=indices,
+                      values=values, n_features=d)
+
+
+def run_program(data, chains, inner, mode, rounds, dtype=jnp.float32):
+    problem = prepare_svm_blocked(data, chains, seed=SEED)
+    cfg = SVMConfig(local_iterations=problem.rows_per_block,
+                    regularization=LAM, seed=SEED, mode=mode, inner=inner,
+                    dtype=dtype)
+    fit, args = compile_svm_fit(problem, cfg, make_mesh(min(chains, 4)))
+    w, alpha = fit(rounds, *args)
+    return (problem, np.asarray(w).astype(np.float64),
+            np.asarray(alpha).astype(np.float64))
+
+
+def run_reference(data, chains, mode, rounds):
+    n = data.n_examples
+    rows = -(-n // chains)
+    slots = slots_of(SEED, n, chains, rows)
+    w, alpha = np.zeros(data.n_features), np.zeros(n)
+    for r in range(rounds):
+        w, alpha = ref.cocoa_round(
+            data.indptr, data.indices, data.values, data.labels, slots,
+            step_draws(SEED, chains, r, rows, rows), w, alpha, LAM, mode=mode)
+    return slots, w, alpha
+
+
+@pytest.mark.parametrize("rounds", [1, 3])
+@pytest.mark.parametrize("mode", ["avg", "add"])
+@pytest.mark.parametrize("inner", ["gram", "scatter"])
+@pytest.mark.parametrize("chains", [1, 4, 16])
+def test_program_agrees_with_the_plain_reference(chains, inner, mode, rounds):
+    data = uneven_documents()
+    problem, w, alpha = run_program(data, chains, inner, mode, rounds)
+    assert problem.idx.shape[-1] == 120  # every row padded to the longest
+    slots, w_ref, a_ref = run_reference(data, chains, mode, rounds)
+    n = data.n_examples
+    a = by_example(alpha, slots, n)
+    assert ref.rel_err(w, w_ref) < TOL
+    assert ref.rel_err(a, a_ref) < TOL
+    # the primal-dual relation both combinations keep, and the box
+    primal = ref.primal_of(data.indptr, data.indices, data.values, a, LAM,
+                           data.n_features)
+    assert ref.rel_err(w, primal) < TOL
+    ya = data.labels * a
+    assert ya.min() >= -1e-7 and ya.max() <= 1 + 1e-6
+    assert a[20] == 0.0 and np.abs(a).max() > 0  # the empty row never moves
+
+
+@pytest.mark.parametrize("inner", ["gram", "scatter"])
+def test_bfloat16_state_misses_the_same_tolerance(inner):
+    data = uneven_documents()
+    _, w, alpha = run_program(data, 4, inner, "avg", 1, dtype=jnp.bfloat16)
+    slots, w_ref, a_ref = run_reference(data, 4, "avg", 1)
+    assert ref.rel_err(w, w_ref) > 20 * TOL
+    assert ref.rel_err(by_example(alpha, slots, data.n_examples), a_ref) > 20 * TOL
+
+
+@pytest.mark.parametrize("dw", ["direct", "sorted", "presorted"])
+def test_every_dw_mode_agrees_and_carries_its_scope(dw, monkeypatch):
+    monkeypatch.setenv("FLINK_MS_SVM_DW", dw)
+    data = uneven_documents()
+    problem, w, _ = run_program(data, 4, "gram", "avg", 2)
+    _, w_ref, _ = run_reference(data, 4, "avg", 2)
+    assert ref.rel_err(w, w_ref) < TOL
+    assert "svm.dw" in lowered_round(problem, "gram")
+
+
+def lowered_round(problem, inner):
+    cfg = SVMConfig(local_iterations=problem.rows_per_block,
+                    regularization=LAM, seed=SEED, inner=inner)
+    fit, args = compile_svm_fit(problem, cfg, make_mesh(1))
+    return jax.jit(lambda *a: fit(1, *a)).lower(*args).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    problem = prepare_svm_blocked(uneven_documents(), 4, seed=SEED)
+    return {inner: lowered_round(problem, inner) for inner in ("gram", "scatter")}
+
+
+@pytest.mark.parametrize("inner, scope", [
+    *(("gram", s) for s in ROUND_SCOPES),
+    *(("scatter", s) for s in ROUND_SCOPES[1:])])
+def test_the_round_carries_its_named_scopes(lowered, inner, scope):
+    assert scope in lowered[inner]
+
+
+def test_the_scatter_engine_has_no_margins_scope(lowered):
+    # its margins are computed inside each step
+    assert "svm.margins" not in lowered["scatter"]
+
+
+def test_the_gram_build_carries_its_scope():
+    problem = prepare_svm_blocked(uneven_documents(), 4, seed=SEED)
+    cfg = SVMConfig(local_iterations=problem.rows_per_block, inner="gram")
+    _, gram_fn, _ = svm._cached_fit(problem, cfg, make_mesh(1))
+    text = gram_fn.lower(jnp.asarray(problem.idx), jnp.asarray(problem.val)
+                         ).as_text(debug_info=True)
+    assert "svm.gram" in text
+
+
+def gauges():
+    return {g["name"]: g["value"]
+            for g in obs_metrics.get_registry().snapshot()["gauges"]
+            if g["name"].startswith("tpums_svm_") and not g["labels"]}
+
+
+@pytest.mark.parametrize("inner, chains, devices", [
+    ("gram", 6, 4), ("scatter", 6, 4), ("gram", 3, 1)])
+def test_gauges_and_round_counter_read_what_the_layout_implies(inner, chains, devices):
+    data = uneven_documents()
+    problem = prepare_svm_blocked(data, chains, seed=SEED)
+    cfg = SVMConfig(local_iterations=5, regularization=LAM, inner=inner)
+    fit, args = compile_svm_fit(problem, cfg, make_mesh(devices))
+    padded_chains = -(-chains // devices) * devices  # empty chains fill the mesh
+    rows = problem.rows_per_block
+    got = gauges()
+    assert got["tpums_svm_rows"] == padded_chains * rows
+    assert got["tpums_svm_row_width"] == 120
+    assert got["tpums_svm_pad_entries"] == (
+        padded_chains * rows * 120 - len(data.indices))
+    assert got["tpums_svm_gram_bytes"] == (
+        padded_chains * rows * rows * 4 if inner == "gram" else 0)
+    assert got["tpums_svm_chains_per_device"] == padded_chains // devices
+    counter = obs_metrics.get_registry().counter("tpums_svm_rounds_total")
+    before = counter.value
+    state = fit(2, *args)
+    fit(jnp.asarray(3, jnp.int32), state[0], *args[1:5], state[1], *args[6:],
+        start=2)
+    assert counter.value - before == 5
+
+
+@pytest.mark.parametrize("choice, resolved", [
+    ("auto", "dynamic"), ("dynamic", "dynamic"), ("onehot", "onehot")])
+def test_step_knob_accepts_its_three_values(choice, resolved, monkeypatch):
+    monkeypatch.setenv("FLINK_MS_SVM_STEP", choice)
+    assert svm._step_choice() == resolved
+
+
+@pytest.mark.parametrize("typo", ["one-hot", "Dynamic", ""])
+def test_step_knob_rejects_anything_else(typo, monkeypatch):
+    monkeypatch.setenv("FLINK_MS_SVM_STEP", typo)
+    with pytest.raises(ValueError, match="FLINK_MS_SVM_STEP"):
+        svm._step_choice()
+
+
+# -- the synthetic documents ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_cfg():
+    with open(os.path.join(REPO, "benchmark", "tests", "tiny-cocoa",
+                           "rcv1-tiny.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def two_seeds(tiny_cfg):
+    return [synth_cocoa.cocoa_problem(tiny_cfg, seed) for seed in (5, 3000000019)]
+
+
+def test_row_lengths_are_the_configurations_not_the_seeds(tiny_cfg, two_seeds):
+    lens = synth_cocoa.row_lengths(tiny_cfg)
+    a = tiny_cfg["assumed"]
+    assert lens.sum() == tiny_cfg["nnz"]
+    assert lens.min() >= a["row_length_min"] and lens.max() == a["row_length_clip"]
+    for indptr, indices, _, _ in two_seeds:
+        assert len(indices) == tiny_cfg["nnz"] == indptr[-1]
+        assert np.array_equal(np.sort(np.diff(indptr)), np.sort(lens))
+    assert not np.array_equal(np.diff(two_seeds[0][0]), np.diff(two_seeds[1][0]))
+
+
+def test_two_seeds_give_one_padded_shape(tiny_cfg, two_seeds):
+    shapes = set()
+    for indptr, indices, values, labels in two_seeds:
+        data = SparseData(labels=labels, indptr=indptr, indices=indices,
+                          values=values, n_features=tiny_cfg["features"])
+        shapes.add(prepare_svm_blocked(data, tiny_cfg["blocks"]).idx.shape)
+    assert shapes == {(tiny_cfg["blocks"], tiny_cfg["local_iterations"],
+                       tiny_cfg["assumed"]["row_length_clip"])}
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_rows_are_unit_norm_with_distinct_features(tiny_cfg, two_seeds, which):
+    indptr, indices, values, labels = two_seeds[which]
+    assert values.dtype == np.float32 and values.min() > 0
+    sq = np.add.reduceat(values.astype(np.float64) ** 2, indptr[:-1])
+    np.testing.assert_allclose(sq, 1.0, atol=1e-6)
+    row_of = np.repeat(np.arange(tiny_cfg["rows"]), np.diff(indptr))
+    keys = row_of * tiny_cfg["features"] + indices
+    assert len(np.unique(keys)) == len(keys)
+    assert 0 <= indices.min() and indices.max() < tiny_cfg["features"]
+    assert set(np.unique(labels)) == {-1.0, 1.0}
+    assert abs((labels > 0).mean() - tiny_cfg["assumed"]["positive_share"]) < 2e-3
+
+
+def test_the_same_seed_gives_the_same_documents(tiny_cfg, two_seeds):
+    again = synth_cocoa.cocoa_problem(tiny_cfg, 5)
+    for a, b in zip(two_seeds[0], again):
+        assert np.array_equal(a, b)
+
+
+def test_repeated_ranks_move_to_the_next_free_one():
+    ranks = np.array([0, 0, 0, 5, 5, 9, 9, 9, 3, 3], np.int64)
+    row_of = np.array([0, 0, 0, 0, 0, 0, 0, 0, 1, 1], np.int64)
+    pos = np.array([0, 1, 2, 3, 4, 5, 6, 7, 0, 1], np.int64)
+    got = synth_cocoa._distinct_ranks(ranks, row_of, pos, 10)
+    assert got.tolist() == [0, 1, 2, 5, 6, 7, 8, 9, 3, 4]
