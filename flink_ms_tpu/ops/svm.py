@@ -36,6 +36,19 @@ The whole fit is one XLA program with a *dynamic* outer-round count
 (``fori_loop`` with a traced bound), so one compiled executable serves any
 ``--iteration`` value — benchmarks time extra rounds without recompiling.
 
+Two engines run the local steps (``SVMConfig.inner``).  The scatter engine
+indexes one padded row per chain per step.  The Gram engine touches the
+weight vector twice a round, one gather for the round-start margins and one
+scatter-add for Δw = XᵀΔα, and both stream a length-bucketed copy of the rows
+(``_bucket_rows``: each row padded to the next width of a short ladder, not
+to the longest row), because their time is their stored entries times 7 ns
+whatever an entry holds.  Measured on one TPU v5e in the benchmark cell
+``rcv1-cocoa.cocoa-rounds`` (PERF.md §5, PR 32; 8192 chains x 83 rows,
+49.6M entries stored as 55.5M in 13 buckets where the rectangle padded to
+256 held 174M): a round takes 0.80 s, the gather 0.38, the scatter-add
+0.38, the 83 steps between them 0.037 (2.78, 1.31, 1.42, 0.037 on the
+rectangle).
+
 Surfaced knobs follow FlinkML's parameter set: Blocks, Iterations,
 LocalIterations, Regularization, Stepsize, Seed [dep]; ThresholdValue /
 OutputDecisionFunction live client-side (SVMPredict.java:33-34,80-86).
@@ -133,6 +146,13 @@ class BlockedSVMProblem:
 
     Padding rows have label 0 and empty features; the SDCA step masks them
     (zero row norm => zero update), so they never affect the solution.
+
+    Who reads what: the scatter engine indexes ``idx`` / ``val`` by row
+    inside every step, so the padded rectangles are its device operands;
+    the Gram engine reads them once, chain by chain, to build its Gram
+    tensor, and runs its rounds over a length-bucketed copy of the rows
+    that ``compile_svm_fit`` cuts out of them by ``row_len``
+    (``_bucket_rows``).  Both read ``label`` and ``sq_norm``.
     """
 
     n_blocks: int
@@ -143,6 +163,8 @@ class BlockedSVMProblem:
     val: np.ndarray      # (K, rows_pb, L) values, 0 where padded
     label: np.ndarray    # (K, rows_pb) +-1, 0 for padding rows
     sq_norm: np.ndarray  # (K, rows_pb) ||x_j||^2
+    row_len: np.ndarray  # (K, rows_pb) int32 entries stored in the row's
+    #                      first positions, 0 for padding rows
 
 
 def prepare_svm_blocked(
@@ -168,8 +190,10 @@ def prepare_svm_blocked(
         idx = np.zeros((n_blocks * rows_pb, L), dtype=np.int32)
         val = np.zeros((n_blocks * rows_pb, L), dtype=dtype)
         label = np.zeros((n_blocks * rows_pb,), dtype=dtype)
+        row_len = np.zeros((n_blocks * rows_pb,), dtype=np.int32)
         idx[:n] = idx_rows[order]
         val[:n] = val_rows[order]
+        row_len[:n] = lens[order]
         signs = np.sign(data.labels[order]).astype(dtype)
         label[:n] = np.where(signs == 0, 1.0, signs)  # labels must be +-1
         sq_norm = np.sum(val.astype(np.float64) ** 2, axis=-1).astype(dtype)
@@ -184,6 +208,7 @@ def prepare_svm_blocked(
             val=val.reshape(n_blocks, rows_pb, L),
             label=label.reshape(n_blocks, rows_pb),
             sq_norm=sq_norm.reshape(n_blocks, rows_pb),
+            row_len=row_len.reshape(n_blocks, rows_pb),
         )
 
 
@@ -191,22 +216,111 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _pad_blocks(a: np.ndarray, Kp: int) -> np.ndarray:
+    """Empty blocks appended so that the K blocks shard evenly: an empty
+    chain makes zero deltas, and the combination scales by the real K."""
+    if Kp == a.shape[0]:
+        return a
+    return np.pad(a, [(0, Kp - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+
+
+# The Gram engine's rounds stream a length-bucketed copy of the rows
+# (``_bucket_rows``): a geometric ladder of widths from the shortest row to
+# the longest, each a multiple of _BUCKET_STEP, a row stored at the first
+# width that holds it.  The ratio bounds the padding inside a bucket at a
+# quarter of a row (a tenth of the entries on log-normal document lengths);
+# the cap bounds what a round traces: a wider spread of lengths widens the
+# ratio until the ladder fits.  Rows of one length get one bucket of that
+# width, which is the padded rectangle itself.
+_BUCKET_RATIO = 1.25
+_BUCKET_CAP = 16
+_BUCKET_STEP = 8
+
+
+def _bucket_widths(lo: int, hi: int) -> list:
+    """The ladder of stored widths for rows of ``lo`` .. ``hi`` entries."""
+    ratio = _BUCKET_RATIO
+    while True:
+        widths = [min(_round_up(lo, _BUCKET_STEP), hi)]
+        while widths[-1] < hi:
+            widths.append(min(hi, _round_up(
+                int(np.ceil(widths[-1] * ratio)), _BUCKET_STEP)))
+        if len(widths) <= _BUCKET_CAP:
+            return widths
+        ratio *= 1.1
+
+
+def _bucket_plan(row_len: np.ndarray, D: int):
+    """-> (widths, rows, bucket_of): the static shape of a device's bucketed
+    rows, the same on every device.  ``widths[b]`` entries are stored for
+    each of the ``rows[b]`` rows of bucket b (the largest device's count);
+    ``bucket_of`` (D, slots per device) names the bucket of every row slot,
+    -1 for a row without entries (pad rows, empty chains, empty documents),
+    which no bucket holds.  Buckets no row falls in are dropped; a layout
+    without any entry keeps one pad row so that the round's operands are
+    never empty."""
+    lens = row_len.reshape(D, -1)
+    real = lens[lens > 0]
+    if not real.size:
+        return (1,), (1,), np.full(lens.shape, -1)
+    ladder = np.asarray(_bucket_widths(int(real.min()), int(real.max())))
+    bucket_of = np.where(lens > 0, np.searchsorted(ladder, lens), -1)
+    counts = np.stack([(bucket_of == b).sum(axis=1)
+                       for b in range(len(ladder))]).max(axis=1)
+    keep = np.flatnonzero(counts)
+    renumber = np.full(len(ladder) + 1, -1)
+    renumber[keep] = np.arange(len(keep))
+    return (tuple(int(w) for w in ladder[keep]),
+            tuple(int(c) for c in counts[keep]),
+            renumber[bucket_of])
+
+
+def _bucket_rows(idx: np.ndarray, val: np.ndarray, plan):
+    """The rows' entries cut out of the padded (Kp, H, L) rectangles into
+    the plan's buckets -> (ids, val, slot): per bucket b one
+    ``(D, widths[b], rows[b])`` pair, entry-major (a row's entries down a
+    column, the rows along the lanes), and ``slot`` (D, sum(rows)), the
+    device-local flat slot ``chain * H + row`` of every bucket row, bucket
+    after bucket.  Rows beyond a device's own count are pads: id 0, value
+    0, slot 0, so they add exact zeros to feature 0 and to slot 0's
+    margin."""
+    widths, rows, bucket_of = plan
+    D = bucket_of.shape[0]
+    idx = idx.reshape(D, bucket_of.shape[1], -1)
+    val = val.reshape(idx.shape)
+    ids_out, val_out = [], []
+    slot_out = np.zeros((D, sum(rows)), np.int32)
+    at = 0
+    for b, (width, n_rows) in enumerate(zip(widths, rows)):
+        ids_b = np.zeros((D, width, n_rows), np.int32)
+        val_b = np.zeros((D, width, n_rows), val.dtype)
+        for dev in range(D):
+            slots = np.flatnonzero(bucket_of[dev] == b)
+            slot_out[dev, at:at + len(slots)] = slots
+            ids_b[dev, :, :len(slots)] = idx[dev, slots, :width].T
+            val_b[dev, :, :len(slots)] = val[dev, slots, :width].T
+        ids_out.append(ids_b)
+        val_out.append(val_b)
+        at += n_rows
+    return tuple(ids_out), tuple(val_out), slot_out
+
+
 def _dw_choice() -> str:
     """FLINK_MS_SVM_DW: how the Gram engine applies the round-end
-    Δw = Xᵀ Δα update.  "direct": one unsorted scatter-add over all
-    (C·H·L) entries.  "sorted": gather the row-major contribution array
-    through a precomputed feature-sorted permutation, then a sorted
-    segment-sum.  "presorted": store val ALREADY feature-sorted at prepare
-    time, so the round end multiplies the streamed sorted values by a
-    gather from only the tiny (C·H) Δα table and segment-sums — no
-    runtime permutation of the big array.  "auto" (default) = direct
-    everywhere.  Measured on one TPU v5e in the benchmark cell
-    ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5; 8192 chains x 83 rows padded
-    to 256, 174M entries of which 49.6M are real): the direct scatter-add
-    takes 1.42 s of a 2.78 s round (8.2 ns an entry, pads included) and
-    the round-start gather ``take(w, idx)`` 1.31 s; the steps between them
-    0.04 s.  "sorted" and "presorted" have no reading on that cell: it is
-    where they are to be judged (ROADMAP S5, D5)."""
+    Δw = Xᵀ Δα update over its bucketed rows (``_bucket_rows``).
+    "direct": an unsorted scatter-add a bucket, over all stored entries.
+    "sorted": gather the flattened contributions through a precomputed
+    feature-sorted permutation, then a sorted segment-sum.  "presorted":
+    store the values ALREADY feature-sorted at set-up, so the round end
+    multiplies the streamed sorted values by a gather from only the tiny
+    (C·H) Δα table and segment-sums — no runtime permutation of the big
+    array.  "auto" (default) = direct everywhere.  Measured on one TPU
+    v5e in the benchmark cell ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5,
+    PR 32; 8192 chains x 83 rows, 49.6M entries stored as 55.5M): the
+    direct scatter-add takes 0.38 s of a 0.80 s round (6.9 ns a stored
+    entry) and the round-start gather 0.38 s; the steps between them
+    0.04 s.  "presorted" read 0.91 s there and "sorted" 1.22 s, rounds
+    of 1.32 and 1.64 s: both lose to the direct form (ROADMAP D5)."""
     choice = os.environ.get("FLINK_MS_SVM_DW", "auto")
     if choice not in ("auto", "direct", "sorted", "presorted"):
         # a typo'd knob must not silently fall through to the direct
@@ -228,11 +342,12 @@ def _step_choice() -> str:
     of the loop and express every read/write as a dense mask/one-hot
     contraction — pure VPU/MXU work, bit-identical results (products are
     exact 0s and 1s).  "auto" = dynamic everywhere.  Measured on one TPU
-    v5e in ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5): the 83 dynamic steps
-    of 8192 chains take 37 ms of a 2.78 s round, 0.45 ms a step; the
-    round's boundary (gather and scatter-add, see _dw_choice) is the other
-    98.5%.  "onehot" has no reading on that cell; it stays selectable for
-    meshes where the boundary shrinks (ROADMAP D5)."""
+    v5e in ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5, PR 32): the 83 dynamic
+    steps of 8192 chains take 37 ms of a 0.80 s round, 0.45 ms a step
+    (4.6%; 1.3% of the 2.78 s round over padded rows); the round's
+    boundary (gather and scatter-add, see _dw_choice) is the other 95%.
+    "onehot" read 43.5 ms there, 17% slower; it stays selectable for
+    meshes where per-step latency resurfaces (ROADMAP D5)."""
     choice = os.environ.get("FLINK_MS_SVM_STEP", "auto")
     if choice not in ("auto", "dynamic", "onehot"):
         # as _dw_choice: a typo must not run the dynamic step in silence
@@ -413,13 +528,17 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             return jax.lax.map(one, (idx_s, val_s), batch_size=B)
 
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
-                  gram=None, dw_a=None, dw_b=None, dw_c=None):
-        # dw_* operands depend on dw_mode: sorted -> (perm, ids), presorted
-        # -> (val_sorted, ids, src_row); unused modes pass nothing
+                  gram=None, slot=None, dw_a=None, dw_b=None, dw_c=None):
+        # scatter engine: idx, val are the device's padded (C, rows, L)
+        # rectangles.  Gram engine: they are its bucketed rows, a tuple of
+        # (1, width, rows) pieces each (_bucket_rows; the jit retraces for
+        # another ladder), with slot the flat (C·rows) slot of every bucket
+        # row; dw_* depend on dw_mode: sorted -> (perm, ids), presorted ->
+        # (val_sorted, ids, src_slot); unused modes pass nothing
         # span = [start, stop): rounds run with ABSOLUTE indices so the
         # per-round RNG (fold_in of the round number) is identical whether
         # the caller runs one long fit or chains warm-started segments
-        # per-device shards: idx (C, rows, L), alpha (C, rows); w0 replicated
+        # per-device shards: alpha (C, rows); w0 replicated
         device_id = jax.lax.axis_index(BLOCK_AXIS)
 
         def chain_keys(it):
@@ -454,15 +573,19 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
         def outer_gram(it, carry):
             w, alpha = carry
-            # round-start margins for every row: ONE (C, H, L) gather of w
-            # HIGHEST: the scatter path computes these margins as full-f32
-            # elementwise work; a default-precision (bf16-pass) contraction
-            # here would seed every SDCA step with ~1e-3 relative error and
-            # break the documented cross-engine equivalence on TPU.
+            # round-start margins for every row: ONE gather of w over the
+            # stored entries, reduced over each bucket's width, then placed
+            # by slot (rows without entries keep 0).  Elementwise f32, as
+            # the scatter path computes its margins: a default-precision
+            # (bf16-pass) contraction here would seed every SDCA step with
+            # ~1e-3 relative error and break the documented cross-engine
+            # equivalence on TPU.
             with jax.named_scope("svm.margins"):
-                wx0 = jnp.einsum("chl,chl->ch", jnp.take(w, idx, axis=0),
-                                 val, precision="highest",
-                                 preferred_element_type=dtype)
+                wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(
+                    jnp.concatenate([
+                        jnp.sum(jnp.take(w, i[0], axis=0) * v[0], axis=0)
+                        for i, v in zip(idx, val)])
+                ).reshape(C, H_rows)
             with jax.named_scope("svm.steps"):
                 keys = chain_keys(it)
                 dalpha = jax.vmap(sdca_gram)(
@@ -472,25 +595,34 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             # per round (the scatter engine pays one per STEP per chain).
             # Mode trade-offs in _dw_choice's docstring.
             with jax.named_scope("svm.dw"):
+                dalpha_flat = dalpha.reshape(-1)
                 if dw_mode == "presorted":
                     # val is stored feature-sorted (dw_a) at prepare time,
                     # so the only runtime gather reads the tiny (C·H) Δα
                     # table
                     dw = jax.ops.segment_sum(
-                        dw_a[0] * dalpha.reshape(-1)[dw_c[0]], dw_b[0],
+                        dw_a[0] * dalpha_flat[dw_c[0]], dw_b[0],
                         num_segments=d, indices_are_sorted=True,
-                    ) / lam_n
-                elif dw_mode == "sorted":
-                    contrib = (val * dalpha[:, :, None]).reshape(-1)
-                    dw = jax.ops.segment_sum(
-                        contrib[dw_a[0]], dw_b[0], num_segments=d,
-                        indices_are_sorted=True,
-                    ) / lam_n
+                    )
                 else:
-                    contrib = (val * dalpha[:, :, None]).reshape(-1)
-                    dw = jnp.zeros((d,), dtype).at[idx.reshape(-1)].add(
-                        contrib
-                    ) / lam_n
+                    # every stored entry's val · Δα of its row: Δα picked
+                    # once a bucket row, spread over the bucket's width
+                    da_rows = dalpha_flat[slot[0]]
+                    ends = np.cumsum([v.shape[2] for v in val])
+                    contrib = [v[0] * da_rows[end - v.shape[2]:end]
+                               for v, end in zip(val, ends)]
+                    if dw_mode == "sorted":
+                        flat = jnp.concatenate(
+                            [c.reshape(-1) for c in contrib])
+                        dw = jax.ops.segment_sum(
+                            flat[dw_a[0]], dw_b[0], num_segments=d,
+                            indices_are_sorted=True,
+                        )
+                    else:
+                        dw = jnp.zeros((d,), dtype)
+                        for i, c in zip(idx, contrib):
+                            dw = dw.at[i[0]].add(c)
+                dw = dw / lam_n
             with jax.named_scope("svm.combine"):
                 w = w + gamma * jax.lax.psum(dw, BLOCK_AXIS)
                 alpha = alpha + gamma * dalpha
@@ -503,11 +635,9 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     spec2 = P(BLOCK_AXIS, None)
     in_specs = (P(), P(), spec3, spec3, spec2, spec2, spec2, P())
     if inner == "gram":
-        in_specs = in_specs + (spec3,)
-        if dw_mode == "sorted":
-            in_specs = in_specs + (spec2, spec2)
-        elif dw_mode == "presorted":
-            in_specs = in_specs + (spec2, spec2, spec2)
+        # idx, val: one spec for the whole tuple of buckets; gram, slot
+        n_dw = {"direct": 0, "sorted": 2, "presorted": 3}[dw_mode]
+        in_specs += (spec3, spec2) + (spec2,) * n_dw
     jfit = jax.jit(shard_map(
         block_fit,
         mesh=mesh,
@@ -578,21 +708,23 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     return fn
 
 
-def _set_layout_gauges(problem: BlockedSVMProblem, Kp: int, D: int,
-                       gram_bytes: int) -> None:
-    """What the installed layout holds, for whoever reads the registry:
-    row slots (pad rows and pad blocks included), the width every row is
-    padded to, the entries of the padded arrays that carry no value, the
-    Gram tensor's bytes (0 on the scatter engine), chains per device."""
+def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
+                       gram_bytes: int, chains: int) -> None:
+    """What the compiled round streams, for whoever reads the registry:
+    row slots (pad rows and pad blocks included); the entries stored per
+    slot, so that rows x row_width is every entry the round's gather and
+    scatter-add touch (the scatter engine: the width every row is padded
+    to; the Gram engine: a mean over its buckets, their pad rows counted);
+    the stored entries that carry no value; the number of length buckets (0
+    on the scatter engine: one padded rectangle); the Gram tensor's bytes
+    (0 on the scatter engine); chains per device."""
     reg = obs_metrics.get_registry()
-    slots = Kp * problem.rows_per_block
-    width = problem.idx.shape[-1]
     reg.gauge("tpums_svm_rows").set(slots)
-    reg.gauge("tpums_svm_row_width").set(width)
-    reg.gauge("tpums_svm_pad_entries").set(
-        slots * width - int(np.count_nonzero(problem.val)))
+    reg.gauge("tpums_svm_row_width").set(stored / slots)
+    reg.gauge("tpums_svm_pad_entries").set(stored - nonzero)
+    reg.gauge("tpums_svm_buckets").set(buckets)
     reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
-    reg.gauge("tpums_svm_chains_per_device").set(Kp // D)
+    reg.gauge("tpums_svm_chains_per_device").set(chains)
 
 
 def compile_svm_fit(
@@ -601,87 +733,81 @@ def compile_svm_fit(
     """-> (fit_fn, dev_args): the compiled CoCoA program plus device-
     resident sharded inputs.  ``fit_fn(iterations, *dev_args)`` -> (w,
     alpha shards).  Benchmarks call ``fit_fn`` directly so host<->device
-    transfer and compile stay out of the timed region."""
+    transfer and compile stay out of the timed region.  ``dev_args[0]`` is
+    w and ``dev_args[5]`` alpha, ``(Kp, rows_per_block)`` in slot order;
+    the rest is the engine's: the padded rectangles at [1], [2] on the
+    scatter engine, the bucketed rows on the Gram engine."""
     D = num_blocks(mesh)
-    K = problem.n_blocks
-    Kp = _round_up(K, D)  # pad with empty blocks so K shards evenly; empty
-    # chains produce zero deltas and the combination scale uses the real K
+    Kp = _round_up(problem.n_blocks, D)
     dtype = config.dtype
-
-    def pad_blocks(a):
-        if Kp == K:
-            return a
-        widths = [(0, Kp - K)] + [(0, 0)] * (a.ndim - 1)
-        return np.pad(a, widths)
-
-    w0 = jnp.zeros((problem.n_features,), dtype=dtype)
-    alpha0 = jnp.zeros((Kp, problem.rows_per_block), dtype=dtype)
     shard3 = block_sharding(mesh, rank=3)
     shard2 = block_sharding(mesh, rank=2)
     rep = NamedSharding(mesh, P())
-    # the two stages end when the device has what they made, so that a
-    # profile shows the transfer and the Gram build, not their dispatch
-    with tracing.stage("svm.place"):
-        dev_args = jax.block_until_ready([
-            jax.device_put(w0, rep),
-            jax.device_put(jnp.asarray(pad_blocks(problem.idx)), shard3),
-            jax.device_put(
-                jnp.asarray(pad_blocks(problem.val).astype(dtype)), shard3
-            ),
-            jax.device_put(
-                jnp.asarray(pad_blocks(problem.label).astype(dtype)), shard2
-            ),
-            jax.device_put(
-                jnp.asarray(pad_blocks(problem.sq_norm).astype(dtype)),
-                shard2,
-            ),
-            jax.device_put(alpha0, shard2),
-            jax.device_put(
-                jnp.asarray([config.seed], dtype=jnp.uint32), rep
-            ),
-        ])
+
+    def put(a, sharding, as_dtype=None):
+        # a: an array, or the Gram engine's tuple of bucket arrays
+        return jax.device_put(jax.tree.map(
+            lambda x: jnp.asarray(x, dtype=as_dtype), a), sharding)
+
     fit, gram_fn, dw_mode = _cached_fit(problem, config, mesh)
+    idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
+    stored, buckets, extra = idx.size, 0, []
     if gram_fn is not None:
+        # the Gram build reads the padded rectangles once and lets them
+        # go: the rounds hold the bucketed rows only
         with tracing.stage("svm.gram_build"):
-            dev_args.append(jax.block_until_ready(
-                gram_fn(dev_args[1], dev_args[2])
-            ))
-    _set_layout_gauges(problem, Kp, D, dev_args[7].nbytes if gram_fn else 0)
-    if dw_mode in ("sorted", "presorted"):
-        # per-device feature-sorted layout of the flattened (C, H, L)
-        # entries (host-side, once per layout).  sorted ships (perm, ids):
-        # the round end gathers the big contribution array through perm.
-        # presorted ships (val_sorted, ids, src_row): values are stored
-        # already sorted, so the round end's only gather is src_row into
-        # the (C·H) Δα table.
-        idx_p = pad_blocks(problem.idx)
-        L = idx_p.shape[-1]
-        Cd = Kp // D
-        M = Cd * problem.rows_per_block * L
-        ids = np.empty((D, M), np.int32)
-        if dw_mode == "sorted":
-            perm = np.empty((D, M), np.int32)
-        else:
-            val_p = pad_blocks(problem.val)
-            val_s = np.empty((D, M), np.dtype(dtype))
-            src = np.empty((D, M), np.int32)
-        for dd in range(D):
-            flat = idx_p[dd * Cd:(dd + 1) * Cd].reshape(-1)
-            order = np.argsort(flat, kind="stable").astype(np.int32)
-            ids[dd] = flat[order]
-            if dw_mode == "sorted":
-                perm[dd] = order
-            else:
-                val_s[dd] = val_p[dd * Cd:(dd + 1) * Cd].reshape(-1)[order]
-                src[dd] = order // L  # device-local flat (C·H) row index
-        if dw_mode == "sorted":
-            dev_args.append(jax.device_put(jnp.asarray(perm), shard2))
-            dev_args.append(jax.device_put(jnp.asarray(ids), shard2))
-        else:
-            dev_args.append(jax.device_put(jnp.asarray(val_s), shard2))
-            dev_args.append(jax.device_put(jnp.asarray(ids), shard2))
-            dev_args.append(jax.device_put(jnp.asarray(src), shard2))
+            extra.append(jax.block_until_ready(
+                gram_fn(put(idx, shard3), put(val, shard3, dtype))))
+    # the stages end when the device has what they made, so that a profile
+    # shows the Gram build and the transfer, not their dispatch
+    with tracing.stage("svm.place"):
+        if gram_fn is not None:
+            plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
+            idx, val, slot = _bucket_rows(idx, val, plan)
+            stored, buckets = sum(a.size for a in idx), len(idx)
+            extra += [put(a, shard2) for a in (
+                slot, *_sorted_dw_operands(dw_mode, idx, val, slot, dtype))]
+        dev_args = jax.block_until_ready([
+            put(np.zeros((problem.n_features,)), rep, dtype),
+            put(idx, shard3),
+            put(val, shard3, dtype),
+            put(_pad_blocks(problem.label, Kp), shard2, dtype),
+            put(_pad_blocks(problem.sq_norm, Kp), shard2, dtype),
+            put(np.zeros((Kp, problem.rows_per_block)), shard2, dtype),
+            put(np.asarray([config.seed], np.uint32), rep),
+            *extra,
+        ])
+    _set_layout_gauges(
+        Kp * problem.rows_per_block, stored,
+        int(np.count_nonzero(problem.val)), buckets,
+        extra[0].nbytes if extra else 0, Kp // D)
     return fit, dev_args
+
+
+def _sorted_dw_operands(dw_mode, ids, val, slot, dtype):
+    """The extra operands of FLINK_MS_SVM_DW=sorted|presorted, each (D, a
+    device's stored entries): the bucketed entries in feature order
+    (host-side, once a layout).  sorted -> (perm, ids): the round end
+    gathers the flattened contributions through perm.  presorted ->
+    (val_sorted, ids, src_slot): values are stored already sorted, so the
+    round end's only gather is src_slot into the (C·H) Δα table."""
+    if dw_mode == "direct":
+        return []
+    D = slot.shape[0]
+    flat = np.concatenate([a.reshape(D, -1) for a in ids], axis=1)
+    order = np.argsort(flat, axis=1, kind="stable").astype(np.int32)
+    ids_sorted = np.take_along_axis(flat, order, axis=1)
+    if dw_mode == "sorted":
+        return [order, ids_sorted]
+    # the slot of every stored entry: a bucket's slots under each of its
+    # entry rows
+    ends = np.cumsum([a.shape[2] for a in ids])
+    slot_of = np.concatenate(
+        [np.tile(slot[:, end - a.shape[2]:end], (1, a.shape[1]))
+         for a, end in zip(ids, ends)], axis=1)
+    val_flat = np.concatenate([a.reshape(D, -1) for a in val], axis=1)
+    return [np.take_along_axis(val_flat, order, axis=1).astype(dtype),
+            ids_sorted, np.take_along_axis(slot_of, order, axis=1)]
 
 
 def svm_fit(

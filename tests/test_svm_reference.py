@@ -1,8 +1,10 @@
 """The CoCoA program against the benchmark's plain reference
 (`benchmark/reference_cocoa.py`: numpy, float64, CSR, nothing of the padded
-arrays, the Gram matrix or the dw modes), on rows of unequal length; the
-synthetic documents of `benchmark/synth_cocoa.py`; and what PR 31 added to
-`ops/svm.py` for whoever profiles it: named scopes, gauges, a counter."""
+arrays, the length buckets, the Gram matrix or the dw modes), on rows of
+unequal length; the Gram engine's length-bucketed rows (PR 32) against the
+CSR and the padded rectangles they are cut from; the synthetic documents of
+`benchmark/synth_cocoa.py`; and what PR 31 added to `ops/svm.py` for
+whoever profiles it: named scopes, gauges, a counter."""
 
 import json
 import os
@@ -46,12 +48,14 @@ def uneven_documents(n=61, d=300, long_row=120, seed=3):
                       values=values, n_features=d)
 
 
-def run_program(data, chains, inner, mode, rounds, dtype=jnp.float32):
+def run_program(data, chains, inner, mode, rounds, dtype=jnp.float32,
+                devices=None):
     problem = prepare_svm_blocked(data, chains, seed=SEED)
     cfg = SVMConfig(local_iterations=problem.rows_per_block,
                     regularization=LAM, seed=SEED, mode=mode, inner=inner,
                     dtype=dtype)
-    fit, args = compile_svm_fit(problem, cfg, make_mesh(min(chains, 4)))
+    fit, args = compile_svm_fit(
+        problem, cfg, make_mesh(devices or min(chains, 4)))
     w, alpha = fit(rounds, *args)
     return (problem, np.asarray(w).astype(np.float64),
             np.asarray(alpha).astype(np.float64))
@@ -161,9 +165,21 @@ def test_gauges_and_round_counter_read_what_the_layout_implies(inner, chains, de
     rows = problem.rows_per_block
     got = gauges()
     assert got["tpums_svm_rows"] == padded_chains * rows
-    assert got["tpums_svm_row_width"] == 120
-    assert got["tpums_svm_pad_entries"] == (
-        padded_chains * rows * 120 - len(data.indices))
+    if inner == "gram":
+        # what the round streams: the bucketed rows, pad rows of a bucket
+        # included; row_width is their mean over the row slots
+        stored = sum(a.size for a in args[1])
+        assert stored == sum(a.size for a in args[2])
+        assert stored < padded_chains * rows * 120
+        assert got["tpums_svm_buckets"] == len(args[1]) > 1
+    else:
+        stored = padded_chains * rows * 120  # every row padded to the longest
+        assert args[1].size == args[2].size == stored
+        assert got["tpums_svm_row_width"] == 120
+        assert got["tpums_svm_buckets"] == 0
+    assert got["tpums_svm_rows"] * got["tpums_svm_row_width"] == pytest.approx(
+        stored, rel=1e-12)
+    assert got["tpums_svm_pad_entries"] == stored - len(data.indices)
     assert got["tpums_svm_gram_bytes"] == (
         padded_chains * rows * rows * 4 if inner == "gram" else 0)
     assert got["tpums_svm_chains_per_device"] == padded_chains // devices
@@ -173,6 +189,169 @@ def test_gauges_and_round_counter_read_what_the_layout_implies(inner, chains, de
     fit(jnp.asarray(3, jnp.int32), state[0], *args[1:5], state[1], *args[6:],
         start=2)
     assert counter.value - before == 5
+
+
+# -- the Gram engine's length-bucketed rows (PR 32) ----------------------------
+
+def bucketed(data, chains, devices):
+    """(problem, plan, ids, val, slot) as `compile_svm_fit` lays them out."""
+    problem = prepare_svm_blocked(data, chains, seed=SEED)
+    padded = -(-chains // devices) * devices
+    pad = lambda a: svm._pad_blocks(a, padded)
+    plan = svm._bucket_plan(pad(problem.row_len), devices)
+    return (problem, plan,
+            *svm._bucket_rows(pad(problem.idx), pad(problem.val), plan))
+
+
+def equal_length_documents(n=50, d=40, length=12, seed=5):
+    rng = np.random.default_rng(seed)
+    indices = np.concatenate([rng.choice(d, length, replace=False)
+                              for _ in range(n)])
+    values = (0.1 + rng.random(n * length)) / np.sqrt(length)
+    return SparseData(labels=np.where(rng.random(n) < 0.5, 1.0, -1.0),
+                      indptr=np.arange(0, (n + 1) * length, length),
+                      indices=indices, values=values, n_features=d)
+
+
+@pytest.mark.parametrize("chains, devices", [(1, 1), (4, 1), (6, 4), (16, 4), (1, 4)])
+def test_buckets_hold_every_nonzero_once_at_its_rows_slot(chains, devices):
+    data = uneven_documents()
+    n = data.n_examples
+    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(
+        data, chains, devices)
+    per_device = bucket_of.shape[1]
+    example_of = np.full(devices * per_device, -1)
+    example_of[:n] = np.random.default_rng(SEED).permutation(n)
+    got = []
+    for b, (i, v) in enumerate(zip(ids, val)):
+        assert i.shape == v.shape == (devices, widths[b], rows[b])
+        at = sum(rows[:b])
+        for dev in range(devices):
+            entry, row = np.nonzero(v[dev])
+            examples = example_of[dev * per_device + slot[dev, at + row]]
+            got.append(np.stack([examples, i[dev, entry, row],
+                                 v[dev, entry, row].view(np.int32)], axis=1))
+            # a stored entry without a value is a pad: id 0
+            assert not i[dev][v[dev] == 0].any()
+    got = np.concatenate(got)
+    want = np.stack([np.repeat(np.arange(n), np.diff(data.indptr)),
+                     data.indices,
+                     data.values.astype(np.float32).view(np.int32)], axis=1)
+    assert len(got) == len(data.indices)
+    assert np.array_equal(got[np.lexsort(got.T[::-1])],
+                          want[np.lexsort(want.T[::-1])])
+    # the padded rectangle says the same of every slot
+    lens = problem.row_len.reshape(-1)
+    assert np.array_equal(lens, (problem.val != 0).sum(-1).reshape(-1))
+
+
+def test_rows_without_entries_fall_in_no_bucket_and_the_longest_gets_its_own():
+    data = uneven_documents()  # row 20 is empty, row 7 holds 120 entries
+    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(data, 6, 4)
+    flat = bucket_of.reshape(-1)
+    lens = svm._pad_blocks(problem.row_len, 8).reshape(-1)
+    order = np.random.default_rng(SEED).permutation(data.n_examples)
+    assert lens[61:].sum() == 0 and len(lens) == 88  # 5 pad rows, 2 empty chains
+    assert (flat[61:] == -1).all()
+    assert flat[np.flatnonzero(order == 20)[0]] == -1
+    assert (flat[:61] >= 0).sum() == 60
+    assert widths[-1] == 120 and widths[0] == 8 and len(widths) == 3
+    top = val[-1]
+    assert sum((top[dev] != 0).any(axis=0).sum() for dev in range(4)) == 1
+    where = np.flatnonzero(order == 7)[0]
+    assert flat[where] == len(widths) - 1
+    dev, local = divmod(where, bucket_of.shape[1])
+    assert slot[dev, sum(rows[:-1])] == local
+    # every other bucket row of the top bucket is a pad aimed at slot 0
+    assert slot[:, sum(rows[:-1]):].sum() == local
+
+
+def test_equal_lengths_give_one_bucket_that_is_the_padded_rectangle():
+    data = equal_length_documents()
+    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(data, 5, 1)
+    assert widths == (12,) and rows == (50,) and (bucket_of == 0).all()
+    assert np.array_equal(ids[0][0], problem.idx.reshape(50, 12).T)
+    assert np.array_equal(val[0][0], problem.val.reshape(50, 12).T)
+    assert np.array_equal(slot[0], np.arange(50))
+    _, w_gram, a_gram = run_program(data, 5, "gram", "avg", 2, devices=1)
+    _, w_padded, a_padded = run_program(data, 5, "scatter", "avg", 2, devices=1)
+    assert ref.rel_err(w_gram, w_padded) < TOL
+    assert ref.rel_err(a_gram, a_padded) < TOL
+
+
+@pytest.mark.parametrize("lo, hi", [(1, 1), (4, 256), (3, 13), (1, 100000), (120, 120)])
+def test_the_ladder_is_short_rising_and_ends_at_the_longest_row(lo, hi):
+    widths = svm._bucket_widths(lo, hi)
+    assert widths[-1] == hi and len(widths) <= svm._BUCKET_CAP
+    assert all(a < b for a, b in zip(widths, widths[1:]))
+    assert all(w % svm._BUCKET_STEP == 0 for w in widths[:-1])
+    assert widths[0] >= lo
+    if lo == hi:
+        assert widths == [hi]
+
+
+def test_the_cells_lengths_keep_their_padding_under_a_fifth():
+    with open(os.path.join(REPO, "benchmark", "configs", "rcv1-cocoa.json")) as f:
+        cfg = json.load(f)
+    lens = synth_cocoa.row_lengths(cfg)
+    widths = np.asarray(svm._bucket_widths(int(lens.min()), int(lens.max())))
+    stored = widths[np.searchsorted(widths, lens)].sum()
+    assert 1 - lens.sum() / stored < 0.12 < 0.715  # the padded rectangle's share
+
+
+@pytest.mark.parametrize("mode", ["avg", "add"])
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("chains", [1, 4, 16])
+def test_bucketed_round_agrees_with_the_reference_and_the_padded_round(
+        chains, devices, mode):
+    data = uneven_documents()
+    _, w, alpha = run_program(data, chains, "gram", mode, 2, devices=devices)
+    slots, w_ref, a_ref = run_reference(data, chains, mode, 2)
+    assert ref.rel_err(w, w_ref) < TOL
+    assert ref.rel_err(by_example(alpha, slots, data.n_examples), a_ref) < TOL
+    # the scatter engine reads the padded rectangles, row by row
+    _, w_padded, a_padded = run_program(data, chains, "scatter", mode, 2,
+                                        devices=devices)
+    assert ref.rel_err(w, w_padded) < TOL
+    assert ref.rel_err(alpha, a_padded) < TOL
+
+
+def boundary_sizes(jaxpr, d):
+    """Indices of every gather from, and scatter-add into, (d,) vectors."""
+    found = {"gather": 0, "scatter-add": 0}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if (eqn.primitive.name in found
+                    and eqn.invars[0].aval.shape[-1:] == (d,)):
+                found[eqn.primitive.name] += int(np.prod(
+                    eqn.invars[1].aval.shape[:-1]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("inner", ["gram", "scatter"])
+def test_the_rounds_gather_and_scatter_run_over_the_stored_entries(inner):
+    data = uneven_documents()
+    problem = prepare_svm_blocked(data, 4, seed=SEED)
+    cfg = SVMConfig(local_iterations=problem.rows_per_block,
+                    regularization=LAM, seed=SEED, inner=inner)
+    fit, args = compile_svm_fit(problem, cfg, make_mesh(1))
+    sizes = boundary_sizes(
+        jax.make_jaxpr(lambda *a: fit(1, *a))(*args).jaxpr,
+        data.n_features)
+    padded = problem.idx.size
+    if inner == "gram":
+        stored = sum(a.size for a in args[1])
+        assert sizes == {"gather": stored, "scatter-add": stored}
+        assert stored == gauges()["tpums_svm_rows"] * gauges()["tpums_svm_row_width"]
+        assert len(data.indices) <= stored < padded
+    else:
+        # one row of every chain a step, each of the padded width
+        assert sizes["gather"] == sizes["scatter-add"] == 4 * 120
 
 
 @pytest.mark.parametrize("choice, resolved", [
