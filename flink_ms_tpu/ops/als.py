@@ -29,13 +29,19 @@ scatter/``segment_sum`` formulation was measured 8-10x slower on v5e: TPU
 scatter serializes per row, and XLA's batched small-matrix Cholesky
 streams the whole (n, k, k) tensor per elimination step.)
 
-Supports the two training modes named in BASELINE.json:
+Two training modes, each timed by a cell of the benchmark:
 
-- explicit feedback (FlinkML parity): weighted-λ regularization
-  (reg_u = n_u, Zhou et al. ALS-WR) or plain λ;
-- implicit feedback (confidence-weighted, Hu-Koren-Volinsky):
+- explicit feedback (FlinkML parity; ``als-ml20m.retrain``): weighted-λ
+  regularization (reg_u = n_u, Zhou et al. ALS-WR) or plain λ;
+- implicit feedback (confidence-weighted, Hu-Koren-Volinsky;
+  ``msd-ials.ials-retrain``):
   A_u = YᵀY + Σ_{i∈Ωu} α·r_ui · y_i y_iᵀ + λ·I with YᵀY a ``psum`` of
-  per-shard Gramians.
+  per-shard Gramians (scope ``als.gram``), plain λ, on the einsum pair.
+
+Each half-sweep either materialises its (per_block, k, k) normal equations
+and solves them in one batch, or solves every assembly chunk where it was
+assembled so that the tensor never exists: ``solves_per_chunk`` decides per
+side from the tensor's bytes and the device's memory.
 
 Everything under ``jit`` is static-shaped; the iteration loop is a
 ``fori_loop`` so a full fit is one XLA program.
@@ -58,6 +64,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs import metrics as obs_metrics
 from ..parallel.mesh import (
     BLOCK_AXIS,
     block_sharding,
@@ -72,7 +79,10 @@ from ..parallel.mesh import (
 @dataclasses.dataclass(frozen=True)
 class ALSConfig:
     """Mirrors the reference's surfaced parameters (ALSImpl.scala:35-49) plus
-    the implicit-feedback mode required by BASELINE.json."""
+    the implicit-feedback mode (``implicit``, ``alpha``: Hu-Koren-Volinsky's
+    confidence c = 1 + alpha*r over play or click counts, plain lambda
+    whatever ``weighted_reg`` says), the mode of ``als_train --implicit
+    true`` and of the benchmark's ``msd-ials`` configuration."""
 
     num_factors: int = 10
     iterations: int = 10
@@ -579,6 +589,31 @@ def _assembly_chunk_bytes() -> int:
     return int(os.environ.get(_ASSEMBLY_CHUNK_ENV, 2 << 30))
 
 
+def _chunk_rows(r, w, k, y_itemsize, itemsize, implicit,
+                per_chunk) -> Optional[int]:
+    """Rows of an (r, w) bucket one assembly step takes, None where the
+    whole bucket runs straight-line.  The peak transient of a step is the
+    gather itself (at the EXCHANGE dtype's width), plus the same-size
+    solve-dtype yw intermediate in implicit mode (budgeted whether or not
+    XLA fuses it into the contraction's operand), plus, where the step also
+    solves (``per_chunk``), its (C, k, k) system and the factorization's
+    intermediates; a bucket above ``_assembly_chunk_bytes()`` is cut into
+    the fewest steps that fit it, of equal size: steps of the largest size
+    that fits left the last one nearly empty wherever a bucket came out
+    just above a multiple of the limit, and msd-ials' item ladder does in
+    nine buckets of eleven (two steps for 1.01-1.05 of one step's rows, the
+    pad rows all gathering slot 0 and contracted like the others: 1.7538
+    against 0.7200 s/iter, PERF.md section 6, PR 33)."""
+    row_bytes = w * k * (y_itemsize + (itemsize if implicit else 0))
+    if per_chunk:
+        row_bytes += 3 * k * k * itemsize
+    limit = _assembly_chunk_bytes()
+    if r * row_bytes <= limit:
+        return None
+    n_chunks = -(-r // max(int(limit // row_bytes), 1))
+    return -(-r // n_chunks)
+
+
 def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
                        precision, post=None, extra=None, platform=None,
                        lanes=False):
@@ -638,9 +673,12 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
                                 interpret=platform != "tpu")
             else:
                 if implicit:
-                    wgt = (alpha * val_c).astype(dtype)  # pads: val 0 -> 0
-                    t = (1.0 + alpha * val_c).astype(dtype)  # pads: y is 0
-                    yw = y * wgt[..., None]
+                    # the confidence weights and the weighted copy of y:
+                    # what implicit mode adds to a bucket's contraction
+                    with jax.named_scope("als.weight"):
+                        wgt = (alpha * val_c).astype(dtype)  # pads: val 0 -> 0
+                        t = (1.0 + alpha * val_c).astype(dtype)  # pads: y is 0
+                        yw = y * wgt[..., None]
                     A = jnp.einsum("rwk,rwl->rkl", yw, y,
                                    precision=precision,
                                    preferred_element_type=dtype)
@@ -654,20 +692,9 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
             return A, b
         return post(A, b, extra_c, in_scan=in_scan)
 
-    # peak transient: the gather itself (at the EXCHANGE dtype's width),
-    # plus the same-size solve-dtype yw intermediate in implicit mode
-    # (TPU dots don't fuse elementwise producers into operands)
-    row_bytes = w * k * (
-        y_all.dtype.itemsize
-        + (np.dtype(dtype).itemsize if implicit else 0)
-    )
-    if post is not None:
-        # the fused solve holds the chunk's (C, k, k) system plus
-        # factorization intermediates in the same transient budget
-        row_bytes += 3 * k * k * np.dtype(dtype).itemsize
-    need = r * row_bytes
-    limit = _assembly_chunk_bytes()
-    if need <= limit:
+    C = _chunk_rows(r, w, k, y_all.dtype.itemsize, np.dtype(dtype).itemsize,
+                    implicit, post is not None)
+    if C is None:
         return compute(idx, val, extra)
     # chunked: reshape to (n_chunks, C, ...) slabs and lax.map WITHOUT
     # batch_size, so the body genuinely computes C rows per step and only
@@ -678,7 +705,6 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     # Pad rows to a chunk multiple: pad gathers hit slot 0 and the padded
     # counts are 0, so the solve masks padded rows to zero and the slice
     # below discards them — per-row arithmetic is untouched.
-    C = max(min(int(limit // row_bytes), r), 1)
     n_chunks = -(-r // C)
     r_pad = n_chunks * C
 
@@ -750,17 +776,64 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     return jnp.concatenate(As, axis=0), jnp.concatenate(bs, axis=0)
 
 
-def _fused_solve() -> bool:
-    """FLINK_MS_ALS_FUSED=1: solve each bucket chunk inside the assembly
-    lax.map, so the (per_block, k, k) normal-equation tensor never
-    materializes and the half-sweep's peak transient stops scaling with
-    the catalog size — required for the 10M-user scale envelope, opt-in
-    until that shape has run on the chip.  It keeps the batch-major
-    hand-off (A (r, k, k) out of the kernel, laid out for the solver by
-    XLA, one solver body a bucket): at the ML-20M shape that was ahead of
-    the default route until PR 30 (0.142549 against 0.154758 s/iter, PR 26)
-    and is behind it since (PERF.md section 7 has the reading)."""
-    return os.environ.get("FLINK_MS_ALS_FUSED", "0") == "1"
+_FUSED_ENV = "FLINK_MS_ALS_FUSED"
+
+# The materialised route holds a side's (per_block, k, k) normal equations
+# twice (A, then A + λ·reg in the solver's padded layout) beside one
+# assembly chunk's transients (up to two of FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES
+# in implicit mode), the ratings and both factor tables.  Up to a quarter
+# of the device's memory the pair is half the chip at most and the rest has
+# room; above it the sweep solves per chunk.  The two readings on either
+# side of the constant (TPU v5e, 15.75 GiB): 8.2% of the memory
+# (als-ml20m's users, 1.38 GB) runs 9.8% slower per chunk (0.142589 against
+# 0.129878 s/iter, PR 30) and 55% (msd-ials' users, 9.36 GB) is refused
+# by the compiler when materialised (27.14 GiB of 15.75, PR 33).
+_MATERIALISE_SHARE = 0.25
+
+
+def solves_per_chunk(rows: int, k: int, itemsize: int,
+                     memory_bytes: Optional[int]) -> bool:
+    """Whether a side of ``rows`` slots per device solves each assembly
+    chunk inside the assembly ``lax.map`` (so that its (rows, k, k)
+    normal-equation tensor never exists and the half-sweep's peak stops
+    scaling with the catalog) or materialises the tensor and solves it in
+    one batch.  FLINK_MS_ALS_FUSED forces it for both sides, ``1`` per
+    chunk and ``0`` materialised; unset, the tensor's bytes are held
+    against ``memory_bytes``, one device's memory as the runtime reports it
+    (``_MATERIALISE_SHARE``), and a runtime that reports none (the CPU)
+    keeps the tensor.  The per-chunk route keeps the batch-major hand-off
+    (A (r, k, k) out of the kernel or the einsums, laid out for the solver
+    by XLA, one solver body a bucket): where both fit, the materialised
+    route is the faster (PERF.md section 7)."""
+    choice = os.environ.get(_FUSED_ENV, "")
+    if choice not in ("", "0", "1"):
+        raise ValueError(
+            f"{_FUSED_ENV}={choice!r}: expected 0, 1 or unset")
+    if choice:
+        return choice == "1"
+    if not memory_bytes:
+        return False
+    return rows * k * k * itemsize > _MATERIALISE_SHARE * memory_bytes
+
+
+def _device_memory(device) -> Optional[int]:
+    """One device's memory in bytes as the runtime reports it, None where
+    it reports none (the CPU backend; a device that is only described)."""
+    try:
+        stats = device.memory_stats()
+    except Exception:  # a described topology has no runtime to ask
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def _routes(problem: "BlockedProblem", config: "ALSConfig",
+            mesh: Mesh) -> Dict[str, bool]:
+    """``solves_per_chunk`` for each side of one fit on ``mesh``."""
+    memory = _device_memory(mesh.devices.flat[0])
+    itemsize = np.dtype(config.dtype).itemsize
+    return {name: solves_per_chunk(side.per_block, config.num_factors,
+                                   itemsize, memory)
+            for name, side in (("u", problem.u), ("i", problem.i))}
 
 
 # Two solvers.  "pallas" on a TPU: one VMEM-resident pass per tile, 7.2 ms
@@ -826,19 +899,25 @@ def resolve_assembly(platform: Optional[str], y_dtype, dtype, implicit: bool,
 
 
 def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
-                  k: int) -> None:
-    """The static choice of one compiled sweep, per side: how many buckets
-    the kernel takes and their share of the padded ratings, and how many of
-    them hand A to the solver lane-major from the kernel itself (with
-    ``lanes``, every bucket whose gather fits one chunk; the others are
-    transposed after their lax.map) and their share of the entities."""
+                  k: int, per_chunk: Dict[str, bool]) -> None:
+    """The static choices of one compiled sweep, per side: the solve's route
+    (``solves_per_chunk``) beside the bytes its normal equations take per
+    device, how many buckets the kernel takes and their share of the padded
+    ratings, and how many of them hand A to the solver lane-major from the
+    kernel itself (with ``lanes`` on the materialised route, every bucket
+    whose gather fits one chunk; the others are transposed after their
+    lax.map) and their share of the entities."""
     parts = []
     for name, side in (("u", problem.u), ("i", problem.i)):
         padded = sum(w * r for w, r in zip(side.widths, side.rows))
         on = len(side.widths) if how == "kernel" else 0
         direct = [r for w, r in zip(side.widths, side.rows)
-                  if lanes and r * w * k * 4 <= _assembly_chunk_bytes()]
-        parts.append(f"{name}-sweep kernel on {on} of {len(side.widths)} "
+                  if lanes and not per_chunk[name]
+                  and r * w * k * 4 <= _assembly_chunk_bytes()]
+        parts.append(f"{name}-sweep solve "
+                     f"{'per chunk' if per_chunk[name] else 'materialised'} "
+                     f"({side.per_block * k * k * 4 / 1e9:.2f} GB of normal "
+                     f"equations), kernel on {on} of {len(side.widths)} "
                      f"buckets ({100.0 if on else 0.0:.1f}% of {padded} "
                      f"padded ratings), lane-major hand-off on {len(direct)} "
                      f"({100.0 * sum(direct) / sum(side.rows):.1f}% of "
@@ -947,12 +1026,13 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     how = resolve_assembly(platform, exchange_dtype or dtype, dtype, implicit,
                            k, config.assembly_precision)
     # the kernel path hands A to the Pallas solver in the solver's own
-    # layout (the fused route below solves per chunk, batch-major)
+    # layout (the per-chunk route below solves batch-major)
     lanes = how == "kernel" and resolve_solver(platform) == "pallas"
+    per_chunk = _routes(problem, config, mesh)
     if platform == "tpu":
-        _log_assembly(problem, how, lanes and not _fused_solve(), k)
+        _log_assembly(problem, how, lanes, k, per_chunk)
 
-    def half_sweep(y_shard, flat, routed: bool):
+    def half_sweep(y_shard, flat, routed: bool, fused: bool):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
         # the three named scopes are how a profile tells the sweep's device
         # time apart (they write metadata only; the program is the same)
@@ -988,12 +1068,16 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         ]
         yty = None
         if implicit:
-            with jax.named_scope("als.assemble"):
+            # als.gram: what implicit mode adds outside the buckets, the
+            # (k, k) Gramian of the whole other side and its add to A
+            with jax.named_scope("als.assemble"), jax.named_scope("als.gram"):
                 yty = jax.lax.psum(
-                    jnp.einsum("nk,nm->km", y_shard[0], y_shard[0]),
+                    jnp.einsum("nk,nm->km", y_shard[0], y_shard[0],
+                               precision=config.assembly_precision,
+                               preferred_element_type=dtype),
                     BLOCK_AXIS,
                 )
-        if _fused_solve():
+        if fused:
             # per-bucket fused assembly+solve: bucket outputs are
             # contiguous slot ranges, so each bucket's factor rows are
             # solved straight out of its assembly chunks and concatenated
@@ -1004,7 +1088,8 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             def solve_chunk(A, bb, cnt, in_scan=False):
                 with jax.named_scope("als.solve"):
                     if yty is not None:
-                        A = A + yty[None, :, :]
+                        with jax.named_scope("als.gram"):
+                            A = A + yty[None, :, :]
                     return _solve_factors(A, bb, cnt, lam, weighted, dtype,
                                           platform, in_scan=in_scan)
 
@@ -1035,7 +1120,8 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                     lam, weighted, platform)
             else:
                 if implicit:
-                    A = A + yty[None, :, :]
+                    with jax.named_scope("als.gram"):
+                        A = A + yty[None, :, :]
                 x = _solve_factors(A, b, counts[0], lam, weighted, dtype,
                                    platform)
         return x[None]  # (1, per_block, k)
@@ -1048,9 +1134,11 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         def one_iter(_, carry):
             uf, itf = carry
             with jax.named_scope("als.user_half"):
-                uf = half_sweep(itf, u_flat, routed=plan["u"] is not None)
+                uf = half_sweep(itf, u_flat, routed=plan["u"] is not None,
+                                fused=per_chunk["u"])
             with jax.named_scope("als.item_half"):
-                itf = half_sweep(uf, i_flat, routed=plan["i"] is not None)
+                itf = half_sweep(uf, i_flat, routed=plan["i"] is not None,
+                                 fused=per_chunk["i"])
             return uf, itf
 
         # dynamic trip count (lowers to while_loop): one compiled program
@@ -1072,7 +1160,24 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         out_specs=(spec3, spec3),
         check_vma=False,
     )
-    return jax.jit(sharded_fit)
+    return _counted(jax.jit(sharded_fit))
+
+
+def _counted(jitted):
+    """``jitted(iterations, *args)`` behind the registry's
+    ``tpums_als_iterations_total``: iterations enqueued, not awaited (their
+    device time lies under the als.* scopes of a profile).  ``lower`` is the
+    jitted function's own, for whoever compiles ahead of time."""
+    def fit(iterations, *args):
+        out = jitted(iterations, *args)
+        if not isinstance(iterations, jax.core.Tracer):
+            # a device scalar is fetched once and then kept by jax
+            obs_metrics.get_registry().counter(
+                "tpums_als_iterations_total").inc(int(iterations))
+        return out
+
+    fit.lower = jitted.lower
+    return fit
 
 
 _SWEEP_CACHE: "dict" = {}
@@ -1112,7 +1217,7 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         # executable; an unknown solver name raises here, before any trace
         resolve_solver(mesh.devices.flat[0].platform),
         _assembly_chunk_bytes(),
-        _fused_solve(),
+        tuple(sorted(_routes(problem, config, mesh).items())),
         # the Pallas solver reads its layout knob at trace time too (when
         # layout=None inside cholesky_solve_batched) — omitting it here
         # would silently reuse an executable compiled under the old layout
@@ -1285,6 +1390,42 @@ def _pad_factors(problem: BlockedProblem, D: int, k: int, dtype,
     )
 
 
+def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
+                       mesh: Mesh) -> None:
+    """What the compiled sweep streams and how it solves, for whoever reads
+    the registry, over both sides and all devices: factor-table slots
+    (``rows``) and those of them on the per-chunk route (``fused_rows``:
+    ``solves_per_chunk`` per side); assembly steps an iteration, one per
+    straight-line bucket and one per lax.map chunk (``chunks``); the bytes
+    one device's (per_block, k, k) normal equations take or would take, the
+    larger side's (``normal_eq_bytes``); the rating slots the gather reads,
+    each rating once a side plus the bucket ladder's padding (``entries``),
+    and the padding alone (``pad_entries``)."""
+    D, k = num_blocks(mesh), config.num_factors
+    itemsize = np.dtype(config.dtype).itemsize
+    exchange = resolve_exchange(config.exchange_dtype,
+                                mesh.devices.flat[0].platform)
+    y_itemsize = np.dtype(exchange).itemsize if exchange else itemsize
+    per_chunk = _routes(problem, config, mesh)
+    rows = fused_rows = chunks = entries = 0
+    for name, side in (("u", problem.u), ("i", problem.i)):
+        rows += D * side.per_block
+        fused_rows += D * side.per_block * per_chunk[name]
+        for w, r in zip(side.widths, side.rows):
+            C = _chunk_rows(r, w, k, y_itemsize, itemsize, config.implicit,
+                            per_chunk[name])
+            chunks += D * (1 if C is None else -(-r // C))
+            entries += D * w * r
+    reg = obs_metrics.get_registry()
+    reg.gauge("tpums_als_rows").set(rows)
+    reg.gauge("tpums_als_fused_rows").set(fused_rows)
+    reg.gauge("tpums_als_chunks").set(chunks)
+    reg.gauge("tpums_als_normal_eq_bytes").set(
+        max(problem.u.per_block, problem.i.per_block) * k * k * itemsize)
+    reg.gauge("tpums_als_entries").set(entries)
+    reg.gauge("tpums_als_pad_entries").set(entries - 2 * problem.nnz)
+
+
 def compile_fit(
     problem: BlockedProblem,
     config: ALSConfig,
@@ -1335,7 +1476,9 @@ def compile_fit(
     for name, side in (("u", problem.u), ("i", problem.i)):
         for a in _flat_side_args(side, dtype, routed=plan[name]):
             dev_args.append(put(a, shard2 if a.ndim == 2 else shard3))
-    return _cached_sweep(problem, config, mesh), dev_args
+    fit_fn = _cached_sweep(problem, config, mesh)
+    _set_layout_gauges(problem, config, mesh)
+    return fit_fn, dev_args
 
 
 def warm_start_factors(

@@ -6,12 +6,19 @@ same ``id,U|I,f1;f2;...`` model rows, so downstream tools (mean-vector job,
 producer/consumer, clients) interoperate with files from either framework.
 
 Flags beyond the reference (TPU-native surface):
-  --implicit true      confidence-weighted implicit-feedback ALS (BASELINE.json)
+  --implicit true      confidence-weighted implicit-feedback ALS (Hu, Koren,
+                       Volinsky: c = 1 + alpha * r over play or click counts)
   --alpha 40.0         implicit confidence scale
   --devices N          mesh size (defaults to all visible devices; the
                        reference's --blocks maps to Flink's internal blocking
                        and is accepted — blocking here always equals the mesh)
   --profileDir DIR     write an XLA profiler trace of the fit (TensorBoard)
+
+On a TPU the fit prints one ``[als] assembly:`` line when its sweep is traced:
+per side, whether the solve is ``materialised`` or ``per chunk`` (chosen
+from the bytes of the side's normal equations and the device's memory;
+``FLINK_MS_ALS_FUSED=0|1`` forces it) and how many buckets run the assembly
+kernel.
 
 ``--temporaryPath`` (reference: stage loop intermediates to disk,
 ALSImpl.scala:42-44) switches the training loop from one fused XLA program

@@ -129,7 +129,8 @@ def _ladder_problem():
     import types
 
     side = {n: types.SimpleNamespace(widths=tuple(w for w, _ in b),
-                                     rows=tuple(r for _, r in b))
+                                     rows=tuple(r for _, r in b),
+                                     per_block=sum(r for _, r in b) + 1)
             for n, b in _ML20M.items()}
     return types.SimpleNamespace(u=side["u"], i=side["i"])
 
@@ -150,13 +151,21 @@ def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     prints the share per side."""
     if limit:
         monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", str(limit))
-    A._log_assembly(_ladder_problem(), "kernel", True, 50)
+    keep = {"u": False, "i": False}
+    A._log_assembly(_ladder_problem(), "kernel", True, 50, keep)
     line = capsys.readouterr().out
     u, i = line.split("i-sweep")
     assert "lane-major hand-off " + want[0] in u
     assert "lane-major hand-off " + want[1] in i
-    A._log_assembly(_ladder_problem(), "kernel", False, 50)   # lax solver, fused
+    assert "u-sweep solve materialised (1.38 GB of normal equations)" in u
+    A._log_assembly(_ladder_problem(), "kernel", False, 50, keep)  # lax solver
     assert "hand-off on 0 (0.0%" in capsys.readouterr().out
+    # a side on the per-chunk route keeps the batch-major hand-off
+    A._log_assembly(_ladder_problem(), "kernel", True, 50,
+                    {"u": True, "i": False})
+    u, i = capsys.readouterr().out.split("i-sweep")
+    assert "solve per chunk" in u and "hand-off on 0 (0.0%" in u
+    assert "solve materialised" in i and "hand-off " + want[1] in i
 
 
 @pytest.mark.parametrize("w", [24, 1032])
