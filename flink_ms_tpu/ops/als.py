@@ -113,6 +113,22 @@ class ALSConfig:
 
 _MIN_BUCKET_W = 8  # smallest rating-list pad width (sublane-friendly)
 
+# Zero slots at the tail of every block of a factor table (the strip).  The
+# OPPOSITE side's pad entries gather them, spread so that no strip slot is
+# named twice within _PAD_STRIP consecutive positions of a bucket's flat
+# (rows, w) order.  A table that lives in HBM serves one row named over and
+# over more slowly than distinct rows: with every pad of a side on ONE slot
+# msd-ials' item half gathered its 41.6M rows (19% of them pads) at 4.9-11.8
+# ns a row by bucket, the longer the pad runs the slower; spread, at 3.95 in
+# every bucket but the widest, which is what its 3%-pad bucket always cost
+# (0.7200 -> 0.6596 s/iter; a table in fast memory, als-ml20m's two, never
+# cared).  Chosen once on the chip (TPU v5e, PR 34, PERF.md section 6): the
+# gather alone reads 8.55 ns a row at 1 slot, 7.14 at 8 and 7.10 at 128,
+# 1,024 and 8,192, the same as pads on distinct real rows; 128 is the
+# smallest size probed that is on the plateau in every bucket, and one lane
+# tile.  A constant, not a knob: it costs a block 127 rows of zeros.
+_PAD_STRIP = 128
+
 
 @dataclasses.dataclass
 class SideLayout:
@@ -125,20 +141,22 @@ class SideLayout:
     contiguous rows and the solve writes factors with no scatter.
     """
 
-    per_block: int            # slots per block (Σ_j rows[j] + 1 — the last
-    #                           slot of every block is a guaranteed dummy)
+    per_block: int            # slots per block (Σ_j rows[j] + _PAD_STRIP:
+    #                           every block ends in a strip of guaranteed-
+    #                           zero slots)
     n_rows: int               # real entity count
     perm: np.ndarray          # (n_rows,) dense index -> global slot
     widths: Tuple[int, ...]   # pad width per bucket, descending
     rows: Tuple[int, ...]     # rows per bucket per block (static across blocks)
     idx: list                 # per bucket: (D, rows[j], widths[j]) int32,
     #                           opposite-side global slot of each rating;
-    #                           PAD entries point at the opposite side's
-    #                           guaranteed-zero dummy slot, so gathered pad
-    #                           rows are exact zeros and assembly needs no
-    #                           mask arrays at all
+    #                           PAD entries are spread over the strip of
+    #                           the opposite side's block of the same
+    #                           number, so gathered pad rows are exact
+    #                           zeros and assembly needs no mask arrays at
+    #                           all
     val: list                 # per bucket: ratings, pad entries 0
-    count: np.ndarray         # (D, per_block) degree per slot (0 for dummies)
+    count: np.ndarray         # (D, per_block) degree per slot (0 on the strip)
 
 
 @dataclasses.dataclass
@@ -170,7 +188,7 @@ class BlockedProblem:
     def n_items(self) -> int:
         return int(self.item_ids.shape[0])
 
-    # factor-table slot counts (include bucket-padding dummy rows)
+    # factor-table slot counts (include bucket-padding rows and the strip)
     @property
     def users_per_block(self) -> int:
         return self.u.per_block
@@ -269,11 +287,11 @@ def _side_order(row_idx: np.ndarray, n_rows: int, n_blocks: int,
     remap = np.cumsum(keep) - 1
     bucket_of = remap[bucket_of]
     offsets = np.concatenate([[0], np.cumsum(rows)])  # slot offset per bucket
-    # +1: the last slot of every block is a guaranteed dummy — its factor
-    # row is zero for the life of the fit (zero-filled at init in
-    # _pad_factors, kept zero by the count==0 mask in _solve_factors), and
-    # the OPPOSITE side's pad gathers point at it
-    per_block = int(offsets[-1]) + 1
+    # every block ends in the strip: _PAD_STRIP slots whose factor rows are
+    # zero for the life of the fit (zero-filled at init in _pad_factors,
+    # kept zero by the count==0 mask in _solve_factors), and the OPPOSITE
+    # side's pad gathers are spread over them (_fill_side)
+    per_block = int(offsets[-1]) + _PAD_STRIP
     # rank of each entity within its (block, bucket), following `order`
     sorted_b = block_of[order]
     sorted_j = bucket_of[order]
@@ -286,19 +304,34 @@ def _side_order(row_idx: np.ndarray, n_rows: int, n_blocks: int,
     return deg, block_of, bucket_of, perm, widths, rows, per_block
 
 
+def _strip_slots(n: int) -> np.ndarray:
+    """Offsets into a strip for ``n`` consecutive pad positions: position p
+    takes slot p mod _PAD_STRIP, so a slot comes back only after every
+    other has been named."""
+    return np.arange(n, dtype=np.int32) % _PAD_STRIP
+
+
 def _fill_side(
     row_idx, col_idx, vals, n_rows, n_blocks, side_order, opp_perm,
-    opp_pad_slot, dtype
+    opp_per_block, dtype
 ) -> SideLayout:
     """Build one side's bucketed arrays from its precomputed ``_side_order``
     result.  ``opp_perm`` maps the opposite side's dense indices to its
     global slots (the positions valid against the all_gather'd factor
-    table); ``opp_pad_slot`` is an opposite-side slot whose factor row is
-    guaranteed zero — pad entries gather it, so no mask array exists."""
+    table); ``opp_per_block`` is the opposite side's slots per block, whose
+    last _PAD_STRIP are the strip: factor rows guaranteed zero — pad
+    entries gather them, so no mask array exists.  Block d's pads name the
+    strip of the opposite side's block d (its own shard: pads never ride a
+    routed exchange), the position p of a bucket's flat (rows, w) order the
+    strip's slot p mod _PAD_STRIP: within a list addresses ascend up to
+    where the strip wraps, and no slot is named twice within _PAD_STRIP
+    consecutive positions."""
     deg, block_of, bucket_of, perm, widths, rows, per_block = side_order
     nb = len(widths)
+    strip = (np.arange(n_blocks, dtype=np.int32)[:, None, None] * opp_per_block
+             + (opp_per_block - _PAD_STRIP))
     idx = [
-        np.full((n_blocks, rows[j], widths[j]), opp_pad_slot, np.int32)
+        strip + _strip_slots(rows[j] * widths[j]).reshape(rows[j], widths[j])
         for j in range(nb)
     ]
     val = [np.zeros((n_blocks, rows[j], widths[j]), dtype) for j in range(nb)]
@@ -387,17 +420,15 @@ def prepare_blocked(
     u_order = _side_order(u_idx, len(user_ids), n_blocks, ratio)
     i_order = _side_order(i_idx, len(item_ids), n_blocks, ratio)
     u_perm, i_perm = u_order[3], i_order[3]
-    # each side's pad gathers target the opposite side's guaranteed dummy
-    # (last slot of block 0 — every block's last slot is a dummy)
-    u_pad_slot = u_order[6] - 1
-    i_pad_slot = i_order[6] - 1
+    # each side's pad gathers are spread over the opposite side's strip
+    # (the tail of every block), found from its slots per block
     u_side = _fill_side(
         u_idx, i_idx, ratings, len(user_ids), n_blocks, u_order, i_perm,
-        i_pad_slot, dtype
+        i_order[6], dtype
     )
     i_side = _fill_side(
         i_idx, u_idx, ratings, len(item_ids), n_blocks, i_order, u_perm,
-        u_pad_slot, dtype
+        u_order[6], dtype
     )
     return BlockedProblem(
         n_blocks=n_blocks,
@@ -432,8 +463,8 @@ class RoutedSide:
 
     send_idx: np.ndarray   # (D, D, r_max) int32: LOCAL factor rows source
     #                        block s sends to destination d; the diagonal
-    #                        (s == d) and pad entries point at s's
-    #                        guaranteed-zero dummy slot — self-owned rows
+    #                        (s == d) and pad entries are spread over
+    #                        s's guaranteed-zero strip — self-owned rows
     #                        never ride the collective
     idx: list              # per bucket: (D, rows_j, w_j) int32 into the
     #                        received table: off-block slots at
@@ -457,7 +488,6 @@ def build_routing(side: SideLayout, opp: SideLayout,
     agree bitwise."""
     D = n_blocks
     opp_pb = opp.per_block
-    pad_local = opp_pb - 1  # every block's last slot is a guaranteed dummy
     routes = [[None] * D for _ in range(D)]  # [src][dst] -> local rows
     r_max = 1
     for d in range(D):
@@ -471,7 +501,10 @@ def build_routing(side: SideLayout, opp: SideLayout,
                 continue  # self-owned rows come from the local shard
             routes[s][d] = loc[src == s]  # sorted (need is sorted)
             r_max = max(r_max, len(routes[s][d]))
-    send_idx = np.full((D, D, r_max), pad_local, np.int32)
+    # every block ends in the zero strip; a route's unused tail and the
+    # diagonal are spread over it like a bucket's pads
+    send_idx = np.broadcast_to(
+        opp_pb - _PAD_STRIP + _strip_slots(r_max), (D, D, r_max)).copy()
     for s in range(D):
         for d in range(D):
             if s == d:
@@ -622,8 +655,8 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     Pallas kernel or the einsum pair, as ``resolve_assembly`` answers for
     ``platform`` (the mesh's; None = einsum).
 
-    No mask arrays exist: pad entries gather the opposite side's dummy
-    slot, whose factor row is zero by construction, so every pad term
+    No mask arrays exist: pad entries gather the opposite side's strip,
+    whose factor rows are zero by construction, so every pad term
     vanishes through y itself (explicit A needs no weighting at all —
     one fewer (r, w, k) transient and multiply on the hot path).
 
@@ -655,7 +688,7 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
         # XLA's either way, the contraction is what `how` chose
         with jax.named_scope("als.gather"):
             # kernel path: out-of-range indices clip (there are none: pads
-            # point at a real dummy slot).  The default mode's fill is a
+            # point at real slots, the strip's).  The default mode's fill is a
             # select over all of y, which XLA folds into the einsum's
             # operands but would run as a pass of its own before a kernel
             y = jnp.take(y_all, idx_c, axis=0,              # (r, w, k)
@@ -704,7 +737,9 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     # batched that padding into a 159 GB broadcast: the round-3 AOT OOM.)
     # Pad rows to a chunk multiple: pad gathers hit slot 0 and the padded
     # counts are 0, so the solve masks padded rows to zero and the slice
-    # below discards them — per-row arithmetic is untouched.
+    # below discards them — per-row arithmetic is untouched.  Since the
+    # steps are of equal size (_chunk_rows) that is fewer rows than the
+    # bucket has steps, not most of a step.
     n_chunks = -(-r // C)
     r_pad = n_chunks * C
 
@@ -749,13 +784,14 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     Implicit:  A = Σ alpha·r·y yᵀ,  b = Σ (1+alpha·r)·y  (HKV; YtY added
                by caller)
 
-    Pad entries have val 0 and idx = the opposite side's dummy slot, whose
-    factor row is zero — every pad term vanishes through y or val.
+    Pad entries have val 0 and idx = a slot of the opposite side's strip,
+    whose factor rows are zero — every pad term vanishes through y or val.
 
     ``lanes``: the hand-off to the Pallas solver with nothing in between —
     At (k, k, n), bt (k, n), each bucket's entities on the lanes followed by
     its zero pad up to a whole lane tile, joined along the lanes (one copy;
-    no relayout, no pad pass, no dummy system: ``_solve_factors_lanes``).
+    no relayout, no pad pass, no system for the strip:
+    ``_solve_factors_lanes``).
     """
     As, bs = [], []
     for idx, val in buckets:
@@ -768,11 +804,11 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     if lanes:
         return jnp.concatenate(As, axis=2), jnp.concatenate(bs, axis=1)
     k = y_all.shape[1]
-    # one zero system for the block's guaranteed dummy last slot (no bucket
-    # row covers it); count==0 regularization keeps it PD and the solve
-    # masks its result to zero, preserving the slot's zero factor row
-    As.append(jnp.zeros((1, k, k), dtype))
-    bs.append(jnp.zeros((1, k), dtype))
+    # zero systems for the block's strip (no bucket row covers it);
+    # count==0 regularization keeps them PD and the solve masks their
+    # results to zero, preserving the strip's zero factor rows
+    As.append(jnp.zeros((_PAD_STRIP, k, k), dtype))
+    bs.append(jnp.zeros((_PAD_STRIP, k), dtype))
     return jnp.concatenate(As, axis=0), jnp.concatenate(bs, axis=0)
 
 
@@ -968,8 +1004,8 @@ def _solve_factors_lanes(At, bt, counts, rows, lam, weighted_reg,
     ``rows`` the buckets' entity counts: the same system per entity, with
     the diagonal added inside the solver's tile.  Only the (n,) diagonal
     goes out to the kernels' lane layout and only x (k, n) comes back from
-    it; a bucket's pad lanes are identity systems, and the block's dummy
-    last slot, which no bucket covers, is the zero row appended here."""
+    it; a bucket's pad lanes are identity systems, and the block's strip,
+    which no bucket covers, is the zero rows appended here."""
     from .assemble_pallas import LANES
     from .cholesky_pallas import cholesky_solve_lanes
 
@@ -983,7 +1019,8 @@ def _solve_factors_lanes(At, bt, counts, rows, lam, weighted_reg,
     x = cholesky_solve_lanes(At, bt, jnp.concatenate(d),
                              interpret=platform != "tpu")
     x = jnp.concatenate([x[:, p] for p in picks]
-                        + [jnp.zeros((x.shape[0], 1), x.dtype)], axis=1)
+                        + [jnp.zeros((x.shape[0], _PAD_STRIP), x.dtype)],
+                        axis=1)
     return jnp.where((counts > 0)[:, None], x.T, 0.0)
 
 
@@ -1050,7 +1087,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             if routed:
                 # need-list exchange: send each destination only the
                 # off-block rows its ratings reference (pad/diagonal rows
-                # are the dummy slot -> zeros); the received (D, r_max, k)
+                # are the strip's -> zeros); the received (D, r_max, k)
                 # stack plus the device's OWN shard is the gather table,
                 # with idx arrays pre-remapped (off-block: s*r_max + pos;
                 # self: D*r_max + local)
@@ -1082,9 +1119,9 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             # contiguous slot ranges, so each bucket's factor rows are
             # solved straight out of its assembly chunks and concatenated
             # in slot order — the full (per_block, k, k) tensor never
-            # exists.  The block's guaranteed dummy last slot gets its
-            # zero row appended explicitly (the unfused path routes it
-            # through a zero system + count mask).
+            # exists.  The block's strip gets its zero rows appended
+            # explicitly (the unfused path routes them through zero
+            # systems + the count mask).
             def solve_chunk(A, bb, cnt, in_scan=False):
                 with jax.named_scope("als.solve"):
                     if yty is not None:
@@ -1105,7 +1142,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                         platform=platform,
                     ))
                 off += rows_j
-            xs.append(jnp.zeros((1, k), dtype))
+            xs.append(jnp.zeros((_PAD_STRIP, k), dtype))
             return jnp.concatenate(xs, axis=0)[None]
         with jax.named_scope("als.assemble"):
             A, b = _assemble_normal_eqs(
@@ -1256,12 +1293,22 @@ def _staging_meta(problem: "BlockedProblem", config: "ALSConfig",
         h.update(np.ascontiguousarray(init[1]).tobytes())
         init_id = h.hexdigest()
     # the actual rating data matters too: same-shaped re-exports of fresh
-    # data must retrain, not resume (bucket arrays cover ids, values, layout)
+    # data must retrain, not resume (bucket arrays cover ids, values, layout).
+    # Slots are hashed with every block's strip folded back to the one slot
+    # a block ended in before the strip, and pads aimed at block 0's as they
+    # were then: the identity does not depend on _PAD_STRIP, and a snapshot
+    # written before the strip keeps resuming (its factors are in dense-id
+    # order, which no layout touches)
+    fold = _PAD_STRIP - 1
+    opp_pb = problem.i.per_block
+    idx = [
+        np.where(ix % opp_pb >= opp_pb - _PAD_STRIP, opp_pb - _PAD_STRIP,
+                 ix - ix // opp_pb * fold).astype(np.int32)
+        for ix in problem.u.idx
+    ]
+    perm = problem.u.perm - problem.u.perm // problem.u.per_block * fold
     hd = hashlib.sha1()
-    for a in (
-        [problem.u.perm, problem.user_ids, problem.item_ids]
-        + problem.u.idx + problem.u.val
-    ):
+    for a in [perm, problem.user_ids, problem.item_ids] + idx + problem.u.val:
         hd.update(np.ascontiguousarray(a).tobytes())
     return {
         "data": hd.hexdigest(),
@@ -1377,7 +1424,8 @@ def init_factors(n_pad: int, k: int, key, dtype) -> jnp.ndarray:
 def _pad_factors(problem: BlockedProblem, D: int, k: int, dtype,
                  uf_raw: np.ndarray, itf_raw: np.ndarray):
     """Dense-id (n_users, k)/(n_items, k) factors -> block-shaped slot
-    layout (D, per_block, k); dummy slots stay zero."""
+    layout (D, per_block, k); the strip and bucket-padding slots stay
+    zero."""
     uf0 = np.zeros((problem.u.per_block * D, k), dtype=dtype)
     uf0[problem.u.perm] = uf_raw
     itf0 = np.zeros((problem.i.per_block * D, k), dtype=dtype)
@@ -1393,14 +1441,16 @@ def _pad_factors(problem: BlockedProblem, D: int, k: int, dtype,
 def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
                        mesh: Mesh) -> None:
     """What the compiled sweep streams and how it solves, for whoever reads
-    the registry, over both sides and all devices: factor-table slots
-    (``rows``) and those of them on the per-chunk route (``fused_rows``:
+    the registry, over both sides and all devices: factor-table slots, the
+    strips of zero slots among them (``rows``), those of them on the
+    per-chunk route (``fused_rows``:
     ``solves_per_chunk`` per side); assembly steps an iteration, one per
     straight-line bucket and one per lax.map chunk (``chunks``); the bytes
     one device's (per_block, k, k) normal equations take or would take, the
     larger side's (``normal_eq_bytes``); the rating slots the gather reads,
     each rating once a side plus the bucket ladder's padding (``entries``),
-    and the padding alone (``pad_entries``)."""
+    and the padding alone (``pad_entries``); the zero slots that padding is
+    spread over, one strip a block (``pad_slots``)."""
     D, k = num_blocks(mesh), config.num_factors
     itemsize = np.dtype(config.dtype).itemsize
     exchange = resolve_exchange(config.exchange_dtype,
@@ -1424,6 +1474,7 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
         max(problem.u.per_block, problem.i.per_block) * k * k * itemsize)
     reg.gauge("tpums_als_entries").set(entries)
     reg.gauge("tpums_als_pad_entries").set(entries - 2 * problem.nnz)
+    reg.gauge("tpums_als_pad_slots").set(2 * D * _PAD_STRIP)
 
 
 def compile_fit(
@@ -1445,7 +1496,7 @@ def compile_fit(
         key_u, key_i = jax.random.split(jax.random.PRNGKey(config.seed))
         # draw in dense-id space (first n rows of the padded draw, keeping
         # the draw shape stable for reproducibility) and place via perm —
-        # dummy slots stay zero so the implicit mode's psum'd Gramian (and
+        # unowned slots stay zero so the implicit mode's psum'd Gramian (and
         # any future dense reduction over the table) never sees them
         init = (
             np.asarray(init_factors(problem.u.per_block * D, k, key_u, dtype))[

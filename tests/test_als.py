@@ -35,24 +35,30 @@ def _numpy_user_halfsweep(u, i, r, itf, k, lam, weighted):
     return out
 
 
+def _is_pad(idx, opp_per_block):
+    """Entries of a (D, rows, w) bucket that name a slot of a strip."""
+    return idx % opp_per_block >= opp_per_block - A._PAD_STRIP
+
+
 def test_prepare_blocked_layout(rng):
     u, i, r = _synthetic(rng)
     p = A.prepare_blocked(u, i, r, 4)
     assert all(a.shape[0] == 4 for a in p.u.idx)
     # every rating accounted for exactly once (counts sum to nnz; pad
-    # entries = idx pointing at the opposite side's dummy slot)
+    # entries = idx pointing into the opposite side's strip)
     assert int(p.u.count.sum()) == p.nnz == len(r)
     assert int(p.i.count.sum()) == p.nnz
-    i_pad_slot = p.i.per_block - 1
-    n_pads = sum(int((ix == i_pad_slot).sum()) for ix in p.u.idx)
+    assert p.i.per_block == sum(p.i.rows) + A._PAD_STRIP
+    n_pads = sum(int(_is_pad(ix, p.i.per_block).sum()) for ix in p.u.idx)
     total_cells = sum(ix.size for ix in p.u.idx)
     assert total_cells - n_pads == p.nnz
     # pad entries carry zero rating
     for ix, v in zip(p.u.idx, p.u.val):
-        assert (v[ix == i_pad_slot] == 0).all()
-    # the dummy slot is real: never a destination for any entity's factors
-    assert i_pad_slot not in set(p.i.perm.tolist())
-    assert (p.i.count[:, -1] == 0).all()  # every block's last slot is dummy
+        assert (v[_is_pad(ix, p.i.per_block)] == 0).all()
+    # the strip is real: never a destination for any entity's factors
+    assert not _is_pad(p.i.perm, p.i.per_block).any()
+    # every block ends in the strip
+    assert (p.i.count[:, -A._PAD_STRIP:] == 0).all()
     # perm is a bijection into the slot space and respects block membership
     assert len(np.unique(p.u.perm)) == p.n_users
     dense_pb = -(-p.n_users // 4)
@@ -61,8 +67,146 @@ def test_prepare_blocked_layout(rng):
     )
     # every bucket row's real-entry count fits its width
     for w, ix in zip(p.u.widths, p.u.idx):
-        per_row = (ix != i_pad_slot).sum(axis=-1)
+        per_row = (~_is_pad(ix, p.i.per_block)).sum(axis=-1)
         assert per_row.max() <= w
+
+
+@pytest.mark.parametrize("blocks", [1, 4])
+@pytest.mark.parametrize("strip", [1, 3, 8, None])
+def test_pads_are_spread_over_the_opposite_strip(rng, monkeypatch, strip,
+                                                 blocks):
+    """Whatever the strip's size (None = the module's): every pad position
+    of block d names a slot of the opposite side's block d strip, no slot
+    twice within a strip's length of consecutive flat positions, a list's
+    real entries come first and ascend, and the routed exchange's unused
+    send slots take the strip the same way."""
+    if strip is not None:
+        monkeypatch.setattr(A, "_PAD_STRIP", strip)
+    P = A._PAD_STRIP
+    u = np.repeat(np.arange(120), rng.integers(1, 60, 120))
+    i = rng.integers(0, 70, len(u))
+    p = A.prepare_blocked(u, i, rng.uniform(1, 5, len(u)), blocks)
+    for side, opp in ((p.u, p.i), (p.i, p.u)):
+        assert side.per_block == sum(side.rows) + P
+        assert (side.count[:, -P:] == 0).all()
+        assert not _is_pad(side.perm, side.per_block).any()
+        seen_pads = 0
+        for ix, val in zip(side.idx, side.val):
+            pad = _is_pad(ix, opp.per_block)
+            seen_pads += int(pad.sum())
+            assert (val[pad] == 0).all() and (val[~pad] != 0).all()
+            for d in range(blocks):
+                flat, flat_pad = ix[d].ravel(), pad[d].ravel()
+                # a pad names its own block's strip, by its flat position
+                where = np.nonzero(flat_pad)[0]
+                np.testing.assert_array_equal(
+                    flat[where], (d + 1) * opp.per_block - P + where % P)
+                # ... so equal slots are a multiple of P positions apart
+                for slot in np.unique(flat[where]):
+                    at = where[flat[where] == slot]
+                    assert (np.diff(at) >= P).all()
+                # real entries lead every list, in ascending address order
+                for row, row_pad in zip(ix[d], pad[d]):
+                    n_real = int((~row_pad).sum())
+                    assert not row_pad[:n_real].any() and row_pad[n_real:].all()
+                    assert (np.diff(row[:n_real]) >= 0).all()
+        assert seen_pads == sum(ix.size for ix in side.idx) - p.nnz > 0
+    if blocks > 1:
+        # pads are the block's own shard's rows and no entity lives on a
+        # strip, so nothing of a strip is needed from another block: a
+        # route is real rows, then the strip spread over what is left
+        routed = A.build_routing(p.u, p.i, blocks)
+        lo = p.i.per_block - P
+        tail = lo + np.arange(routed.r_max) % P
+        for s in range(blocks):
+            np.testing.assert_array_equal(routed.send_idx[s, s], tail)
+            for d in range(blocks):
+                n_real = int((routed.send_idx[s, d] < lo).sum())
+                assert (routed.send_idx[s, d, :n_real] < lo).all()
+                np.testing.assert_array_equal(
+                    routed.send_idx[s, d, n_real:], tail[n_real:])
+
+
+def _one_slot_layout(problem):
+    """The layout before the strip: every pad of every block names ONE
+    slot, the last of the opposite side's block 0.  Same shapes, same real
+    entries in the same places; only where the pads point differs."""
+    import dataclasses
+
+    def aimed(side, opp):
+        idx = [np.where(_is_pad(ix, opp.per_block),
+                        np.int32(opp.per_block - 1), ix) for ix in side.idx]
+        return dataclasses.replace(side, idx=idx)
+
+    return dataclasses.replace(
+        problem, u=aimed(problem.u, problem.i), i=aimed(problem.i, problem.u),
+        routing={})
+
+
+def _kernel_where_explicit(platform, y_dtype, dtype, implicit, k,
+                           precision="highest"):
+    return "einsum" if implicit else "kernel"
+
+
+@pytest.mark.parametrize("blocks, exchange", [
+    (1, "auto"), (4, "gather"), (4, "routed")])
+@pytest.mark.parametrize("route", ["materialised", "per chunk"])
+@pytest.mark.parametrize("mode, assembly", [
+    ("explicit", "einsum"), ("explicit", "kernel"), ("implicit", "einsum")])
+def test_the_strip_stays_zero_and_moves_no_factor(rng, monkeypatch, mode,
+                                                  assembly, route, blocks,
+                                                  exchange):
+    """Two iterations on the strip layout against the same problem with
+    every pad aimed at one slot, as before PR 34: the same terms in the
+    same order, so real entities' factors agree to the last bit; the
+    strip's rows are exact zeros at the start and after the iterations on
+    both sides; the gauge counts the strips."""
+    from flink_ms_tpu.obs import metrics as obs_metrics
+
+    monkeypatch.setenv("FLINK_MS_ALS_FUSED", "1" if route == "per chunk" else "0")
+    monkeypatch.setenv("FLINK_MS_ALS_EXCHANGE_MODE", exchange)
+    # small enough that the widest buckets run under lax.map
+    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", "4096")
+    if assembly == "kernel":
+        # the chip's kernel interpreted, handing A to the Pallas solver
+        monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
+        monkeypatch.setattr(A, "resolve_assembly", _kernel_where_explicit)
+    A._SWEEP_CACHE.clear()   # the resolver is not in the sweep's cache key
+    P, k = A._PAD_STRIP, 4
+    u = np.repeat(np.arange(90), rng.integers(1, 40, 90))
+    i = rng.integers(0, 50, len(u))
+    r = np.abs(rng.normal(size=len(u))) + 0.5
+    problem = A.prepare_blocked(u, i, r, blocks)
+    init = (rng.normal(size=(problem.n_users, k)).astype(np.float32),
+            rng.normal(size=(problem.n_items, k)).astype(np.float32))
+    cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
+                      implicit=mode == "implicit", alpha=10.0,
+                      exchange_dtype=None)
+    mesh = make_mesh(blocks)
+    states = []
+    try:
+        for layout in (problem, _one_slot_layout(problem)):
+            fit_fn, dev_args = A.compile_fit(layout, cfg, mesh, init=init)
+            if layout is problem:
+                if exchange == "routed":
+                    assert A._exchange_plan(layout, blocks)["u"] is not None
+                gauges = {g["name"]: g["value"] for g in
+                          obs_metrics.get_registry().snapshot()["gauges"]
+                          if not g["labels"]}
+                assert gauges["tpums_als_pad_slots"] == 2 * blocks * P
+                for table in dev_args[:2]:
+                    assert (np.asarray(table)[:, -P:] == 0).all()
+            states.append([np.asarray(t) for t in
+                           fit_fn(jnp.asarray(2, jnp.int32), *dev_args)])
+    finally:
+        A._SWEEP_CACHE.clear()
+    (uf, itf), (uf_old, itf_old) = states
+    for table, old, side in ((uf, uf_old, problem.u), (itf, itf_old, problem.i)):
+        assert table.shape == (blocks, side.per_block, k)
+        assert (table[:, -P:] == 0).all()
+        got = table.reshape(-1, k)[side.perm]
+        assert np.abs(got).min(axis=1).max() > 0  # the iterations ran
+        np.testing.assert_array_equal(got, old.reshape(-1, k)[side.perm])
 
 
 def test_assembly_matches_numpy(rng):
@@ -547,6 +691,29 @@ def test_staged_fit_resumes_from_snapshot(rng, tmp_path):
     np.testing.assert_allclose(
         resumed.user_factors, full.user_factors, rtol=2e-4, atol=2e-5
     )
+
+
+@pytest.mark.parametrize("blocks, parent_digest", [
+    (1, "145d4516bb755f3a9f7edccb37ee0abe5f28a618"),
+    (4, "09c7057c1104001430d7714831c359b6bcd2cd46"),
+])
+@pytest.mark.parametrize("strip", [None, 8])
+def test_staging_identity_is_the_one_from_before_the_strip(monkeypatch, strip,
+                                                           blocks,
+                                                           parent_digest):
+    """A snapshot written before PR 34 (one dummy slot a block) must keep
+    resuming: the run's identity hashes the layout with the strip folded
+    away, so it reads what commit 0f8ff06 computed for the same ratings
+    (the digests are that commit's), whatever the strip's size."""
+    if strip is not None:
+        monkeypatch.setattr(A, "_PAD_STRIP", strip)
+    rng = np.random.default_rng(34)
+    u = np.repeat(np.arange(90), rng.integers(1, 40, 90))
+    i = rng.integers(0, 50, len(u))
+    r = rng.uniform(1, 5, len(u))
+    p = A.prepare_blocked(u, i, r, blocks)
+    cfg = A.ALSConfig(num_factors=4, iterations=2, lambda_=0.1)
+    assert A._staging_meta(p, cfg, None, "cpu")["data"] == parent_digest
 
 
 def test_staged_mismatched_snapshot_ignored(rng, tmp_path):

@@ -130,7 +130,8 @@ def _ladder_problem():
 
     side = {n: types.SimpleNamespace(widths=tuple(w for w, _ in b),
                                      rows=tuple(r for _, r in b),
-                                     per_block=sum(r for _, r in b) + 1)
+                                     per_block=sum(r for _, r in b)
+                                     + A._PAD_STRIP)
             for n, b in _ML20M.items()}
     return types.SimpleNamespace(u=side["u"], i=side["i"])
 
@@ -157,7 +158,9 @@ def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     u, i = line.split("i-sweep")
     assert "lane-major hand-off " + want[0] in u
     assert "lane-major hand-off " + want[1] in i
-    assert "u-sweep solve materialised (1.38 GB of normal equations)" in u
+    users_gb = (138493 + A._PAD_STRIP) * 50 * 50 * 4 / 1e9
+    assert (f"u-sweep solve materialised ({users_gb:.2f} GB of normal "
+            "equations)") in u
     A._log_assembly(_ladder_problem(), "kernel", False, 50, keep)  # lax solver
     assert "hand-off on 0 (0.0%" in capsys.readouterr().out
     # a side on the per-chunk route keeps the batch-major hand-off

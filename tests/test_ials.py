@@ -122,7 +122,7 @@ def test_implicit_iteration_agrees_with_the_reference(rng, monkeypatch, route,
 def test_the_sweep_decides_per_side_and_still_agrees(rng, monkeypatch):
     """A device whose memory holds four of the item side's tensors and not
     four of the user side's: users per chunk, items materialised."""
-    users_bytes, items_bytes = 61 * 8 * 8 * 4, 26 * 8 * 8 * 4
+    users_bytes, items_bytes = ((n + A._PAD_STRIP) * 8 * 8 * 4 for n in (60, 25))
     memory = 4 * items_bytes + 64
     assert 4 * users_bytes > memory
     problem, cfg, mesh, model, want_u, want_i = one_iteration(
@@ -264,6 +264,7 @@ def test_gauges_and_the_counter_read_what_the_layout_implies(
     assert got["tpums_als_fused_rows"] == (rows if route == "1" else 0)
     assert got["tpums_als_entries"] == stored
     assert got["tpums_als_pad_entries"] == stored - 2 * len(plays)
+    assert got["tpums_als_pad_slots"] == 2 * devices * A._PAD_STRIP
     assert got["tpums_als_normal_eq_bytes"] == (
         max(problem.u.per_block, problem.i.per_block) * k * k * 4)
     # one step a straight-line bucket, several where 4 KiB cuts one up

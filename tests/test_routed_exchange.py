@@ -76,9 +76,11 @@ def test_block_local_ratings_route_almost_nothing():
     # the per-block catalog slice
     assert routed.net_rows < gather_rows / 4
     assert routed.r_max <= max(problem.i.per_block // 4, 2)
-    # the diagonal send slots are all the dummy (nothing self-shipped)
-    pad_local = problem.i.per_block - 1
-    assert set(routed.send_idx[2, 2].tolist()) == {pad_local}
+    # the diagonal send slots are all the zero strip's (nothing self-shipped)
+    strip = problem.i.per_block - als._PAD_STRIP
+    np.testing.assert_array_equal(
+        routed.send_idx[2, 2],
+        strip + np.arange(routed.r_max) % als._PAD_STRIP)
 
 
 def test_exchange_volume_shrinks_with_mesh_size():
