@@ -135,13 +135,128 @@ def stage(name: str, **fields):
     process that never imported jax (clients, the load generator) it is a
     shared no-op object, and jax is not imported for its sake.
 
-    Not a ``span``: those are per-request, head-sampled, on ``time.time()``
+    Not a ``span``: those are per-request, head-sampled, on the wall clock
     and spill to JSONL; a stage is per-thread wall time for whoever is
-    profiling, and records nothing otherwise."""
+    profiling, and records nothing otherwise (``phase`` is the stage that
+    also records)."""
     jax = sys.modules.get("jax")
     if jax is None:
         return _NO_STAGE
     return jax.profiler.TraceAnnotation(name, **fields)
+
+
+# ---------------------------------------------------------------------------
+# set-up phases
+# ---------------------------------------------------------------------------
+
+# One wall offset for the process, read at import: every instant this module
+# hands out as wall time is ``perf_counter() + wall_offset()``, so a span, its
+# parent and the batcher's stamps sit on one clock and nest without the drift
+# of a fresh wall-clock reading each.
+_WALL_OFFSET = time.time() - time.perf_counter()
+_PHASE_CAP = 1024
+
+
+def wall_offset() -> float:
+    """Wall time minus ``time.perf_counter()``, as read when this module was
+    imported."""
+    return _WALL_OFFSET
+
+
+class _PhaseLocal(threading.local):
+    stack = None  # names of the phases open on this thread, outermost first
+
+
+_phases = _PhaseLocal()
+# deque.append is atomic; entries are appended whole on exit and never edited
+_phase_log: Deque[dict] = deque(maxlen=_PHASE_CAP)
+
+
+class phase:
+    """``with phase("als.prepare.fill"):`` — a named piece of SET-UP, timed
+    where it happens and kept.  On exit one entry ``{name, start, end,
+    parent, thread}`` goes to a bounded in-memory log (``phase_log()``):
+    ``start`` / ``end`` on ``time.perf_counter()``, ``parent`` the name of
+    the innermost phase open on the same thread (None for a root; a thread
+    started inside a phase begins a root of its own).  The same enter / exit
+    opens the ``stage`` of that name, so a profile shows the phase on the
+    profiler's clock, and observes ``tpums_phase_seconds{kind=<name>}``, so
+    a METRICS scrape shows what each retrain wave or index rebuild spent
+    where.  There is no switch: a phase is always recorded.
+
+    A phase belongs at the boundaries of work done once a fit, once a
+    build or once a process (tens of entries a process), never on a
+    per-iteration, per-round, per-frame or per-request path (``stage`` is
+    for those).  It adds no wait of its own: around asynchronous work
+    (a ``device_put``) it times the enqueue only."""
+
+    __slots__ = ("name", "_parent", "_stage", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "phase":
+        stack = _phases.stack
+        if stack is None:
+            stack = _phases.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._stage = stage(self.name)
+        self._stage.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end = time.perf_counter()
+        self._stage.__exit__(exc_type, exc, tb)
+        _phases.stack.pop()
+        _phase_log.append({"name": self.name, "start": self._t0, "end": end,
+                           "parent": self._parent,
+                           "thread": threading.get_ident()})
+        _metrics.get_registry().histogram(
+            "tpums_phase_seconds", kind=self.name).observe(end - self._t0)
+
+
+def phase_log() -> List[dict]:
+    """The phases that have ended, oldest first (the last ``_PHASE_CAP``)."""
+    return list(_phase_log)
+
+
+def clear_phases() -> None:
+    _phase_log.clear()
+
+
+def phase_children(entry: dict, entries: List[dict]) -> List[dict]:
+    """The entries opened directly under ``entry``: same thread, its name as
+    their parent, inside its interval.  Phases of one thread nest, so the
+    children do not overlap: self time is the duration minus their sum."""
+    return [e for e in entries
+            if e["parent"] == entry["name"] and e["thread"] == entry["thread"]
+            and e["start"] >= entry["start"] and e["end"] <= entry["end"]]
+
+
+def phase_seconds(entries) -> Dict[str, float]:
+    """name -> summed seconds of the given entries, in the order met."""
+    out: Dict[str, float] = {}
+    for e in entries:
+        out[e["name"]] = out.get(e["name"], 0.0) + e["end"] - e["start"]
+    return out
+
+
+def phase_report(entries: Optional[List[dict]] = None) -> str:
+    """One line for an operator: every root by name with its seconds and,
+    in brackets, its children's, e.g. ``als.prepare 9.73s (als.prepare.order
+    2.10, als.prepare.fill 7.60), als.place 1.20s``.  Phases of one name
+    (two rebuilds) are summed."""
+    entries = phase_log() if entries is None else entries
+    roots = [e for e in entries if e["parent"] is None]
+    parts = []
+    for name, secs in phase_seconds(roots).items():
+        inner = ", ".join(f"{n} {s:.2f}" for n, s in phase_seconds(
+            c for e in roots if e["name"] == name
+            for c in phase_children(e, entries)).items())
+        parts.append(f"{name} {secs:.2f}s" + (f" ({inner})" if inner else ""))
+    return ", ".join(parts) if parts else "none"
 
 
 _ring_lock = threading.Lock()
@@ -252,9 +367,11 @@ class span:
     """``with span("stage", op=...):`` — one timed node in the request's
     causal tree.  Allocates a span id, parents under the innermost open
     span on this thread, and emits a single event carrying
-    ``sid``/``psid``/``t0``/``dur_s`` on exit.  A no-op (no id, no event)
-    when no trace context is active, so instrumented code pays one
-    thread-local read on the untraced path."""
+    ``sid``/``psid``/``t0``/``dur_s`` on exit: both instants are
+    ``perf_counter`` readings, ``t0`` moved to wall time by the process's one
+    ``wall_offset()``, so a child's ``t0 + dur_s`` never passes its
+    parent's.  A no-op (no id, no event) when no trace context is active,
+    so instrumented code pays one thread-local read on the untraced path."""
 
     __slots__ = ("kind", "fields", "tid", "sid", "_psid", "_t0")
 
@@ -276,7 +393,7 @@ class span:
             stack = _local.spans = []
         stack.append(self.sid)
         push_stage(self.kind)
-        self._t0 = time.time()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -286,8 +403,9 @@ class span:
         pop_stage()
         if exc_type is not None:
             self.fields.setdefault("error", repr(exc))
+        dur_s = time.perf_counter() - self._t0
         event(self.kind, tid=self.tid, sid=self.sid, psid=self._psid,
-              t0=self._t0, dur_s=time.time() - self._t0, **self.fields)
+              t0=self._t0 + _WALL_OFFSET, dur_s=dur_s, **self.fields)
 
 
 def span_event(kind: str, tid: Optional[str] = None,

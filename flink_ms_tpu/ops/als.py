@@ -65,6 +65,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import metrics as obs_metrics
+from ..obs import tracing
 from ..parallel.mesh import (
     BLOCK_AXIS,
     block_sharding,
@@ -404,32 +405,40 @@ def prepare_blocked(
     pad layout per block in both orientations.  ``bucket_ratio`` pins the
     width-ladder growth factor (default: validated
     FLINK_MS_ALS_BUCKET_RATIO env, 1.5) — multi-process launchers should
-    pass it explicitly so every host builds identical shapes."""
-    users = np.asarray(users)
-    items = np.asarray(items)
-    ratings = np.asarray(ratings, dtype=np.float64)
-    if users.shape[0] == 0:
-        raise ValueError("empty ratings input")
+    pass it explicitly so every host builds identical shapes.  Phases: the
+    root ``als.prepare`` with ``als.prepare.order`` (dense ids, degrees,
+    ladder, perms) and ``als.prepare.fill`` (both sides' key sort and
+    ragged fill)."""
+    with tracing.phase("als.prepare"):
+        with tracing.phase("als.prepare.order"):
+            users = np.asarray(users)
+            items = np.asarray(items)
+            ratings = np.asarray(ratings, dtype=np.float64)
+            if users.shape[0] == 0:
+                raise ValueError("empty ratings input")
 
-    user_ids, u_idx = _dense_ids(users)
-    item_ids, i_idx = _dense_ids(items)
+            user_ids, u_idx = _dense_ids(users)
+            item_ids, i_idx = _dense_ids(items)
 
-    # slot orders first: each side's idx arrays point at the OPPOSITE side's
-    # slots, so both perms must exist before either fill
-    ratio = bucket_ratio if bucket_ratio is not None else _bucket_ratio()
-    u_order = _side_order(u_idx, len(user_ids), n_blocks, ratio)
-    i_order = _side_order(i_idx, len(item_ids), n_blocks, ratio)
-    u_perm, i_perm = u_order[3], i_order[3]
-    # each side's pad gathers are spread over the opposite side's strip
-    # (the tail of every block), found from its slots per block
-    u_side = _fill_side(
-        u_idx, i_idx, ratings, len(user_ids), n_blocks, u_order, i_perm,
-        i_order[6], dtype
-    )
-    i_side = _fill_side(
-        i_idx, u_idx, ratings, len(item_ids), n_blocks, i_order, u_perm,
-        u_order[6], dtype
-    )
+            # slot orders first: each side's idx arrays point at the
+            # OPPOSITE side's slots, so both perms must exist before either
+            # fill
+            ratio = bucket_ratio if bucket_ratio is not None \
+                else _bucket_ratio()
+            u_order = _side_order(u_idx, len(user_ids), n_blocks, ratio)
+            i_order = _side_order(i_idx, len(item_ids), n_blocks, ratio)
+            u_perm, i_perm = u_order[3], i_order[3]
+        # each side's pad gathers are spread over the opposite side's strip
+        # (the tail of every block), found from its slots per block
+        with tracing.phase("als.prepare.fill"):
+            u_side = _fill_side(
+                u_idx, i_idx, ratings, len(user_ids), n_blocks, u_order,
+                i_perm, i_order[6], dtype
+            )
+            i_side = _fill_side(
+                i_idx, u_idx, ratings, len(item_ids), n_blocks, i_order,
+                u_perm, u_order[6], dtype
+            )
     return BlockedProblem(
         n_blocks=n_blocks,
         user_ids=user_ids,
@@ -584,7 +593,8 @@ def _exchange_plan(problem: BlockedProblem, D: int) -> dict:
             )
             plan[name] = None
             continue
-        routed = build_routing(side, opp, D)
+        with tracing.phase("als.prepare.route"):
+            routed = build_routing(side, opp, D)
         # ICI win condition: the all_to_all crosses (D-1)*r_max rows per
         # device vs the gather's (D-1)*opp_pb — route when the need-lists
         # are meaningfully thinner (margin for the extra take + concat)
@@ -1487,48 +1497,59 @@ def compile_fit(
     device-resident, block-sharded inputs.  ``fit_fn(iterations, *dev_args)``
     returns the factor shards as device arrays.  ``als_fit`` drives this;
     benchmarks call ``fit_fn`` directly so host<->device transfer stays out
-    of the timed region."""
+    of the timed region.  Phases: ``als.place`` (the host draw of starting
+    factors, the slot layout, every ``device_put``) and ``als.sweep`` (the
+    jitted sweep looked up or made, the layout gauges); the sweep is traced,
+    lowered and compiled by the first ``fit_fn`` call, which the
+    ``tpums_jax_*_seconds_total`` counters see."""
     D = num_blocks(mesh)
     k = config.num_factors
     dtype = config.dtype
-
-    if init is None:
-        key_u, key_i = jax.random.split(jax.random.PRNGKey(config.seed))
-        # draw in dense-id space (first n rows of the padded draw, keeping
-        # the draw shape stable for reproducibility) and place via perm —
-        # unowned slots stay zero so the implicit mode's psum'd Gramian (and
-        # any future dense reduction over the table) never sees them
-        init = (
-            np.asarray(init_factors(problem.u.per_block * D, k, key_u, dtype))[
-                : problem.n_users
-            ],
-            np.asarray(init_factors(problem.i.per_block * D, k, key_i, dtype))[
-                : problem.n_items
-            ],
-        )
-    uf0, itf0 = _pad_factors(problem, D, k, dtype, init[0], init[1])
-
-    shard3 = block_sharding(mesh, rank=3)
-    shard2 = block_sharding(mesh, rank=2)
-    # single-process: device_put straight from numpy — an intermediate
-    # jnp.asarray stages an unsharded default-device copy first, doubling
-    # the HBM transient for every array (the 10Mx1M envelope OOM'd on it).
-    # multi-process: device_put of raw numpy onto a multi-host sharding
-    # routes through multihost_utils.assert_equal (a cross-host allgather
-    # of the full array) and breaks under the DCN test harness — keep the
-    # committed-local-array path there.
-    def put(a, sharding):
-        if jax.process_count() > 1:
-            a = jnp.asarray(a)
-        return jax.device_put(a, sharding)
-
-    dev_args = [put(uf0, shard3), put(itf0, shard3)]
+    # the routing tables are host prep (phase als.prepare.route, where a
+    # mesh of more than one device builds them), not placement
     plan = _exchange_plan(problem, D)
-    for name, side in (("u", problem.u), ("i", problem.i)):
-        for a in _flat_side_args(side, dtype, routed=plan[name]):
-            dev_args.append(put(a, shard2 if a.ndim == 2 else shard3))
-    fit_fn = _cached_sweep(problem, config, mesh)
-    _set_layout_gauges(problem, config, mesh)
+
+    # enqueue only: device_put returns before the transfer ends, and its
+    # tail falls to whoever waits first (the first fit_fn call)
+    with tracing.phase("als.place"):
+        if init is None:
+            key_u, key_i = jax.random.split(jax.random.PRNGKey(config.seed))
+            # draw in dense-id space (first n rows of the padded draw,
+            # keeping the draw shape stable for reproducibility) and place
+            # via perm — unowned slots stay zero so the implicit mode's
+            # psum'd Gramian (and any future dense reduction over the table)
+            # never sees them
+            init = (
+                np.asarray(init_factors(
+                    problem.u.per_block * D, k, key_u, dtype
+                ))[: problem.n_users],
+                np.asarray(init_factors(
+                    problem.i.per_block * D, k, key_i, dtype
+                ))[: problem.n_items],
+            )
+        uf0, itf0 = _pad_factors(problem, D, k, dtype, init[0], init[1])
+
+        shard3 = block_sharding(mesh, rank=3)
+        shard2 = block_sharding(mesh, rank=2)
+        # single-process: device_put straight from numpy — an intermediate
+        # jnp.asarray stages an unsharded default-device copy first,
+        # doubling the HBM transient for every array (the 10Mx1M envelope
+        # OOM'd on it).  multi-process: device_put of raw numpy onto a
+        # multi-host sharding routes through multihost_utils.assert_equal
+        # (a cross-host allgather of the full array) and breaks under the
+        # DCN test harness — keep the committed-local-array path there.
+        def put(a, sharding):
+            if jax.process_count() > 1:
+                a = jnp.asarray(a)
+            return jax.device_put(a, sharding)
+
+        dev_args = [put(uf0, shard3), put(itf0, shard3)]
+        for name, side in (("u", problem.u), ("i", problem.i)):
+            for a in _flat_side_args(side, dtype, routed=plan[name]):
+                dev_args.append(put(a, shard2 if a.ndim == 2 else shard3))
+    with tracing.phase("als.sweep"):
+        fit_fn = _cached_sweep(problem, config, mesh)
+        _set_layout_gauges(problem, config, mesh)
     return fit_fn, dev_args
 
 

@@ -171,8 +171,8 @@ def prepare_svm_blocked(
     data: SparseData, n_blocks: int, seed: int = 0, dtype=np.float32
 ) -> BlockedSVMProblem:
     """Vectorized re-layout: shuffle examples across K blocks, pad each row
-    to the max nnz (static shapes for XLA)."""
-    with tracing.stage("svm.prepare"):
+    to the max nnz (static shapes for XLA).  Phase ``svm.prepare``."""
+    with tracing.phase("svm.prepare"):
         n = data.n_examples
         rows_pb = -(-n // n_blocks) if n else 1
         lens = (data.indptr[1:] - data.indptr[:-1]).astype(np.int64)
@@ -736,7 +736,9 @@ def compile_svm_fit(
     transfer and compile stay out of the timed region.  ``dev_args[0]`` is
     w and ``dev_args[5]`` alpha, ``(Kp, rows_per_block)`` in slot order;
     the rest is the engine's: the padded rectangles at [1], [2] on the
-    scatter engine, the bucketed rows on the Gram engine."""
+    scatter engine, the bucketed rows on the Gram engine.  Phases, each
+    awaited: ``svm.gram_build`` (Gram engine only) and ``svm.place``, with
+    the host's bucket copy under it as ``svm.bucket``."""
     D = num_blocks(mesh)
     Kp = _round_up(problem.n_blocks, D)
     dtype = config.dtype
@@ -755,18 +757,20 @@ def compile_svm_fit(
     if gram_fn is not None:
         # the Gram build reads the padded rectangles once and lets them
         # go: the rounds hold the bucketed rows only
-        with tracing.stage("svm.gram_build"):
+        with tracing.phase("svm.gram_build"):
             extra.append(jax.block_until_ready(
                 gram_fn(put(idx, shard3), put(val, shard3, dtype))))
-    # the stages end when the device has what they made, so that a profile
+    # the phases end when the device has what they made, so that a profile
     # shows the Gram build and the transfer, not their dispatch
-    with tracing.stage("svm.place"):
+    with tracing.phase("svm.place"):
         if gram_fn is not None:
-            plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
-            idx, val, slot = _bucket_rows(idx, val, plan)
-            stored, buckets = sum(a.size for a in idx), len(idx)
-            extra += [put(a, shard2) for a in (
-                slot, *_sorted_dw_operands(dw_mode, idx, val, slot, dtype))]
+            with tracing.phase("svm.bucket"):
+                plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
+                idx, val, slot = _bucket_rows(idx, val, plan)
+                stored, buckets = sum(a.size for a in idx), len(idx)
+                dw_operands = _sorted_dw_operands(
+                    dw_mode, idx, val, slot, dtype)
+            extra += [put(a, shard2) for a in (slot, *dw_operands)]
         dev_args = jax.block_until_ready([
             put(np.zeros((problem.n_features,)), rep, dtype),
             put(idx, shard3),

@@ -21,15 +21,31 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import metrics as obs_metrics
+from ..obs import tracing
 
 BLOCK_AXIS = "blocks"
 
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# The four costs of getting a program, each a duration event of jax's:
+# tracing the Python body, lowering the jaxpr to a module, loading the
+# executable from the persistent cache, and the backend's compile, which
+# CONTAINS the load (jax times the whole compile-or-load call), so on a
+# warm cache ``compile`` is mostly ``cache load``.
+_SECONDS_SERIES = {
+    _TRACE_EVENT: "tpums_jax_trace_seconds_total",
+    _LOWER_EVENT: "tpums_jax_lower_seconds_total",
+    _CACHE_LOAD_EVENT: "tpums_jax_cache_load_seconds_total",
+    _BACKEND_COMPILE_EVENT: "tpums_jax_compile_seconds_total",
+}
 
 _acquire_lock = threading.Lock()
 _acquired = False
+_listening = False
 
 
 def repo_cache_dir() -> str:
@@ -52,30 +68,92 @@ def _compile_counters() -> tuple:
 
 
 def _count_compiles() -> None:
-    """Feed jax's own compile events into the metrics registry, so a
-    trainer's summary line and a server's METRICS reply both say what the
-    process spent compiling and whether the persistent cache answered."""
-    secs, hits, misses = _compile_counters()
-    events = {_CACHE_HIT_EVENT: hits, _CACHE_MISS_EVENT: misses}
+    """Feed jax's own compile events into the metrics registry, once a
+    process, so a trainer's summary line and a server's METRICS reply both
+    say what the process spent tracing, lowering, loading and compiling,
+    for which function, and whether the persistent cache answered.  Each
+    seconds series keeps its unlabelled total and gains one
+    ``{kind=<function>}`` child per function (jax's ``fun_name``, without
+    the ``jit(...)`` the lowering and the compile wrap it in).  jax fires
+    these only when it traces, lowers, loads or compiles: never on a cached
+    call.  Counters are looked up when an event fires, so a registry reset
+    (tests) loses nothing after it."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    counter = obs_metrics.get_registry().counter
+    for name in _SECONDS_SERIES.values():
+        counter(name)  # a scrape reads 0, not nothing, before the first event
+    events = {_CACHE_HIT_EVENT: "tpums_jax_compile_cache_hits_total",
+              _CACHE_MISS_EVENT: "tpums_jax_compile_cache_misses_total"}
+    # per thread: how many trace events are open (a jit called while another
+    # is traced reports its own trace inside the caller's: only the
+    # outermost counts, or the total would pass the wall), and the seconds
+    # the cache's load event reported since the last compile event (jax
+    # names no function on it; it fires inside the backend-compile event of
+    # the function being loaded)
+    local = threading.local()
 
-    def on_duration(event, duration, **_kw):
-        if event == _BACKEND_COMPILE_EVENT:
-            secs.inc(duration)
+    def on_start(event, _start, **_kw):
+        if event == _TRACE_EVENT:
+            local.tracing = getattr(local, "tracing", 0) + 1
+
+    def on_duration(event, duration, fun_name=None, **_kw):
+        series = _SECONDS_SERIES.get(event)
+        if series is None:
+            return
+        if event == _TRACE_EVENT:
+            local.tracing = max(getattr(local, "tracing", 1) - 1, 0)
+            if local.tracing:
+                return
+        counter(series).inc(duration)
+        if event == _CACHE_LOAD_EVENT:
+            local.loaded = getattr(local, "loaded", 0.0) + duration
+            return
+        if fun_name is None:
+            return
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]
+        counter(series, kind=fun_name).inc(duration)
+        if event == _BACKEND_COMPILE_EVENT and getattr(local, "loaded", 0.0):
+            counter(_SECONDS_SERIES[_CACHE_LOAD_EVENT],
+                    kind=fun_name).inc(local.loaded)
+            local.loaded = 0.0
 
     def on_event(event, **_kw):
-        counter = events.get(event)
-        if counter is not None:
-            counter.inc()
+        name = events.get(event)
+        if name is not None:
+            counter(name).inc()
 
+    jax.monitoring.register_scalar_listener(on_start)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
 
 
 def compile_report() -> str:
-    """One-line summary of this process's compiles so far."""
+    """One-line summary of what this process spent getting its programs so
+    far, and the three functions that cost most (trace + lower + compile,
+    the load being part of the compile)."""
     secs, hits, misses = _compile_counters()
+    snap = obs_metrics.get_registry().snapshot()["counters"]
+    total = {c["name"]: c["value"] for c in snap if not c["labels"]}
+    trace, lower, load = (
+        total.get(_SECONDS_SERIES[e], 0.0)
+        for e in (_TRACE_EVENT, _LOWER_EVENT, _CACHE_LOAD_EVENT))
+    costed = {_SECONDS_SERIES[e] for e in (
+        _TRACE_EVENT, _LOWER_EVENT, _BACKEND_COMPILE_EVENT)}
+    by_fun: dict = {}
+    for c in snap:
+        kind = c["labels"].get("kind")
+        if kind is not None and c["name"] in costed:
+            by_fun[kind] = by_fun.get(kind, 0.0) + c["value"]
+    top = sorted(by_fun.items(), key=lambda kv: -kv[1])[:3]
     return (f"compile {secs.value:.2f}s, persistent cache "
-            f"{hits.value} hit(s) / {misses.value} miss(es)")
+            f"{hits.value} hit(s) / {misses.value} miss(es); "
+            f"trace {trace:.2f}s, lower {lower:.2f}s, cache load {load:.2f}s; "
+            "costliest: "
+            + (", ".join(f"{k} {v:.2f}s" for k, v in top) or "none"))
 
 
 def acquire_devices(host_pinned: bool = False) -> list:
@@ -90,7 +168,8 @@ def acquire_devices(host_pinned: bool = False) -> list:
       ``TPUMS_TOPK_PLATFORM=cpu`` pin, which returns the host devices even
       when a chip is present;
     - logs one line — platform, device_kind, device count — which is how an
-      operator (and ``chip_smoke.py``) learns where a job ran;
+      operator (and ``chip_smoke.py``) learns where a job ran, and records
+      the backend's start as the phase ``device.backend``;
     - turns the persistent compile cache on when the backend is ``tpu``:
       at ``$JAX_COMPILATION_CACHE_DIR`` when that is set (jax reads it
       itself; nothing is set in code), else at ``repo_cache_dir()``, with
@@ -98,7 +177,12 @@ def acquire_devices(host_pinned: bool = False) -> list:
 
     Call it after ``jax.distributed.initialize`` in multi-process jobs."""
     global _acquired
-    devices = jax.devices()
+    if _acquired:
+        devices = jax.devices()
+    else:
+        # the process's first look at its devices starts the backend
+        with tracing.phase("device.backend"):
+            devices = jax.devices()
     backend = devices[0].platform
     asked_for_cpu = os.environ.get("JAX_PLATFORMS", "").strip() == "cpu"
     if backend == "cpu" and not (asked_for_cpu or host_pinned):

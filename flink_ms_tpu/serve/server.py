@@ -711,7 +711,9 @@ class LookupServer:
             if reply.startswith("E") and not shed:
                 errors.inc()
         if tid is not None:
-            t_end = time.time()
+            # the request's start and the batcher's stamps are perf_counter
+            # instants; events carry wall time, by the process's one offset
+            wall = obs_tracing.wall_offset()
             sid = obs_tracing.new_span_id()
             fields = {"verb": verb, "job_id": self.job_id,
                       "port": self.port, "lat_s": round(dt, 6),
@@ -725,12 +727,9 @@ class LookupServer:
                         fields[name] = round(v, 6) if isinstance(v, float) \
                             else v
             obs_tracing.event("server_reply", tid=trace_id, sid=sid,
-                              psid=psid, t0=t_end - dt,
+                              psid=psid, t0=t0 + wall,
                               dur_s=round(dt, 9), **fields)
             if pending is not None and pending.t_dispatch is not None:
-                # the batcher's stamps are perf_counter instants; events
-                # carry wall time
-                wall = time.time() - time.perf_counter()
                 obs_tracing.event(
                     "mb_queue_wait", tid=trace_id,
                     sid=obs_tracing.new_span_id(), psid=sid,

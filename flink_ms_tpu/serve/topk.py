@@ -61,7 +61,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..obs import metrics as obs_metrics
-from ..obs.tracing import stage
+from ..obs.tracing import phase, stage
 from .table import ModelTable
 
 
@@ -505,24 +505,25 @@ class DeviceFactorIndex:
         rows = np.asarray(rows, dtype=np.float32)
         mesh = self._mesh_if_sharding(rows.shape[0])
         if mesh is None:
-            return (
-                jax.device_put(rows, _target_device()), None,
-                rows.shape[0], False,
-            )
+            with phase("topk.build.place"):
+                matrix = jax.device_put(rows, _target_device())
+            return matrix, None, rows.shape[0], False
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.mesh import BLOCK_AXIS, num_blocks, row_bucket
 
         self._mesh = mesh
         n = rows.shape[0]
-        n_pad = row_bucket(n, num_blocks(mesh))
-        mat = np.zeros((n_pad, rows.shape[1]), np.float32)
-        mat[:n] = rows
-        bias = np.zeros((n_pad,), np.float32)
-        bias[n:] = _PAD_SCORE
-        matrix = jax.device_put(
-            mat, NamedSharding(mesh, P(BLOCK_AXIS, None)))
-        bias = jax.device_put(bias, NamedSharding(mesh, P(BLOCK_AXIS)))
+        with phase("topk.build.pad"):
+            n_pad = row_bucket(n, num_blocks(mesh))
+            mat = np.zeros((n_pad, rows.shape[1]), np.float32)
+            mat[:n] = rows
+            bias = np.zeros((n_pad,), np.float32)
+            bias[n:] = _PAD_SCORE
+        with phase("topk.build.place"):
+            matrix = jax.device_put(
+                mat, NamedSharding(mesh, P(BLOCK_AXIS, None)))
+            bias = jax.device_put(bias, NamedSharding(mesh, P(BLOCK_AXIS)))
         return matrix, bias, n_pad, True
 
     def _maybe_build_ann(self, rows):
@@ -540,7 +541,8 @@ class DeviceFactorIndex:
         try:
             from .ann import IVFIndex
 
-            ann = IVFIndex.build(np.asarray(rows, dtype=np.float32))
+            with phase("topk.build.ann"):
+                ann = IVFIndex.build(np.asarray(rows, dtype=np.float32))
         except Exception as e:  # pragma: no cover - defensive
             self._obs_device_errors.inc()
             print(f"[topk] IVF build failed (serving exact): {e}",
@@ -562,31 +564,43 @@ class DeviceFactorIndex:
     def _assemble(self, ids, rows, width) -> dict:
         """The expensive half of a (re)build — device placement, ANN
         training, scatter warm-up — safe to run OFF the index lock.  The
-        result swaps in atomically via ``_swap_locked``."""
-        matrix = bias = ann = None
-        n_pad, sharded = 0, False
-        if len(rows):
-            matrix, bias, n_pad, sharded = self._pack(rows)
-            ann = self._maybe_build_ann(rows)
-            if ann is not None and sharded:
-                # the re-rank gathers from the SHARDED matrix: the tiny
-                # quantizer arrays must live on the same mesh or jit
-                # refuses the device mix
-                ann.colocate(self._mesh)
-            if not self._counter_mode:
-                # warm the fixed-shape update scatter at the NEW matrix
-                # shape (result discarded — pure compile warm-up) so the
-                # first streaming update never pays a compile on the
-                # query path
-                pos = np.zeros((self.apply_cap,), dtype=np.int32)
-                vec = np.zeros(
-                    (self.apply_cap, matrix.shape[1]), dtype=np.float32)
-                matrix.at[pos].set(vec).block_until_ready()
-        return {
-            "ids": ids, "id_pos": {id_: i for i, id_ in enumerate(ids)},
-            "n_real": len(ids), "k_real": width, "matrix": matrix,
-            "bias": bias, "n_pad": n_pad, "sharded": sharded, "ann": ann,
-        }
+        result swaps in atomically via ``_swap_locked``.
+
+        One phase ``topk.build`` a build, whoever asks for it (``bulk_load``,
+        the first query's full build, the background rebuild on its own
+        thread), with children ``.pad`` (sharded layout only), ``.place``
+        (the ``device_put``s: enqueue only, the transfer's tail falls to
+        ``.warm_scatter``, which waits), ``.ann`` (only where the tier
+        builds), ``.warm_scatter`` and ``.ids``."""
+        with phase("topk.build"):
+            matrix = bias = ann = None
+            n_pad, sharded = 0, False
+            if len(rows):
+                matrix, bias, n_pad, sharded = self._pack(rows)
+                ann = self._maybe_build_ann(rows)
+                if ann is not None and sharded:
+                    # the re-rank gathers from the SHARDED matrix: the tiny
+                    # quantizer arrays must live on the same mesh or jit
+                    # refuses the device mix
+                    ann.colocate(self._mesh)
+                if not self._counter_mode:
+                    # warm the fixed-shape update scatter at the NEW matrix
+                    # shape (result discarded — a compile and a whole-matrix
+                    # copy) so the first streaming update never pays a
+                    # compile on the query path
+                    with phase("topk.build.warm_scatter"):
+                        pos = np.zeros((self.apply_cap,), dtype=np.int32)
+                        vec = np.zeros(
+                            (self.apply_cap, matrix.shape[1]),
+                            dtype=np.float32)
+                        matrix.at[pos].set(vec).block_until_ready()
+            with phase("topk.build.ids"):
+                id_pos = {id_: i for i, id_ in enumerate(ids)}
+            return {
+                "ids": ids, "id_pos": id_pos,
+                "n_real": len(ids), "k_real": width, "matrix": matrix,
+                "bias": bias, "n_pad": n_pad, "sharded": sharded, "ann": ann,
+            }
 
     def _swap_locked(self, a: dict) -> None:
         """Install an assembled index state (under self._lock)."""
@@ -616,7 +630,8 @@ class DeviceFactorIndex:
         self._drain_dirty()
         with self._dirty_lock:
             self._replay_backlog = 0  # full build absorbs the replay rows
-        ids, rows, width = self._snapshot_rows()
+        with phase("topk.build.snapshot"):
+            ids, rows, width = self._snapshot_rows()
         self._swap_locked(self._assemble(ids, rows, width))
 
     def bulk_load(self, ids, rows) -> None:
@@ -737,7 +752,8 @@ class DeviceFactorIndex:
                 with self._dirty_lock:
                     replay_snap = self._replay_backlog
                     self._replay_backlog = 0
-                ids, rows, width = self._snapshot_rows()
+                with phase("topk.build.snapshot"):
+                    ids, rows, width = self._snapshot_rows()
                 # device placement, scatter warm-up, and the (potentially
                 # seconds-long) IVF k-means all run OFF the index lock —
                 # queries keep answering from the current index meanwhile
@@ -997,16 +1013,17 @@ class DeviceFactorIndex:
         of a bucket otherwise pays its XLA compile inside a live dispatch,
         charging tens of milliseconds to every request sharing that batch
         — a one-time cost per process that belongs at build time, not in
-        the serving tail."""
-        with self._lock:
-            self._maintain_locked()
-            if self._matrix is None:
-                return
-            width = self._k_real
-        b = 1
-        while b <= max_batch:
-            self.topk_many(np.zeros((b, width), dtype=np.float32), k)
-            b *= 2
+        the serving tail.  Phase ``topk.warm``."""
+        with phase("topk.warm"):
+            with self._lock:
+                self._maintain_locked()
+                if self._matrix is None:
+                    return
+                width = self._k_real
+            b = 1
+            while b <= max_batch:
+                self.topk_many(np.zeros((b, width), dtype=np.float32), k)
+                b *= 2
 
 
 class ALSTopkHandler:

@@ -38,6 +38,7 @@ import numpy as np
 from ..core import formats as F
 from ..core.params import Params, field_delimiter_from
 from ..ops.als import ALSConfig, ALSModel, als_fit, rmse
+from ..obs.tracing import phase_report
 from ..parallel.distributed import is_primary, maybe_init_distributed
 from ..parallel.mesh import compile_report, mesh_for_blocks
 from ..utils import profiling
@@ -101,6 +102,7 @@ def run(params: Params) -> ALSModel | None:
         f"train RMSE={rmse(model, users, items, ratings):.4f}"
     )
     print(f"[ALS] {compile_report()}")
+    print(f"[phases] {phase_report()}")
 
     if not is_primary():  # one process materializes job output
         return model

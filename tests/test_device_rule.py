@@ -13,6 +13,7 @@ import jax
 import numpy as np
 import pytest
 
+from flink_ms_tpu.obs.metrics import get_registry
 from flink_ms_tpu.parallel import mesh as M
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -127,11 +128,9 @@ def test_cache_path_is_fixed_and_gitignored():
 
 
 def test_compile_counters_feed_the_report(monkeypatch):
-    from flink_ms_tpu.obs.metrics import get_registry
-
-    # hook the listeners up again: they hold the counters of the registry
-    # as it was when this process first acquired, and an earlier test file
-    # in the same worker may have reset it since (test_native_protocol does)
+    # listening, whichever test file ran before (once a process: the
+    # listeners look their counters up when an event fires, so a registry
+    # an earlier test file reset loses nothing)
     monkeypatch.setattr(M, "_acquired", False)
     M.acquire_devices()
     secs = get_registry().counter("tpums_jax_compile_seconds_total")
@@ -139,8 +138,69 @@ def test_compile_counters_feed_the_report(monkeypatch):
     jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)))
     assert secs.value > before
     assert re.match(
-        r"compile \d+\.\d\ds, persistent cache \d+ hit\(s\) / \d+ miss\(es\)$",
+        r"compile \d+\.\d\ds, persistent cache \d+ hit\(s\) / \d+ miss\(es\); "
+        r"trace \d+\.\d\ds, lower \d+\.\d\ds, cache load \d+\.\d\ds; "
+        r"costliest: (\S+ \d+\.\d\ds)(, \S+ \d+\.\d\ds){0,2}$",
         M.compile_report())
+
+
+def _seconds(kind=None):
+    """The four seconds series of the device rule, as they stand: the
+    unlabelled totals, or one function's children."""
+    reg = get_registry()
+    labels = {} if kind is None else {"kind": kind}
+    return {name: reg.counter(f"tpums_jax_{name}_seconds_total", **labels).value
+            for name in ("trace", "lower", "cache_load", "compile")}
+
+
+def test_a_cold_jit_raises_its_functions_children_and_a_cached_call_none(
+        monkeypatch):
+    monkeypatch.setattr(M, "_acquired", False)
+    M.acquire_devices()  # listening, whichever test file ran before
+
+    def inner_helper(x):
+        return x * 2
+
+    def a_function_named_for_this_test(x):
+        # the nested jit's trace lies inside this one's: counted once
+        return jax.jit(inner_helper)(x) + 1
+
+    fn = jax.jit(a_function_named_for_this_test)
+    totals, mine = _seconds(), _seconds("a_function_named_for_this_test")
+    assert set(mine.values()) == {0}
+    jax.block_until_ready(fn(np.arange(5.0)))
+    cold, cold_totals = _seconds("a_function_named_for_this_test"), _seconds()
+    for series in ("trace", "lower", "compile"):
+        assert cold[series] > 0, series
+        # the total moved by what the outermost function reports, no more
+        assert cold_totals[series] - totals[series] == pytest.approx(
+            cold[series], abs=1e-9), series
+    assert _seconds("inner_helper")["trace"] == 0  # inside its caller's
+    assert cold["cache_load"] == 0  # no persistent cache on the host
+    jax.block_until_ready(fn(np.arange(5.0)))  # cached: jax fires nothing
+    assert _seconds("a_function_named_for_this_test") == cold
+    assert _seconds() == cold_totals
+    assert any(c["labels"].get("kind") == "a_function_named_for_this_test"
+               for c in get_registry().snapshot()["counters"])
+
+
+def test_a_cache_load_is_booked_to_the_function_being_compiled(monkeypatch):
+    """jax names no function on the cache's retrieval event; it fires
+    inside the backend-compile event of the function being loaded."""
+    monkeypatch.setattr(M, "_acquired", False)
+    M.acquire_devices()
+    before, total = _seconds("loaded_fn"), _seconds()
+    jax.monitoring.record_event_duration_secs(M._CACHE_LOAD_EVENT, 0.25)
+    jax.monitoring.record_event_duration_secs(
+        M._BACKEND_COMPILE_EVENT, 0.5, fun_name="jit(loaded_fn)")
+    jax.monitoring.record_event_duration_secs(
+        M._BACKEND_COMPILE_EVENT, 0.5, fun_name="jit(compiled_fn)")
+    after = _seconds("loaded_fn")
+    assert after["cache_load"] - before["cache_load"] == pytest.approx(0.25)
+    assert after["compile"] - before["compile"] == pytest.approx(0.5)
+    assert _seconds("compiled_fn")["cache_load"] == 0
+    assert _seconds()["cache_load"] - total["cache_load"] == pytest.approx(0.25)
+    assert _seconds()["compile"] - total["compile"] == pytest.approx(1.0)
 
 
 def test_host_draw_needs_no_cpu_backend(monkeypatch):

@@ -318,7 +318,12 @@ def test_metrics_schema_matches_python(pysrv, nsrv):
     # separately.
     self_time = {(n, v) for (n, v) in series(nat)
                  if n == "tpums_native_self_seconds_total"}
-    assert series(nat) - self_time == series(py)
+    # likewise Python-only by design: the device rule's tpums_jax_* series
+    # (what the TOPK verbs' programs cost to get; the native plane runs no
+    # jax).  Their listener looks its counters up when jax fires, so they
+    # reappear after the reset above whenever this test is first to compile
+    jax_cost = {(n, v) for (n, v) in series(py) if n.startswith("tpums_jax_")}
+    assert series(nat) - self_time == series(py) - jax_cost
     for verb in ("GET", "MGET", "TOPK", "TOPKV", "DOT", "COUNT", "PING"):
         assert ("tpums_server_requests_total", verb) in series(nat)
         assert ("tpums_native_self_seconds_total", verb) in self_time

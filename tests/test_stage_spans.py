@@ -292,7 +292,7 @@ def test_traced_mb_events_sit_on_the_stamped_instants(served):
     T.clear_events()
     with T.trace_span() as tid:
         client.topk_pipelined(STATE, ["2", "3", "4"], 5)
-    wall = time.time() - time.perf_counter()
+    wall = T.wall_offset()  # the one offset every traced instant carries
     pending = sorted(seen[-3:], key=lambda p: p.t_enqueue)
     waits = sorted(T.recent_events(tid=tid, kind="mb_queue_wait"),
                    key=lambda e: e["t0"])
@@ -303,10 +303,10 @@ def test_traced_mb_events_sit_on_the_stamped_instants(served):
     for p, wait, dev in zip(pending, waits, devices):  # one frame: the
         # three share t_dispatch, so any order of `devices` pairs up
         assert wait["psid"] in replies and dev["psid"] in replies
-        assert wait["t0"] == pytest.approx(p.t_enqueue + wall, abs=2e-3)
+        assert wait["t0"] == pytest.approx(p.t_enqueue + wall, abs=1e-6)
         assert wait["dur_s"] == pytest.approx(p.t_dispatch - p.t_enqueue,
                                               abs=1e-8)
-        assert dev["t0"] == pytest.approx(p.t_dispatch + wall, abs=2e-3)
+        assert dev["t0"] == pytest.approx(p.t_dispatch + wall, abs=1e-6)
         assert dev["dur_s"] == pytest.approx(p.t_done - p.t_dispatch, abs=1e-8)
         assert dev["batch_size"] == p.batch_size == 3
     # queue wait ends where the dispatch starts: nothing is invented between
