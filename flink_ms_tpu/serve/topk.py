@@ -26,16 +26,18 @@ RETRIEVAL TIERS (round 11).  Two levers lift the catalog ceiling from the
   laid out as a permanently mesh-resident array, row-sharded over
   ``make_mesh()``'s block axis and padded to the shared power-of-two
   bucket discipline (``mesh.row_bucket``; pad rows carry a ``-1e30``
-  score bias so they can never surface).  A batched TOPK is then ONE
-  compiled ``shard_map`` program per batch-shape bucket: each device
-  scores and ``top_k``'s its own row slice, an ``all_gather`` of the
-  (D, B, k) partials feeds a tiny cross-shard merge, and only the final
-  (B, k) winners ever reach the host — zero host round-trips on the
-  steady path.  The dirty-row scatter and background rebuild run against
-  the sharded array unchanged (XLA routes each row's update to its
-  owning shard), so streaming SGD never forces full rebuilds here
-  either.  Engages automatically past ``TPUMS_TOPK_SHARD_MIN_ROWS`` when
-  the mesh has >1 device; ``TPUMS_TOPK_SHARDED=1|0`` forces/disables.
+  score bias so they can never surface; the padded matrix exists on the
+  devices only, each shard put from its own rows, ``_pack``).  A batched
+  TOPK is then ONE compiled ``shard_map`` program per batch-shape
+  bucket: each device scores and ``top_k``'s its own row slice, an
+  ``all_gather`` of the (D, B, k) partials feeds a tiny cross-shard
+  merge, and only the final (B, k) winners ever reach the host — zero
+  host round-trips on the steady path.  The dirty-row scatter and
+  background rebuild run against the sharded array unchanged (XLA routes
+  each row's update to its owning shard), so streaming SGD never forces
+  full rebuilds here either.  Engages automatically past
+  ``TPUMS_TOPK_SHARD_MIN_ROWS`` when the mesh has >1 device;
+  ``TPUMS_TOPK_SHARDED=1|0`` forces/disables.
 
 - **IVF ANN tier** (``serve/ann.py``) — a coarse k-means quantizer over
   the item factors (trained on-device, refreshed by the same background
@@ -328,6 +330,9 @@ class DeviceFactorIndex:
         self._obs_pad_rows = reg.gauge("tpums_topk_pad_rows")
         self._obs_sharded_frames = reg.counter(
             "tpums_topk_sharded_frames_total")
+        # catalog bytes the last build copied on the host (``_pack``)
+        self._obs_host_copy_bytes = reg.gauge(
+            "tpums_topk_build_host_copy_bytes")
         self._oldest_dirty_ts: Optional[float] = None
         # dirty-key plumbing: the table's writer thread appends, the query
         # path drains.  Tables without listener support (none in-tree) fall
@@ -499,7 +504,23 @@ class DeviceFactorIndex:
         row-sharded over the mesh's block axis, with a same-sharded bias
         vector stamping ``_PAD_SCORE`` on pad rows so they can never win
         a merge — the padding keeps XLA at a handful of compiled shapes
-        over the catalog's whole growth curve."""
+        over the catalog's whole growth curve.
+
+        The padded matrix exists on the devices only.  Each device's row
+        range is read from the sharding's own index map; a shard that
+        lies wholly inside the real rows is put from a view of ``rows``
+        (no host copy), and only a shard that straddles the last real row
+        or lies beyond it gets a host buffer (its rows, then zeros).
+        ``tpums_topk_build_host_copy_bytes`` is the bytes of those
+        buffers (0 on one device).
+
+        ONE transfer at a time: a put to the next device waits for the
+        one before it.  Four shards of 3.36 GB enqueued side by side, as
+        a ``device_put`` under the ``NamedSharding`` enqueues them, land
+        in 32 s on a host of four TPU v5e chips; one after the other in
+        1.7 (PERF.md §6, PR 36).  So here ``topk.build.place`` holds the
+        transfers themselves; the single-device put stays asynchronous
+        and ``_assemble`` builds ``id_pos`` under it."""
         import jax
 
         rows = np.asarray(rows, dtype=np.float32)
@@ -507,23 +528,36 @@ class DeviceFactorIndex:
         if mesh is None:
             with phase("topk.build.place"):
                 matrix = jax.device_put(rows, _target_device())
+            self._obs_host_copy_bytes.set(0)
             return matrix, None, rows.shape[0], False
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.mesh import BLOCK_AXIS, num_blocks, row_bucket
 
         self._mesh = mesh
-        n = rows.shape[0]
+        n, k = rows.shape
+        n_pad = row_bucket(n, num_blocks(mesh))
+        sharding = NamedSharding(mesh, P(BLOCK_AXIS, None))
         with phase("topk.build.pad"):
-            n_pad = row_bucket(n, num_blocks(mesh))
-            mat = np.zeros((n_pad, rows.shape[1]), np.float32)
-            mat[:n] = rows
+            blocks, copied = {}, 0
+            for dev, index in sharding.addressable_devices_indices_map(
+                    (n_pad, k)).items():
+                lo, hi, _ = index[0].indices(n_pad)
+                if hi <= n:
+                    blocks[dev] = rows[lo:hi]
+                else:
+                    blocks[dev] = np.zeros((hi - lo, k), np.float32)
+                    blocks[dev][:max(n - lo, 0)] = rows[lo:n]
+                    copied += blocks[dev].nbytes
             bias = np.zeros((n_pad,), np.float32)
             bias[n:] = _PAD_SCORE
         with phase("topk.build.place"):
-            matrix = jax.device_put(
-                mat, NamedSharding(mesh, P(BLOCK_AXIS, None)))
+            matrix = jax.make_array_from_single_device_arrays(
+                (n_pad, k), sharding,
+                [jax.device_put(block, dev).block_until_ready()
+                 for dev, block in blocks.items()])
             bias = jax.device_put(bias, NamedSharding(mesh, P(BLOCK_AXIS)))
+        self._obs_host_copy_bytes.set(copied)
         return matrix, bias, n_pad, True
 
     def _maybe_build_ann(self, rows):
@@ -568,15 +602,27 @@ class DeviceFactorIndex:
 
         One phase ``topk.build`` a build, whoever asks for it (``bulk_load``,
         the first query's full build, the background rebuild on its own
-        thread), with children ``.pad`` (sharded layout only), ``.place``
-        (the ``device_put``s: enqueue only, the transfer's tail falls to
-        ``.warm_scatter``, which waits), ``.ann`` (only where the tier
-        builds), ``.warm_scatter`` and ``.ids``."""
+        thread), with children ``.place`` (the ``device_put``s: the
+        enqueue on one device, the awaited transfers of the sharded
+        layout, ``_pack``), ``.pad`` (sharded layout only: the host buffers
+        of the shards that are not wholly real rows, before the layout's
+        ``.place``), ``.ids`` (the ``id_pos`` dict, made before the
+        first wait so that one device's transfer flies under it), ``.ann``
+        (only where the tier builds) and ``.warm_scatter`` (a compile or
+        load, a whole-matrix copy, and what is left of one device's
+        transfer)."""
         with phase("topk.build"):
             matrix = bias = ann = None
             n_pad, sharded = 0, False
             if len(rows):
                 matrix, bias, n_pad, sharded = self._pack(rows)
+            with phase("topk.build.ids"):
+                # a comprehension, not ``dict(zip(...))``: the C-level form
+                # is 2-5% faster (the inserts are the cost, PERF.md §6) and
+                # would hold the interpreter lock for all of it, against
+                # the threads that answer queries while a rebuild runs
+                id_pos = {id_: i for i, id_ in enumerate(ids)}
+            if len(rows):
                 ann = self._maybe_build_ann(rows)
                 if ann is not None and sharded:
                     # the re-rank gathers from the SHARDED matrix: the tiny
@@ -594,8 +640,6 @@ class DeviceFactorIndex:
                             (self.apply_cap, matrix.shape[1]),
                             dtype=np.float32)
                         matrix.at[pos].set(vec).block_until_ready()
-            with phase("topk.build.ids"):
-                id_pos = {id_: i for i, id_ in enumerate(ids)}
             return {
                 "ids": ids, "id_pos": id_pos,
                 "n_real": len(ids), "k_real": width, "matrix": matrix,
@@ -643,7 +687,14 @@ class DeviceFactorIndex:
         normal dirty-set maintenance (unknown ids trigger a rebuild whose
         snapshot reads the TABLE, so a bulk-loaded catalog absent from
         the table reverts — this is a load ramp, not a second source of
-        truth)."""
+        truth).
+
+        An f32 ``rows`` is not copied on the host: the device matrix (in
+        the sharded layout, every shard that is all real rows) is put
+        from a view of the caller's array.  The caller leaves it
+        unmodified while the index holds this catalog: the transfer reads
+        it after ``device_put`` has returned, and a CPU backend's array
+        may go on sharing its memory."""
         rows = np.asarray(rows, dtype=np.float32)
         if rows.ndim != 2 or len(ids) != rows.shape[0]:
             raise ValueError("bulk_load needs ids aligned with (n, k) rows")

@@ -255,11 +255,46 @@ def test_setup_paths_record_their_named_phases(path, tree, log, monkeypatch):
     entries = T.phase_log()
     assert len([e for e in entries if e["name"] != "device.backend"]) \
         == len(tree), "a phase ran twice"
+    if path.startswith("topk"):
+        # the dict is made before the first wait (one device: under the transfer)
+        assert by["topk.build.place"]["end"] <= by["topk.build.ids"]["start"]
+        assert by["topk.build.ids"]["end"] \
+            <= by["topk.build.warm_scatter"]["start"]
     for name, entry in by.items():
         children = T.phase_children(entry, entries)
         assert {c["name"] for c in children} == {
             n for n, parent in tree.items() if parent == name}
         assert sum(_dur(c) for c in children) <= _dur(entry)
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_an_update_of_a_bulk_loaded_id_lands_in_place(sharded, monkeypatch):
+    """`id_pos`, made before the build's first wait, holds every id at its
+    row: an UPDATE after the build is one scatter into it, no rebuild."""
+    from flink_ms_tpu.serve.table import ModelTable
+    from flink_ms_tpu.serve.topk import DeviceFactorIndex
+
+    monkeypatch.setenv("TPUMS_TOPK_SHARDED", "1" if sharded else "0")
+    monkeypatch.setenv("TPUMS_TOPK_TIER", "exact")
+    rng = np.random.default_rng(7)
+    n, width = 1000, 4  # 8 shards of 128: the last one 104 real rows
+    ids = [f"item{i}" for i in range(n)]
+    rows = rng.normal(size=(n, width)).astype(np.float32)
+    table = ModelTable(2)
+    index = DeviceFactorIndex(table, "-I")
+    index.bulk_load(ids, rows)
+    assert index._is_sharded == sharded
+    assert index._id_pos == {id_: i for i, id_ in enumerate(ids)}
+    for pos in (0, 613, n - 1):
+        table.put(f"item{pos}-I", ";".join(["9.5"] * width))
+    top = index.topk(np.ones(width, np.float32), 3)
+    assert sorted(item for item, _ in top) == ["item0", "item613", "item999"]
+    assert [score for _, score in top] == [38.0] * 3
+    assert (index.full_builds, index.inplace_updates) == (1, 3)
+    want = rows.copy()
+    want[[0, 613, n - 1]] = 9.5
+    assert np.array_equal(np.asarray(index._matrix)[:n], want)
+    assert not np.asarray(index._matrix)[n:].any()
 
 
 def test_routing_tables_are_host_prep_with_a_root_of_their_own(

@@ -5,12 +5,14 @@ benchmark's own blockwise numpy reference, which knows nothing of shards,
 padding or the merge.  One parametrised test: every case builds (or shares)
 an index through the normal path and names what it checks."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
 from benchmark import reference
 from flink_ms_tpu.obs import metrics as obs_metrics
-from flink_ms_tpu.parallel.mesh import make_mesh, row_bucket
+from flink_ms_tpu.parallel.mesh import BLOCK_AXIS, make_mesh, row_bucket
 from flink_ms_tpu.serve import topk as topk_mod
 from flink_ms_tpu.serve.table import ModelTable
 
@@ -175,6 +177,56 @@ def counters_and_gauges(monkeypatch):
     assert counter("tpums_topk_sharded_frames_total") == before
 
 
+def built_from_the_unpadded_rows(n, monkeypatch):
+    """`_pack` against the bucket (4 x 1,024): the padded matrix exists on
+    the devices only.  Whole shards are put from views of `rows`; only a
+    shard that is not all real rows gets a host buffer, and the gauge
+    counts those buffers' bytes.  What the devices hold is what the padded
+    host copy used to give them, bit for bit."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    ids, rows = catalog(n, 37)
+    n_pad = row_bucket(n, SHARDS)
+    per = n_pad // SHARDS
+    made = []  # elements of every host array numpy is asked to make
+    with monkeypatch.context() as patch:
+        for name in ("zeros", "empty"):
+            def recording(shape, *args, _make=getattr(np, name), **kwargs):
+                made.append(int(np.prod(shape)))
+                return _make(shape, *args, **kwargs)
+
+            patch.setattr(np, name, recording)
+        index = build(ids, rows)
+    not_whole = sum(1 for s in range(SHARDS) if (s + 1) * per > n)
+    assert not_whole == {4096: 0, 4059: 1, 2049: 2}[n]
+    assert gauge("tpums_topk_build_host_copy_bytes") \
+        == not_whole * per * RANK * 4
+    assert n_pad in made and max(made) < n_pad * RANK  # the bias; no (n_pad, k)
+    padded = np.zeros((n_pad, RANK), np.float32)
+    padded[:n] = rows
+    mesh = index._mesh
+    assert index._matrix.sharding == NamedSharding(mesh, P(BLOCK_AXIS, None))
+    assert index._bias.sharding == NamedSharding(mesh, P(BLOCK_AXIS))
+    assert index._matrix.shape == (n_pad, RANK)
+    assert np.array_equal(np.asarray(index._matrix).view(np.uint32),
+                          padded.view(np.uint32))
+    bias = np.asarray(index._bias)
+    assert not bias[:n].any() and (bias[n:] == topk_mod._PAD_SCORE).all()
+    for shard in index._matrix.addressable_shards:  # each device its own rows
+        assert np.array_equal(np.asarray(shard.data), padded[shard.index])
+    q = queries(6, n)
+    got = index.topk_many(q, 10)
+    monkeypatch.setenv("TPUMS_TOPK_SHARDED", "0")
+    single = topk_mod.DeviceFactorIndex(ModelTable(), "-I")
+    single.bulk_load(ids, rows)
+    assert not single._is_sharded
+    assert gauge("tpums_topk_build_host_copy_bytes") == 0
+    want = single.topk_many(q, 10)
+    assert [[i for i, _ in g] for g in got] == [[i for i, _ in w] for w in want]
+    np.testing.assert_allclose([[s for _, s in g] for g in got],
+                               [[s for _, s in w] for w in want], atol=1e-6)
+
+
 CASES = [
     *(pytest.param(ragged_rows, (n,), id=f"rows-{n}")
       for n in (1026, 4059, 4097, 5003)),
@@ -188,6 +240,10 @@ CASES = [
     *(pytest.param(batch_shape, (b,), id=f"batch-{b}") for b in range(1, 33)),
     pytest.param(scopes_in_the_program, (), id="scopes"),
     pytest.param(counters_and_gauges, None, id="counters-and-gauges"),
+    *(pytest.param(partial(built_from_the_unpadded_rows, n), None,
+                   id=f"unpadded-rows-{what}")
+      for n, what in ((4096, "fill-the-bucket"), (4059, "end-in-the-last-shard"),
+                      (2049, "leave-a-shard-all-pad"))),
 ]
 
 
