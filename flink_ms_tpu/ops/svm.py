@@ -36,10 +36,16 @@ The whole fit is one XLA program with a *dynamic* outer-round count
 (``fori_loop`` with a traced bound), so one compiled executable serves any
 ``--iteration`` value — benchmarks time extra rounds without recompiling.
 
+Examples are held in one of two row layouts, picked from the data alone by
+``stores_rows_dense`` (no knob): **sparse** (feature ids and values, 8 B a
+stored entry in f32) below a density of 50%, **dense** (one value a
+feature, no ids) from there up, whichever stores fewer bytes.
+
 Two engines run the local steps (``SVMConfig.inner``).  The scatter engine
-indexes one padded row per chain per step.  The Gram engine touches the
-weight vector twice a round, one gather for the round-start margins and one
-scatter-add for Δw = XᵀΔα, and both stream a length-bucketed copy of the rows
+indexes one row per chain per step.  The Gram engine touches the weight
+vector twice a round, once for the round-start margins and once for
+Δw = XᵀΔα.  On sparse rows those are one gather and one scatter-add, and
+both stream a length-bucketed copy of the rows
 (``_bucket_rows``: each row padded to the next width of a short ladder, not
 to the longest row), because their time is their stored entries times 7 ns
 whatever an entry holds.  Measured on one TPU v5e in the benchmark cell
@@ -47,7 +53,14 @@ whatever an entry holds.  Measured on one TPU v5e in the benchmark cell
 49.6M entries stored as 55.5M in 13 buckets where the rectangle padded to
 256 held 174M): a round takes 0.80 s, the gather 0.38, the scatter-add
 0.38, the 83 steps between them 0.037 (2.78, 1.31, 1.42, 0.037 on the
-rectangle).
+rectangle).  On dense rows they are two products over X, ``X w`` and
+``Xᵀ Δα``, each one pass over the matrix where it lies: measured in
+``epsilon-cocoa-plus.dense-rounds`` (PERF.md §5, PR 37; CoCoA+, 8192
+chains x 49 rows, 400,000 x 2,000, every cell stored, 3.2 GB): a round
+takes 0.0313 s, each pass 0.00425 (92% of the chip's 819 GB/s), and the 49
+steps between them 0.0222, 71% of the round.  The same data through the
+sparse layout (PR 37's probe of the parent): 13.2 s a round, 6.5 GB of ids
+and values on the device, 46 GB on the host.
 
 Surfaced knobs follow FlinkML's parameter set: Blocks, Iterations,
 LocalIterations, Regularization, Stepsize, Seed [dep]; ThresholdValue /
@@ -58,6 +71,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import jax
@@ -96,7 +110,10 @@ class SVMConfig:
     # gather for wx0, one scatter for X^T dalpha) instead of once per
     # step.  Same update sequence (same RNG, same closed-form dual step),
     # reassociated arithmetic.  "auto": gram when the (C, H, H) tensor
-    # fits FLINK_MS_SVM_GRAM_BYTES (default 1 GiB per device).
+    # fits FLINK_MS_SVM_GRAM_BYTES (default 1 GiB per device).  Both
+    # engines run on both row layouts: on dense rows the scatter step
+    # reads one dense row a chain and the Gram engine's two touches of w
+    # are products over X.
     inner: str = "auto"
 
     def __post_init__(self):
@@ -138,77 +155,189 @@ class SVMModel:
 # host-side layout
 # ---------------------------------------------------------------------------
 
+# The dense layout's products over X (round-start margins, Δw, the Gram
+# build).  A default-precision f32 product on a TPU is one bf16 pass.
+_DENSE_PRECISION = "highest"
+
+
+def stores_rows_dense(n_examples: int, n_features: int, nnz: int,
+                      itemsize: int) -> bool:
+    """The row layout, from the data alone: dense where the whole
+    ``(examples, features)`` value matrix takes no more bytes than the
+    stored entries' ids and values do (4 B an id beside each value), that
+    is from a density of 1 / (1 + 4 / itemsize) up: 50% in float32.  Below
+    it an entry costs its id and a gather; above it the ids say nothing a
+    position does not, and the round streams X instead of indexing w."""
+    return nnz > 0 and n_examples * n_features * itemsize <= nnz * (4 + itemsize)
+
+
 @dataclasses.dataclass
 class BlockedSVMProblem:
-    """Examples split into K logical blocks with per-row padded sparse
-    storage (K = the reference's ``setBlocks``; independent of the device
-    count — the kernel stacks ceil(K/D) blocks per device).
+    """Examples split into K logical blocks (K = the reference's
+    ``setBlocks``; independent of the device count — the kernel stacks
+    ceil(K/D) blocks per device), their rows held in one of two layouts
+    that ``prepare_svm_blocked`` picks by ``stores_rows_dense``: per-row
+    padded sparse storage (``idx`` and ``val``, every row padded to the
+    longest), or one dense value row per example (``idx`` is None, ``val``
+    spans every feature, absent features are zeros).
 
     Padding rows have label 0 and empty features; the SDCA step masks them
     (zero row norm => zero update), so they never affect the solution.
 
-    Who reads what: the scatter engine indexes ``idx`` / ``val`` by row
-    inside every step, so the padded rectangles are its device operands;
-    the Gram engine reads them once, chain by chain, to build its Gram
-    tensor, and runs its rounds over a length-bucketed copy of the rows
-    that ``compile_svm_fit`` cuts out of them by ``row_len``
-    (``_bucket_rows``).  Both read ``label`` and ``sq_norm``.
+    Who reads what.  Sparse: the scatter engine indexes ``idx`` / ``val``
+    by row inside every step, so the padded rectangles are its device
+    operands; the Gram engine reads them once, chain by chain, to build its
+    Gram tensor, and runs its rounds over a length-bucketed copy of the
+    rows that ``compile_svm_fit`` cuts out of them by ``row_len``
+    (``_bucket_rows``).  Dense: ``val`` goes to the device once, as
+    ``(K·rows, features)``, and both engines, the Gram build and the
+    round's two products read it there.  Both read ``label`` and
+    ``sq_norm``.
+
+    What the choice is worth at epsilon's shape (400,000 x 2,000, every
+    cell stored; one TPU v5e, PERF.md §5-6, PR 37): dense, 3.2 GB on the
+    host and on the device, a CoCoA+ round of 0.0313 s whose two passes
+    over X take 4.25 ms each; the same rows sparse, 46 GB of host memory,
+    6.5 GB of ids and values on the device and 13.2 s a round.  At RCV1's
+    0.16% the rule keeps the sparse layout, whose round did not change.
     """
 
     n_blocks: int
     n_examples: int      # real examples (pre-padding)
     n_features: int
     rows_per_block: int
-    idx: np.ndarray      # (K, rows_pb, L) int32 feature indices (0-based)
-    val: np.ndarray      # (K, rows_pb, L) values, 0 where padded
+    idx: Optional[np.ndarray]  # (K, rows_pb, L) int32 feature indices
+    #                      (0-based); None on the dense layout
+    val: np.ndarray      # (K, rows_pb, L) values, 0 where padded; dense:
+    #                      (K, rows_pb, n_features)
     label: np.ndarray    # (K, rows_pb) +-1, 0 for padding rows
     sq_norm: np.ndarray  # (K, rows_pb) ||x_j||^2
-    row_len: np.ndarray  # (K, rows_pb) int32 entries stored in the row's
-    #                      first positions, 0 for padding rows
+    row_len: np.ndarray  # (K, rows_pb) int32 entries the example came
+    #                      with (sparse: stored in the row's first
+    #                      positions), 0 for padding rows
+
+    @property
+    def dense(self) -> bool:
+        return self.idx is None
+
+
+# The host passes over a layout's rows run in strips of 16 MB of float64
+# (temporaries of this size are reused from the heap; larger ones are mapped
+# and faulted in anew at every strip, 2.5x slower at 64 MB) on a few threads
+# (epsilon's 3.2 GB matrix and its norms: 7.1-7.8 s on 8 threads, 23.6-27.8
+# on one; PERF.md section 6, PR 37).
+_STRIP_BYTES = 16 << 20
+_STRIP_THREADS = 8
+
+
+def _each_strip(fn, n_rows: int, width: int) -> None:
+    """``fn(lo, hi)`` over strips of rows, on ``_STRIP_THREADS`` threads:
+    numpy lets go of the interpreter lock inside its copies and casts, and
+    a fresh matrix is faulted in faster by several threads than by one."""
+    step = max(_STRIP_BYTES // (max(width, 1) * 8), 1)
+    bounds = [(lo, min(lo + step, n_rows)) for lo in range(0, n_rows, step)]
+    with ThreadPoolExecutor(_STRIP_THREADS) as pool:
+        # list(): a strip's exception is raised here, not dropped
+        list(pool.map(lambda b: fn(*b), bounds))
+
+
+def _padded_rows(data: SparseData, lens, order, slots: int, dtype):
+    """-> (idx, val), each (slots, L): slot s holds example order[s]'s
+    entries in CSR order, padded to the longest row."""
+    n = data.n_examples
+    L = max(int(lens.max()) if n else 1, 1)
+    # padded row-major staging in original example order
+    mask = np.arange(L)[None, :] < lens[:, None]           # (n, L)
+    idx_rows = np.zeros((n, L), dtype=np.int32)
+    val_rows = np.zeros((n, L), dtype=dtype)
+    idx_rows[mask] = data.indices                          # CSR order
+    val_rows[mask] = data.values.astype(dtype)
+    idx = np.zeros((slots, L), dtype=np.int32)
+    val = np.zeros((slots, L), dtype=dtype)
+    idx[:n] = idx_rows[order]
+    val[:n] = val_rows[order]
+    return idx, val
+
+
+def _dense_rows(data: SparseData, lens, order, slots: int, dtype):
+    """-> val (slots, features): slot s holds example order[s]'s values
+    at their features' columns, zeros elsewhere.  No id rectangle is
+    built: the host holds the caller's data and this one matrix, filled a
+    strip of rows at a time.  Where every row is full (a dense LIBSVM
+    file) a strip in feature order is one copy; any other strip is
+    scattered by id (a row's ids are distinct)."""
+    n, d = data.n_examples, data.n_features
+    val = np.zeros((slots, d), dtype=dtype)
+    full = n > 0 and int(lens.min()) == d
+    if full:
+        ids2, vals2 = data.indices.reshape(n, d), data.values.reshape(n, d)
+
+    def fill(lo, hi):
+        ex = order[lo:hi]
+        if full:
+            ids, vals = ids2[ex], vals2[ex]
+            if (ids == np.arange(d)).all():
+                val[lo:hi] = vals
+                return
+            owner = np.arange(hi - lo)[:, None]
+        else:
+            # flat positions of the strip's entries, row after row
+            mine = lens[ex]
+            owner = np.repeat(np.arange(hi - lo), mine)
+            first = np.cumsum(mine) - mine
+            flat = (data.indptr[ex][owner]
+                    + np.arange(int(mine.sum())) - first[owner])
+            ids, vals = data.indices[flat], data.values[flat]
+        val[lo:hi][owner, ids] = vals
+
+    _each_strip(fill, n, d)
+    return val
 
 
 def prepare_svm_blocked(
     data: SparseData, n_blocks: int, seed: int = 0, dtype=np.float32
 ) -> BlockedSVMProblem:
-    """Vectorized re-layout: shuffle examples across K blocks, pad each row
-    to the max nnz (static shapes for XLA).  Phase ``svm.prepare``."""
+    """Vectorized re-layout: shuffle examples across K blocks and store
+    their rows in the layout ``stores_rows_dense`` picks for the data:
+    every row padded to the max nnz, or every row dense (static shapes for
+    XLA either way).  Phase ``svm.prepare``."""
     with tracing.phase("svm.prepare"):
         n = data.n_examples
         rows_pb = -(-n // n_blocks) if n else 1
+        slots = n_blocks * rows_pb
         lens = (data.indptr[1:] - data.indptr[:-1]).astype(np.int64)
-        L = max(int(lens.max()) if n else 1, 1)
-
-        # padded row-major staging in original example order
-        mask = np.arange(L)[None, :] < lens[:, None]           # (n, L)
-        idx_rows = np.zeros((n, L), dtype=np.int32)
-        val_rows = np.zeros((n, L), dtype=dtype)
-        idx_rows[mask] = data.indices                          # CSR order
-        val_rows[mask] = data.values.astype(dtype)
-
         # slot s <- example order[s]
         order = np.random.default_rng(seed).permutation(n)
-        idx = np.zeros((n_blocks * rows_pb, L), dtype=np.int32)
-        val = np.zeros((n_blocks * rows_pb, L), dtype=dtype)
-        label = np.zeros((n_blocks * rows_pb,), dtype=dtype)
-        row_len = np.zeros((n_blocks * rows_pb,), dtype=np.int32)
-        idx[:n] = idx_rows[order]
-        val[:n] = val_rows[order]
+        if stores_rows_dense(n, data.n_features, int(lens.sum()),
+                             np.dtype(dtype).itemsize):
+            idx, val = None, _dense_rows(data, lens, order, slots, dtype)
+        else:
+            idx, val = _padded_rows(data, lens, order, slots, dtype)
+        label = np.zeros((slots,), dtype=dtype)
+        row_len = np.zeros((slots,), dtype=np.int32)
         row_len[:n] = lens[order]
         signs = np.sign(data.labels[order]).astype(dtype)
         label[:n] = np.where(signs == 0, 1.0, signs)  # labels must be +-1
-        sq_norm = np.sum(val.astype(np.float64) ** 2, axis=-1).astype(dtype)
+        sq_norm = np.empty((slots,), dtype=dtype)
+
+        def norms(lo, hi):
+            sq_norm[lo:hi] = np.sum(
+                val[lo:hi].astype(np.float64) ** 2, axis=-1)
+
+        _each_strip(norms, slots, val.shape[1])
         # slot s -> (block s // rows_pb, row s % rows_pb): contiguous rows per
         # block, matching the reference's partition-then-iterate layout
+        shape = (n_blocks, rows_pb)
         return BlockedSVMProblem(
             n_blocks=n_blocks,
             n_examples=n,
             n_features=data.n_features,
             rows_per_block=rows_pb,
-            idx=idx.reshape(n_blocks, rows_pb, L),
-            val=val.reshape(n_blocks, rows_pb, L),
-            label=label.reshape(n_blocks, rows_pb),
-            sq_norm=sq_norm.reshape(n_blocks, rows_pb),
-            row_len=row_len.reshape(n_blocks, rows_pb),
+            idx=None if idx is None else idx.reshape(*shape, -1),
+            val=val.reshape(*shape, -1),
+            label=label.reshape(shape),
+            sq_norm=sq_norm.reshape(shape),
+            row_len=row_len.reshape(shape),
         )
 
 
@@ -379,6 +508,17 @@ def _resolve_inner(problem: BlockedSVMProblem, config: SVMConfig,
 # device-side kernel
 # ---------------------------------------------------------------------------
 
+def _combine_scales(config: SVMConfig, K: int):
+    """-> (γ, σ'): what a round's combination multiplies the chains'
+    summed updates by, and the smoothing of the local subproblem."""
+    if config.mode == "avg":
+        return config.stepsize / K, 1.0  # averaged combination (CoCoA-v1)
+    # added combination (CoCoA+); safe default σ' = γK
+    return config.stepsize, (
+        config.sigma_prime if config.sigma_prime is not None
+        else config.stepsize * K)
+
+
 def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     D = num_blocks(mesh)
     K = problem.n_blocks               # real logical blocks
@@ -388,35 +528,37 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     H = config.local_iterations
     lam_n = lam * max(n, 1)
     dtype = config.dtype
-    if config.mode == "avg":
-        gamma = config.stepsize / K    # averaged combination (CoCoA-v1)
-        sigma_p = 1.0
-    else:
-        gamma = config.stepsize        # added combination (CoCoA+)
-        sigma_p = (                    # safe default σ' = γK
-            config.sigma_prime if config.sigma_prime is not None
-            else config.stepsize * K
-        )
+    gamma, sigma_p = _combine_scales(config, K)
 
     H_rows = problem.rows_per_block
     d = problem.n_features
+    dense = problem.dense
     inner = _resolve_inner(problem, config, mesh)
     step_mode = _step_choice()
-    dw_mode = _dw_choice() if inner == "gram" else "direct"
+    # the dense layout has one form of Δw, a product over X
+    dw_mode = _dw_choice() if inner == "gram" and not dense else "direct"
 
-    def chain_sdca(w, idx_c, val_c, label_c, sqn_c, alpha_c, key_c):
+    def chain_sdca(w, idx_c, val_c, label_c, sqn_c, alpha_c, key_c,
+                   row0_c=None):
         """H serial SDCA steps of ONE chain; vmapped over the C chains of a
-        device so every step is a (C, L)-wide gather/compute/scatter."""
+        device so every step is a (C, L)-wide gather/compute/scatter.  On
+        the dense layout ``idx_c`` is None, ``val_c`` is the device's whole
+        ``(C·rows, d)`` matrix and the chain's rows start at ``row0_c``: a
+        step reads one dense row a chain and touches all of w_loc."""
         rows = label_c.shape[0]
 
         def sdca_step(h, inner):
             w_loc, a = inner
             j = jax.random.randint(jax.random.fold_in(key_c, h), (), 0, rows)
-            ids = idx_c[j]
-            x = val_c[j]
+            if idx_c is None:
+                x, w_x = val_c[row0_c + j], w_loc
+            else:
+                ids = idx_c[j]
+                x, w_x = val_c[j], jnp.take(w_loc, ids)
             y = label_c[j]
             qii = sqn_c[j]
-            wx = jnp.sum(jnp.take(w_loc, ids) * x)
+            # elementwise f32 on both layouts: no matmul precision applies
+            wx = jnp.sum(w_x * x)
             grad = 1.0 - y * wx
             # closed-form hinge dual step on the σ'-smoothed local
             # subproblem, clipped to the box [0, 1]
@@ -430,7 +572,8 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             # the chain-local view carries σ' (CoCoA+ models the quadratic
             # coupling of its OWN updates σ'-fold, so later coordinates in
             # the chain see the smoothed effect); σ' = 1 in avg mode
-            w_loc = w_loc.at[ids].add(sigma_p * delta * x / lam_n)
+            step = sigma_p * delta * x / lam_n
+            w_loc = w_loc + step if idx_c is None else w_loc.at[ids].add(step)
             return w_loc, a
 
         w_loc, a = jax.lax.fori_loop(0, H, sdca_step, (w, alpha_c))
@@ -527,9 +670,38 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         with jax.named_scope("svm.gram"):
             return jax.lax.map(one, (idx_s, val_s), batch_size=B)
 
+    def build_gram_dense(val):
+        """Per-chain row-Gram G[c] = X_c X_cᵀ straight from the device's
+        dense ``(C·H, d)`` rows: a batched product over a step of chains at
+        a time.  The steps bound the transient: a step's rows are copied
+        out as ``(chains, H, d)``, whose tiles pad H to a multiple of 8,
+        which is why X itself is kept flat.  The last step starts where it
+        ends on the last chain, so every step has one shape; the chains it
+        shares with the step before are computed twice and kept once."""
+        B = min(max(int((256 << 20)
+                        // max(H_rows * d * np.dtype(dtype).itemsize, 1)),
+                    1), C)
+        steps = -(-C // B)
+
+        def step(first):
+            x = jax.lax.dynamic_slice_in_dim(
+                val, first * H_rows, B * H_rows).reshape(B, H_rows, d)
+            return jnp.einsum("chd,cgd->chg", x, x,
+                              precision=_DENSE_PRECISION,
+                              preferred_element_type=dtype)
+
+        with jax.named_scope("svm.gram"):
+            gram = jax.lax.map(
+                step, jnp.minimum(jnp.arange(steps) * B, C - B))
+            return jnp.concatenate([
+                gram[:-1].reshape(-1, H_rows, H_rows),
+                gram[-1, steps * B - C:]])
+
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
                   gram=None, slot=None, dw_a=None, dw_b=None, dw_c=None):
-        # scatter engine: idx, val are the device's padded (C, rows, L)
+        # dense layout, both engines: idx is None and val the device's
+        # (C·rows, d) matrix.  Sparse layout, scatter engine: idx, val are
+        # the device's padded (C, rows, L)
         # rectangles.  Gram engine: they are its bucketed rows, a tuple of
         # (1, width, rows) pieces each (_bucket_rows; the jit retraces for
         # another ladder), with slot the flat (C·rows) slot of every bucket
@@ -561,9 +733,15 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             w, alpha = carry
             with jax.named_scope("svm.steps"):
                 keys = chain_keys(it)
-                dw, dalpha = jax.vmap(
-                    chain_sdca, in_axes=(None, 0, 0, 0, 0, 0, 0)
-                )(w, idx, val, label, sq_norm, alpha, keys)
+                if dense:
+                    dw, dalpha = jax.vmap(
+                        chain_sdca, in_axes=(None, None, None, 0, 0, 0, 0, 0)
+                    )(w, None, val, label, sq_norm, alpha, keys,
+                      jnp.arange(C) * H_rows)
+                else:
+                    dw, dalpha = jax.vmap(
+                        chain_sdca, in_axes=(None, 0, 0, 0, 0, 0, 0)
+                    )(w, idx, val, label, sq_norm, alpha, keys)
             with jax.named_scope("svm.dw"):
                 dw = jnp.sum(dw, axis=0)
             with jax.named_scope("svm.combine"):
@@ -580,12 +758,18 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             # (bf16-pass) contraction here would seed every SDCA step with
             # ~1e-3 relative error and break the documented cross-engine
             # equivalence on TPU.
+            # The dense layout streams X instead: one product, X w.
             with jax.named_scope("svm.margins"):
-                wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(
-                    jnp.concatenate([
-                        jnp.sum(jnp.take(w, i[0], axis=0) * v[0], axis=0)
-                        for i, v in zip(idx, val)])
-                ).reshape(C, H_rows)
+                if dense:
+                    wx0 = jnp.einsum(
+                        "nd,d->n", val, w, precision=_DENSE_PRECISION,
+                        preferred_element_type=dtype).reshape(C, H_rows)
+                else:
+                    wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(
+                        jnp.concatenate([
+                            jnp.sum(jnp.take(w, i[0], axis=0) * v[0], axis=0)
+                            for i, v in zip(idx, val)])
+                    ).reshape(C, H_rows)
             with jax.named_scope("svm.steps"):
                 keys = chain_keys(it)
                 dalpha = jax.vmap(sdca_gram)(
@@ -596,7 +780,13 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             # Mode trade-offs in _dw_choice's docstring.
             with jax.named_scope("svm.dw"):
                 dalpha_flat = dalpha.reshape(-1)
-                if dw_mode == "presorted":
+                if dense:
+                    # the second pass over X: Xᵀ Δα
+                    dw = jnp.einsum(
+                        "nd,n->d", val, dalpha_flat,
+                        precision=_DENSE_PRECISION,
+                        preferred_element_type=dtype)
+                elif dw_mode == "presorted":
                     # val is stored feature-sorted (dw_a) at prepare time,
                     # so the only runtime gather reads the tiny (C·H) Δα
                     # table
@@ -633,11 +823,16 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
     spec3 = P(BLOCK_AXIS, None, None)
     spec2 = P(BLOCK_AXIS, None)
-    in_specs = (P(), P(), spec3, spec3, spec2, spec2, spec2, P())
+    # dense: no ids (None has no leaves), X as (slots, d) split by rows
+    rows_specs = (P(), spec2) if dense else (spec3, spec3)
+    in_specs = (P(), P(), *rows_specs, spec2, spec2, spec2, P())
     if inner == "gram":
-        # idx, val: one spec for the whole tuple of buckets; gram, slot
-        n_dw = {"direct": 0, "sorted": 2, "presorted": 3}[dw_mode]
-        in_specs += (spec3, spec2) + (spec2,) * n_dw
+        in_specs += (spec3,)  # gram
+        if not dense:
+            # idx, val: one spec for the whole tuple of buckets; slot, then
+            # the dw mode's operands
+            n_dw = {"direct": 0, "sorted": 2, "presorted": 3}[dw_mode]
+            in_specs += (spec2,) * (1 + n_dw)
     jfit = jax.jit(shard_map(
         block_fit,
         mesh=mesh,
@@ -667,9 +862,11 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     # calls (benchmark loops, retrain cycles) don't pay it again
     gram_fn = None
     if inner == "gram":
+        build, specs = ((build_gram_dense, (spec2,)) if dense
+                        else (build_gram, (spec3, spec3)))
         gram_fn = jax.jit(shard_map(
-            build_gram, mesh=mesh,
-            in_specs=(spec3, spec3), out_specs=spec3, check_vma=False,
+            build, mesh=mesh,
+            in_specs=specs, out_specs=spec3, check_vma=False,
         ))
     return fit, gram_fn, dw_mode if inner == "gram" else "direct"
 
@@ -686,7 +883,8 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         mesh,
         problem.n_blocks,
         problem.rows_per_block,
-        problem.idx.shape,
+        problem.val.shape,
+        problem.dense,
         problem.n_features,
         problem.n_examples,  # lam_n = lam * n is baked into the program
         config.local_iterations,
@@ -709,15 +907,20 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
 
 def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
-                       gram_bytes: int, chains: int) -> None:
+                       gram_bytes: int, chains: int, dense: bool,
+                       sigma_prime: float) -> None:
     """What the compiled round streams, for whoever reads the registry:
     row slots (pad rows and pad blocks included); the entries stored per
     slot, so that rows x row_width is every entry the round's gather and
     scatter-add touch (the scatter engine: the width every row is padded
-    to; the Gram engine: a mean over its buckets, their pad rows counted);
-    the stored entries that carry no value; the number of length buckets (0
-    on the scatter engine: one padded rectangle); the Gram tensor's bytes
-    (0 on the scatter engine); chains per device."""
+    to; the Gram engine: a mean over its buckets, their pad rows counted;
+    the dense layout: the feature count, every cell of X); the stored
+    entries that carry no value (dense: the pad rows' cells and the zeros
+    inside real rows); the number of length buckets (0 on the scatter
+    engine and the dense layout: one rectangle); the Gram tensor's bytes
+    (0 on the scatter engine); chains per device; the cells of X held dense
+    (0 on the sparse layouts), so that dense_entries over rows x row_width
+    says which layout served a fit; the σ' in force (1 in avg mode)."""
     reg = obs_metrics.get_registry()
     reg.gauge("tpums_svm_rows").set(slots)
     reg.gauge("tpums_svm_row_width").set(stored / slots)
@@ -725,6 +928,25 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
     reg.gauge("tpums_svm_buckets").set(buckets)
     reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
     reg.gauge("tpums_svm_chains_per_device").set(chains)
+    reg.gauge("tpums_svm_dense_entries").set(stored if dense else 0)
+    reg.gauge("tpums_svm_sigma_prime").set(sigma_prime)
+
+
+def layout_report() -> str:
+    """One clause for the trainer's ``[SVM]`` line, from the gauges the
+    last ``compile_svm_fit`` set: which row layout it chose, what the
+    layout stores, and the σ' in force."""
+    reg = obs_metrics.get_registry()
+    rows, width, dense_cells, buckets, sigma = (
+        reg.gauge("tpums_svm_" + name).value for name in (
+            "rows", "row_width", "dense_entries", "buckets", "sigma_prime"))
+    if dense_cells:
+        layout = f"dense rows ({int(rows)} x {int(width)} cells, no ids)"
+    else:
+        layout = (f"sparse rows ({int(rows)} x {width:.2f} stored entries, "
+                  + (f"{int(buckets)} length buckets)" if buckets
+                     else "one padded rectangle)"))
+    return f"layout {layout}, sigma' {sigma:g}"
 
 
 def compile_svm_fit(
@@ -735,10 +957,14 @@ def compile_svm_fit(
     alpha shards).  Benchmarks call ``fit_fn`` directly so host<->device
     transfer and compile stay out of the timed region.  ``dev_args[0]`` is
     w and ``dev_args[5]`` alpha, ``(Kp, rows_per_block)`` in slot order;
-    the rest is the engine's: the padded rectangles at [1], [2] on the
-    scatter engine, the bucketed rows on the Gram engine.  Phases, each
+    the rest is the layout's and the engine's: the padded rectangles at
+    [1], [2] on the scatter engine, the bucketed rows on the Gram engine;
+    on the dense layout [1] is None and [2] is X as ``(Kp·rows, features)``,
+    placed once for the Gram build and the rounds alike.  Phases, each
     awaited: ``svm.gram_build`` (Gram engine only) and ``svm.place``, with
-    the host's bucket copy under it as ``svm.bucket``."""
+    the host's bucket copy under it as ``svm.bucket`` (the dense layout
+    opens ``svm.place`` twice, for X before the Gram build and for the
+    small arrays after it, and has no bucket copy)."""
     D = num_blocks(mesh)
     Kp = _round_up(problem.n_blocks, D)
     dtype = config.dtype
@@ -752,18 +978,31 @@ def compile_svm_fit(
             lambda x: jnp.asarray(x, dtype=as_dtype), a), sharding)
 
     fit, gram_fn, dw_mode = _cached_fit(problem, config, mesh)
-    idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
-    stored, buckets, extra = idx.size, 0, []
-    if gram_fn is not None:
-        # the Gram build reads the padded rectangles once and lets them
-        # go: the rounds hold the bucketed rows only
-        with tracing.phase("svm.gram_build"):
-            extra.append(jax.block_until_ready(
-                gram_fn(put(idx, shard3), put(val, shard3, dtype))))
+    buckets, extra = 0, []
+    if problem.dense:
+        with tracing.phase("svm.place"):
+            # straight from the host matrix to its shards: no staging copy
+            # on the default device
+            idx, val = None, jax.block_until_ready(jax.device_put(
+                _pad_blocks(problem.val, Kp).reshape(
+                    -1, problem.n_features), shard2).astype(dtype))
+        stored = val.size
+        if gram_fn is not None:
+            with tracing.phase("svm.gram_build"):
+                extra.append(jax.block_until_ready(gram_fn(val)))
+    else:
+        idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
+        stored = idx.size
+        if gram_fn is not None:
+            # the Gram build reads the padded rectangles once and lets them
+            # go: the rounds hold the bucketed rows only
+            with tracing.phase("svm.gram_build"):
+                extra.append(jax.block_until_ready(
+                    gram_fn(put(idx, shard3), put(val, shard3, dtype))))
     # the phases end when the device has what they made, so that a profile
     # shows the Gram build and the transfer, not their dispatch
     with tracing.phase("svm.place"):
-        if gram_fn is not None:
+        if gram_fn is not None and not problem.dense:
             with tracing.phase("svm.bucket"):
                 plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
                 idx, val, slot = _bucket_rows(idx, val, plan)
@@ -773,8 +1012,8 @@ def compile_svm_fit(
             extra += [put(a, shard2) for a in (slot, *dw_operands)]
         dev_args = jax.block_until_ready([
             put(np.zeros((problem.n_features,)), rep, dtype),
-            put(idx, shard3),
-            put(val, shard3, dtype),
+            idx if problem.dense else put(idx, shard3),
+            val if problem.dense else put(val, shard3, dtype),
             put(_pad_blocks(problem.label, Kp), shard2, dtype),
             put(_pad_blocks(problem.sq_norm, Kp), shard2, dtype),
             put(np.zeros((Kp, problem.rows_per_block)), shard2, dtype),
@@ -784,7 +1023,8 @@ def compile_svm_fit(
     _set_layout_gauges(
         Kp * problem.rows_per_block, stored,
         int(np.count_nonzero(problem.val)), buckets,
-        extra[0].nbytes if extra else 0, Kp // D)
+        extra[0].nbytes if extra else 0, Kp // D, problem.dense,
+        _combine_scales(config, problem.n_blocks)[1])
     return fit, dev_args
 
 

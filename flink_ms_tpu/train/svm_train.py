@@ -22,7 +22,8 @@ import time
 from ..core import formats as F
 from ..core.params import Params
 from ..obs.tracing import phase_report
-from ..ops.svm import SVMConfig, SVMModel, prepare_svm_blocked, svm_fit
+from ..ops.svm import (SVMConfig, SVMModel, layout_report,
+                       prepare_svm_blocked, svm_fit)
 from ..parallel.distributed import is_primary, maybe_init_distributed
 from ..parallel.mesh import compile_report, mesh_for_blocks
 from ..utils import profiling
@@ -68,7 +69,7 @@ def run(params: Params) -> SVMModel:
         f"hinge+reg objective="
         f"{model.hinge_loss(data, config.regularization):.6f}"
     )
-    print(f"[SVM] {compile_report()}")
+    print(f"[SVM] {layout_report()}; {compile_report()}")
     print(f"[phases] {phase_report()}")
 
     if not is_primary():  # one process materializes job output

@@ -42,7 +42,7 @@ def _accuracy(model, X, y):
 def test_prepare_blocked_masks_padding(rng):
     data, _, _ = _blob_data(rng, n=13, d=4)
     p = prepare_svm_blocked(data, 4)
-    assert p.idx.shape[0] == 4
+    assert p.dense and p.val.shape[:2] == (4, p.rows_per_block)  # full rows: no ids
     n_pad = 4 * p.rows_per_block - 13
     assert (p.label == 0).sum() == n_pad
     assert (p.sq_norm[p.label == 0] == 0).all()
