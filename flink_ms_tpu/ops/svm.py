@@ -62,6 +62,13 @@ steps between them 0.0222, 71% of the round.  The same data through the
 sparse layout (PR 37's probe of the parent): 13.2 s a round, 6.5 GB of ids
 and values on the device, 46 GB on the host.
 
+Those steps were XLA's: six per-element gathers and scatters a step.  On a
+TPU the Gram engine now runs all of a round's steps in one Pallas kernel
+(``sdca_pallas.sdca_steps_lanes``, chosen by ``resolve_step``), the chains
+on the lanes and each block's Gram rows resident in VMEM: 0.0004 s of a
+0.0090 s round at epsilon's shape, 0.0014 of 0.767 at RCV1's (PERF.md §5,
+PR 38).  Every CPU fit, the scatter engine and bf16 state keep the XLA step.
+
 Surfaced knobs follow FlinkML's parameter set: Blocks, Iterations,
 LocalIterations, Regularization, Stepsize, Seed [dep]; ThresholdValue /
 OutputDecisionFunction live client-side (SVMPredict.java:33-34,80-86).
@@ -70,6 +77,7 @@ OutputDecisionFunction live client-side (SVMPredict.java:33-34,80-86).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
@@ -464,28 +472,55 @@ def _dw_choice() -> str:
 
 
 def _step_choice() -> str:
-    """FLINK_MS_SVM_STEP: how the Gram engine's SDCA step touches chain
-    state.  "dynamic": per-chain dynamic gather of the Gram row + scatter-
-    add into alpha — O(1) memory touched per step, with a threefry draw
-    inside the fori_loop.  "onehot": hoist the (C, H) step-index draw out
-    of the loop and express every read/write as a dense mask/one-hot
-    contraction — pure VPU/MXU work, bit-identical results (products are
-    exact 0s and 1s).  "auto" = dynamic everywhere.  Measured on one TPU
-    v5e in ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5, PR 32): the 83 dynamic
-    steps of 8192 chains take 37 ms of a 0.80 s round, 0.45 ms a step
-    (4.6%; 1.3% of the 2.78 s round over padded rows); the round's
-    boundary (gather and scatter-add, see _dw_choice) is the other 95%.
-    "onehot" read 43.5 ms there, 17% slower; it stays selectable for
-    meshes where per-step latency resurfaces (ROADMAP D5)."""
+    """FLINK_MS_SVM_STEP: the form of the Gram engine's SDCA steps, for an
+    A/B.  "dynamic": XLA's ``fori_loop`` over ``vmap(chain_sdca_gram)``, a
+    threefry draw, a Gram-row gather, four one-element picks and an α
+    scatter a step.  "kernel": ``sdca_pallas.sdca_steps_lanes``, all of a
+    round's steps in one Pallas call with the draws hoisted (interpreted
+    off the chip).  "auto" (default) lets ``resolve_step`` decide from what
+    the fit can see."""
     choice = os.environ.get("FLINK_MS_SVM_STEP", "auto")
-    if choice not in ("auto", "dynamic", "onehot"):
+    if choice not in ("auto", "dynamic", "kernel"):
         # as _dw_choice: a typo must not run the dynamic step in silence
         raise ValueError(
-            f"FLINK_MS_SVM_STEP={choice!r} must be auto|dynamic|onehot"
+            f"FLINK_MS_SVM_STEP={choice!r} must be auto|dynamic|kernel"
         )
-    if choice == "auto":
-        return "dynamic"
     return choice
+
+
+def resolve_step(platform: Optional[str], inner: str, dtype, h_rows: int,
+                 steps: int) -> str:
+    """How a compiled fit runs its SDCA steps: "kernel" or "dynamic"
+    (``_step_choice``).  The kernel engages where a chip run priced it: a
+    TPU, the Gram engine, f32 state, and a lane block of 128 chains that
+    fits the kernel's VMEM twice (``sdca_pallas.fits_vmem``, from the rows
+    a chain and the steps a round: up to 113 rows at one local pass).
+    Everything else keeps the XLA step unchanged: every CPU fit, the scatter
+    engine, bf16 state, a chain too long for VMEM.  One code path for both
+    benchmark cells, its block following the rows a chain.
+
+    Measured on one TPU v5e (PERF.md section 5-6, PR 38; traced pairs, the
+    parent's dynamic step first).  ``epsilon-cocoa-plus.dense-rounds`` (8192
+    chains x 49 rows): ``cocoa_steps_s`` 0.022247 -> 0.000413 of a round of
+    0.031287 -> 0.008968 s.  ``rcv1-cocoa.cocoa-rounds`` (8192 chains x 83
+    rows): 0.037162 -> 0.001446 of 0.803775 -> 0.767115.  The kernel alone
+    takes 0.39 and 1.38 ms; the hoisted draws 0.25 ms."""
+    choice = _step_choice()
+    # the scatter engine has no Gram step: the knob does not reach it
+    if inner != "gram" or choice == "dynamic" or (
+            choice == "auto" and platform != "tpu"):
+        return "dynamic"
+    from .sdca_pallas import fits_vmem  # pallas: a second of import
+
+    if jnp.dtype(dtype) == jnp.float32 and fits_vmem(h_rows, steps):
+        return "kernel"
+    if choice == "kernel":
+        # an A/B must run what it asked for
+        raise ValueError(
+            "FLINK_MS_SVM_STEP=kernel needs f32 state and chains whose "
+            f"Gram block fits VMEM; got {jnp.dtype(dtype).name}, "
+            f"{h_rows} rows a chain, {steps} steps")
+    return "dynamic"
 
 
 def _resolve_inner(problem: BlockedSVMProblem, config: SVMConfig,
@@ -519,6 +554,45 @@ def _combine_scales(config: SVMConfig, K: int):
         else config.stepsize * K)
 
 
+def chain_sdca_gram(wx0, gram_c, label_c, sqn_c, alpha_c, key_c, *,
+                    steps: int, lam_n: float, sigma_p: float):
+    """``steps`` serial SDCA steps of ONE chain, Gram-matrix inner loop: the
+    running margin vector wx[i] = w_loc·x_i absorbs each update via
+    one Gram row (wx += σ'·Δα_j/λn · G[j, :]), so no step touches the
+    (d,)-dim weights.  Same RNG and dual step as ``chain_sdca`` —
+    identical update sequence, reassociated arithmetic."""
+    def sdca_step(h, inner_c):
+        wx, a = inner_c
+        j = jax.random.randint(jax.random.fold_in(key_c, h), (), 0,
+                               label_c.shape[0])
+        y = label_c[j]
+        qii = sqn_c[j]
+        a_j = a[j]
+        grad = 1.0 - y * wx[j]
+        new_dual = jnp.clip(
+            a_j * y + grad * lam_n / (sigma_p * jnp.maximum(qii, 1e-12)),
+            0.0, 1.0,
+        )
+        delta = jnp.where(qii > 0, y * new_dual - a_j, 0.0)
+        a = a.at[j].add(delta)
+        wx = wx + (sigma_p * delta / lam_n) * gram_c[j]
+        return wx, a
+
+    _, a = jax.lax.fori_loop(0, steps, sdca_step, (wx0, alpha_c))
+    return a - alpha_c
+
+
+def hoisted_draws(keys, steps: int, rows: int):
+    """The rows ``chain_sdca_gram`` draws, step by step, for every chain at
+    once: ``(C, steps)`` int32 from the chains' keys, the same
+    ``fold_in(key_c, h)`` sequence, so that a step outside the loop that
+    draws (the Pallas kernel) runs the identical update sequence."""
+    return jax.vmap(lambda key_c: jax.vmap(
+        lambda h: jax.random.randint(
+            jax.random.fold_in(key_c, h), (), 0, rows)
+    )(jnp.arange(steps)))(keys)
+
+
 def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     D = num_blocks(mesh)
     K = problem.n_blocks               # real logical blocks
@@ -534,7 +608,17 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     d = problem.n_features
     dense = problem.dense
     inner = _resolve_inner(problem, config, mesh)
-    step_mode = _step_choice()
+    platform = mesh.devices.flat[0].platform
+    in_kernel = resolve_step(platform, inner, dtype, H_rows, H) == "kernel"
+    if in_kernel:
+        from .sdca_pallas import LANES, SUBLANES, sdca_steps_lanes
+
+        # the kernel's geometry: rows on the sublanes, chains on the lanes
+        Hp, Cp = _round_up(H_rows, SUBLANES), _round_up(C, LANES)
+
+        def to_lanes(x, rows=Hp):
+            """(C, ·) of a device's chains -> (rows, Cp), zero pads."""
+            return jnp.pad(x.T, ((0, rows - x.shape[1]), (0, Cp - C)))
     # the dense layout has one form of Δw, a product over X
     dw_mode = _dw_choice() if inner == "gram" and not dense else "direct"
 
@@ -580,72 +664,17 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         # Δw of this chain under the TRUE coupling: (w_loc − w)/σ'
         return (w_loc - w) / sigma_p, a - alpha_c
 
-    def chain_sdca_gram(wx0, gram_c, label_c, sqn_c, alpha_c, key_c):
-        """H serial SDCA steps of ONE chain, Gram-matrix inner loop: the
-        running margin vector wx[i] = w_loc·x_i absorbs each update via
-        one Gram row (wx += σ'·Δα_j/λn · G[j, :]), so no step touches the
-        (d,)-dim weights.  Same RNG and dual step as ``chain_sdca`` —
-        identical update sequence, reassociated arithmetic."""
-        def sdca_step(h, inner_c):
-            wx, a = inner_c
-            j = jax.random.randint(jax.random.fold_in(key_c, h), (), 0,
-                                   label_c.shape[0])
-            y = label_c[j]
-            qii = sqn_c[j]
-            a_j = a[j]
-            grad = 1.0 - y * wx[j]
-            new_dual = jnp.clip(
-                a_j * y + grad * lam_n / (sigma_p * jnp.maximum(qii, 1e-12)),
-                0.0, 1.0,
-            )
-            delta = jnp.where(qii > 0, y * new_dual - a_j, 0.0)
-            a = a.at[j].add(delta)
-            wx = wx + (sigma_p * delta / lam_n) * gram_c[j]
-            return wx, a
+    sdca_gram = functools.partial(
+        chain_sdca_gram, steps=H, lam_n=lam_n, sigma_p=sigma_p)
 
-        _, a = jax.lax.fori_loop(0, H, sdca_step, (wx0, alpha_c))
-        return a - alpha_c
-
-    def chain_sdca_gram_onehot(wx0, gram_c, label_c, sqn_c, alpha_c, key_c):
-        """``chain_sdca_gram`` with every dynamic access rewritten as a
-        dense one-hot contraction and the per-step RNG hoisted out of the
-        loop: no gather, no scatter, no threefry inside the fori_loop.
-        Bit-identical to the dynamic path — the index draw is the same
-        fold_in(key, h) sequence (vectorized), and one-hot reads/writes
-        multiply by exact 1.0/0.0 so no value is ever rounded
-        (``precision="highest"`` keeps the Gram-row contraction in f32)."""
-        rows = label_c.shape[0]
-        j_all = jax.vmap(
-            lambda h: jax.random.randint(
-                jax.random.fold_in(key_c, h), (), 0, rows
-            )
-        )(jnp.arange(H))
-        iota = jnp.arange(rows)
-
-        def sdca_step(h, inner_c):
-            wx, a = inner_c
-            onehot = (iota == j_all[h]).astype(dtype)      # (rows,)
-            y = jnp.sum(label_c * onehot)
-            qii = jnp.sum(sqn_c * onehot)
-            a_j = jnp.sum(a * onehot)
-            grad = 1.0 - y * jnp.sum(wx * onehot)
-            new_dual = jnp.clip(
-                a_j * y + grad * lam_n / (sigma_p * jnp.maximum(qii, 1e-12)),
-                0.0, 1.0,
-            )
-            delta = jnp.where(qii > 0, y * new_dual - a_j, 0.0)
-            a = a + delta * onehot
-            grow = jnp.einsum("r,rk->k", onehot, gram_c,
-                              precision="highest",
-                              preferred_element_type=dtype)
-            wx = wx + (sigma_p * delta / lam_n) * grow
-            return wx, a
-
-        _, a = jax.lax.fori_loop(0, H, sdca_step, (wx0, alpha_c))
-        return a - alpha_c
-
-    sdca_gram = (chain_sdca_gram_onehot if step_mode == "onehot"
-                 else chain_sdca_gram)
+    def gram_out(gram):
+        """The Gram build's (C, H, H) as the step reads it: as it is, or
+        for the kernel chain-minor, (H_rows, Hp, Cp), inside the build's own
+        program, so that no chain-major copy outlives it."""
+        if not in_kernel:
+            return gram
+        return jnp.pad(jnp.transpose(gram, (1, 2, 0)),
+                       ((0, 0), (0, Hp - H_rows), (0, Cp - C)))
 
     def build_gram(idx_s, val_s):
         """Per-chain row-Gram G[c] = S_c S_cᵀ via densify-matmul: scatter
@@ -668,7 +697,7 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                               preferred_element_type=dtype)
 
         with jax.named_scope("svm.gram"):
-            return jax.lax.map(one, (idx_s, val_s), batch_size=B)
+            return gram_out(jax.lax.map(one, (idx_s, val_s), batch_size=B))
 
     def build_gram_dense(val):
         """Per-chain row-Gram G[c] = X_c X_cᵀ straight from the device's
@@ -693,9 +722,9 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         with jax.named_scope("svm.gram"):
             gram = jax.lax.map(
                 step, jnp.minimum(jnp.arange(steps) * B, C - B))
-            return jnp.concatenate([
+            return gram_out(jnp.concatenate([
                 gram[:-1].reshape(-1, H_rows, H_rows),
-                gram[-1, steps * B - C:]])
+                gram[-1, steps * B - C:]]))
 
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
                   gram=None, slot=None, dw_a=None, dw_b=None, dw_c=None):
@@ -772,9 +801,18 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                     ).reshape(C, H_rows)
             with jax.named_scope("svm.steps"):
                 keys = chain_keys(it)
-                dalpha = jax.vmap(sdca_gram)(
-                    wx0, gram, label, sq_norm, alpha, keys
-                )
+                if in_kernel:
+                    # gram, label and sq_norm lie chain-minor already
+                    dalpha = sdca_steps_lanes(
+                        to_lanes(hoisted_draws(keys, H, H_rows),
+                                 _round_up(H, SUBLANES)),
+                        gram, to_lanes(wx0), label, sq_norm, to_lanes(alpha),
+                        steps=H, lam_n=float(lam_n), sigma_p=float(sigma_p),
+                        interpret=platform != "tpu")[:H_rows, :C].T
+                else:
+                    dalpha = jax.vmap(sdca_gram)(
+                        wx0, gram, label, sq_norm, alpha, keys
+                    )
             # this device's Δw = Σ_chains X_cᵀ Δα_c / λn: ONE reduction
             # per round (the scatter engine pays one per STEP per chain).
             # Mode trade-offs in _dw_choice's docstring.
@@ -825,9 +863,13 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     spec2 = P(BLOCK_AXIS, None)
     # dense: no ids (None has no leaves), X as (slots, d) split by rows
     rows_specs = (P(), spec2) if dense else (spec3, spec3)
-    in_specs = (P(), P(), *rows_specs, spec2, spec2, spec2, P())
+    # the kernel step reads the Gram tensor, the labels and the norms
+    # chain-minor: a device's chains on the lanes
+    lanes3, lanes2 = P(None, None, BLOCK_AXIS), P(None, BLOCK_AXIS)
+    by_chain = lanes2 if in_kernel else spec2
+    in_specs = (P(), P(), *rows_specs, by_chain, by_chain, spec2, P())
     if inner == "gram":
-        in_specs += (spec3,)  # gram
+        in_specs += (lanes3 if in_kernel else spec3,)  # gram
         if not dense:
             # idx, val: one spec for the whole tuple of buckets; slot, then
             # the dw mode's operands
@@ -866,9 +908,12 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                         else (build_gram, (spec3, spec3)))
         gram_fn = jax.jit(shard_map(
             build, mesh=mesh,
-            in_specs=specs, out_specs=spec3, check_vma=False,
+            in_specs=specs,
+            out_specs=lanes3 if in_kernel else spec3,
+            check_vma=False,
         ))
-    return fit, gram_fn, dw_mode if inner == "gram" else "direct"
+    return (fit, gram_fn, dw_mode if inner == "gram" else "direct",
+            (Hp, Cp) if in_kernel else None)
 
 
 _FIT_CACHE: "dict" = {}
@@ -908,7 +953,7 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
 def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
                        gram_bytes: int, chains: int, dense: bool,
-                       sigma_prime: float) -> None:
+                       sigma_prime: float, step_kernel: bool) -> None:
     """What the compiled round streams, for whoever reads the registry:
     row slots (pad rows and pad blocks included); the entries stored per
     slot, so that rows x row_width is every entry the round's gather and
@@ -920,7 +965,9 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
     engine and the dense layout: one rectangle); the Gram tensor's bytes
     (0 on the scatter engine); chains per device; the cells of X held dense
     (0 on the sparse layouts), so that dense_entries over rows x row_width
-    says which layout served a fit; the σ' in force (1 in avg mode)."""
+    says which layout served a fit; the σ' in force (1 in avg mode); the
+    chains a device whose SDCA steps the compiled round runs in the Pallas
+    kernel (all of them, or 0 on the XLA step: ``resolve_step``)."""
     reg = obs_metrics.get_registry()
     reg.gauge("tpums_svm_rows").set(slots)
     reg.gauge("tpums_svm_row_width").set(stored / slots)
@@ -928,6 +975,7 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
     reg.gauge("tpums_svm_buckets").set(buckets)
     reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
     reg.gauge("tpums_svm_chains_per_device").set(chains)
+    reg.gauge("tpums_svm_step_kernel_chains").set(chains if step_kernel else 0)
     reg.gauge("tpums_svm_dense_entries").set(stored if dense else 0)
     reg.gauge("tpums_svm_sigma_prime").set(sigma_prime)
 
@@ -935,18 +983,20 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
 def layout_report() -> str:
     """One clause for the trainer's ``[SVM]`` line, from the gauges the
     last ``compile_svm_fit`` set: which row layout it chose, what the
-    layout stores, and the σ' in force."""
+    layout stores, the σ' in force, and the form of the SDCA steps."""
     reg = obs_metrics.get_registry()
-    rows, width, dense_cells, buckets, sigma = (
+    rows, width, dense_cells, buckets, sigma, in_kernel = (
         reg.gauge("tpums_svm_" + name).value for name in (
-            "rows", "row_width", "dense_entries", "buckets", "sigma_prime"))
+            "rows", "row_width", "dense_entries", "buckets", "sigma_prime",
+            "step_kernel_chains"))
     if dense_cells:
         layout = f"dense rows ({int(rows)} x {int(width)} cells, no ids)"
     else:
         layout = (f"sparse rows ({int(rows)} x {width:.2f} stored entries, "
                   + (f"{int(buckets)} length buckets)" if buckets
                      else "one padded rectangle)"))
-    return f"layout {layout}, sigma' {sigma:g}"
+    return (f"layout {layout}, sigma' {sigma:g}, steps "
+            + ("in the Pallas kernel" if in_kernel else "in XLA"))
 
 
 def compile_svm_fit(
@@ -957,6 +1007,9 @@ def compile_svm_fit(
     alpha shards).  Benchmarks call ``fit_fn`` directly so host<->device
     transfer and compile stay out of the timed region.  ``dev_args[0]`` is
     w and ``dev_args[5]`` alpha, ``(Kp, rows_per_block)`` in slot order;
+    [3] and [4] are the labels and squared norms, in that shape too or, where
+    the SDCA steps run in the Pallas kernel (``resolve_step``), chain-minor
+    beside the Gram tensor at [7], ``(rows, rows8, chains128)`` a device;
     the rest is the layout's and the engine's: the padded rectangles at
     [1], [2] on the scatter engine, the bucketed rows on the Gram engine;
     on the dense layout [1] is None and [2] is X as ``(Kp·rows, features)``,
@@ -977,28 +1030,42 @@ def compile_svm_fit(
         return jax.device_put(jax.tree.map(
             lambda x: jnp.asarray(x, dtype=as_dtype), a), sharding)
 
-    fit, gram_fn, dw_mode = _cached_fit(problem, config, mesh)
-    buckets, extra = 0, []
     if problem.dense:
         with tracing.phase("svm.place"):
             # straight from the host matrix to its shards: no staging copy
-            # on the default device
-            idx, val = None, jax.block_until_ready(jax.device_put(
+            # on the default device.  X sets out before the program is
+            # looked up: on a TPU the lookup imports the Pallas step's
+            # module, a second that the transfer flies under
+            idx, val = None, jax.device_put(
                 _pad_blocks(problem.val, Kp).reshape(
-                    -1, problem.n_features), shard2).astype(dtype))
-        stored = val.size
-        if gram_fn is not None:
-            with tracing.phase("svm.gram_build"):
-                extra.append(jax.block_until_ready(gram_fn(val)))
+                    -1, problem.n_features), shard2).astype(dtype)
+            made = _cached_fit(problem, config, mesh)
+            jax.block_until_ready(val)
     else:
+        made = _cached_fit(problem, config, mesh)
         idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
-        stored = idx.size
-        if gram_fn is not None:
-            # the Gram build reads the padded rectangles once and lets them
-            # go: the rounds hold the bucketed rows only
-            with tracing.phase("svm.gram_build"):
-                extra.append(jax.block_until_ready(
-                    gram_fn(put(idx, shard3), put(val, shard3, dtype))))
+    fit, gram_fn, dw_mode, step_lanes = made
+    stored, buckets, extra = val.size, 0, []
+    if gram_fn is not None:
+        # of sparse rows the Gram build reads the padded rectangles once
+        # and lets them go: the rounds hold the bucketed rows only
+        with tracing.phase("svm.gram_build"):
+            extra.append(jax.block_until_ready(gram_fn(*(
+                (val,) if problem.dense
+                else (put(idx, shard3), put(val, shard3, dtype))))))
+
+    def by_chain(a):
+        """Labels or squared norms, (K, rows) -> the device: (Kp, rows)
+        in slot order, or chain-minor as the kernel step reads them, (Hp,
+        D·Cp), a device's chains on its lanes, zero pads."""
+        a = _pad_blocks(a, Kp)
+        if not step_lanes:
+            return put(a, shard2, dtype)
+        Hp, Cp = step_lanes
+        a = a.reshape(D, Kp // D, -1).transpose(2, 0, 1)
+        a = np.pad(a, ((0, Hp - a.shape[0]), (0, 0), (0, Cp - Kp // D)))
+        return put(a.reshape(Hp, D * Cp),
+                   NamedSharding(mesh, P(None, BLOCK_AXIS)), dtype)
     # the phases end when the device has what they made, so that a profile
     # shows the Gram build and the transfer, not their dispatch
     with tracing.phase("svm.place"):
@@ -1014,8 +1081,8 @@ def compile_svm_fit(
             put(np.zeros((problem.n_features,)), rep, dtype),
             idx if problem.dense else put(idx, shard3),
             val if problem.dense else put(val, shard3, dtype),
-            put(_pad_blocks(problem.label, Kp), shard2, dtype),
-            put(_pad_blocks(problem.sq_norm, Kp), shard2, dtype),
+            by_chain(problem.label),
+            by_chain(problem.sq_norm),
             put(np.zeros((Kp, problem.rows_per_block)), shard2, dtype),
             put(np.asarray([config.seed], np.uint32), rep),
             *extra,
@@ -1024,7 +1091,7 @@ def compile_svm_fit(
         Kp * problem.rows_per_block, stored,
         int(np.count_nonzero(problem.val)), buckets,
         extra[0].nbytes if extra else 0, Kp // D, problem.dense,
-        _combine_scales(config, problem.n_blocks)[1])
+        _combine_scales(config, problem.n_blocks)[1], bool(step_lanes))
     return fit, dev_args
 
 

@@ -134,6 +134,9 @@ _TPU_LOWERED = {
     # bucket of ML-20M, rank 64, and the item side's 64,728-wide bucket,
     # which goes through the tiled-w path with a ragged last tile
     "assemble_pallas.py": [(24, 50), (144, 50), (328, 64), (64728, 50)],
+    # (rows a chain, steps) of the SDCA kernel at the two CoCoA cells,
+    # epsilon-cocoa-plus and rcv1-cocoa, and the longest chain it takes
+    "sdca_pallas.py": [(49, 49), (83, 83), (113, 113)],
 }
 # (r, w, k) of the lane-major form at the extremes of als-ml20m.retrain:
 # the most rows, the middle of the user side, the widest whole bucket
@@ -213,3 +216,24 @@ def test_cholesky_kernel_with_diagonal_lowers_for_tpu(k, n):
         jax.ShapeDtypeStruct((n,), jnp.float32),
     ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("h_rows,steps", _TPU_LOWERED["sdca_pallas.py"])
+def test_sdca_kernel_lowers_for_tpu(h_rows, steps):
+    """8192 chains a device, as both cells run."""
+    from flink_ms_tpu.ops.sdca_pallas import fits_vmem, sdca_steps_lanes
+
+    assert fits_vmem(h_rows, steps)
+    hp, hs, cp = -(-h_rows // 8) * 8, -(-steps // 8) * 8, 8192
+    state = jax.ShapeDtypeStruct((hp, cp), jnp.float32)
+    lowered = jax.jit(
+        lambda j, g, wx, y, q, a: sdca_steps_lanes(
+            j, g, wx, y, q, a, steps=steps, lam_n=0.4, sigma_p=8192.0,
+            interpret=False)
+    ).trace(
+        jax.ShapeDtypeStruct((hs, cp), jnp.int32),
+        jax.ShapeDtypeStruct((h_rows, hp, cp), jnp.float32),
+        state, state, state, state,
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    assert tuple(lowered.out_info.shape) == (hp, cp)

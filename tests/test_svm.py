@@ -275,13 +275,13 @@ def test_gram_inner_matches_scatter(rng):
         np.testing.assert_allclose(w_g, w_s, rtol=2e-4, atol=1e-6)
 
 
-def test_gram_onehot_step_bit_identical_to_dynamic(rng, monkeypatch):
-    """FLINK_MS_SVM_STEP=onehot (a selectable lowering: dense mask/
-    one-hot contractions, RNG hoisted out of the loop — chip-neutral
-    single-chip, kept for meshes where per-step latency resurfaces) runs
-    the identical index sequence and multiplies only by exact 0s/1s, so
-    the trained weights must be BIT-identical to the dynamic
-    gather/scatter step that "auto" resolves to."""
+def test_gram_kernel_step_bit_identical_to_dynamic(rng, monkeypatch):
+    """FLINK_MS_SVM_STEP=kernel (the Pallas kernel of ops/sdca_pallas.py,
+    interpreted off the chip: the draws hoisted out of the loop, every
+    access a select against the draw) runs the identical index sequence
+    and only selects values or adds exact zeros, so the trained weights
+    must be BIT-identical to the dynamic gather/scatter step that "auto"
+    resolves to on the CPU."""
     data = _sparse_blob(rng, n=500, d=250, nnz_row=10)
     mesh = make_mesh(4)
     p = prepare_svm_blocked(data, 16, seed=0)
@@ -290,9 +290,9 @@ def test_gram_onehot_step_bit_identical_to_dynamic(rng, monkeypatch):
                     inner="gram")
     monkeypatch.setenv("FLINK_MS_SVM_STEP", "dynamic")
     w_dyn = svm_fit(data, cfg, mesh, problem=p).weights
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "onehot")
-    w_oh = svm_fit(data, cfg, mesh, problem=p).weights
-    np.testing.assert_array_equal(w_oh, w_dyn)
+    monkeypatch.setenv("FLINK_MS_SVM_STEP", "kernel")
+    w_k = svm_fit(data, cfg, mesh, problem=p).weights
+    np.testing.assert_array_equal(w_k, w_dyn)
 
 
 def test_segmented_fit_bit_identical_to_one_shot(rng):

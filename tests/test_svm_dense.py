@@ -301,7 +301,7 @@ def lowered_round(inner="gram"):
     cfg = SVMConfig(local_iterations=problem.rows_per_block,
                     regularization=LAM, seed=SEED, mode="add", inner=inner)
     fit, args = compile_svm_fit(problem, cfg, make_mesh(1))
-    _, gram_fn, _ = svm._cached_fit(problem, cfg, make_mesh(1))
+    _, gram_fn, _, _ = svm._cached_fit(problem, cfg, make_mesh(1))
     return (jax.jit(lambda *a: fit(1, *a)).lower(*args).as_text(debug_info=True),
             gram_fn.lower(args[2]).as_text(debug_info=True) if gram_fn else "")
 

@@ -142,7 +142,7 @@ def test_the_scatter_engine_has_no_margins_scope(lowered):
 def test_the_gram_build_carries_its_scope():
     problem = prepare_svm_blocked(uneven_documents(), 4, seed=SEED)
     cfg = SVMConfig(local_iterations=problem.rows_per_block, inner="gram")
-    _, gram_fn, _ = svm._cached_fit(problem, cfg, make_mesh(1))
+    _, gram_fn, _, _ = svm._cached_fit(problem, cfg, make_mesh(1))
     text = gram_fn.lower(jnp.asarray(problem.idx), jnp.asarray(problem.val)
                          ).as_text(debug_info=True)
     assert "svm.gram" in text
@@ -355,13 +355,16 @@ def test_the_rounds_gather_and_scatter_run_over_the_stored_entries(inner):
 
 
 @pytest.mark.parametrize("choice, resolved", [
-    ("auto", "dynamic"), ("dynamic", "dynamic"), ("onehot", "onehot")])
+    ("auto", "dynamic"), ("dynamic", "dynamic"), ("kernel", "kernel")])
 def test_step_knob_accepts_its_three_values(choice, resolved, monkeypatch):
+    """What a Gram fit on the CPU runs under each value: "auto" keeps the
+    XLA step there, "kernel" interprets the Pallas kernel."""
     monkeypatch.setenv("FLINK_MS_SVM_STEP", choice)
-    assert svm._step_choice() == resolved
+    assert svm._step_choice() == choice
+    assert svm.resolve_step("cpu", "gram", np.float32, 83, 83) == resolved
 
 
-@pytest.mark.parametrize("typo", ["one-hot", "Dynamic", ""])
+@pytest.mark.parametrize("typo", ["onehot", "Dynamic", ""])
 def test_step_knob_rejects_anything_else(typo, monkeypatch):
     monkeypatch.setenv("FLINK_MS_SVM_STEP", typo)
     with pytest.raises(ValueError, match="FLINK_MS_SVM_STEP"):
