@@ -42,10 +42,15 @@ RETRIEVAL TIERS (round 11).  Two levers lift the catalog ceiling from the
 - **IVF ANN tier** (``serve/ann.py``) — a coarse k-means quantizer over
   the item factors (trained on-device, refreshed by the same background
   rebuild thread) makes retrieval cost sublinear in the catalog: a query
-  probes the ``TPUMS_ANN_NPROBE`` nearest centroid lists and the
-  shortlist is re-ranked EXACTLY against the resident factor matrix, so
-  the only approximation is a missing candidate — which the build-time
-  recall probe measures and gates on (``TPUMS_ANN_RECALL_MIN``).
+  probes the ``TPUMS_ANN_NPROBE`` nearest centroid lists and the rows of
+  those lists are scored EXACTLY, so the only approximation is a missing
+  candidate — which the build-time recall probe measures and gates on
+  (``TPUMS_ANN_RECALL_MIN``).  While the tier serves, the resident matrix
+  itself lies in list order (a list is a run of whole blocks of rows,
+  read as blocks; ``_ids`` / ``_id_pos`` follow, pad positions hold no
+  id), so the catalog still exists once and an UPDATE still lands where
+  the next query reads.  One device only: a mesh-sharded catalog keeps
+  the sharded exact tier.
   ``TPUMS_TOPK_TIER`` picks: ``exact``, ``ivf``, or ``auto`` (default —
   IVF past ``TPUMS_ANN_MIN_ROWS`` while the measured recall holds the
   gate, exact otherwise, so the approximation is a contract, not a
@@ -309,6 +314,7 @@ class DeviceFactorIndex:
         self._bias = None        # (n_pad,) pad-row score bias (sharded)
         self._n_pad = 0
         self._ann = None         # serve.ann.IVFIndex when the tier is built
+        self._said_ann_unsharded = False
         # retrieval-plane health (obs/scrape.fleet_signals): rebuild rate,
         # dirty backlog depth, and how stale the serving matrix is
         # relative to the oldest unabsorbed update
@@ -322,6 +328,19 @@ class DeviceFactorIndex:
             "tpums_topk_index_staleness_seconds", pid=str(os.getpid()))
         self._obs_ann_recall = reg.gauge(
             "tpums_ann_recall_probe", pid=str(os.getpid()))
+        # which tier answered: frames the IVF program ran and the queries
+        # in them, the rows in the union of a frame's probed lists and the
+        # rows scored summed over its queries, the pad rows of their blocks
+        # among them (both counted by the program, fetched with the
+        # results), and the builds that failed and left the exact tier
+        # serving
+        self._obs_ann_frames = reg.counter("tpums_ann_frames_total")
+        self._obs_ann_queries = reg.counter("tpums_ann_queries_total")
+        self._obs_ann_union_rows = reg.counter("tpums_ann_union_rows_total")
+        self._obs_ann_probed_rows = reg.counter(
+            "tpums_ann_probed_rows_total")
+        self._obs_ann_build_failures = reg.counter(
+            "tpums_ann_build_failures_total")
         # the installed layout (set at every swap): devices the rows are
         # split over (1 = the single-device layout), rows a device holds,
         # pad rows among them; and the frames the shard_map program ran
@@ -560,28 +579,40 @@ class DeviceFactorIndex:
         self._obs_host_copy_bytes.set(copied)
         return matrix, bias, n_pad, True
 
-    def _maybe_build_ann(self, rows):
-        """Build the IVF tier for this catalog snapshot, or None when the
-        tier knob / size threshold says exact-only.  Runs OFF the index
-        lock on the rebuild path (k-means + list assignment is the
-        expensive half of a swap); a failed build degrades to the exact
-        tier rather than poisoning the swap."""
+    def _wants_ann(self, n: int) -> bool:
+        """Whether a build of ``n`` rows tries the IVF tier: the tier knob
+        and, under ``auto``, the size threshold."""
+        if self.tier == "exact" or n == 0:
+            return False
+        return self.tier == "ivf" or n >= self._ann_min_rows
+
+    def _maybe_build_ann(self, rows, matrix):
+        """Build the IVF tier for this catalog snapshot -> ``(ann, listed,
+        position)`` (``serve/ann.IVFIndex.build``), or None where the exact
+        tier serves: the build failed, or under ``auto`` its recall probe
+        missed the gate.  Runs OFF the index lock on the rebuild path
+        (k-means + list assignment is the expensive half of a swap); a
+        failed build degrades to the exact tier rather than poisoning the
+        swap, is counted (``tpums_ann_build_failures_total``) and, where
+        the operator forced ``ivf``, says that their tier is not served."""
         tier = self.tier
-        n = len(rows)
-        if tier == "exact" or n == 0:
-            return None
-        if tier == "auto" and n < self._ann_min_rows:
-            return None
         try:
             from .ann import IVFIndex
 
             with phase("topk.build.ann"):
-                ann = IVFIndex.build(np.asarray(rows, dtype=np.float32))
-        except Exception as e:  # pragma: no cover - defensive
+                built = IVFIndex.build(
+                    np.asarray(rows, dtype=np.float32), matrix)
+        except Exception as e:
             self._obs_device_errors.inc()
+            self._obs_ann_build_failures.inc()
             print(f"[topk] IVF build failed (serving exact): {e}",
                   file=sys.stderr)
+            if tier == "ivf":
+                print("[topk] TPUMS_TOPK_TIER=ivf is NOT being served: every "
+                      "answer comes from the exact tier until a rebuild "
+                      "succeeds", file=sys.stderr)
             return None
+        ann = built[0]
         self._obs_ann_recall.set(ann.recall_probe)
         if tier == "auto" and ann.recall_probe < self._ann_recall_min:
             # the recall contract failed on THIS catalog's geometry: auto
@@ -593,7 +624,7 @@ class DeviceFactorIndex:
                 file=sys.stderr,
             )
             return None
-        return ann
+        return built
 
     def _assemble(self, ids, rows, width) -> dict:
         """The expensive half of a (re)build — device placement, ANN
@@ -607,42 +638,62 @@ class DeviceFactorIndex:
         layout, ``_pack``), ``.pad`` (sharded layout only: the host buffers
         of the shards that are not wholly real rows, before the layout's
         ``.place``), ``.ids`` (the ``id_pos`` dict, made before the
-        first wait so that one device's transfer flies under it), ``.ann``
-        (only where the tier builds) and ``.warm_scatter`` (a compile or
-        load, a whole-matrix copy, and what is left of one device's
-        transfer)."""
+        first wait so that one device's transfer flies under it; where the
+        IVF tier is tried, after it, since the tier decides the positions),
+        ``.ann`` (only where the tier builds; its own children ``.train``,
+        ``.assign``, ``.lists``, ``.recall``) and ``.warm_scatter`` (a
+        compile or load, a whole-matrix copy, and what is left of one
+        device's transfer)."""
         with phase("topk.build"):
-            matrix = bias = ann = None
+            matrix = bias = ann = id_pos = None
             n_pad, sharded = 0, False
+            n_real = len(ids)
             if len(rows):
                 matrix, bias, n_pad, sharded = self._pack(rows)
-            with phase("topk.build.ids"):
-                # a comprehension, not ``dict(zip(...))``: the C-level form
-                # is 2-5% faster (the inserts are the cost, PERF.md §6) and
-                # would hold the interpreter lock for all of it, against
-                # the threads that answer queries while a rebuild runs
-                id_pos = {id_: i for i, id_ in enumerate(ids)}
-            if len(rows):
-                ann = self._maybe_build_ann(rows)
-                if ann is not None and sharded:
-                    # the re-rank gathers from the SHARDED matrix: the tiny
-                    # quantizer arrays must live on the same mesh or jit
-                    # refuses the device mix
-                    ann.colocate(self._mesh)
-                if not self._counter_mode:
-                    # warm the fixed-shape update scatter at the NEW matrix
-                    # shape (result discarded — a compile and a whole-matrix
-                    # copy) so the first streaming update never pays a
-                    # compile on the query path
-                    with phase("topk.build.warm_scatter"):
-                        pos = np.zeros((self.apply_cap,), dtype=np.int32)
-                        vec = np.zeros(
-                            (self.apply_cap, matrix.shape[1]),
-                            dtype=np.float32)
-                        matrix.at[pos].set(vec).block_until_ready()
+            tries_ann = self._wants_ann(len(rows))
+            if tries_ann and sharded:
+                tries_ann = False
+                if not self._said_ann_unsharded:
+                    # once an index, under ``auto`` too: the setting asks
+                    # for a tier that this layout does not serve
+                    self._said_ann_unsharded = True
+                    print(f"[topk] TPUMS_TOPK_TIER={self.tier}: the IVF tier "
+                          "lives on one device; this mesh-sharded catalog "
+                          "serves the sharded exact tier", file=sys.stderr)
+            if tries_ann:
+                built = self._maybe_build_ann(rows, matrix)
+                if built is not None:
+                    # the list-ordered matrix is the resident one from here
+                    # (the row-ordered one is dropped); ids follow it
+                    ann, matrix, position = built
+                    n_pad = matrix.shape[0]
+                    with phase("topk.build.ids"):
+                        listed = np.full((n_pad,), None, dtype=object)
+                        listed[position] = ids
+                        id_pos = dict(zip(ids, position.tolist()))
+                        ids = listed.tolist()
+            if id_pos is None:
+                with phase("topk.build.ids"):
+                    # a comprehension, not ``dict(zip(...))``: the C-level
+                    # form is 2-5% faster (the inserts are the cost, PERF.md
+                    # §6) and would hold the interpreter lock for all of it,
+                    # against the threads that answer queries while a
+                    # rebuild runs
+                    id_pos = {id_: i for i, id_ in enumerate(ids)}
+            if len(rows) and not self._counter_mode:
+                # warm the fixed-shape update scatter at the NEW matrix
+                # shape (result discarded — a compile and a whole-matrix
+                # copy) so the first streaming update never pays a
+                # compile on the query path
+                with phase("topk.build.warm_scatter"):
+                    pos = np.zeros((self.apply_cap,), dtype=np.int32)
+                    vec = np.zeros(
+                        (self.apply_cap, matrix.shape[1]),
+                        dtype=np.float32)
+                    matrix.at[pos].set(vec).block_until_ready()
             return {
                 "ids": ids, "id_pos": id_pos,
-                "n_real": len(ids), "k_real": width, "matrix": matrix,
+                "n_real": n_real, "k_real": width, "matrix": matrix,
                 "bias": bias, "n_pad": n_pad, "sharded": sharded, "ann": ann,
             }
 
@@ -902,19 +953,37 @@ class DeviceFactorIndex:
         query program per batch bucket."""
         return self._is_sharded or self._ann is not None
 
-    def _dispatch_frame_locked(self, q: np.ndarray, k_eff: int):
-        """One device dispatch for a ``(B, n_factors)`` query frame ->
+    def _dispatch_frame_locked(self, q: np.ndarray, k_eff: int,
+                               n_queries: int):
+        """One device dispatch for a ``(B, n_factors)`` query frame, its
+        first ``n_queries`` rows real ->
         ``(scores, idx)`` host arrays of shape (B, k_eff) — the tier
         router.  ANN (when built and gated in) probes centroid lists and
-        exactly re-ranks the shortlist against the SAME resident matrix;
+        scores their rows, read as blocks of the SAME resident matrix (one
+        dispatch a slice of 32 real rows; at least ``n_queries`` rows back);
         the sharded exact tier runs the shard_map partial-top-k + merge;
         otherwise the legacy single-device batched program.  Every branch
-        funnels through ``_to_host`` with one (B, 2k) array only — the
-        catalog never leaves the device."""
+        funnels through ``_to_host`` with one (B, 2k) array only (the IVF
+        program's carries two counts beside it) — the catalog never leaves
+        the device."""
+        if self._ann is not None:
+            # the tier's program takes up to ``wide`` queries: a wider frame
+            # (the push plane's group, TPUMS_TOPK_BATCH_MAX above it) goes
+            # as slices of its real rows, all enqueued before the first wait
+            wide = self._ann.max_frame
+            with stage("topk.enqueue"):
+                real = q[:n_queries]
+                packed = [self._ann.search(self._matrix, real[lo:lo + wide], k_eff)
+                          for lo in range(0, n_queries, wide)]
+            scores, idx, counts = self._fetch(packed, 2)
+            self._obs_ann_frames.inc(len(packed))
+            self._obs_ann_queries.inc(n_queries)
+            # a slice's union is the same in each of its rows
+            self._obs_ann_union_rows.inc(int(counts[::wide, 0].sum()))
+            self._obs_ann_probed_rows.inc(int(counts[:n_queries, 1].sum()))
+            return scores, idx
         with stage("topk.enqueue"):
-            if self._ann is not None:
-                packed = self._ann.search(self._matrix, q, k_eff)
-            elif self._is_sharded:
+            if self._is_sharded:
                 fn = _sharded_topk_program(self._mesh)
                 packed = fn(self._matrix, self._bias, q, k_eff)
                 self._obs_sharded_frames.inc()
@@ -937,15 +1006,20 @@ class DeviceFactorIndex:
                 packed = self._topk_many_fn(self._matrix, q, k_eff)
         return self._fetch(packed)
 
-    def _fetch(self, packed):
+    def _fetch(self, packed, counts: int = 0):
         """The wait for the device and the one result copy, between two
         stamped instants: the one stage of a dispatch that contains the
-        device's work.  -> ``(scores, idx)`` host arrays."""
+        device's work.  -> ``(scores, idx)`` host arrays and, where the
+        program put ``counts`` int32 columns of its own behind them,
+        those.  A list of arrays (the slices of a frame wider than the IVF
+        program's) is fetched in order and stacked."""
         t_enqueued = time.perf_counter()
         with stage("topk.fetch"):
-            out = _unpack_results(_to_host(packed))
+            host = (np.concatenate([_to_host(p) for p in packed])
+                    if isinstance(packed, list) else _to_host(packed))
+            out = _unpack_results(host[..., :-counts] if counts else host)
         self._stamps.last = (t_enqueued, time.perf_counter())
-        return out
+        return (*out, host[..., -counts:]) if counts else out
 
     def last_fetch(self) -> Optional[Tuple[float, float]]:
         """``perf_counter`` instants of the CALLING thread's last dispatch:
@@ -988,7 +1062,8 @@ class DeviceFactorIndex:
             if self.prefers_frames:
                 # sharded / ANN tiers only compile the frame program; a
                 # lone query rides it as a (1, k) frame
-                scores, idx = self._dispatch_frame_locked(q[None, :], k_eff)
+                scores, idx = self._dispatch_frame_locked(
+                    q[None, :], k_eff, 1)
                 with stage("topk.format"):
                     return self._format_rows(scores, idx, 1)[0]
             with stage("topk.enqueue"):
@@ -1054,7 +1129,7 @@ class DeviceFactorIndex:
                 if b_pad != n_queries:
                     q = np.concatenate([q, np.broadcast_to(
                         q[:1], (b_pad - n_queries, q.shape[1]))])
-            scores, idx = self._dispatch_frame_locked(q, k_eff)
+            scores, idx = self._dispatch_frame_locked(q, k_eff, n_queries)
             with stage("topk.format"):
                 return self._format_rows(scores, idx, n_queries)
 

@@ -165,6 +165,36 @@ def test_ivf_auto_gate_degrades_to_exact(monkeypatch):
     assert idx2._ann is None
 
 
+@pytest.mark.parametrize("tier", ["ivf", "auto"])
+def test_failed_ivf_build_is_counted_and_never_silent(monkeypatch, capsys, tier):
+    """A build that fails leaves the exact tier serving, as before; it is
+    counted, and where the operator forced ``ivf`` stderr says that their
+    tier is not the one answering."""
+    from flink_ms_tpu.serve.ann import IVFIndex
+
+    rows = _clustered_rows(2000, 8, seed=5)
+    table = _fill_table(rows)
+
+    def no_room(*a, **kw):
+        raise MemoryError("RESOURCE_EXHAUSTED: 32 GB on a 16 GB chip")
+
+    monkeypatch.setattr(IVFIndex, "build", classmethod(no_room))
+    idx = _index(table, monkeypatch, sharded="0", tier=tier,
+                 TPUMS_ANN_MIN_ROWS=1000)
+    failures = idx._obs_ann_build_failures.value
+    errors = idx._obs_device_errors.value
+    exact = _index(table, monkeypatch, sharded="0", tier="exact")
+    q = np.ones(8, dtype=np.float32)
+    assert idx.topk(q, 5) == exact.topk(q, 5)
+    assert idx._ann is None and not idx.prefers_frames
+    assert idx._obs_ann_build_failures.value == failures + 1
+    assert idx._obs_device_errors.value == errors + 1
+    assert idx._obs_ann_frames.value == 0 or idx._ann is None
+    err = capsys.readouterr().err
+    assert "IVF build failed (serving exact)" in err and "32 GB" in err
+    assert ("TPUMS_TOPK_TIER=ivf is NOT being served" in err) == (tier == "ivf")
+
+
 def test_tier_auto_single_device_fallback(catalog, monkeypatch):
     # one visible device: the mesh is None, sharding can't engage even
     # when forced, and auto tier serves single-device exact
