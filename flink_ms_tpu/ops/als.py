@@ -69,6 +69,7 @@ from ..obs import tracing
 from ..parallel.mesh import (
     BLOCK_AXIS,
     block_sharding,
+    device_memory,
     host_device,
     num_blocks,
 )
@@ -862,20 +863,10 @@ def solves_per_chunk(rows: int, k: int, itemsize: int,
     return rows * k * k * itemsize > _MATERIALISE_SHARE * memory_bytes
 
 
-def _device_memory(device) -> Optional[int]:
-    """One device's memory in bytes as the runtime reports it, None where
-    it reports none (the CPU backend; a device that is only described)."""
-    try:
-        stats = device.memory_stats()
-    except Exception:  # a described topology has no runtime to ask
-        return None
-    return (stats or {}).get("bytes_limit")
-
-
 def _routes(problem: "BlockedProblem", config: "ALSConfig",
             mesh: Mesh) -> Dict[str, bool]:
     """``solves_per_chunk`` for each side of one fit on ``mesh``."""
-    memory = _device_memory(mesh.devices.flat[0])
+    memory = device_memory(mesh.devices.flat[0])
     itemsize = np.dtype(config.dtype).itemsize
     return {name: solves_per_chunk(side.per_block, config.num_factors,
                                    itemsize, memory)

@@ -53,7 +53,16 @@ whatever an entry holds.  Measured on one TPU v5e in the benchmark cell
 49.6M entries stored as 55.5M in 13 buckets where the rectangle padded to
 256 held 174M): a round takes 0.80 s, the gather 0.38, the scatter-add
 0.38, the 83 steps between them 0.037 (2.78, 1.31, 1.42, 0.037 on the
-rectangle).  On dense rows they are two products over X, ``X w`` and
+rectangle).  Where the device reports its memory, the hottest columns leave
+that price: ``head_width`` asks the dense layout's question a column at a
+time and by time, and the columns that are cheaper streamed, zeros and all,
+than gathered are held as one dense block beside the tail of the others
+(the split layout: ``_tail_tiles``, ``_head_entries``; at RCV1's shape
+1,536 of 47,236 columns, 54.8% of the entries, a quarter of the chip: a
+round of 0.392 s where it took 0.767, the gather and the scatter-add 0.19
+each over 24.9M stored entries, the block's two passes 5.5 ms each;
+PERF.md §5, PR 41).
+On dense rows they are two products over X, ``X w`` and
 ``Xᵀ Δα``, each one pass over the matrix where it lies: measured in
 ``epsilon-cocoa-plus.dense-rounds`` (PERF.md §5, PR 37; CoCoA+, 8192
 chains x 49 rows, 400,000 x 2,000, every cell stored, 3.2 GB): a round
@@ -91,7 +100,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.formats import SparseData
 from ..obs import metrics as obs_metrics
 from ..obs import tracing
-from ..parallel.mesh import BLOCK_AXIS, block_sharding, num_blocks
+from ..parallel.mesh import (BLOCK_AXIS, block_sharding, device_memory,
+                             num_blocks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,8 +185,54 @@ def stores_rows_dense(n_examples: int, n_features: int, nnz: int,
     stored entries' ids and values do (4 B an id beside each value), that
     is from a density of 1 / (1 + 4 / itemsize) up: 50% in float32.  Below
     it an entry costs its id and a gather; above it the ids say nothing a
-    position does not, and the round streams X instead of indexing w."""
+    position does not, and the round streams X instead of indexing w.  The
+    question is asked once for the whole matrix and by bytes stored;
+    ``head_width`` asks it of a sparse layout's columns one at a time and by
+    time, so RCV1 (0.16%) is sparse here and still streams its 1,536
+    hottest columns."""
     return nnz > 0 and n_examples * n_features * itemsize <= nnz * (4 + itemsize)
+
+
+# What the column split of the sparse layout is decided by.  A gathered or
+# scatter-added entry of the Gram engine's round costs _ENTRY_NS on a TPU
+# v5e whatever the layout (6.85 ns a stored entry in cocoa_margins_s and
+# in cocoa_dw_s of rcv1-cocoa.cocoa-rounds: PERF.md section 5, PR 38;
+# 6.8-8.75 in PR 32's probes of the two operations alone), and a pass over a
+# dense f32 matrix streams _STREAM_BYTES_PER_NS (0.75 TB/s, 91.5-91.9% of
+# the chip's 819 GB/s: cocoa_dense_margins_roofline and _dw_roofline of
+# epsilon-cocoa-plus.dense-rounds, PERF.md section 5, PR 38).  The dense
+# block may take _HEAD_SHARE of a device's memory: beside it the round holds
+# the tail, the Gram tensor and w, and the build holds the padded
+# rectangles for a moment (ops/als._MATERIALISE_SHARE is the precedent).
+_ENTRY_NS = 6.85
+_STREAM_BYTES_PER_NS = 750.0
+_HEAD_SHARE = 0.25
+_LANES = 128
+
+
+def head_width(col_counts, slots: int, itemsize: int,
+               memory_bytes: Optional[int]) -> int:
+    """How many of a sparse layout's hottest feature columns a device holds
+    as one dense ``(slots, F)`` value block and streams, the entries of the
+    other columns (the tail) staying gathered: a pure function of what a
+    fit can observe, no knob.  ``col_counts``: the rows of a device that
+    hold each column; ``slots``: its row slots; ``memory_bytes``: its memory
+    as the runtime reports it.  Columns are taken hottest first while
+    streaming one, zeros and all, is cheaper than gathering its entries
+    (count x ``_ENTRY_NS`` > slots x itemsize / ``_STREAM_BYTES_PER_NS``:
+    from 0.08% of the rows in f32), in whole lane tiles of 128, until the
+    block reaches ``_HEAD_SHARE`` of the memory.  A runtime that reports no
+    memory (the CPU, where a gather is cheap) takes no head.  At RCV1's
+    shape on one TPU v5e (679,936 slots, 16.9 GB): 1,536 columns, 4.18 GB,
+    54.8% of the entries; ``rcv1-cocoa.cocoa-rounds`` read 0.39202 s a round
+    against the parent's 0.76719 (PERF.md section 6, PR 41)."""
+    if not memory_bytes or not slots:
+        return 0
+    column_bytes = slots * itemsize
+    break_even = column_bytes / _STREAM_BYTES_PER_NS / _ENTRY_NS
+    cheaper_streamed = int(np.count_nonzero(np.asarray(col_counts) > break_even))
+    fits = int(_HEAD_SHARE * memory_bytes // column_bytes)
+    return min(cheaper_streamed, fits) // _LANES * _LANES
 
 
 @dataclasses.dataclass
@@ -207,7 +263,11 @@ class BlockedSVMProblem:
     host and on the device, a CoCoA+ round of 0.0313 s whose two passes
     over X take 4.25 ms each; the same rows sparse, 46 GB of host memory,
     6.5 GB of ids and values on the device and 13.2 s a round.  At RCV1's
-    0.16% the rule keeps the sparse layout, whose round did not change.
+    0.16% the rule keeps the sparse layout, and on a device that reports
+    its memory ``compile_svm_fit`` splits it by column (``head_width``): a
+    sparse row's entries are stored coldest column first (``col_rank``), so
+    that whatever number of hottest columns the fit holds dense, the other
+    columns' entries, which the round still gathers, are the row's first.
     """
 
     n_blocks: int
@@ -223,6 +283,11 @@ class BlockedSVMProblem:
     row_len: np.ndarray  # (K, rows_pb) int32 entries the example came
     #                      with (sparse: stored in the row's first
     #                      positions), 0 for padding rows
+    col_count: Optional[np.ndarray] = None  # (n_features,) rows that hold
+    #                      each feature; None on the dense layout
+    col_rank: Optional[np.ndarray] = None   # (n_features,) a feature's
+    #                      place by descending col_count, 0 the hottest; a
+    #                      sparse row's entries descend by it
 
     @property
     def dense(self) -> bool:
@@ -249,21 +314,38 @@ def _each_strip(fn, n_rows: int, width: int) -> None:
         list(pool.map(lambda b: fn(*b), bounds))
 
 
-def _padded_rows(data: SparseData, lens, order, slots: int, dtype):
+def _strip_entries(data: SparseData, lens, ex):
+    """The CSR entries of examples ``ex``, row after row -> (owner, pos,
+    flat): the row of ``ex`` each entry belongs to, its position inside
+    that row, and its place in ``data.indices`` / ``data.values``."""
+    mine = lens[ex]
+    owner = np.repeat(np.arange(len(ex)), mine)
+    pos = np.arange(int(mine.sum())) - (np.cumsum(mine) - mine)[owner]
+    return owner, pos, data.indptr[ex][owner] + pos
+
+
+def _padded_rows(data: SparseData, lens, order, slots: int, dtype,
+                 col_rank):
     """-> (idx, val), each (slots, L): slot s holds example order[s]'s
-    entries in CSR order, padded to the longest row."""
-    n = data.n_examples
+    entries, padded to the longest row, **coldest column first**
+    (descending ``col_rank``): whatever number F of hottest columns a fit
+    then holds dense (``head_width``), the entries of the other columns,
+    its tail, are the row's first ones.  Nothing else reads a row's order:
+    the Gram build scatters by id and a margin is a sum.  A strip of rows
+    at a time, on threads, as the dense layout is filled."""
+    n, d = data.n_examples, data.n_features
     L = max(int(lens.max()) if n else 1, 1)
-    # padded row-major staging in original example order
-    mask = np.arange(L)[None, :] < lens[:, None]           # (n, L)
-    idx_rows = np.zeros((n, L), dtype=np.int32)
-    val_rows = np.zeros((n, L), dtype=dtype)
-    idx_rows[mask] = data.indices                          # CSR order
-    val_rows[mask] = data.values.astype(dtype)
     idx = np.zeros((slots, L), dtype=np.int32)
     val = np.zeros((slots, L), dtype=dtype)
-    idx[:n] = idx_rows[order]
-    val[:n] = val_rows[order]
+
+    def fill(lo, hi):
+        owner, pos, flat = _strip_entries(data, lens, order[lo:hi])
+        ids = data.indices[flat]
+        by = np.argsort(owner * d + (d - 1 - col_rank[ids]), kind="stable")
+        idx[lo:hi][owner, pos] = ids[by]
+        val[lo:hi][owner, pos] = data.values[flat[by]]
+
+    _each_strip(fill, n, L)
     return idx, val
 
 
@@ -289,12 +371,7 @@ def _dense_rows(data: SparseData, lens, order, slots: int, dtype):
                 return
             owner = np.arange(hi - lo)[:, None]
         else:
-            # flat positions of the strip's entries, row after row
-            mine = lens[ex]
-            owner = np.repeat(np.arange(hi - lo), mine)
-            first = np.cumsum(mine) - mine
-            flat = (data.indptr[ex][owner]
-                    + np.arange(int(mine.sum())) - first[owner])
+            owner, _, flat = _strip_entries(data, lens, ex)
             ids, vals = data.indices[flat], data.values[flat]
         val[lo:hi][owner, ids] = vals
 
@@ -316,11 +393,17 @@ def prepare_svm_blocked(
         lens = (data.indptr[1:] - data.indptr[:-1]).astype(np.int64)
         # slot s <- example order[s]
         order = np.random.default_rng(seed).permutation(n)
+        col_count = col_rank = None
         if stores_rows_dense(n, data.n_features, int(lens.sum()),
                              np.dtype(dtype).itemsize):
             idx, val = None, _dense_rows(data, lens, order, slots, dtype)
         else:
-            idx, val = _padded_rows(data, lens, order, slots, dtype)
+            # a row's ids are distinct: an id's occurrences are its rows
+            col_count = np.bincount(data.indices, minlength=data.n_features)
+            col_rank = np.empty(data.n_features, np.int64)
+            col_rank[np.argsort(-col_count, kind="stable")] = np.arange(
+                data.n_features)
+            idx, val = _padded_rows(data, lens, order, slots, dtype, col_rank)
         label = np.zeros((slots,), dtype=dtype)
         row_len = np.zeros((slots,), dtype=np.int32)
         row_len[:n] = lens[order]
@@ -346,6 +429,8 @@ def prepare_svm_blocked(
             label=label.reshape(shape),
             sq_norm=sq_norm.reshape(shape),
             row_len=row_len.reshape(shape),
+            col_count=col_count,
+            col_rank=col_rank,
         )
 
 
@@ -442,6 +527,164 @@ def _bucket_rows(idx: np.ndarray, val: np.ndarray, plan):
     return tuple(ids_out), tuple(val_out), slot_out
 
 
+# On the split layout (``head_width`` > 0) the round streams the hottest
+# columns and gathers only the tail: each row's first ``tail_len`` entries.
+# The tail is stored as tiles of _TILE_STEP positions x _TILE_ROWS rows
+# (whole (8, 128) device tiles, entry-major as the buckets are), the rows
+# ordered by tail length so that a block of rows needs the steps of its
+# first row and no more, and the round loops over the tiles that hold an
+# entry: their number is an operand, not a shape.  The shapes are a
+# function of the TOTAL row lengths alone (a row's tail is no longer than
+# the row), so every seed of one length sequence runs one compiled program,
+# whichever features it drew.
+_TILE_STEP = 8
+_TILE_ROWS = 1024
+# the head's entries reach the device as pieces of at most this many (row,
+# column, value) triples, each scattered into a window of this many rows of
+# the zeroed block: one program whatever their number.  A window, because
+# the compiler scatters into a flat copy of its operand (PERF.md section 6,
+# PR 41: 4.2 GB of scratch held for good when the operand was the block)
+_HEAD_CHUNK = 1 << 22
+_HEAD_WINDOW = 1 << 16
+
+
+def _tail_lengths(idx: np.ndarray, row_len: np.ndarray, col_rank, head: int):
+    """(slots,) entries of each row outside the ``head`` hottest columns:
+    a row's entries descend by ``col_rank`` (``_padded_rows``), so the
+    tail ends at the first entry ranked under ``head``, found by bisection."""
+    rows, L = np.arange(len(row_len)), idx.shape[1]
+    lo, hi = np.zeros(len(row_len), np.int64), row_len.astype(np.int64)
+    for _ in range(L.bit_length()):
+        mid = (lo + hi) // 2
+        in_tail = col_rank[idx[rows, np.minimum(mid, L - 1)]] >= head
+        undecided = lo < hi
+        lo = np.where(undecided & in_tail, mid + 1, lo)
+        hi = np.where(undecided & ~in_tail, mid, hi)
+    return lo
+
+
+def _tile_steps(lens: np.ndarray) -> np.ndarray:
+    """(blocks,) the steps of _TILE_STEP positions that each block of
+    _TILE_ROWS rows needs, the rows taken longest first."""
+    longest = np.sort(lens)[::-1][::_TILE_ROWS]
+    return -(-longest // _TILE_STEP)
+
+
+def _tail_tiles(idx: np.ndarray, val: np.ndarray, row_len: np.ndarray,
+                tail_len: np.ndarray, D: int):
+    """The rows' tail entries cut out of the padded rectangles into tiles
+    -> (ids, val, slot, row0, n_tiles): ``ids`` / ``val`` (D, tiles,
+    _TILE_STEP, _TILE_ROWS), a device's tiles block after block, step after
+    step, zeros past a row's tail and past ``n_tiles`` (D, 1), the tiles that
+    hold an entry; ``slot`` (D, blocks x _TILE_ROWS) the device-local flat
+    slot of every tile row (0 for the pads of the last block, which add
+    exact zeros) and ``row0`` (D, tiles) the first of them under each tile.
+    The tile capacity is what the total lengths would need."""
+    S = row_len.size // D
+    L = idx.shape[-1]
+    idx, val = idx.reshape(D, S, L), val.reshape(D, S, L)
+    row_len, tail_len = row_len.reshape(D, S), tail_len.reshape(D, S)
+    blocks = -(-S // _TILE_ROWS)
+    capacity = max(int(max(_tile_steps(lens).sum() for lens in row_len)), 1)
+    ids_t = np.zeros((D, capacity, _TILE_STEP, _TILE_ROWS), np.int32)
+    val_t = np.zeros(ids_t.shape, val.dtype)
+    slot = np.zeros((D, blocks * _TILE_ROWS), np.int32)
+    row0 = np.zeros((D, capacity), np.int32)
+    n_tiles = np.zeros((D, 1), np.int32)
+    jobs = []
+    for dev in range(D):
+        order = np.argsort(-tail_len[dev], kind="stable")
+        slot[dev, :S] = order
+        steps = _tile_steps(tail_len[dev])
+        ends = np.cumsum(steps)
+        n_tiles[dev] = ends[-1]
+        row0[dev, :ends[-1]] = np.repeat(
+            np.arange(blocks) * _TILE_ROWS, steps)
+        jobs += [(dev, order[b * _TILE_ROWS:(b + 1) * _TILE_ROWS],
+                  int(end - n), int(end))
+                 for b, (n, end) in enumerate(zip(steps, ends)) if n]
+
+    def fill(job):
+        dev, rows, first, last = job
+        width = min((last - first) * _TILE_STEP, L)
+        keep = np.arange(width) < tail_len[dev, rows, None]
+        for src, out in ((idx, ids_t), (val, val_t)):
+            block = np.zeros(((last - first) * _TILE_STEP, len(rows)),
+                             out.dtype)
+            block[:width] = np.where(keep, src[dev, rows, :width], 0).T
+            out[dev, first:last, :, :len(rows)] = block.reshape(
+                last - first, _TILE_STEP, len(rows))
+
+    with ThreadPoolExecutor(_STRIP_THREADS) as pool:
+        list(pool.map(fill, jobs))
+    return ids_t, val_t, slot, row0, n_tiles
+
+
+def _head_pieces(ends: np.ndarray, chunk: int, window: int):
+    """A device's head entries, listed row after row (``ends[r]`` of them
+    up to and with row r), cut into pieces of at most ``chunk`` entries
+    whose rows lie within ``window`` rows -> [(first row of the window,
+    first entry, end entry)].  How many pieces, and where they are cut,
+    follows the data; their shape does not."""
+    pieces, at = [], 0
+    while at < ends[-1]:
+        row = int(np.searchsorted(ends, at, side="right"))
+        first = min(row, len(ends) - window)
+        end = min(at + chunk, int(ends[first + window - 1]))
+        pieces.append((first, at, end))
+        at = end
+    return pieces
+
+
+def _head_entries(idx: np.ndarray, val: np.ndarray, row_len: np.ndarray,
+                  tail_len: np.ndarray, col_rank, D: int, window: int):
+    """The entries of the head columns, every entry past its row's tail, as
+    pieces of a compact list -> (first, rows, cols, vals): ``first`` (D,
+    pieces, 1) the device-local row slot at which a piece's window of
+    ``window`` rows starts, and per entry its row within that window, its
+    column of the block (the feature's ``col_rank``) and its value, (D,
+    pieces, chunk) each.  What a piece does not fill names distinct cells
+    of the window's first column with value 0.  The chunk is
+    ``_HEAD_CHUNK`` or, on small data, what a device's entries of all
+    columns would fill: a function of the total lengths."""
+    S = row_len.size // D
+    L = idx.shape[-1]
+    row_len, tail_len = row_len.reshape(D, S), tail_len.reshape(D, S)
+    chunk = min(_HEAD_CHUNK,
+                _round_up(max(int(row_len.sum(axis=1).max()), 1), _LANES))
+    counts = row_len - tail_len
+    ends = np.cumsum(counts, axis=1)
+    rows, cols = (np.zeros((D, int(ends[:, -1].max())), np.int32)
+                  for _ in range(2))
+    vals = np.zeros(rows.shape, val.dtype)
+    idx, val = idx.reshape(D, S, L), val.reshape(D, S, L)
+    pos = np.arange(L)
+
+    def fill(dev, lo, hi):
+        mine = ((pos >= tail_len[dev, lo:hi, None])
+                & (pos < row_len[dev, lo:hi, None]))
+        at = slice(int(ends[dev, lo] - counts[dev, lo]), int(ends[dev, hi - 1]))
+        rows[dev, at] = np.repeat(np.arange(lo, hi), counts[dev, lo:hi])
+        cols[dev, at] = col_rank[idx[dev, lo:hi][mine]]
+        vals[dev, at] = val[dev, lo:hi][mine]
+
+    for dev in range(D):
+        _each_strip(functools.partial(fill, dev), S, L)
+    cuts = [_head_pieces(ends[dev], chunk, window) for dev in range(D)]
+    shape = (D, max(max(len(c) for c in cuts), 1), chunk)
+    first = np.zeros(shape[:2] + (1,), np.int32)
+    out_rows = np.broadcast_to(
+        np.arange(chunk, dtype=np.int32) % window, shape).copy()
+    out_cols, out_vals = np.zeros(shape, np.int32), np.zeros(shape, val.dtype)
+    for dev, pieces in enumerate(cuts):
+        for k, (start, lo, hi) in enumerate(pieces):
+            first[dev, k] = start
+            out_rows[dev, k, :hi - lo] = rows[dev, lo:hi] - start
+            out_cols[dev, k, :hi - lo] = cols[dev, lo:hi]
+            out_vals[dev, k, :hi - lo] = vals[dev, lo:hi]
+    return first, out_rows, out_cols, out_vals
+
+
 def _dw_choice() -> str:
     """FLINK_MS_SVM_DW: how the Gram engine applies the round-end
     Δw = Xᵀ Δα update over its bucketed rows (``_bucket_rows``).
@@ -457,7 +700,11 @@ def _dw_choice() -> str:
     direct scatter-add takes 0.38 s of a 0.80 s round (6.9 ns a stored
     entry) and the round-start gather 0.38 s; the steps between them
     0.04 s.  "presorted" read 0.91 s there and "sorted" 1.22 s, rounds
-    of 1.32 and 1.64 s: both lose to the direct form (ROADMAP D5)."""
+    of 1.32 and 1.64 s: both lose to the direct form (ROADMAP D5).  Both
+    keep that whole-row program: only the direct form ("auto" included)
+    splits the layout by column (``head_width``), so under "sorted" and
+    "presorted" every entry stays in the buckets and no column is held
+    dense."""
     choice = os.environ.get("FLINK_MS_SVM_DW", "auto")
     if choice not in ("auto", "direct", "sorted", "presorted"):
         # a typo'd knob must not silently fall through to the direct
@@ -593,7 +840,8 @@ def hoisted_draws(keys, steps: int, rows: int):
     )(jnp.arange(steps)))(keys)
 
 
-def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
+def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
+              head: int = 0):
     D = num_blocks(mesh)
     K = problem.n_blocks               # real logical blocks
     C = _round_up(K, D) // D           # chains stacked per device
@@ -619,8 +867,10 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
         def to_lanes(x, rows=Hp):
             """(C, ·) of a device's chains -> (rows, Cp), zero pads."""
             return jnp.pad(x.T, ((0, rows - x.shape[1]), (0, Cp - C)))
-    # the dense layout has one form of Δw, a product over X
+    # the dense layout has one form of Δw, a product over X; so has the
+    # split layout (head > 0: compile_svm_fit takes no head under a knob)
     dw_mode = _dw_choice() if inner == "gram" and not dense else "direct"
+    split = head > 0
 
     def chain_sdca(w, idx_c, val_c, label_c, sqn_c, alpha_c, key_c,
                    row0_c=None):
@@ -727,15 +977,18 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                 gram[-1, steps * B - C:]]))
 
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
-                  gram=None, slot=None, dw_a=None, dw_b=None, dw_c=None):
+                  gram=None, slot=None, *more):
         # dense layout, both engines: idx is None and val the device's
         # (C·rows, d) matrix.  Sparse layout, scatter engine: idx, val are
         # the device's padded (C, rows, L)
         # rectangles.  Gram engine: they are its bucketed rows, a tuple of
         # (1, width, rows) pieces each (_bucket_rows; the jit retraces for
         # another ladder), with slot the flat (C·rows) slot of every bucket
-        # row; dw_* depend on dw_mode: sorted -> (perm, ids), presorted ->
-        # (val_sorted, ids, src_slot); unused modes pass nothing
+        # row; more depends on dw_mode: sorted -> (perm, ids), presorted ->
+        # (val_sorted, ids, src_slot), direct nothing.  Split layout (Gram
+        # engine, head > 0): idx, val are the tail's tiles, (1, tiles,
+        # step, rows) each (_tail_tiles), slot the flat slot of every tile
+        # row, and more = (row0, n_tiles, head block, head ids)
         # span = [start, stop): rounds run with ABSOLUTE indices so the
         # per-round RNG (fold_in of the round number) is identical whether
         # the caller runs one long fit or chains warm-started segments
@@ -778,6 +1031,13 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                 alpha = alpha + gamma * dalpha
             return w, alpha
 
+        if split:
+            row0, n_tiles, head_val, head_ids = (
+                more[0][0], more[1][0, 0], more[2], more[3])
+
+            def tile_rows(rows, t):
+                return jax.lax.dynamic_slice_in_dim(rows, row0[t], _TILE_ROWS)
+
         def outer_gram(it, carry):
             w, alpha = carry
             # round-start margins for every row: ONE gather of w over the
@@ -793,6 +1053,25 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                     wx0 = jnp.einsum(
                         "nd,d->n", val, w, precision=_DENSE_PRECISION,
                         preferred_element_type=dtype).reshape(C, H_rows)
+                elif split:
+                    # the head's columns streamed, the tail's entries
+                    # gathered a tile at a time: only the tiles that hold
+                    # an entry, whose number is an operand
+                    def tile_margins(t, sums):
+                        part = jnp.sum(
+                            jnp.take(w, idx[0, t], axis=0) * val[0, t], axis=0)
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            sums, tile_rows(sums, t) + part, row0[t], 0)
+
+                    sums = jax.lax.fori_loop(
+                        0, n_tiles, tile_margins,
+                        jnp.zeros((slot.shape[1],), dtype))
+                    wx0 = (jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(sums)
+                           + jnp.einsum(
+                               "nf,f->n", head_val, w[head_ids],
+                               precision=_DENSE_PRECISION,
+                               preferred_element_type=dtype)
+                           ).reshape(C, H_rows)
                 else:
                     wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(
                         jnp.concatenate([
@@ -824,12 +1103,28 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                         "nd,n->d", val, dalpha_flat,
                         precision=_DENSE_PRECISION,
                         preferred_element_type=dtype)
+                elif split:
+                    da_rows = dalpha_flat[slot[0]]
+
+                    def tile_dw(t, dw):
+                        return dw.at[idx[0, t]].add(
+                            val[0, t] * tile_rows(da_rows, t))
+
+                    dw = jax.lax.fori_loop(
+                        0, n_tiles, tile_dw, jnp.zeros((d,), dtype))
+                    # the head's ids are distinct: one F-element scatter
+                    dw = dw.at[head_ids].add(jnp.einsum(
+                        "nf,n->f", head_val, dalpha_flat,
+                        precision=_DENSE_PRECISION,
+                        preferred_element_type=dtype))
                 elif dw_mode == "presorted":
-                    # val is stored feature-sorted (dw_a) at prepare time,
+                    # val is stored feature-sorted at prepare time,
                     # so the only runtime gather reads the tiny (C·H) Δα
                     # table
+                    val_sorted, ids_sorted, src_slot = more
                     dw = jax.ops.segment_sum(
-                        dw_a[0] * dalpha_flat[dw_c[0]], dw_b[0],
+                        val_sorted[0] * dalpha_flat[src_slot[0]],
+                        ids_sorted[0],
                         num_segments=d, indices_are_sorted=True,
                     )
                 else:
@@ -842,8 +1137,9 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
                     if dw_mode == "sorted":
                         flat = jnp.concatenate(
                             [c.reshape(-1) for c in contrib])
+                        perm, ids_sorted = more
                         dw = jax.ops.segment_sum(
-                            flat[dw_a[0]], dw_b[0], num_segments=d,
+                            flat[perm[0]], ids_sorted[0], num_segments=d,
                             indices_are_sorted=True,
                         )
                     else:
@@ -861,8 +1157,11 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
     spec3 = P(BLOCK_AXIS, None, None)
     spec2 = P(BLOCK_AXIS, None)
-    # dense: no ids (None has no leaves), X as (slots, d) split by rows
-    rows_specs = (P(), spec2) if dense else (spec3, spec3)
+    # dense: no ids (None has no leaves), X as (slots, d) split by rows;
+    # split: the tiles, (devices, tiles, step, rows)
+    rows_specs = ((P(), spec2) if dense
+                  else (P(BLOCK_AXIS, None, None, None),) * 2 if split
+                  else (spec3, spec3))
     # the kernel step reads the Gram tensor, the labels and the norms
     # chain-minor: a device's chains on the lanes
     lanes3, lanes2 = P(None, None, BLOCK_AXIS), P(None, BLOCK_AXIS)
@@ -875,6 +1174,9 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
             # the dw mode's operands
             n_dw = {"direct": 0, "sorted": 2, "presorted": 3}[dw_mode]
             in_specs += (spec2,) * (1 + n_dw)
+            if split:
+                # row0, n_tiles and the head block by device, its ids whole
+                in_specs += (spec2, spec2, spec2, P())
     jfit = jax.jit(shard_map(
         block_fit,
         mesh=mesh,
@@ -920,12 +1222,14 @@ _FIT_CACHE: "dict" = {}
 _FIT_CACHE_MAX = 8
 
 
-def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
+def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
+                head: int = 0):
     """One compiled program per (layout shapes, config-sans-iterations,
-    mesh): repeat fits and benchmark loops skip retracing; the round count
-    is a traced argument."""
+    mesh, head columns): repeat fits and benchmark loops skip retracing;
+    the round count is a traced argument."""
     key = (
         mesh,
+        head,
         problem.n_blocks,
         problem.rows_per_block,
         problem.val.shape,
@@ -944,7 +1248,7 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
     )
     fn = _FIT_CACHE.pop(key, None)
     if fn is None:
-        fn = _make_fit(problem, config, mesh)
+        fn = _make_fit(problem, config, mesh, head)
     _FIT_CACHE[key] = fn  # re-insert: dict order gives LRU eviction
     while len(_FIT_CACHE) > _FIT_CACHE_MAX:
         del _FIT_CACHE[next(iter(_FIT_CACHE))]
@@ -952,32 +1256,43 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh):
 
 
 def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
-                       gram_bytes: int, chains: int, dense: bool,
-                       sigma_prime: float, step_kernel: bool) -> None:
+                       gram_bytes: int, chains: int, dense_entries: int,
+                       sigma_prime: float, step_kernel: bool,
+                       head: int, head_nonzeros: int) -> None:
     """What the compiled round streams, for whoever reads the registry:
     row slots (pad rows and pad blocks included); the entries stored per
     slot, so that rows x row_width is every entry the round's gather and
     scatter-add touch (the scatter engine: the width every row is padded
-    to; the Gram engine: a mean over its buckets, their pad rows counted;
-    the dense layout: the feature count, every cell of X); the stored
-    entries that carry no value (dense: the pad rows' cells and the zeros
-    inside real rows); the number of length buckets (0 on the scatter
-    engine and the dense layout: one rectangle); the Gram tensor's bytes
-    (0 on the scatter engine); chains per device; the cells of X held dense
-    (0 on the sparse layouts), so that dense_entries over rows x row_width
-    says which layout served a fit; the σ' in force (1 in avg mode); the
-    chains a device whose SDCA steps the compiled round runs in the Pallas
-    kernel (all of them, or 0 on the XLA step: ``resolve_step``)."""
+    to; the Gram engine: a mean over its buckets, their pad rows counted,
+    or on the split layout over the tail's tiles that hold an entry; the
+    dense layout: the feature count, every cell of X); the stored entries
+    that carry no value (dense: the pad rows' cells and the zeros inside
+    real rows; split: of the tail's tiles); the number of length buckets
+    (0 on the scatter engine, the dense layout, one rectangle each, and the
+    split layout, whose tail is tiles); the Gram tensor's bytes (0 on the
+    scatter engine); chains per device; the cells held dense (all of X on
+    the dense layout, the head block's on the split one, 0 on the other
+    sparse layouts), so that dense_entries over rows x row_width says
+    whether the dense layout served a fit; the σ' in force (1 in avg mode);
+    the chains a device whose SDCA steps the compiled round runs in the
+    Pallas kernel (all of them, or 0 on the XLA step: ``resolve_step``);
+    the feature columns held dense beside a sparse tail (``head_width``; 0
+    where no head was taken), the non-zeros they hold, and all the
+    non-zeros of the data, so that head_nonzeros over nonzeros is the share
+    of the entries that left the gather and the scatter-add."""
     reg = obs_metrics.get_registry()
     reg.gauge("tpums_svm_rows").set(slots)
     reg.gauge("tpums_svm_row_width").set(stored / slots)
-    reg.gauge("tpums_svm_pad_entries").set(stored - nonzero)
+    reg.gauge("tpums_svm_pad_entries").set(stored - (nonzero - head_nonzeros))
     reg.gauge("tpums_svm_buckets").set(buckets)
     reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
     reg.gauge("tpums_svm_chains_per_device").set(chains)
     reg.gauge("tpums_svm_step_kernel_chains").set(chains if step_kernel else 0)
-    reg.gauge("tpums_svm_dense_entries").set(stored if dense else 0)
+    reg.gauge("tpums_svm_dense_entries").set(dense_entries)
     reg.gauge("tpums_svm_sigma_prime").set(sigma_prime)
+    reg.gauge("tpums_svm_head_columns").set(head)
+    reg.gauge("tpums_svm_head_nonzeros").set(head_nonzeros)
+    reg.gauge("tpums_svm_nonzeros").set(nonzero)
 
 
 def layout_report() -> str:
@@ -985,11 +1300,16 @@ def layout_report() -> str:
     last ``compile_svm_fit`` set: which row layout it chose, what the
     layout stores, the σ' in force, and the form of the SDCA steps."""
     reg = obs_metrics.get_registry()
-    rows, width, dense_cells, buckets, sigma, in_kernel = (
-        reg.gauge("tpums_svm_" + name).value for name in (
-            "rows", "row_width", "dense_entries", "buckets", "sigma_prime",
-            "step_kernel_chains"))
-    if dense_cells:
+    (rows, width, dense_cells, buckets, sigma, in_kernel, head, head_nnz,
+     nnz) = (reg.gauge("tpums_svm_" + name).value for name in (
+         "rows", "row_width", "dense_entries", "buckets", "sigma_prime",
+         "step_kernel_chains", "head_columns", "head_nonzeros", "nonzeros"))
+    if head:
+        layout = (f"sparse rows split by column ({int(head)} head columns "
+                  f"held dense with {100 * head_nnz / max(nnz, 1):.1f}% of "
+                  f"the non-zeros, tail {int(rows)} x {width:.2f} stored "
+                  "entries in tiles)")
+    elif dense_cells:
         layout = f"dense rows ({int(rows)} x {int(width)} cells, no ids)"
     else:
         layout = (f"sparse rows ({int(rows)} x {width:.2f} stored entries, "
@@ -1000,7 +1320,8 @@ def layout_report() -> str:
 
 
 def compile_svm_fit(
-    problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh
+    problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
+    head_columns: Optional[int] = None,
 ):
     """-> (fit_fn, dev_args): the compiled CoCoA program plus device-
     resident sharded inputs.  ``fit_fn(iterations, *dev_args)`` -> (w,
@@ -1017,10 +1338,34 @@ def compile_svm_fit(
     awaited: ``svm.gram_build`` (Gram engine only) and ``svm.place``, with
     the host's bucket copy under it as ``svm.bucket`` (the dense layout
     opens ``svm.place`` twice, for X before the Gram build and for the
-    small arrays after it, and has no bucket copy)."""
+    small arrays after it, and has no bucket copy).
+
+    The Gram engine's sparse layout is split by column where
+    ``head_width`` says so, from the columns' counts, the itemsize and the
+    device's reported memory (``head_columns`` names a width instead: for
+    tests): the hottest columns are one dense ``(Kp·rows, F)`` block at
+    [11], their feature ids at [12], built on the device from a compact
+    list of their entries (phase ``svm.head`` under ``svm.place``), and [1],
+    [2] hold only the other columns' entries, as tiles
+    (``_tail_tiles``; [8] their rows' slots, [9] each tile's first row,
+    [10] the tiles that hold an entry).  f32 state and the direct Δw only:
+    bf16 state and FLINK_MS_SVM_DW=sorted|presorted keep whole rows."""
     D = num_blocks(mesh)
     Kp = _round_up(problem.n_blocks, D)
     dtype = config.dtype
+    slots = Kp * problem.rows_per_block
+    head = 0
+    if (not problem.dense and _resolve_inner(problem, config, mesh) == "gram"
+            and _dw_choice() == "direct"
+            and jnp.dtype(dtype) == jnp.float32):
+        head = (head_width(problem.col_count / D, slots // D,
+                           jnp.dtype(dtype).itemsize,
+                           device_memory(mesh.devices.flat[0]))
+                if head_columns is None else head_columns)
+        if not 0 <= head <= problem.n_features:
+            raise ValueError(
+                f"head_columns={head}: the layout has "
+                f"{problem.n_features} feature columns")
     shard3 = block_sharding(mesh, rank=3)
     shard2 = block_sharding(mesh, rank=2)
     rep = NamedSharding(mesh, P())
@@ -1042,10 +1387,10 @@ def compile_svm_fit(
             made = _cached_fit(problem, config, mesh)
             jax.block_until_ready(val)
     else:
-        made = _cached_fit(problem, config, mesh)
+        made = _cached_fit(problem, config, mesh, head)
         idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
     fit, gram_fn, dw_mode, step_lanes = made
-    stored, buckets, extra = val.size, 0, []
+    stored, buckets, extra, head_nonzeros = val.size, 0, [], 0
     if gram_fn is not None:
         # of sparse rows the Gram build reads the padded rectangles once
         # and lets them go: the rounds hold the bucketed rows only
@@ -1069,7 +1414,22 @@ def compile_svm_fit(
     # the phases end when the device has what they made, so that a profile
     # shows the Gram build and the transfer, not their dispatch
     with tracing.phase("svm.place"):
-        if gram_fn is not None and not problem.dense:
+        if head:
+            row_len = _pad_blocks(problem.row_len, Kp).reshape(-1)
+            with tracing.phase("svm.bucket"):
+                tail_len = _tail_lengths(
+                    idx.reshape(slots, -1), row_len, problem.col_rank, head)
+                tail = _tail_tiles(idx, val, row_len, tail_len, D)
+            with tracing.phase("svm.head"):
+                block, head_nonzeros = _place_head(
+                    mesh, idx, val, row_len, tail_len, problem.col_rank,
+                    head, dtype)
+            idx, val, slot, row0, n_tiles = tail
+            stored = int(n_tiles.sum()) * _TILE_STEP * _TILE_ROWS
+            extra += [put(a, shard2) for a in (slot, row0, n_tiles)] + [
+                block, put(np.argsort(problem.col_rank)[:head], rep,
+                           jnp.int32)]
+        elif gram_fn is not None and not problem.dense:
             with tracing.phase("svm.bucket"):
                 plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
                 idx, val, slot = _bucket_rows(idx, val, plan)
@@ -1077,10 +1437,11 @@ def compile_svm_fit(
                 dw_operands = _sorted_dw_operands(
                     dw_mode, idx, val, slot, dtype)
             extra += [put(a, shard2) for a in (slot, *dw_operands)]
+        shard_rows = block_sharding(mesh, rank=4) if head else shard3
         dev_args = jax.block_until_ready([
             put(np.zeros((problem.n_features,)), rep, dtype),
-            idx if problem.dense else put(idx, shard3),
-            val if problem.dense else put(val, shard3, dtype),
+            idx if problem.dense else put(idx, shard_rows),
+            val if problem.dense else put(val, shard_rows, dtype),
             by_chain(problem.label),
             by_chain(problem.sq_norm),
             put(np.zeros((Kp, problem.rows_per_block)), shard2, dtype),
@@ -1088,11 +1449,48 @@ def compile_svm_fit(
             *extra,
         ])
     _set_layout_gauges(
-        Kp * problem.rows_per_block, stored,
-        int(np.count_nonzero(problem.val)), buckets,
-        extra[0].nbytes if extra else 0, Kp // D, problem.dense,
-        _combine_scales(config, problem.n_blocks)[1], bool(step_lanes))
+        slots, stored, int(np.count_nonzero(problem.val)), buckets,
+        extra[0].nbytes if extra else 0, Kp // D,
+        stored if problem.dense else slots * head,
+        _combine_scales(config, problem.n_blocks)[1], bool(step_lanes),
+        head, head_nonzeros)
     return fit, dev_args
+
+
+@functools.lru_cache(maxsize=_FIT_CACHE_MAX)
+def _head_scatter(mesh: Mesh, window: int):
+    """The program that adds one piece of (row, column, value) triples to
+    a window of a device's rows of the head block, the block donated and
+    updated in place."""
+    def scatter(block, first, rows, cols, vals):
+        part = jax.lax.dynamic_slice_in_dim(block, first[0, 0], window)
+        return jax.lax.dynamic_update_slice_in_dim(
+            block, part.at[rows[0], cols[0]].add(vals[0]), first[0, 0], 0)
+
+    by_rows = P(BLOCK_AXIS, None)
+    return jax.jit(shard_map(
+        scatter, mesh=mesh, in_specs=(by_rows,) * 5, out_specs=by_rows,
+        check_vma=False), donate_argnums=0)
+
+
+def _place_head(mesh: Mesh, idx, val, row_len, tail_len, col_rank,
+                head: int, dtype):
+    """-> (block, its non-zeros): the head's dense block on the devices,
+    (slots, head) split by rows as the dense layout's X is: zeroed there,
+    then the pieces of the compact entry list (``_head_entries``) scattered
+    one after the other into windows of ``_HEAD_WINDOW`` rows (of all a
+    device's rows on small data), by one program whatever their number.
+    The host never holds the block."""
+    D = num_blocks(mesh)
+    window = min(_HEAD_WINDOW, row_len.size // D)
+    entries = _head_entries(idx, val, row_len, tail_len, col_rank, D, window)
+    by_rows = block_sharding(mesh, rank=2)
+    block = jax.jit(lambda: jnp.zeros((row_len.size, head), dtype),
+                    out_shardings=by_rows)()
+    scatter = _head_scatter(mesh, window)
+    for piece in zip(*(a.transpose(1, 0, 2) for a in entries)):
+        block = scatter(block, *(jax.device_put(a, by_rows) for a in piece))
+    return block, int(np.count_nonzero(entries[3]))
 
 
 def _sorted_dw_operands(dw_mode, ids, val, slot, dtype):

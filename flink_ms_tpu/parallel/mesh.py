@@ -304,6 +304,16 @@ def row_bucket(n: int, n_shards: int, floor: int = 8) -> int:
     return n_shards * (1 << (per_shard - 1).bit_length())
 
 
+def device_memory(device) -> Optional[int]:
+    """One device's memory in bytes as the runtime reports it, None where
+    it reports none (the CPU backend; a device that is only described)."""
+    try:
+        stats = device.memory_stats()
+    except Exception:  # a described topology has no runtime to ask
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

@@ -88,7 +88,7 @@ def one_iteration(rng, monkeypatch, devices, env, memory=None, **config):
     for name, value in env.items():
         monkeypatch.setenv(name, value)
     if memory is not None:
-        monkeypatch.setattr(A, "_device_memory", lambda device: memory)
+        monkeypatch.setattr(A, "device_memory", lambda device: memory)
     cfg = A.ALSConfig(num_factors=k, iterations=1, lambda_=LAM, implicit=True,
                       alpha=ALPHA, exchange_dtype=None, **config)
     mesh = make_mesh(devices)
@@ -198,7 +198,7 @@ def test_an_unknown_route_value_raises(monkeypatch, value):
 
 def test_the_cpu_reports_no_memory_and_keeps_the_tensor():
     mesh = make_mesh(1)
-    assert A._device_memory(mesh.devices.flat[0]) is None
+    assert A.device_memory(mesh.devices.flat[0]) is None
 
 
 # -- scopes, gauges, the counter ------------------------------------------------

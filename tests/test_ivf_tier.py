@@ -600,7 +600,10 @@ def test_benchmark_gains_one_configuration_and_one_cell():
            "index_ann_train_s", "index_ann_assign_s", "index_ann_lists_s",
            "index_ann_recall_s"}
     assert new <= set(mine)
-    assert [m["name"] for m in bench["per_layer"]][-len(new):] == [
+    # appended in this order, one after the other (later PRs append after)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("ivf_probe_ms")
+    assert names[first:first + len(new)] == [
         "ivf_probe_ms", "ivf_scan_ms", "ivf_select_ms", "ivf_scan_roofline",
         "ivf_scanned_share", "ivf_recall_at_10", "index_ann_build_s",
         "index_ann_train_s", "index_ann_assign_s", "index_ann_lists_s",
