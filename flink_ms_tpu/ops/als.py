@@ -16,13 +16,15 @@ grouped by degree class (a geometric width ladder, default ratio 1.5 with
 rungs rounded to multiples of 8 — FLINK_MS_ALS_BUCKET_RATIO) and each
 group's rating lists are padded to the class width, so normal-equation
 assembly is a short list of dense batched contractions — a row gather,
-then MXU matmuls, no scatter.  On a TPU, in explicit mode with an f32
-exchange and a rank up to 64, each bucket's gathered rows are contracted
-where the gather left them by one Pallas kernel (``assemble_pallas.py``: A
-and b from a single read, the transpose done in VMEM, written batch-minor
+then MXU matmuls, no scatter.  On a TPU, with an f32 exchange and a rank up
+to 64, in either mode, each bucket's gathered rows are contracted where
+the gather left them by one Pallas kernel (``assemble_pallas.py``: A
+and b from a single read, the transpose and implicit mode's confidence
+weights done in VMEM, written batch-minor
 as the Pallas solver reads them, which adds λ·reg to the diagonal itself:
-between the two kernels A is joined along the lanes and nothing else);
-every other path (bf16 exchange, implicit mode, CPU) is the ``einsum``
+between the two kernels A is joined along the lanes and, in implicit
+mode, YᵀY added, and nothing else);
+every other path (bf16 exchange, three-pass products, CPU) is the ``einsum``
 pair, which XLA runs as a relayout copy, a convolution and a
 multiply-reduce — ``resolve_assembly`` decides per sweep.  (A
 scatter/``segment_sum`` formulation was measured 8-10x slower on v5e: TPU
@@ -36,7 +38,9 @@ Two training modes, each timed by a cell of the benchmark:
 - implicit feedback (confidence-weighted, Hu-Koren-Volinsky;
   ``msd-ials.ials-retrain``):
   A_u = YᵀY + Σ_{i∈Ωu} α·r_ui · y_i y_iᵀ + λ·I with YᵀY a ``psum`` of
-  per-shard Gramians (scope ``als.gram``), plain λ, on the einsum pair.
+  per-shard Gramians (scope ``als.gram``), plain λ; the weights α·r and
+  1 + α·r are applied inside the assembly kernel, or by XLA (scope
+  ``als.weight``) before the einsum pair.
 
 Each half-sweep either materialises its (per_block, k, k) normal equations
 and solves them in one batch, or solves every assembly chunk where it was
@@ -633,22 +637,50 @@ def _assembly_chunk_bytes() -> int:
     return int(os.environ.get(_ASSEMBLY_CHUNK_ENV, 2 << 30))
 
 
-def _chunk_rows(r, w, k, y_itemsize, itemsize, implicit,
+_LANES = 128  # one lane tile: what a minor dimension occupies in HBM
+
+
+def _chunk_rows(r, w, k, y_itemsize, itemsize, how, implicit,
                 per_chunk) -> Optional[int]:
     """Rows of an (r, w) bucket one assembly step takes, None where the
     whole bucket runs straight-line.  The peak transient of a step is the
-    gather itself (at the EXCHANGE dtype's width), plus the same-size
-    solve-dtype yw intermediate in implicit mode (budgeted whether or not
-    XLA fuses it into the contraction's operand), plus, where the step also
-    solves (``per_chunk``), its (C, k, k) system and the factorization's
-    intermediates; a bucket above ``_assembly_chunk_bytes()`` is cut into
+    gather itself (at the EXCHANGE dtype's width), counted as what is live
+    on the path ``how`` (``resolve_assembly``'s answer) takes:
+
+    - "einsum": the values' bytes, plus in implicit mode the same-size
+      solve-dtype yw intermediate (budgeted whether or not XLA fuses it
+      into the contraction's operand);
+    - "kernel": the rows as the take leaves them in HBM for the kernel to
+      read, k values in whole lane tiles of 128, and no weighted copy (the
+      kernel weights in VMEM).  At msd-ials' rank 64 that is the einsum
+      pair's count to the byte, so nine of its eleven item buckets (1.09 GB
+      of values, 2.17 GB in lane tiles) stay two steps.  Counted at their
+      values' bytes they ran straight-line and lost: the takes of
+      (2524, 1680) and (8743, 496) read 9.84 and 9.86 ns a row where each
+      one's two halves read 3.95 and 3.97 (41.7 and 42.8 ms against 16.8
+      and 17.2, +51 ms an iteration; the seven others read the same whole
+      as halved; chip run, PERF.md section 6, PR 42).
+      als-ml20m's largest, 658 MB of values at rank 50, is 1.68 GB.
+
+    Where the step also solves (``per_chunk``) its (C, k, k) system and the
+    factorization's intermediates are added.  A bucket above
+    ``_assembly_chunk_bytes()`` is cut into
     the fewest steps that fit it, of equal size: steps of the largest size
     that fits left the last one nearly empty wherever a bucket came out
     just above a multiple of the limit, and msd-ials' item ladder does in
     nine buckets of eleven (two steps for 1.01-1.05 of one step's rows, the
     pad rows all gathering slot 0 and contracted like the others: 1.7538
-    against 0.7200 s/iter, PERF.md section 6, PR 33)."""
-    row_bytes = w * k * (y_itemsize + (itemsize if implicit else 0))
+    against 0.7200 s/iter, PERF.md section 6, PR 33).  The steps stay
+    ragged: cut in whole lane tiles of rows (which would spare the solver's
+    pad of a ragged batch) they compiled to a program whose item table no
+    longer stays in fast memory, and the user half's takes went from 1.33
+    ns a row to 3.96 and 9.9 (0.847 against 0.569 s/iter; chip run, PERF.md
+    section 6, PR 42).  What the solver needs in whole tiles is padded
+    after the contraction (``_bucket_normal_eqs``), not gathered."""
+    if how == "kernel":
+        row_bytes = w * -(-k // _LANES) * _LANES * y_itemsize
+    else:
+        row_bytes = w * k * (y_itemsize + (itemsize if implicit else 0))
     if per_chunk:
         row_bytes += 3 * k * k * itemsize
     limit = _assembly_chunk_bytes()
@@ -680,19 +712,29 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     batch-major layout) or straight-line (lane-major compiles and is ~9%
     faster).  ``extra`` is an optional (rows, ...) operand sliced
     alongside idx/val (the per-slot counts).
-    ``lanes`` (kernel path only): return At (k, k, n), bt (k, n), the batch
+    ``lanes`` (kernel path only: the Pallas solver takes the result).
+    Without ``post``: return At (k, k, n), bt (k, n), the batch
     on the lanes and zero-padded to whole lane tiles as the Pallas solver
     reads it, instead of A (r, k, k), b (r, k).  A straight-line bucket's
     kernel writes it itself; inside the lax.map chunks the batch-major
     kernel stays (no lane-major operand may be laid out there, see
     ``_chol_solve``) and the bucket is transposed once after the map.
+    With ``post``: each step's A and b are zero-padded to whole lane tiles
+    of entities before ``post`` (the rows past the step's are systems of
+    count 0 whose x is dropped after it).  ``cholesky_solve_batched`` pads
+    a ragged batch by a copy of all of it; behind XLA's convolution that
+    copy was the only pass between the contraction and the solver, behind
+    a kernel it came after the pass that adds YᵀY and λ·reg (18.7 GB read
+    and written once more over msd-ials' user half: ``als_solve_s`` 0.2068
+    against 0.1519), and a pad here is fused into that pass (chip run,
+    PERF.md section 6, PR 42).  The step's rows, and so its gather, stay
+    as ``_chunk_rows`` cut them.
     Chunking is over the batch row axis only (the contraction axis w is
     untouched), so chunked and unchunked results are arithmetically
     identical per row."""
     r, w = idx.shape
     k = y_all.shape[1]
-    how = resolve_assembly(platform, y_all.dtype, dtype, implicit, k,
-                           precision)
+    how = resolve_assembly(platform, y_all.dtype, dtype, k, precision)
 
     def compute(idx_c, val_c, extra_c, in_scan=False):
         # the two scopes split als.assemble in a profile: the gather is
@@ -710,15 +752,18 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
             if how == "kernel":
                 from . import assemble_pallas
 
-                assemble = (assemble_pallas.assemble_bucket_lanes
-                            if lanes and not in_scan
-                            else assemble_pallas.assemble_bucket)
-                A, b = assemble(y, val_c, precision=precision,
-                                interpret=platform != "tpu")
+                # implicit mode: the confidence weights are applied to the
+                # gathered rows in VMEM (no als.weight operation is left)
+                kw = dict(precision=precision, interpret=platform != "tpu",
+                          alpha=alpha if implicit else None)
+                if lanes and post is None and not in_scan:
+                    A, b = assemble_pallas.assemble_bucket_lanes(y, val_c, **kw)
+                else:
+                    A, b = assemble_pallas.assemble_bucket(y, val_c, **kw)
             else:
                 if implicit:
                     # the confidence weights and the weighted copy of y:
-                    # what implicit mode adds to a bucket's contraction
+                    # what implicit mode adds to the einsum pair
                     with jax.named_scope("als.weight"):
                         wgt = (alpha * val_c).astype(dtype)  # pads: val 0 -> 0
                         t = (1.0 + alpha * val_c).astype(dtype)  # pads: y is 0
@@ -734,10 +779,19 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
                                preferred_element_type=dtype)
         if post is None:
             return A, b
-        return post(A, b, extra_c, in_scan=in_scan)
+        rows = A.shape[0]
+        n = -(-rows // _LANES) * _LANES
+        if not lanes or n == rows:
+            return post(A, b, extra_c, in_scan=in_scan)
+        # whole lane tiles for the Pallas solver: XLA fuses this pad into
+        # the pass that adds YᵀY and λ·reg (the docstring's ``lanes``)
+        def pad(a):
+            return jnp.pad(a, ((0, n - rows),) + ((0, 0),) * (a.ndim - 1))
+
+        return post(pad(A), pad(b), pad(extra_c), in_scan=in_scan)[:rows]
 
     C = _chunk_rows(r, w, k, y_all.dtype.itemsize, np.dtype(dtype).itemsize,
-                    implicit, post is not None)
+                    how, implicit, post is not None)
     if C is None:
         return compute(idx, val, extra)
     # chunked: reshape to (n_chunks, C, ...) slabs and lax.map WITHOUT
@@ -775,7 +829,7 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
         lambda t: t.reshape((r_pad,) + t.shape[2:])[:r],
         jax.lax.map(one_chunk, operands),
     )
-    if lanes:
+    if lanes and post is None:
         from .assemble_pallas import to_lanes
 
         return to_lanes(*out)
@@ -827,8 +881,8 @@ _FUSED_ENV = "FLINK_MS_ALS_FUSED"
 
 # The materialised route holds a side's (per_block, k, k) normal equations
 # twice (A, then A + λ·reg in the solver's padded layout) beside one
-# assembly chunk's transients (up to two of FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES
-# in implicit mode), the ratings and both factor tables.  Up to a quarter
+# assembly chunk's transients (FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES as
+# _chunk_rows counts them), the ratings and both factor tables.  Up to a quarter
 # of the device's memory the pair is half the chip at most and the rest has
 # room; above it the sweep solves per chunk.  The two readings on either
 # side of the constant (TPU v5e, 15.75 GiB): 8.2% of the memory
@@ -911,28 +965,39 @@ def resolve_exchange(exchange_dtype: Optional[str],
     return exchange_dtype
 
 
-def resolve_assembly(platform: Optional[str], y_dtype, dtype, implicit: bool,
-                     k: int, precision: str = "highest") -> str:
+def resolve_assembly(platform: Optional[str], y_dtype, dtype, k: int,
+                     precision: str = "highest") -> str:
     """How a sweep's buckets contract their gathered rows: "kernel"
     (``assemble_pallas.assemble_bucket``: A and b from one read of y, no
     relayout copy) or "einsum" (the pair XLA schedules itself).  The kernel
-    engages where a chip run priced it — a TPU, explicit mode, an f32
+    engages where a chip run priced it — a TPU, an f32
     exchange and solve, full-f32 or one-pass products, rank up to 64 (the
-    cell's is 50; the widest tiles compile and agree with float64 on the
-    chip up to k = 128, but the MXU work grows with k squared where the
-    bytes do not, and nothing has timed that against the einsum pair:
+    cells' are 50 and 64; the widest tiles compile and agree with float64
+    on the chip up to k = 128, but the MXU work grows with k squared where
+    the bytes do not, and nothing has timed that against the einsum pair:
     PERF.md section 6, PR 26) — and everything else keeps the einsum pair
     unchanged: the bf16 exchange (``als_train``'s default on a TPU),
-    implicit mode's weighted operand, three-pass products and every CPU
-    fit.  It holds no width: on the chip the kernel is ahead at every
-    width of the ML-20M ladder, w = 24 included."""
-    if platform != "tpu" or implicit:
+    three-pass products and every CPU fit.  The mode does not enter:
+    implicit feedback's weights are one multiply in the kernel's VMEM
+    (PERF.md section 6, PR 42).  It holds no width: on the chip the kernel
+    is ahead at every width of the ML-20M ladder, w = 24 included."""
+    if platform != "tpu":
         return "einsum"
     if jnp.dtype(y_dtype) != jnp.float32 or jnp.dtype(dtype) != jnp.float32:
         return "einsum"
     if precision == "high":
         return "einsum"  # Mosaic's matmul has no three-pass mode
     return "kernel" if k <= 64 else "einsum"
+
+
+def _exchange_and_assembly(config: "ALSConfig", platform: Optional[str]):
+    """-> (the exchange dtype or None for the solve dtype's, "kernel" |
+    "einsum") of a fit of ``config`` on ``platform``."""
+    resolved = resolve_exchange(config.exchange_dtype, platform)
+    exchange = jnp.dtype(resolved) if resolved else None
+    return exchange, resolve_assembly(
+        platform, exchange or config.dtype, config.dtype, config.num_factors,
+        config.assembly_precision)
 
 
 def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
@@ -942,15 +1007,15 @@ def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
     device, how many buckets the kernel takes and their share of the padded
     ratings, and how many of them hand A to the solver lane-major from the
     kernel itself (with ``lanes`` on the materialised route, every bucket
-    whose gather fits one chunk; the others are transposed after their
-    lax.map) and their share of the entities."""
+    ``_chunk_rows`` leaves straight-line; the others are transposed after
+    their lax.map) and their share of the entities."""
     parts = []
     for name, side in (("u", problem.u), ("i", problem.i)):
         padded = sum(w * r for w, r in zip(side.widths, side.rows))
         on = len(side.widths) if how == "kernel" else 0
         direct = [r for w, r in zip(side.widths, side.rows)
                   if lanes and not per_chunk[name]
-                  and r * w * k * 4 <= _assembly_chunk_bytes()]
+                  and _chunk_rows(r, w, k, 4, 4, how, False, False) is None]
         parts.append(f"{name}-sweep solve "
                      f"{'per chunk' if per_chunk[name] else 'materialised'} "
                      f"({side.per_block * k * k * 4 / 1e9:.2f} GB of normal "
@@ -1057,12 +1122,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     platform = mesh.devices.flat[0].platform
     plan = _exchange_plan(problem, num_blocks(mesh))
 
-    resolved_exchange = resolve_exchange(config.exchange_dtype, platform)
-    exchange_dtype = (
-        jnp.dtype(resolved_exchange) if resolved_exchange else None
-    )
-    how = resolve_assembly(platform, exchange_dtype or dtype, dtype, implicit,
-                           k, config.assembly_precision)
+    exchange_dtype, how = _exchange_and_assembly(config, platform)
     # the kernel path hands A to the Pallas solver in the solver's own
     # layout (the per-chunk route below solves batch-major)
     lanes = how == "kernel" and resolve_solver(platform) == "pallas"
@@ -1140,7 +1200,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                         y_all, idx_b, val_b, implicit, alpha, dtype,
                         config.assembly_precision,
                         post=solve_chunk, extra=counts[0][off:off + rows_j],
-                        platform=platform,
+                        platform=platform, lanes=lanes,
                     ))
                 off += rows_j
             xs.append(jnp.zeros((_PAD_STRIP, k), dtype))
@@ -1153,6 +1213,11 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             )
         with jax.named_scope("als.solve"):
             if lanes:
+                if implicit:
+                    # pad lanes become YᵀY + I: still positive definite,
+                    # and their x is discarded as before
+                    with jax.named_scope("als.gram"):
+                        A = A + yty[:, :, None]
                 x = _solve_factors_lanes(
                     A, b, counts[0], [idx_b.shape[0] for idx_b, _ in buckets],
                     lam, weighted, platform)
@@ -1454,17 +1519,17 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     spread over, one strip a block (``pad_slots``)."""
     D, k = num_blocks(mesh), config.num_factors
     itemsize = np.dtype(config.dtype).itemsize
-    exchange = resolve_exchange(config.exchange_dtype,
-                                mesh.devices.flat[0].platform)
-    y_itemsize = np.dtype(exchange).itemsize if exchange else itemsize
+    exchange, how = _exchange_and_assembly(config,
+                                           mesh.devices.flat[0].platform)
+    y_itemsize = exchange.itemsize if exchange else itemsize
     per_chunk = _routes(problem, config, mesh)
     rows = fused_rows = chunks = entries = 0
     for name, side in (("u", problem.u), ("i", problem.i)):
         rows += D * side.per_block
         fused_rows += D * side.per_block * per_chunk[name]
         for w, r in zip(side.widths, side.rows):
-            C = _chunk_rows(r, w, k, y_itemsize, itemsize, config.implicit,
-                            per_chunk[name])
+            C = _chunk_rows(r, w, k, y_itemsize, itemsize, how,
+                            config.implicit, per_chunk[name])
             chunks += D * (1 if C is None else -(-r // C))
             entries += D * w * r
     reg = obs_metrics.get_registry()

@@ -26,6 +26,26 @@ left it:
 - pad entries stay exact zeros through ``y`` itself; only the ragged last
   w tile is masked (an out-of-bounds block read is not zeros).
 
+The weighted form (``alpha``: implicit feedback, Hu-Koren-Volinsky over
+play counts t) is the same read and the same contraction.  After the
+transpose the rating axis lies on the lanes, where ``t8 (8, Wt)`` already
+lies, so the confidence weights are one multiply there: rows 0..k-1 become
+``y8ᵀ · (α t)[:, None, :]`` and row k ``1 + α t``, and the product yields
+``A = Σ α t · y yᵀ`` and ``b = Σ (1 + α t) · y`` (``YᵀY`` and ``λI`` are the
+caller's).  A pad or masked entry has t = 0: weight 0, and the 1 it leaves
+in row k meets a zero row of y.  ``alpha`` is a static of the kernel body:
+without it the body is the explicit one, not a multiply by ones.  XLA's
+einsum pair needs a weighted copy ``yw`` of the whole gathered tensor for
+this (fused into the convolution's operand on the chip, but behind the same
+relayout copy); here nothing of that size is written.  On the chip, at
+msd-ials' rank 64 and batch-major (a ``(64, 64)`` A is 32 KB of HBM an
+entity, written beside 512 B read a rating): 1.01-1.20x the time of its
+bytes at 819 GB/s from w = 216 up, both halves, 1.23-1.33x at w = 64 to 144,
+1.52x at w = 40 and 1.79x at w = 24, where six MXU passes of
+``(65, w) x (w, 64)`` an entity bound it; 31.2 + 70.0 ms an iteration over
+41.57M + 41.53M padded ratings against the einsum pair's 47.9 + 108.2 and
+its 91.0 ms of relayout copies (PERF.md section 5, PR 42).
+
 Two output layouts, one contraction.  ``assemble_bucket`` writes A
 ``(r, k, k)``, b ``(r, k)``: batch-major, each 50x50 tile padded to 56x128
 in HBM (28.7 KB an entity where 10 KB are data), which the Pallas solver
@@ -130,10 +150,13 @@ def lane_tile_sizes(w: int, k: int):
     return cs, w
 
 
-def _contract_groups(y_ref, t_ref, j, put, *, w: int, k: int, one_pass: bool):
+def _contract_groups(y_ref, t_ref, j, put, *, w: int, k: int, one_pass: bool,
+                     alpha):
     """One grid step's contraction of y (C, Wt, k), t (C, Wt), w tile j: for
     each sublane tile g of 8 entities, ``put(g, res)`` receives
-    ``res (8, k+1, k)``, A in rows 0..k-1 and b in row k."""
+    ``res (8, k+1, k)``, A in rows 0..k-1 and b in row k.  ``alpha`` None is
+    the explicit form; a number weights the transposed rows by ``alpha * t``
+    and makes row k ``1 + alpha * t`` (the module's header)."""
     C, wt, _ = y_ref.shape
     ragged = w % wt != 0
     if ragged:
@@ -151,8 +174,15 @@ def _contract_groups(y_ref, t_ref, j, put, *, w: int, k: int, one_pass: bool):
         if ragged:
             y8 = jnp.where(y_keep, y8, 0.0)
             t8 = jnp.where(t_keep, t8, 0.0)
-        lhs = jnp.concatenate(
-            [jnp.swapaxes(y8, 1, 2), t8[:, None, :]], axis=1)   # (8, k+1, Wt)
+        yt = jnp.swapaxes(y8, 1, 2)                         # (8, k, Wt)
+        if alpha is not None:
+            # the rating axis lies on the lanes of both: one multiply, each
+            # entity's confidence row broadcast over the k sublanes.  A pad
+            # or masked entry has t 0: weight 0, and its 1 in row k meets a
+            # zero row of y8
+            c8 = alpha * t8
+            yt, t8 = yt * c8[:, None, :], 1.0 + c8
+        lhs = jnp.concatenate([yt, t8[:, None, :]], axis=1)   # (8, k+1, Wt)
         if one_pass:
             lhs, y8 = lhs.astype(jnp.bfloat16), y8.astype(jnp.bfloat16)
         put(g, jax.lax.dot_general(
@@ -164,7 +194,7 @@ def _contract_groups(y_ref, t_ref, j, put, *, w: int, k: int, one_pass: bool):
 
 
 def _assemble_kernel(y_ref, t_ref, a_ref, b_ref, *, w: int, k: int,
-                     one_pass: bool):
+                     one_pass: bool, alpha):
     """One grid step: y (C, Wt, k), t (C, Wt) -> A (C, k, k), b (C, k)."""
     tiled = y_ref.shape[1] < w
     j = pl.program_id(1)
@@ -180,11 +210,12 @@ def _assemble_kernel(y_ref, t_ref, a_ref, b_ref, *, w: int, k: int,
         a_ref[rows] = a_ref[rows] + res[:, :k] if tiled else res[:, :k]
         b_ref[rows] = b_ref[rows] + res[:, k] if tiled else res[:, k]
 
-    _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass)
+    _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass,
+                     alpha=alpha)
 
 
 def _assemble_kernel_lanes(y_ref, t_ref, a_ref, b_ref, acc_ref, *, r: int,
-                           w: int, k: int, one_pass: bool):
+                           w: int, k: int, one_pass: bool, alpha):
     """Grid step (i, s, j): entity sub-block s of lane tile i, w tile j.
     y (Cs, Wt, k), t (Cs, Wt) land in rows s*Cs.. of the resident
     ``acc (128, k+1, k)``; the lane tile's last step transposes it in VMEM
@@ -209,7 +240,8 @@ def _assemble_kernel_lanes(y_ref, t_ref, a_ref, b_ref, acc_ref, *, r: int,
             rows = pl.ds(pl.multiple_of(base + g * _GROUP, _GROUP), _GROUP)
             acc_ref[rows] = acc_ref[rows] + res if tiled else res
 
-        _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass)
+        _contract_groups(y_ref, t_ref, j, put, w=w, k=k, one_pass=one_pass,
+                         alpha=alpha)
 
     @pl.when((s == pl.num_programs(1) - 1) & (j == pl.num_programs(2) - 1))
     def _():
@@ -226,14 +258,16 @@ def _one_pass(precision: str) -> bool:
     return precision == "default"
 
 
-def assemble_bucket(y, t, *, precision: str, interpret: bool):
+def assemble_bucket(y, t, *, precision: str, interpret: bool, alpha=None):
     """A = einsum("rwk,rwl->rkl", y, y), b = einsum("rwk,rw->rk", y, t) from
     one read of y.  y (r, w, k) float32 with w a multiple of 8, t (r, w);
-    ``precision`` "highest" or "default"."""
+    ``precision`` "highest" or "default".  With ``alpha`` (a Python number,
+    implicit feedback over play counts t): A = einsum("rw,rwk,rwl->rkl",
+    alpha * t, y, y), b = einsum("rwk,rw->rk", y, 1 + alpha * t)."""
     r, w, k = y.shape
     c, wt = tile_sizes(w, k)
     kernel = functools.partial(_assemble_kernel, w=w, k=k,
-                               one_pass=_one_pass(precision))
+                               one_pass=_one_pass(precision), alpha=alpha)
     return pl.pallas_call(
         kernel,
         grid=(pl.cdiv(r, c), pl.cdiv(w, wt)),
@@ -255,10 +289,12 @@ def assemble_bucket(y, t, *, precision: str, interpret: bool):
     )(y, t.astype(jnp.float32))
 
 
-def assemble_bucket_lanes(y, t, *, precision: str, interpret: bool):
-    """The same sums with the batch on the lanes, as the Pallas solver reads
-    them: At (k, k, n), bt (k, n) with n = r rounded up to 128 and the lanes
-    past r exact zeros."""
+def assemble_bucket_lanes(y, t, *, precision: str, interpret: bool,
+                          alpha=None):
+    """The same sums (``assemble_bucket``'s, ``alpha`` included) with the
+    batch on the lanes, as the Pallas solver reads them: At (k, k, n),
+    bt (k, n) with n = r rounded up to 128 and the lanes past r exact
+    zeros."""
     r, w, k = y.shape
     cs, wt = lane_tile_sizes(w, k)
     n_sub = min(LANES // cs, pl.cdiv(r, cs))
@@ -271,7 +307,7 @@ def assemble_bucket_lanes(y, t, *, precision: str, interpret: bool):
 
     n = _round_up(r, LANES)
     kernel = functools.partial(_assemble_kernel_lanes, r=r, w=w, k=k,
-                               one_pass=_one_pass(precision))
+                               one_pass=_one_pass(precision), alpha=alpha)
     return pl.pallas_call(
         kernel,
         grid=(n // LANES, n_sub, n_w),

@@ -143,16 +143,16 @@ def _one_slot_layout(problem):
         routing={})
 
 
-def _kernel_where_explicit(platform, y_dtype, dtype, implicit, k,
-                           precision="highest"):
-    return "einsum" if implicit else "kernel"
+def _kernel(platform, y_dtype, dtype, k, precision="highest"):
+    return "kernel"
 
 
 @pytest.mark.parametrize("blocks, exchange", [
     (1, "auto"), (4, "gather"), (4, "routed")])
 @pytest.mark.parametrize("route", ["materialised", "per chunk"])
 @pytest.mark.parametrize("mode, assembly", [
-    ("explicit", "einsum"), ("explicit", "kernel"), ("implicit", "einsum")])
+    ("explicit", "einsum"), ("explicit", "kernel"), ("implicit", "einsum"),
+    ("implicit", "kernel")])
 def test_the_strip_stays_zero_and_moves_no_factor(rng, monkeypatch, mode,
                                                   assembly, route, blocks,
                                                   exchange):
@@ -170,7 +170,7 @@ def test_the_strip_stays_zero_and_moves_no_factor(rng, monkeypatch, mode,
     if assembly == "kernel":
         # the chip's kernel interpreted, handing A to the Pallas solver
         monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
-        monkeypatch.setattr(A, "resolve_assembly", _kernel_where_explicit)
+        monkeypatch.setattr(A, "resolve_assembly", _kernel)
     A._SWEEP_CACHE.clear()   # the resolver is not in the sweep's cache key
     P, k = A._PAD_STRIP, 4
     u = np.repeat(np.arange(90), rng.integers(1, 40, 90))

@@ -3,10 +3,14 @@ interpreter mode, in both output layouts (batch-major, and lane-major as
 the Pallas solver reads it), the precision it is asked for, end-to-end ALS
 parity with the resolver patched onto the kernel (unfused, fused, chunked,
 and the lane-major hand-off to the solver), which buckets take that
-hand-off, and the resolver's answers — the bf16-exchange, implicit and CPU
-paths must keep the einsum pair and trace no new kernel.  The TPU
+hand-off, and the resolver's answers — the bf16-exchange and CPU paths must keep
+the einsum pair and trace no new kernel, in either mode; implicit mode's
+weighted contraction (A = Σ αr·y yᵀ, b = Σ (1 + αr)·y) runs beside the
+explicit one in every numerical case.  The TPU
 cross-lowering cases are beside the Cholesky kernel's in
 ``test_cholesky_pallas.py``."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,23 +36,41 @@ def _bucket(rng, r, w, k):
     return y, t
 
 
-def _einsum64(y, t):
+def _einsum64(y, t, alpha=None):
+    """Explicit: A = Σ y yᵀ, b = Σ t·y.  Implicit (``alpha``, t the play
+    counts): A = Σ αt·y yᵀ, b = Σ (1 + αt)·y."""
     y, t = y.astype(np.float64), t.astype(np.float64)
-    return (np.einsum("rwk,rwl->rkl", y, y), np.einsum("rwk,rw->rk", y, t))
+    if alpha is None:
+        return (np.einsum("rwk,rwl->rkl", y, y), np.einsum("rwk,rw->rk", y, t))
+    return (np.einsum("rw,rwk,rwl->rkl", alpha * t, y, y),
+            np.einsum("rwk,rw->rk", y, 1.0 + alpha * t))
 
 
+def _dropped_weight_shows(y, t, alpha, want_a, want_b):
+    """The ratings vary (0.5 .. 5), so sums that took every weight as one
+    constant, or b's as α·t, are far from the weighted ones."""
+    flat_a, _ = _einsum64(y, np.full_like(t, t.mean()), alpha)
+    _, no_one = _einsum64(y, alpha * t)
+    return (np.abs(flat_a - want_a).max() > 1e-2 * np.abs(want_a).max()
+            and np.abs(no_one - want_b).max() > 1e-3 * np.abs(want_b).max())
+
+
+@pytest.mark.parametrize("alpha", [None, 40.0])
 @pytest.mark.parametrize("k", [10, 50, 64])
 @pytest.mark.parametrize("w", sorted(_ROWS))
-def test_kernel_matches_float64_einsum(rng, w, k):
+def test_kernel_matches_float64_einsum(rng, w, k, alpha):
     """w = 1032 is one full tile of 1024 and a ragged tile of 8: the rows
-    the second block reads past the array must not reach A or b."""
+    the second block reads past the array must not reach A or b (under
+    ``alpha`` their row k would be 1, not 0, were y not masked)."""
     r = _ROWS[w]
     c, wt = tile_sizes(w, k)
     assert r % c and (w <= wt or w % wt)
     y, t = _bucket(rng, r, w, k)
     got_a, got_b = assemble_bucket(
-        jnp.asarray(y), jnp.asarray(t), precision="highest", interpret=True)
-    want_a, want_b = _einsum64(y, t)
+        jnp.asarray(y), jnp.asarray(t), precision="highest", interpret=True,
+        alpha=alpha)
+    want_a, want_b = _einsum64(y, t, alpha)
+    assert alpha is None or _dropped_weight_shows(y, t, alpha, want_a, want_b)
     np.testing.assert_allclose(got_a, want_a, rtol=1e-5,
                                atol=1e-6 * np.abs(want_a).max())
     np.testing.assert_allclose(got_b, want_b, rtol=1e-5,
@@ -56,10 +78,11 @@ def test_kernel_matches_float64_einsum(rng, w, k):
     assert not np.asarray(got_a)[1].any() and not np.asarray(got_b)[1].any()
 
 
+@pytest.mark.parametrize("alpha", [None, 40.0])
 @pytest.mark.parametrize("k", [10, 50, 64])
 @pytest.mark.parametrize("w", [24, 144, 1032])
 @pytest.mark.parametrize("r", [5, 130, 257])
-def test_lane_major_kernel_matches_float64_einsum(rng, r, w, k):
+def test_lane_major_kernel_matches_float64_einsum(rng, r, w, k, alpha):
     """The same sums with the batch on the lanes: r under one lane tile,
     ragged over two, and over three; w = 24 whole in one sub-block of 128
     entities, 144 in sub-blocks of 64 (at r = 5 the second one lies wholly
@@ -70,10 +93,12 @@ def test_lane_major_kernel_matches_float64_einsum(rng, r, w, k):
     assert 128 % cs == 0 and (w <= wt or w % wt)
     y, t = _bucket(rng, r, w, k)
     got_a, got_b = (np.asarray(x) for x in assemble_bucket_lanes(
-        jnp.asarray(y), jnp.asarray(t), precision="highest", interpret=True))
+        jnp.asarray(y), jnp.asarray(t), precision="highest", interpret=True,
+        alpha=alpha))
     n = -(-r // 128) * 128
     assert got_a.shape == (k, k, n) and got_b.shape == (k, n)
-    want_a, want_b = _einsum64(y, t)
+    want_a, want_b = _einsum64(y, t, alpha)
+    assert alpha is None or _dropped_weight_shows(y, t, alpha, want_a, want_b)
     np.testing.assert_allclose(got_a[:, :, :r].transpose(2, 0, 1), want_a,
                                rtol=1e-5, atol=1e-6 * np.abs(want_a).max())
     np.testing.assert_allclose(got_b[:, :r].T, want_b, rtol=1e-5,
@@ -82,13 +107,14 @@ def test_lane_major_kernel_matches_float64_einsum(rng, r, w, k):
     assert not got_a[:, :, 1].any() and not got_b[:, 1].any()
 
 
-def test_lane_major_kernel_sums_what_the_batch_major_one_sums(rng):
+@pytest.mark.parametrize("alpha", [None, 40.0])
+def test_lane_major_kernel_sums_what_the_batch_major_one_sums(rng, alpha):
     """Same contraction per entity, same order over the w tiles: the two
     layouts hold the same floats."""
     y, t = _bucket(rng, 21, 1032, 50)
-    a, b = assemble_bucket(jnp.asarray(y), jnp.asarray(t),
+    a, b = assemble_bucket(jnp.asarray(y), jnp.asarray(t), alpha=alpha,
                            precision="highest", interpret=True)
-    at, bt = assemble_bucket_lanes(jnp.asarray(y), jnp.asarray(t),
+    at, bt = assemble_bucket_lanes(jnp.asarray(y), jnp.asarray(t), alpha=alpha,
                                    precision="highest", interpret=True)
     np.testing.assert_array_equal(np.asarray(at)[:, :, :21].transpose(2, 0, 1), a)
     np.testing.assert_array_equal(np.asarray(bt)[:, :21].T, b)
@@ -137,14 +163,19 @@ def _ladder_problem():
 
 
 @pytest.mark.parametrize("limit,want", [
-    # the cell: the largest gather is 658 MB of the 2 GiB chunk, so all 33
-    # buckets are written lane-major by the kernel itself
+    # the cell: the largest gather is 658 MB of values, 1.68 GB as the take
+    # leaves it (50 values in a lane tile of 128), of the 2 GiB chunk, so
+    # all 33 buckets are written lane-major by the kernel itself
     (None, ("on 16 (100.0% of 138493 entities)",
             "on 17 (100.0% of 26744 entities)")),
-    # a 512 MB chunk: the user side's four largest gathers (w = 216..744)
-    # run in lax.map chunks, batch-major, and are transposed after
-    (512 << 20, ("on 12 (75.8% of 138493 entities)",
-                 "on 17 (100.0% of 26744 entities)")),
+    # a 1311 MB chunk (512 MB of values): the user side's four largest
+    # gathers (w = 216..744) run in lax.map chunks, batch-major, and are
+    # transposed after
+    (1311 << 20, ("on 12 (75.8% of 138493 entities)",
+                  "on 17 (100.0% of 26744 entities)")),
+    # 512 MB of lane tiles: most of both sides
+    (512 << 20, ("on 6 (34.2% of 138493 entities)",
+                 "on 2 (20.5% of 26744 entities)")),
 ])
 def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     """The rule holds no width and no row count: a bucket's kernel writes
@@ -171,24 +202,56 @@ def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     assert "solve materialised" in i and "hand-off " + want[1] in i
 
 
+@pytest.mark.parametrize("how,implicit,per_chunk,r,w,k,want", [
+    # msd-ials' item buckets, 1.09 GB of values and 2.17 GB in lane tiles:
+    # two steps on the kernel as on the einsum pair (whose second gigabyte
+    # is the weighted copy), straight-line only where neither is counted
+    ("kernel", True, False, 2524, 1680, 64, 1262),
+    ("einsum", True, False, 2524, 1680, 64, 1262),
+    ("einsum", False, False, 2524, 1680, 64, None),
+    # its narrowest user bucket solves per chunk: six equal steps, the
+    # parent's, whichever path assembles
+    ("kernel", True, True, 180614, 24, 64, 30103),
+    ("einsum", True, True, 180614, 24, 64, 30103),
+    # als-ml20m's largest gather stays whole: 1.68 GB at rank 50
+    ("kernel", False, False, 10033, 328, 50, None),
+])
+def test_a_step_is_budgeted_by_what_its_path_keeps_live(monkeypatch, how,
+                                                        implicit, per_chunk,
+                                                        r, w, k, want):
+    monkeypatch.delenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", raising=False)
+    assert A._chunk_rows(r, w, k, 4, 4, how, implicit, per_chunk) == want
+
+
+@pytest.mark.parametrize("alpha", [None, 40.0])
 @pytest.mark.parametrize("w", [24, 1032])
-def test_default_precision_is_one_bf16_pass(rng, w):
+def test_default_precision_is_one_bf16_pass(rng, w, alpha):
     """`precision="default"` rounds both operands to bfloat16 and
     accumulates in f32, as the einsum it replaces does on a TPU: the
-    benchmark's `bf16_assembly` control must stay wrong."""
+    benchmark's `bf16_assembly` control must stay wrong, in implicit mode
+    too, where what is rounded is the weighted operand (α·t·y, and
+    1 + α·t), as the einsum pair rounds `yw`."""
     y, t = _bucket(rng, 9, w, 50)
     got_a, got_b = assemble_bucket(
-        jnp.asarray(y), jnp.asarray(t), precision="default", interpret=True)
+        jnp.asarray(y), jnp.asarray(t), precision="default", interpret=True,
+        alpha=alpha)
 
     def rounded(x):
         return np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
 
-    want_a, want_b = _einsum64(rounded(y), rounded(t))
+    if alpha is None:
+        want_a, want_b = _einsum64(rounded(y), rounded(t))
+    else:
+        yw = rounded(y * (np.float32(alpha) * t)[..., None]).astype(np.float64)
+        y64 = rounded(y).astype(np.float64)
+        want_a = np.einsum("rwk,rwl->rkl", yw, y64)
+        want_b = np.einsum("rwk,rw->rk", y64,
+                           rounded(1.0 + np.float32(alpha) * t))
     np.testing.assert_allclose(got_a, want_a, rtol=1e-5,
                                atol=1e-6 * np.abs(want_a).max())
     np.testing.assert_allclose(got_b, want_b, rtol=1e-5,
                                atol=1e-6 * np.abs(want_b).max())
-    full_a, _ = _einsum64(y, t)
+    full_a, _ = _einsum64(y, t, alpha)
     assert np.abs(np.asarray(got_a) - full_a).max() > 1e-4 * np.abs(full_a).max()
 
 
@@ -198,9 +261,8 @@ def test_unknown_precision_is_refused():
         assemble_bucket(y, y[..., 0], precision="high", interpret=True)
 
 
-def _kernel_everywhere(platform, y_dtype, dtype, implicit, k,
-                       precision="highest"):
-    return "einsum" if implicit else "kernel"
+def _kernel_everywhere(platform, y_dtype, dtype, k, precision="highest"):
+    return "kernel"
 
 
 @pytest.mark.parametrize("mode", ["unfused", "fused", "chunked"])
@@ -270,7 +332,8 @@ def test_als_fit_hands_off_lane_major(rng, monkeypatch, mode):
     if mode != "lax_solver":
         monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
     if mode == "lanes_chunked":
-        monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", "4096")
+        # a gathered row of 4 values counts as the lane tile it occupies
+        monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", str(1 << 17))
     A._SWEEP_CACHE.clear()
     monkeypatch.setattr(A, "resolve_assembly", _kernel_everywhere)
     seen = _count_lane_calls(monkeypatch)
@@ -282,7 +345,7 @@ def test_als_fit_hands_off_lane_major(rng, monkeypatch, mode):
     if mode == "lanes":
         assert len(rows) >= 3 and rows[-1] > 128
     elif mode == "lanes_chunked":
-        assert rows and rows[-1] * 16 * k * 4 <= 4096 < 205 * 24 * k * 4
+        assert rows and rows[-1] * 16 * 512 <= 1 << 17 < 205 * 24 * 512
     else:
         assert not rows
     np.testing.assert_allclose(
@@ -291,17 +354,76 @@ def test_als_fit_hands_off_lane_major(rng, monkeypatch, mode):
         kernel.item_factors, base.item_factors, rtol=1e-3, atol=1e-5)
 
 
-@pytest.mark.parametrize("exchange,implicit,on_kernel", [
-    ("bfloat16", False, False),   # `als_train`'s default on a TPU
-    (None, True, False),          # implicit mode
-    (None, False, True),          # explicit f32: one kernel a bucket
+@pytest.mark.parametrize("assembly,solver,chunked,implicit", [
+    ("kernel", "pallas", False, False), ("kernel", "pallas", True, False),
+    ("kernel", "pallas", False, True), ("kernel", "pallas", True, True),
+    ("einsum", "pallas", True, True),    # the einsum pair: ragged as it was
+    ("kernel", "lax", True, True),       # no Pallas solver: nothing to tile
 ])
-def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
-                                           implicit, on_kernel):
-    """A sweep on the Pallas solver with the resolver as a TPU would answer
-    it: the bf16 exchange and implicit mode call no assembly kernel and
-    hand `(n, k, k)` to `cholesky_solve_batched` as before; only the kernel
-    path reaches the solver through `cholesky_solve_lanes`."""
+def test_per_chunk_solve_takes_whole_lane_tiles(rng, monkeypatch, assembly,
+                                                solver, chunked, implicit):
+    """The per-chunk route, one device: behind the kernel every batch the
+    Pallas solver is handed is whole lane tiles of systems (it pads a ragged
+    one by a copy of all of A), the steps themselves stay as ragged as
+    ``_chunk_rows`` cut them, and the factors are the materialised einsum
+    fit's: the systems past a step's rows are solved and dropped."""
+    from flink_ms_tpu.ops import cholesky_pallas
+
+    n_users, n_items, k = 300, 30, 4
+    u, i = np.nonzero(rng.uniform(size=(n_users, n_items)) < 0.6)
+    r = rng.integers(1, 30, len(u)).astype(np.float32)
+    init = (rng.random((n_users, k), dtype=np.float32) / 2,
+            rng.random((n_items, k), dtype=np.float32) / 2)
+    cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
+                      implicit=implicit, alpha=2.0)
+    mesh = make_mesh(1)
+    base = A.als_fit(u, i, r, cfg, mesh, init=init)
+    monkeypatch.setenv("FLINK_MS_ALS_FUSED", "1")
+    monkeypatch.setenv("FLINK_MS_ALS_SOLVER", solver)
+    if chunked:
+        monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", str(1 << 17))
+    if assembly == "kernel":
+        monkeypatch.setattr(A, "resolve_assembly", _kernel_everywhere)
+    A._SWEEP_CACHE.clear()
+    batches, steps = [], []
+    _spy(monkeypatch, cholesky_pallas, "cholesky_solve_batched",
+         lambda a: batches.append(a[0].shape[0]))
+    _spy(monkeypatch, assemble_pallas, "assemble_bucket",
+         lambda a: steps.append(a[0].shape[0]))
+    try:
+        got = A.als_fit(u, i, r, cfg, mesh, init=init)
+    finally:
+        A._SWEEP_CACHE.clear()
+    assert (solver == "pallas") == bool(batches)
+    assert (assembly == "kernel") == bool(steps)
+    if assembly == "kernel":
+        assert any(rows % 128 for rows in steps)
+        if batches:   # one solve a step, the step's rows rounded up
+            assert sorted(batches) == sorted(-(-rows // 128) * 128
+                                             for rows in steps)
+    else:
+        assert any(rows % 128 for rows in batches)
+    # f32 sums in another order, through two ill-conditioned rank-4 solves
+    np.testing.assert_allclose(
+        got.user_factors, base.user_factors, rtol=1e-3, atol=5e-4)
+    np.testing.assert_allclose(
+        got.item_factors, base.item_factors, rtol=1e-3, atol=5e-4)
+
+
+@pytest.mark.parametrize("platform,exchange,implicit,on_kernel", [
+    ("tpu", "bfloat16", False, False),   # `als_train`'s default on a TPU
+    ("tpu", None, True, True),           # implicit f32: the weighted kernel
+    ("tpu", None, False, True),          # explicit f32: one kernel a bucket
+    ("tpu", "bfloat16", True, False),    # the bf16 exchange, implicit too
+    ("cpu", None, True, False),          # a CPU fit, implicit too
+])
+def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, platform,
+                                           exchange, implicit, on_kernel):
+    """A sweep on the Pallas solver with the resolver as `platform` would
+    answer it: the bf16 exchange and a CPU call no assembly kernel in
+    either mode and hand `(n, k, k)` to `cholesky_solve_batched` as before;
+    only the kernel path reaches the solver through `cholesky_solve_lanes`,
+    implicit mode's with `alpha` and explicit mode's without."""
     from flink_ms_tpu.ops import cholesky_pallas
 
     n_users, n_items, k = 40, 30, 4
@@ -313,8 +435,13 @@ def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
     real = A.resolve_assembly
     monkeypatch.setattr(
         A, "resolve_assembly",
-        lambda platform, *a, **kw: real("tpu", *a, **kw))
+        lambda _, *a, **kw: real(platform, *a, **kw))
     calls = {"kernel": [], "batched": [], "lanes": []}
+    alphas = []
+    real_lanes = assemble_pallas.assemble_bucket_lanes
+    monkeypatch.setattr(
+        assemble_pallas, "assemble_bucket_lanes",
+        lambda *a, **kw: (alphas.append(kw.get("alpha")), real_lanes(*a, **kw))[1])
     for module, name, key in [
             (assemble_pallas, "assemble_bucket", "kernel"),
             (assemble_pallas, "assemble_bucket_lanes", "kernel"),
@@ -329,6 +456,7 @@ def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
         assert len(calls["kernel"]) == (len(problem.u.widths)
                                         + len(problem.i.widths))
         assert len(calls["lanes"]) == 2 and not calls["batched"]
+        assert set(alphas) == ({cfg.alpha} if implicit else {None})
     else:
         assert not calls["kernel"] and not calls["lanes"]
         assert calls["batched"] == [(problem.u.per_block, k, k),
@@ -340,7 +468,10 @@ def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
     ("tpu", "float32", False, 97096, 50, "highest", "kernel"),
     ("tpu", "float32", False, 144, 64, "default", "kernel"),
     ("tpu", "bfloat16", False, 144, 50, "highest", "einsum"),   # bf16 exchange
-    ("tpu", "float32", True, 144, 50, "highest", "einsum"),     # implicit
+    ("tpu", "float32", True, 144, 50, "highest", "kernel"),     # implicit
+    ("tpu", "float32", True, 24, 64, "default", "kernel"),      # its control
+    ("tpu", "bfloat16", True, 144, 50, "highest", "einsum"),    # bf16, implicit
+    ("cpu", "float32", True, 144, 50, "highest", "einsum"),     # CPU, implicit
     ("cpu", "float32", False, 144, 50, "highest", "einsum"),    # CPU mesh
     (None, "float32", False, 144, 50, "highest", "einsum"),
     ("tpu", "float32", False, 144, 50, "high", "einsum"),       # no 3-pass
@@ -350,7 +481,7 @@ def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, exchange,
 ])
 def test_resolver_and_what_it_traces(platform, y_dtype, implicit, w, k,
                                      precision, want):
-    assert A.resolve_assembly(platform, y_dtype, "float32", implicit, k,
+    assert A.resolve_assembly(platform, y_dtype, "float32", k,
                               precision) == want
     # ... and the bucket really traces that: a pallas_call only where the
     # resolver said kernel, the einsum pair's two dot_generals otherwise
@@ -364,3 +495,7 @@ def test_resolver_and_what_it_traces(platform, y_dtype, implicit, w, k,
     assert ("pallas_call" in jaxpr) == (want == "kernel")
     if want == "einsum":
         assert jaxpr.count("dot_general") == 2
+    else:
+        # the weights are a static branch of the kernel's body: two f32
+        # multiplies under implicit, none (no multiply by ones) without
+        assert len(re.findall(r"f32\[[^\]]*\] = mul ", jaxpr)) == 2 * implicit

@@ -205,6 +205,39 @@ def test_lane_major_assembly_kernel_lowers_for_tpu(r, w, k):
     assert [tuple(o.shape) for o in lowered.out_info] == [(k, k, n), (k, n)]
 
 
+# (r, w) of the weighted (implicit feedback) form at msd-ials.ials-retrain,
+# rank 64: batch-major as the user half's lax.map chunks run it (the
+# narrowest bucket's chunk, a middle one, the widest list), lane-major as
+# the item half's straight-line buckets do (the most rows, the first
+# tiled width, the widest: 196 lists of 12,784 in tiles of 640)
+_TPU_LOWERED_WEIGHTED = [
+    ("batch", 36123, 24), ("batch", 12134, 216), ("batch", 1, 3784),
+    ("lanes", 13366, 328), ("lanes", 3826, 1120), ("lanes", 196, 12784)]
+
+
+@pytest.mark.parametrize("layout,r,w", _TPU_LOWERED_WEIGHTED)
+def test_weighted_assembly_kernel_lowers_for_tpu(layout, r, w):
+    """`alpha` is a static of the kernel body: the multiply by the
+    confidence row and the 1 + alpha*t of row k must lower in Mosaic at
+    the cell's widths, in both output layouts."""
+    from flink_ms_tpu.ops.assemble_pallas import (
+        assemble_bucket, assemble_bucket_lanes)
+
+    k = 64
+    assemble = assemble_bucket_lanes if layout == "lanes" else assemble_bucket
+    lowered = jax.jit(
+        lambda y, t: assemble(y, t, precision="highest", interpret=False,
+                              alpha=40.0)
+    ).trace(
+        jax.ShapeDtypeStruct((r, w, k), jnp.float32),
+        jax.ShapeDtypeStruct((r, w), jnp.float32),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    n = -(-r // 128) * 128
+    assert [tuple(o.shape) for o in lowered.out_info] == (
+        [(k, k, n), (k, n)] if layout == "lanes" else [(r, k, k), (r, k)])
+
+
 @pytest.mark.parametrize("k,n", [(50, 138496), (64, 4096)])
 def test_cholesky_kernel_with_diagonal_lowers_for_tpu(k, n):
     """(50, 50, 138496): the user side of als-ml20m.retrain, padded."""

@@ -1,7 +1,8 @@
 """Implicit-feedback ALS (Hu, Koren, Volinsky) as the `msd-ials` cell runs it:
 the benchmark's plain reference against a brute-force minimiser of HKV's
 objective; the program's implicit sweep against that reference on both solve
-routes and both solvers; the route decision as a function of sizes; the
+routes, both solvers and both assemblies (the einsum pair, and the Pallas
+kernel's weighted form interpreted); the route decision as a function of sizes; the
 scopes, gauges and the counter PR 33 added to `ops/als.py`; the synthetic
 play counts; and the cell's rehearsal on the CPU."""
 
@@ -80,8 +81,16 @@ def test_reference_counts_a_repeated_pair_twice_and_reads_the_play_count(rng):
 
 # -- the program against the reference ----------------------------------------
 
-def one_iteration(rng, monkeypatch, devices, env, memory=None, **config):
+def one_iteration(rng, monkeypatch, devices, env, memory=None,
+                  assembly="einsum", **config):
     users, items, plays = plays_problem(rng)
+    if assembly == "kernel":
+        # the resolver answered as a TPU would, the kernel interpreted; it
+        # is not in the sweep's cache key
+        real = A.resolve_assembly
+        monkeypatch.setattr(A, "resolve_assembly",
+                            lambda _, *a, **kw: real("tpu", *a, **kw))
+        monkeypatch.setattr(A, "_SWEEP_CACHE", {})
     k = 8
     init = (rng.random((60, k), dtype=np.float32) / np.sqrt(k),
             rng.random((25, k), dtype=np.float32) / np.sqrt(k))
@@ -104,19 +113,44 @@ def row_error(got, want):
     return float((np.abs(got - want).max(axis=1) / np.abs(want).max(axis=1)).max())
 
 
+@pytest.mark.parametrize("assembly", ["einsum", "kernel"])
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("solver", ["lax", "pallas"])
 @pytest.mark.parametrize("route", ["0", "1"])
 def test_implicit_iteration_agrees_with_the_reference(rng, monkeypatch, route,
-                                                      solver, devices):
-    """Both routes (materialised, per chunk), both solvers, chunks forced
-    small enough that the larger buckets run under lax.map."""
+                                                      solver, devices,
+                                                      assembly):
+    """Both routes (materialised, per chunk), both solvers, both
+    assemblies, chunks forced small enough that the larger buckets run
+    under lax.map.  Kernel, Pallas solver, materialised: A arrives
+    lane-major (from the kernel itself where a bucket runs straight-line,
+    transposed after the map elsewhere) and Y^T Y is added to it there."""
+    from flink_ms_tpu.ops import assemble_pallas
+
+    seen = []
+    for name in ("assemble_bucket", "assemble_bucket_lanes"):
+        fn = getattr(assemble_pallas, name)
+        monkeypatch.setattr(
+            assemble_pallas, name,
+            lambda *a, fn=fn, name=name, **kw: (
+                seen.append((name, kw["alpha"])), fn(*a, **kw))[1])
+    # the kernel path counts a gathered row of 8 values as the lane tile of
+    # 128 it occupies: sixteen times the budget for the same steps
+    budget = "4096" if assembly == "einsum" else "65536"
     _, _, _, model, want_u, want_i = one_iteration(
         rng, monkeypatch, devices,
         {"FLINK_MS_ALS_FUSED": route, "FLINK_MS_ALS_SOLVER": solver,
-         "FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES": "4096"})
+         "FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES": budget}, assembly=assembly)
     assert row_error(model.user_factors, want_u) < TOL
     assert row_error(model.item_factors, want_i) < TOL
+    if assembly == "einsum":
+        assert not seen
+    else:
+        assert {alpha for _, alpha in seen} == {ALPHA}
+        lane_major = solver == "pallas" and route == "0"
+        assert ("assemble_bucket_lanes" in {n for n, _ in seen}) is lane_major
+        if devices == 1:   # where the budget cuts the larger buckets up
+            assert "assemble_bucket" in {n for n, _ in seen}
 
 
 def test_the_sweep_decides_per_side_and_still_agrees(rng, monkeypatch):
@@ -135,7 +169,9 @@ def test_the_sweep_decides_per_side_and_still_agrees(rng, monkeypatch):
     assert gauges["tpums_als_rows"] == problem.u.per_block + problem.i.per_block
 
 
-def test_a_sweep_that_ignored_the_play_counts_would_fail(rng, monkeypatch):
+@pytest.mark.parametrize("assembly", ["einsum", "kernel"])
+def test_a_sweep_that_ignored_the_play_counts_would_fail(rng, monkeypatch,
+                                                         assembly):
     seen = {}
     real = ref.hkv_rows
 
@@ -144,7 +180,8 @@ def test_a_sweep_that_ignored_the_play_counts_would_fail(rng, monkeypatch):
         return real(sample, row_of, col_of, plays, other, lam, alpha)
 
     monkeypatch.setattr(ref, "hkv_rows", spy)
-    _, _, _, model, _, _ = one_iteration(rng, monkeypatch, 1, {})
+    _, _, _, model, _, _ = one_iteration(rng, monkeypatch, 1, {},
+                                         assembly=assembly)
     sample, row_of, col_of, plays, other = seen["first"]
     as_ones = real(sample, row_of, col_of, np.ones_like(plays), other, LAM, ALPHA)
     assert plays.max() > 1
