@@ -1034,7 +1034,7 @@ def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
         # in_scan (the fused per-chunk solve inside lax.map): the kernel's
         # lane-major operand relayout is uncompilable there (degenerate-
         # dim copy, 62.5 GB AOT OOM) -- force the batch-major variant
-        layout = "batch_major" if in_scan else None
+        layout = "batch_major" if in_scan else "lane_major"
         return cholesky_solve_batched(
             A, b, interpret=platform != "tpu", layout=layout
         ).astype(A.dtype)
@@ -1321,10 +1321,6 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         resolve_solver(mesh.devices.flat[0].platform),
         _assembly_chunk_bytes(),
         tuple(sorted(_routes(problem, config, mesh).items())),
-        # the Pallas solver reads its layout knob at trace time too (when
-        # layout=None inside cholesky_solve_batched) — omitting it here
-        # would silently reuse an executable compiled under the old layout
-        os.environ.get("FLINK_MS_PALLAS_LAYOUT", "lane_major"),
     )
     fn = _SWEEP_CACHE.pop(key, None)
     if fn is None:

@@ -37,7 +37,6 @@ blocked ALS [dep], reached from ``ALSImpl.scala:52`` (SURVEY.md §2.2).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -152,7 +151,7 @@ def cholesky_solve_lanes(At, bt, d, *, interpret: bool, tile: int = 128):
 
 
 def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
-                           layout=None):
+                           layout="lane_major"):
     """Batched SPD solve A x = b.  A (n, k, k), b (n, k) -> x (n, k).
 
     ``tile`` batch elements ride the lane axis per grid step; VMEM holds
@@ -163,16 +162,14 @@ def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
 
     ``layout``: "lane_major" transposes A/b to (k, k, n)/(k, n) at the
     XLA level before the kernel; "batch_major" feeds (n, k, k) blocks
-    directly and transposes per tile inside VMEM.  None resolves to
-    FLINK_MS_PALLAS_LAYOUT or "lane_major" — chip-measured 62.7 vs 68.3
+    directly and transposes per tile inside VMEM.  "lane_major" is the
+    default — chip-measured 62.7 vs 68.3
     ms/iter at 5M nnz / k=50 (the in-kernel transpose costs ~9%).  The
     fused assembly+solve path passes "batch_major" explicitly: inside a
     lax.map body XLA materializes the whole-array lane-major relayout as
     a degenerate-dim copy lane-padded x128 (62.5 GB for a (43648, 50, 50)
     chunk — the round-3 fused-mode AOT OOM), which batch_major sidesteps
     by never asking XLA for that layout."""
-    if layout is None:
-        layout = os.environ.get("FLINK_MS_PALLAS_LAYOUT", "lane_major")
     n, k = b.shape
     if layout == "batch_major":
         # the batch-major kernel keeps ~9 k²·tile f32 buffers live (input

@@ -44,24 +44,21 @@ feature, no ids) from there up, whichever stores fewer bytes.
 Two engines run the local steps (``SVMConfig.inner``).  The scatter engine
 indexes one row per chain per step.  The Gram engine touches the weight
 vector twice a round, once for the round-start margins and once for
-Δw = XᵀΔα.  On sparse rows those are one gather and one scatter-add, and
-both stream a length-bucketed copy of the rows
-(``_bucket_rows``: each row padded to the next width of a short ladder, not
-to the longest row), because their time is their stored entries times 7 ns
-whatever an entry holds.  Measured on one TPU v5e in the benchmark cell
-``rcv1-cocoa.cocoa-rounds`` (PERF.md §5, PR 32; 8192 chains x 83 rows,
-49.6M entries stored as 55.5M in 13 buckets where the rectangle padded to
-256 held 174M): a round takes 0.80 s, the gather 0.38, the scatter-add
-0.38, the 83 steps between them 0.037 (2.78, 1.31, 1.42, 0.037 on the
-rectangle).  Where the device reports its memory, the hottest columns leave
-that price: ``head_width`` asks the dense layout's question a column at a
-time and by time, and the columns that are cheaper streamed, zeros and all,
-than gathered are held as one dense block beside the tail of the others
-(the split layout: ``_tail_tiles``, ``_head_entries``; at RCV1's shape
-1,536 of 47,236 columns, 54.8% of the entries, a quarter of the chip: a
-round of 0.392 s where it took 0.767, the gather and the scatter-add 0.19
-each over 24.9M stored entries, the block's two passes 5.5 ms each;
-PERF.md §5, PR 41).
+Δw = XᵀΔα.  On sparse rows those are one gather and one scatter-add, whose
+time is their stored entries times 7 ns whatever an entry holds, so both
+loop over a copy of the rows cut into tiles of 8 positions x 1,024 rows,
+the rows ordered by length (``_tail_tiles``: a block of rows is stored as
+deep as its own longest row).  Where the device reports its memory, the
+hottest columns leave that price: ``head_width`` asks the dense layout's
+question a column at a time and by time, and the columns that are cheaper
+streamed, zeros and all, than gathered are held as one dense block, the
+head, beside the tiles of the others, the tail.  Measured on one TPU v5e in
+``rcv1-cocoa.cocoa-rounds`` (PERF.md §5, PR 41; 8192 chains x 83 rows,
+1,536 of 47,236 columns, 54.8% of the 49.6M entries, a quarter of the
+chip): a round takes 0.392 s, the gather and the scatter-add 0.19 each
+over 24.9M stored entries, the block's two passes 5.5 ms each.  Where no
+column is worth a head (a CPU, which reports no memory; bf16 state) the
+tail is the whole row and the same round runs without the block's operands.
 On dense rows they are two products over X, ``X w`` and
 ``Xᵀ Δα``, each one pass over the matrix where it lies: measured in
 ``epsilon-cocoa-plus.dense-rounds`` (PERF.md §5, PR 37; CoCoA+, 8192
@@ -251,9 +248,9 @@ class BlockedSVMProblem:
     Who reads what.  Sparse: the scatter engine indexes ``idx`` / ``val``
     by row inside every step, so the padded rectangles are its device
     operands; the Gram engine reads them once, chain by chain, to build its
-    Gram tensor, and runs its rounds over a length-bucketed copy of the
-    rows that ``compile_svm_fit`` cuts out of them by ``row_len``
-    (``_bucket_rows``).  Dense: ``val`` goes to the device once, as
+    Gram tensor, and runs its rounds over the tiles that ``compile_svm_fit``
+    cuts out of them by ``row_len`` (``_tail_tiles``).  Dense: ``val`` goes
+    to the device once, as
     ``(K·rows, features)``, and both engines, the Gram build and the
     round's two products read it there.  Both read ``label`` and
     ``sq_norm``.
@@ -446,92 +443,13 @@ def _pad_blocks(a: np.ndarray, Kp: int) -> np.ndarray:
     return np.pad(a, [(0, Kp - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
 
 
-# The Gram engine's rounds stream a length-bucketed copy of the rows
-# (``_bucket_rows``): a geometric ladder of widths from the shortest row to
-# the longest, each a multiple of _BUCKET_STEP, a row stored at the first
-# width that holds it.  The ratio bounds the padding inside a bucket at a
-# quarter of a row (a tenth of the entries on log-normal document lengths);
-# the cap bounds what a round traces: a wider spread of lengths widens the
-# ratio until the ladder fits.  Rows of one length get one bucket of that
-# width, which is the padded rectangle itself.
-_BUCKET_RATIO = 1.25
-_BUCKET_CAP = 16
-_BUCKET_STEP = 8
-
-
-def _bucket_widths(lo: int, hi: int) -> list:
-    """The ladder of stored widths for rows of ``lo`` .. ``hi`` entries."""
-    ratio = _BUCKET_RATIO
-    while True:
-        widths = [min(_round_up(lo, _BUCKET_STEP), hi)]
-        while widths[-1] < hi:
-            widths.append(min(hi, _round_up(
-                int(np.ceil(widths[-1] * ratio)), _BUCKET_STEP)))
-        if len(widths) <= _BUCKET_CAP:
-            return widths
-        ratio *= 1.1
-
-
-def _bucket_plan(row_len: np.ndarray, D: int):
-    """-> (widths, rows, bucket_of): the static shape of a device's bucketed
-    rows, the same on every device.  ``widths[b]`` entries are stored for
-    each of the ``rows[b]`` rows of bucket b (the largest device's count);
-    ``bucket_of`` (D, slots per device) names the bucket of every row slot,
-    -1 for a row without entries (pad rows, empty chains, empty documents),
-    which no bucket holds.  Buckets no row falls in are dropped; a layout
-    without any entry keeps one pad row so that the round's operands are
-    never empty."""
-    lens = row_len.reshape(D, -1)
-    real = lens[lens > 0]
-    if not real.size:
-        return (1,), (1,), np.full(lens.shape, -1)
-    ladder = np.asarray(_bucket_widths(int(real.min()), int(real.max())))
-    bucket_of = np.where(lens > 0, np.searchsorted(ladder, lens), -1)
-    counts = np.stack([(bucket_of == b).sum(axis=1)
-                       for b in range(len(ladder))]).max(axis=1)
-    keep = np.flatnonzero(counts)
-    renumber = np.full(len(ladder) + 1, -1)
-    renumber[keep] = np.arange(len(keep))
-    return (tuple(int(w) for w in ladder[keep]),
-            tuple(int(c) for c in counts[keep]),
-            renumber[bucket_of])
-
-
-def _bucket_rows(idx: np.ndarray, val: np.ndarray, plan):
-    """The rows' entries cut out of the padded (Kp, H, L) rectangles into
-    the plan's buckets -> (ids, val, slot): per bucket b one
-    ``(D, widths[b], rows[b])`` pair, entry-major (a row's entries down a
-    column, the rows along the lanes), and ``slot`` (D, sum(rows)), the
-    device-local flat slot ``chain * H + row`` of every bucket row, bucket
-    after bucket.  Rows beyond a device's own count are pads: id 0, value
-    0, slot 0, so they add exact zeros to feature 0 and to slot 0's
-    margin."""
-    widths, rows, bucket_of = plan
-    D = bucket_of.shape[0]
-    idx = idx.reshape(D, bucket_of.shape[1], -1)
-    val = val.reshape(idx.shape)
-    ids_out, val_out = [], []
-    slot_out = np.zeros((D, sum(rows)), np.int32)
-    at = 0
-    for b, (width, n_rows) in enumerate(zip(widths, rows)):
-        ids_b = np.zeros((D, width, n_rows), np.int32)
-        val_b = np.zeros((D, width, n_rows), val.dtype)
-        for dev in range(D):
-            slots = np.flatnonzero(bucket_of[dev] == b)
-            slot_out[dev, at:at + len(slots)] = slots
-            ids_b[dev, :, :len(slots)] = idx[dev, slots, :width].T
-            val_b[dev, :, :len(slots)] = val[dev, slots, :width].T
-        ids_out.append(ids_b)
-        val_out.append(val_b)
-        at += n_rows
-    return tuple(ids_out), tuple(val_out), slot_out
-
-
-# On the split layout (``head_width`` > 0) the round streams the hottest
-# columns and gathers only the tail: each row's first ``tail_len`` entries.
-# The tail is stored as tiles of _TILE_STEP positions x _TILE_ROWS rows
-# (whole (8, 128) device tiles, entry-major as the buckets are), the rows
-# ordered by tail length so that a block of rows needs the steps of its
+# The Gram engine's rounds gather a sparse row's tail: its first
+# ``tail_len`` entries, which is the whole row where no column is held
+# dense, and with a head (``head_width`` > 0) the entries outside the
+# hottest columns, which the round streams.  The tail is stored as tiles of
+# _TILE_STEP positions x _TILE_ROWS rows (whole (8, 128) device tiles,
+# entry-major: a row's entries down a column, the rows along the lanes), the
+# rows ordered by tail length so that a block of rows needs the steps of its
 # first row and no more, and the round loops over the tiles that hold an
 # entry: their number is an operand, not a shape.  The shapes are a
 # function of the TOTAL row lengths alone (a row's tail is no longer than
@@ -685,64 +603,16 @@ def _head_entries(idx: np.ndarray, val: np.ndarray, row_len: np.ndarray,
     return first, out_rows, out_cols, out_vals
 
 
-def _dw_choice() -> str:
-    """FLINK_MS_SVM_DW: how the Gram engine applies the round-end
-    Δw = Xᵀ Δα update over its bucketed rows (``_bucket_rows``).
-    "direct": an unsorted scatter-add a bucket, over all stored entries.
-    "sorted": gather the flattened contributions through a precomputed
-    feature-sorted permutation, then a sorted segment-sum.  "presorted":
-    store the values ALREADY feature-sorted at set-up, so the round end
-    multiplies the streamed sorted values by a gather from only the tiny
-    (C·H) Δα table and segment-sums — no runtime permutation of the big
-    array.  "auto" (default) = direct everywhere.  Measured on one TPU
-    v5e in the benchmark cell ``rcv1-cocoa.cocoa-rounds`` (PERF.md §5,
-    PR 32; 8192 chains x 83 rows, 49.6M entries stored as 55.5M): the
-    direct scatter-add takes 0.38 s of a 0.80 s round (6.9 ns a stored
-    entry) and the round-start gather 0.38 s; the steps between them
-    0.04 s.  "presorted" read 0.91 s there and "sorted" 1.22 s, rounds
-    of 1.32 and 1.64 s: both lose to the direct form (ROADMAP D5).  Both
-    keep that whole-row program: only the direct form ("auto" included)
-    splits the layout by column (``head_width``), so under "sorted" and
-    "presorted" every entry stays in the buckets and no column is held
-    dense."""
-    choice = os.environ.get("FLINK_MS_SVM_DW", "auto")
-    if choice not in ("auto", "direct", "sorted", "presorted"):
-        # a typo'd knob must not silently fall through to the direct
-        # scatter — A/B verdicts depend on the requested path running
-        raise ValueError(
-            f"FLINK_MS_SVM_DW={choice!r} must be "
-            "auto|direct|sorted|presorted"
-        )
-    if choice == "auto":
-        return "direct"
-    return choice
-
-
-def _step_choice() -> str:
-    """FLINK_MS_SVM_STEP: the form of the Gram engine's SDCA steps, for an
-    A/B.  "dynamic": XLA's ``fori_loop`` over ``vmap(chain_sdca_gram)``, a
-    threefry draw, a Gram-row gather, four one-element picks and an α
-    scatter a step.  "kernel": ``sdca_pallas.sdca_steps_lanes``, all of a
-    round's steps in one Pallas call with the draws hoisted (interpreted
-    off the chip).  "auto" (default) lets ``resolve_step`` decide from what
-    the fit can see."""
-    choice = os.environ.get("FLINK_MS_SVM_STEP", "auto")
-    if choice not in ("auto", "dynamic", "kernel"):
-        # as _dw_choice: a typo must not run the dynamic step in silence
-        raise ValueError(
-            f"FLINK_MS_SVM_STEP={choice!r} must be auto|dynamic|kernel"
-        )
-    return choice
-
-
 def resolve_step(platform: Optional[str], inner: str, dtype, h_rows: int,
                  steps: int) -> str:
-    """How a compiled fit runs its SDCA steps: "kernel" or "dynamic"
-    (``_step_choice``).  The kernel engages where a chip run priced it: a
-    TPU, the Gram engine, f32 state, and a lane block of 128 chains that
-    fits the kernel's VMEM twice (``sdca_pallas.fits_vmem``, from the rows
-    a chain and the steps a round: up to 113 rows at one local pass).
-    Everything else keeps the XLA step unchanged: every CPU fit, the scatter
+    """How a compiled fit runs its SDCA steps: "dynamic", XLA's
+    ``fori_loop`` over ``vmap(chain_sdca_gram)``, or "kernel", all of a
+    round's steps in one Pallas call with the draws hoisted
+    (``sdca_pallas.sdca_steps_lanes``).  The kernel engages where a chip run
+    priced it: a TPU, the Gram engine, f32 state, and a lane block of 128
+    chains that fits the kernel's VMEM twice (``sdca_pallas.fits_vmem``,
+    from the rows a chain and the steps a round: up to 113 rows at one local
+    pass).  Everything else keeps the XLA step: every CPU fit, the scatter
     engine, bf16 state, a chain too long for VMEM.  One code path for both
     benchmark cells, its block following the rows a chain.
 
@@ -752,22 +622,12 @@ def resolve_step(platform: Optional[str], inner: str, dtype, h_rows: int,
     0.031287 -> 0.008968 s.  ``rcv1-cocoa.cocoa-rounds`` (8192 chains x 83
     rows): 0.037162 -> 0.001446 of 0.803775 -> 0.767115.  The kernel alone
     takes 0.39 and 1.38 ms; the hoisted draws 0.25 ms."""
-    choice = _step_choice()
-    # the scatter engine has no Gram step: the knob does not reach it
-    if inner != "gram" or choice == "dynamic" or (
-            choice == "auto" and platform != "tpu"):
+    if (platform != "tpu" or inner != "gram"
+            or jnp.dtype(dtype) != jnp.float32):
         return "dynamic"
     from .sdca_pallas import fits_vmem  # pallas: a second of import
 
-    if jnp.dtype(dtype) == jnp.float32 and fits_vmem(h_rows, steps):
-        return "kernel"
-    if choice == "kernel":
-        # an A/B must run what it asked for
-        raise ValueError(
-            "FLINK_MS_SVM_STEP=kernel needs f32 state and chains whose "
-            f"Gram block fits VMEM; got {jnp.dtype(dtype).name}, "
-            f"{h_rows} rows a chain, {steps} steps")
-    return "dynamic"
+    return "kernel" if fits_vmem(h_rows, steps) else "dynamic"
 
 
 def _resolve_inner(problem: BlockedSVMProblem, config: SVMConfig,
@@ -867,10 +727,8 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
         def to_lanes(x, rows=Hp):
             """(C, ·) of a device's chains -> (rows, Cp), zero pads."""
             return jnp.pad(x.T, ((0, rows - x.shape[1]), (0, Cp - C)))
-    # the dense layout has one form of Δw, a product over X; so has the
-    # split layout (head > 0: compile_svm_fit takes no head under a knob)
-    dw_mode = _dw_choice() if inner == "gram" and not dense else "direct"
-    split = head > 0
+    # the Gram engine's sparse rows: tiles, beside a head of `head` columns
+    tiled = inner == "gram" and not dense
 
     def chain_sdca(w, idx_c, val_c, label_c, sqn_c, alpha_c, key_c,
                    row0_c=None):
@@ -977,18 +835,16 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                 gram[-1, steps * B - C:]]))
 
     def block_fit(span, w0, idx, val, label, sq_norm, alpha0, seed_arr,
-                  gram=None, slot=None, *more):
+                  gram=None, slot=None, row0=None, n_tiles=None,
+                  head_val=None, head_ids=None):
         # dense layout, both engines: idx is None and val the device's
         # (C·rows, d) matrix.  Sparse layout, scatter engine: idx, val are
-        # the device's padded (C, rows, L)
-        # rectangles.  Gram engine: they are its bucketed rows, a tuple of
-        # (1, width, rows) pieces each (_bucket_rows; the jit retraces for
-        # another ladder), with slot the flat (C·rows) slot of every bucket
-        # row; more depends on dw_mode: sorted -> (perm, ids), presorted ->
-        # (val_sorted, ids, src_slot), direct nothing.  Split layout (Gram
-        # engine, head > 0): idx, val are the tail's tiles, (1, tiles,
-        # step, rows) each (_tail_tiles), slot the flat slot of every tile
-        # row, and more = (row0, n_tiles, head block, head ids)
+        # the device's padded (C, rows, L) rectangles.  Gram engine: they
+        # are the tail's tiles, (1, tiles, step, rows) each (_tail_tiles),
+        # slot the flat (C·rows) slot of every tile row, row0 the first of
+        # them under each tile, n_tiles the tiles that hold an entry, and
+        # with a head (head > 0) head_val its (C·rows, head) block and
+        # head_ids its columns' feature ids
         # span = [start, stop): rounds run with ABSOLUTE indices so the
         # per-round RNG (fold_in of the round number) is identical whether
         # the caller runs one long fit or chains warm-started segments
@@ -1031,9 +887,8 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                 alpha = alpha + gamma * dalpha
             return w, alpha
 
-        if split:
-            row0, n_tiles, head_val, head_ids = (
-                more[0][0], more[1][0, 0], more[2], more[3])
+        if tiled:
+            row0, n_tiles = row0[0], n_tiles[0, 0]
 
             def tile_rows(rows, t):
                 return jax.lax.dynamic_slice_in_dim(rows, row0[t], _TILE_ROWS)
@@ -1041,7 +896,7 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
         def outer_gram(it, carry):
             w, alpha = carry
             # round-start margins for every row: ONE gather of w over the
-            # stored entries, reduced over each bucket's width, then placed
+            # stored entries, reduced over each tile's positions, then placed
             # by slot (rows without entries keep 0).  Elementwise f32, as
             # the scatter path computes its margins: a default-precision
             # (bf16-pass) contraction here would seed every SDCA step with
@@ -1053,10 +908,11 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                     wx0 = jnp.einsum(
                         "nd,d->n", val, w, precision=_DENSE_PRECISION,
                         preferred_element_type=dtype).reshape(C, H_rows)
-                elif split:
-                    # the head's columns streamed, the tail's entries
-                    # gathered a tile at a time: only the tiles that hold
-                    # an entry, whose number is an operand
+                else:
+                    # the tail's entries gathered a tile at a time: only
+                    # the tiles that hold an entry, whose number is an
+                    # operand; the head's columns, where there are any,
+                    # streamed
                     def tile_margins(t, sums):
                         part = jnp.sum(
                             jnp.take(w, idx[0, t], axis=0) * val[0, t], axis=0)
@@ -1066,18 +922,13 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                     sums = jax.lax.fori_loop(
                         0, n_tiles, tile_margins,
                         jnp.zeros((slot.shape[1],), dtype))
-                    wx0 = (jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(sums)
-                           + jnp.einsum(
-                               "nf,f->n", head_val, w[head_ids],
-                               precision=_DENSE_PRECISION,
-                               preferred_element_type=dtype)
-                           ).reshape(C, H_rows)
-                else:
-                    wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(
-                        jnp.concatenate([
-                            jnp.sum(jnp.take(w, i[0], axis=0) * v[0], axis=0)
-                            for i, v in zip(idx, val)])
-                    ).reshape(C, H_rows)
+                    wx0 = jnp.zeros((C * H_rows,), dtype).at[slot[0]].add(sums)
+                    if head:
+                        wx0 = wx0 + jnp.einsum(
+                            "nf,f->n", head_val, w[head_ids],
+                            precision=_DENSE_PRECISION,
+                            preferred_element_type=dtype)
+                    wx0 = wx0.reshape(C, H_rows)
             with jax.named_scope("svm.steps"):
                 keys = chain_keys(it)
                 if in_kernel:
@@ -1094,7 +945,8 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                     )
             # this device's Δw = Σ_chains X_cᵀ Δα_c / λn: ONE reduction
             # per round (the scatter engine pays one per STEP per chain).
-            # Mode trade-offs in _dw_choice's docstring.
+            # Sparse rows: an unsorted scatter-add a tile, 0.380 s of the
+            # rcv1 round where sorted segment-sums took 1.223, 0.906 (PR 32).
             with jax.named_scope("svm.dw"):
                 dalpha_flat = dalpha.reshape(-1)
                 if dense:
@@ -1103,7 +955,7 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
                         "nd,n->d", val, dalpha_flat,
                         precision=_DENSE_PRECISION,
                         preferred_element_type=dtype)
-                elif split:
+                else:
                     da_rows = dalpha_flat[slot[0]]
 
                     def tile_dw(t, dw):
@@ -1112,40 +964,12 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
 
                     dw = jax.lax.fori_loop(
                         0, n_tiles, tile_dw, jnp.zeros((d,), dtype))
-                    # the head's ids are distinct: one F-element scatter
-                    dw = dw.at[head_ids].add(jnp.einsum(
-                        "nf,n->f", head_val, dalpha_flat,
-                        precision=_DENSE_PRECISION,
-                        preferred_element_type=dtype))
-                elif dw_mode == "presorted":
-                    # val is stored feature-sorted at prepare time,
-                    # so the only runtime gather reads the tiny (C·H) Δα
-                    # table
-                    val_sorted, ids_sorted, src_slot = more
-                    dw = jax.ops.segment_sum(
-                        val_sorted[0] * dalpha_flat[src_slot[0]],
-                        ids_sorted[0],
-                        num_segments=d, indices_are_sorted=True,
-                    )
-                else:
-                    # every stored entry's val · Δα of its row: Δα picked
-                    # once a bucket row, spread over the bucket's width
-                    da_rows = dalpha_flat[slot[0]]
-                    ends = np.cumsum([v.shape[2] for v in val])
-                    contrib = [v[0] * da_rows[end - v.shape[2]:end]
-                               for v, end in zip(val, ends)]
-                    if dw_mode == "sorted":
-                        flat = jnp.concatenate(
-                            [c.reshape(-1) for c in contrib])
-                        perm, ids_sorted = more
-                        dw = jax.ops.segment_sum(
-                            flat[perm[0]], ids_sorted[0], num_segments=d,
-                            indices_are_sorted=True,
-                        )
-                    else:
-                        dw = jnp.zeros((d,), dtype)
-                        for i, c in zip(idx, contrib):
-                            dw = dw.at[i[0]].add(c)
+                    if head:
+                        # the head's ids are distinct: one F-element scatter
+                        dw = dw.at[head_ids].add(jnp.einsum(
+                            "nf,n->f", head_val, dalpha_flat,
+                            precision=_DENSE_PRECISION,
+                            preferred_element_type=dtype))
                 dw = dw / lam_n
             with jax.named_scope("svm.combine"):
                 w = w + gamma * jax.lax.psum(dw, BLOCK_AXIS)
@@ -1158,9 +982,9 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
     spec3 = P(BLOCK_AXIS, None, None)
     spec2 = P(BLOCK_AXIS, None)
     # dense: no ids (None has no leaves), X as (slots, d) split by rows;
-    # split: the tiles, (devices, tiles, step, rows)
+    # tiled: the tiles, (devices, tiles, step, rows)
     rows_specs = ((P(), spec2) if dense
-                  else (P(BLOCK_AXIS, None, None, None),) * 2 if split
+                  else (P(BLOCK_AXIS, None, None, None),) * 2 if tiled
                   else (spec3, spec3))
     # the kernel step reads the Gram tensor, the labels and the norms
     # chain-minor: a device's chains on the lanes
@@ -1169,14 +993,10 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
     in_specs = (P(), P(), *rows_specs, by_chain, by_chain, spec2, P())
     if inner == "gram":
         in_specs += (lanes3 if in_kernel else spec3,)  # gram
-        if not dense:
-            # idx, val: one spec for the whole tuple of buckets; slot, then
-            # the dw mode's operands
-            n_dw = {"direct": 0, "sorted": 2, "presorted": 3}[dw_mode]
-            in_specs += (spec2,) * (1 + n_dw)
-            if split:
-                # row0, n_tiles and the head block by device, its ids whole
-                in_specs += (spec2, spec2, spec2, P())
+        if tiled:
+            in_specs += (spec2, spec2, spec2)  # slot, row0, n_tiles
+            if head:
+                in_specs += (spec2, P())  # the block by device, its ids whole
     jfit = jax.jit(shard_map(
         block_fit,
         mesh=mesh,
@@ -1214,8 +1034,7 @@ def _make_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
             out_specs=lanes3 if in_kernel else spec3,
             check_vma=False,
         ))
-    return (fit, gram_fn, dw_mode if inner == "gram" else "direct",
-            (Hp, Cp) if in_kernel else None)
+    return fit, gram_fn, (Hp, Cp) if in_kernel else None
 
 
 _FIT_CACHE: "dict" = {}
@@ -1227,6 +1046,7 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
     """One compiled program per (layout shapes, config-sans-iterations,
     mesh, head columns): repeat fits and benchmark loops skip retracing;
     the round count is a traced argument."""
+    inner = _resolve_inner(problem, config, mesh)
     key = (
         mesh,
         head,
@@ -1242,9 +1062,9 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
         config.mode,
         config.sigma_prime,
         str(config.dtype),
-        _resolve_inner(problem, config, mesh),
-        _dw_choice(),
-        _step_choice(),
+        inner,
+        resolve_step(mesh.devices.flat[0].platform, inner, config.dtype,
+                     problem.rows_per_block, config.local_iterations),
     )
     fn = _FIT_CACHE.pop(key, None)
     if fn is None:
@@ -1255,7 +1075,7 @@ def _cached_fit(problem: BlockedSVMProblem, config: SVMConfig, mesh: Mesh,
     return fn
 
 
-def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
+def _set_layout_gauges(slots: int, stored: int, nonzero: int,
                        gram_bytes: int, chains: int, dense_entries: int,
                        sigma_prime: float, step_kernel: bool,
                        head: int, head_nonzeros: int) -> None:
@@ -1263,17 +1083,15 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
     row slots (pad rows and pad blocks included); the entries stored per
     slot, so that rows x row_width is every entry the round's gather and
     scatter-add touch (the scatter engine: the width every row is padded
-    to; the Gram engine: a mean over its buckets, their pad rows counted,
-    or on the split layout over the tail's tiles that hold an entry; the
-    dense layout: the feature count, every cell of X); the stored entries
-    that carry no value (dense: the pad rows' cells and the zeros inside
-    real rows; split: of the tail's tiles); the number of length buckets
-    (0 on the scatter engine, the dense layout, one rectangle each, and the
-    split layout, whose tail is tiles); the Gram tensor's bytes (0 on the
-    scatter engine); chains per device; the cells held dense (all of X on
-    the dense layout, the head block's on the split one, 0 on the other
-    sparse layouts), so that dense_entries over rows x row_width says
-    whether the dense layout served a fit; the σ' in force (1 in avg mode);
+    to; the Gram engine: a mean over the tail's tiles that hold an entry;
+    the dense layout: the feature count, every cell of X); the stored
+    entries that carry no value (dense: the pad rows' cells and the zeros
+    inside real rows; the Gram engine: of the tail's tiles); the Gram
+    tensor's bytes (0 on the scatter engine); chains per device; the cells
+    held dense (all of X on the dense layout, the head block's on a sparse
+    layout with a head, 0 without one), so that dense_entries over rows x
+    row_width says whether the dense layout served a fit; the σ' in force
+    (1 in avg mode);
     the chains a device whose SDCA steps the compiled round runs in the
     Pallas kernel (all of them, or 0 on the XLA step: ``resolve_step``);
     the feature columns held dense beside a sparse tail (``head_width``; 0
@@ -1284,7 +1102,6 @@ def _set_layout_gauges(slots: int, stored: int, nonzero: int, buckets: int,
     reg.gauge("tpums_svm_rows").set(slots)
     reg.gauge("tpums_svm_row_width").set(stored / slots)
     reg.gauge("tpums_svm_pad_entries").set(stored - (nonzero - head_nonzeros))
-    reg.gauge("tpums_svm_buckets").set(buckets)
     reg.gauge("tpums_svm_gram_bytes").set(gram_bytes)
     reg.gauge("tpums_svm_chains_per_device").set(chains)
     reg.gauge("tpums_svm_step_kernel_chains").set(chains if step_kernel else 0)
@@ -1300,9 +1117,9 @@ def layout_report() -> str:
     last ``compile_svm_fit`` set: which row layout it chose, what the
     layout stores, the σ' in force, and the form of the SDCA steps."""
     reg = obs_metrics.get_registry()
-    (rows, width, dense_cells, buckets, sigma, in_kernel, head, head_nnz,
+    (rows, width, dense_cells, gram_bytes, sigma, in_kernel, head, head_nnz,
      nnz) = (reg.gauge("tpums_svm_" + name).value for name in (
-         "rows", "row_width", "dense_entries", "buckets", "sigma_prime",
+         "rows", "row_width", "dense_entries", "gram_bytes", "sigma_prime",
          "step_kernel_chains", "head_columns", "head_nonzeros", "nonzeros"))
     if head:
         layout = (f"sparse rows split by column ({int(head)} head columns "
@@ -1312,9 +1129,9 @@ def layout_report() -> str:
     elif dense_cells:
         layout = f"dense rows ({int(rows)} x {int(width)} cells, no ids)"
     else:
+        # the Gram engine (its tensor has bytes) holds sparse rows in tiles
         layout = (f"sparse rows ({int(rows)} x {width:.2f} stored entries, "
-                  + (f"{int(buckets)} length buckets)" if buckets
-                     else "one padded rectangle)"))
+                  + ("in tiles)" if gram_bytes else "one padded rectangle)"))
     return (f"layout {layout}, sigma' {sigma:g}, steps "
             + ("in the Pallas kernel" if in_kernel else "in XLA"))
 
@@ -1332,31 +1149,30 @@ def compile_svm_fit(
     the SDCA steps run in the Pallas kernel (``resolve_step``), chain-minor
     beside the Gram tensor at [7], ``(rows, rows8, chains128)`` a device;
     the rest is the layout's and the engine's: the padded rectangles at
-    [1], [2] on the scatter engine, the bucketed rows on the Gram engine;
+    [1], [2] on the scatter engine, the rows' tiles on the Gram engine
+    (``_tail_tiles``; [8] their rows' slots, [9] each tile's first row,
+    [10] the tiles that hold an entry);
     on the dense layout [1] is None and [2] is X as ``(Kp·rows, features)``,
     placed once for the Gram build and the rounds alike.  Phases, each
     awaited: ``svm.gram_build`` (Gram engine only) and ``svm.place``, with
-    the host's bucket copy under it as ``svm.bucket`` (the dense layout
+    the host's copy into tiles under it as ``svm.bucket`` (the dense layout
     opens ``svm.place`` twice, for X before the Gram build and for the
-    small arrays after it, and has no bucket copy).
+    small arrays after it, and cuts no tiles).
 
     The Gram engine's sparse layout is split by column where
     ``head_width`` says so, from the columns' counts, the itemsize and the
     device's reported memory (``head_columns`` names a width instead: for
     tests): the hottest columns are one dense ``(Kp·rows, F)`` block at
     [11], their feature ids at [12], built on the device from a compact
-    list of their entries (phase ``svm.head`` under ``svm.place``), and [1],
-    [2] hold only the other columns' entries, as tiles
-    (``_tail_tiles``; [8] their rows' slots, [9] each tile's first row,
-    [10] the tiles that hold an entry).  f32 state and the direct Δw only:
-    bf16 state and FLINK_MS_SVM_DW=sorted|presorted keep whole rows."""
+    list of their entries (phase ``svm.head`` under ``svm.place``), and the
+    tiles hold only the other columns' entries.  f32 state only: bf16
+    state takes no head, and a fit without one has no [11], [12]."""
     D = num_blocks(mesh)
     Kp = _round_up(problem.n_blocks, D)
     dtype = config.dtype
     slots = Kp * problem.rows_per_block
     head = 0
     if (not problem.dense and _resolve_inner(problem, config, mesh) == "gram"
-            and _dw_choice() == "direct"
             and jnp.dtype(dtype) == jnp.float32):
         head = (head_width(problem.col_count / D, slots // D,
                            jnp.dtype(dtype).itemsize,
@@ -1371,9 +1187,7 @@ def compile_svm_fit(
     rep = NamedSharding(mesh, P())
 
     def put(a, sharding, as_dtype=None):
-        # a: an array, or the Gram engine's tuple of bucket arrays
-        return jax.device_put(jax.tree.map(
-            lambda x: jnp.asarray(x, dtype=as_dtype), a), sharding)
+        return jax.device_put(jnp.asarray(a, dtype=as_dtype), sharding)
 
     if problem.dense:
         with tracing.phase("svm.place"):
@@ -1389,11 +1203,12 @@ def compile_svm_fit(
     else:
         made = _cached_fit(problem, config, mesh, head)
         idx, val = _pad_blocks(problem.idx, Kp), _pad_blocks(problem.val, Kp)
-    fit, gram_fn, dw_mode, step_lanes = made
-    stored, buckets, extra, head_nonzeros = val.size, 0, [], 0
+    fit, gram_fn, step_lanes = made
+    tiled = gram_fn is not None and not problem.dense
+    stored, extra, head_nonzeros = val.size, [], 0
     if gram_fn is not None:
         # of sparse rows the Gram build reads the padded rectangles once
-        # and lets them go: the rounds hold the bucketed rows only
+        # and lets them go: the rounds hold the tiles only
         with tracing.phase("svm.gram_build"):
             extra.append(jax.block_until_ready(gram_fn(*(
                 (val,) if problem.dense
@@ -1414,30 +1229,27 @@ def compile_svm_fit(
     # the phases end when the device has what they made, so that a profile
     # shows the Gram build and the transfer, not their dispatch
     with tracing.phase("svm.place"):
-        if head:
-            row_len = _pad_blocks(problem.row_len, Kp).reshape(-1)
+        if tiled:
+            # without a head a row's tail is the row
+            row_len = tail_len = _pad_blocks(problem.row_len, Kp).reshape(-1)
             with tracing.phase("svm.bucket"):
-                tail_len = _tail_lengths(
-                    idx.reshape(slots, -1), row_len, problem.col_rank, head)
-                tail = _tail_tiles(idx, val, row_len, tail_len, D)
-            with tracing.phase("svm.head"):
-                block, head_nonzeros = _place_head(
-                    mesh, idx, val, row_len, tail_len, problem.col_rank,
-                    head, dtype)
-            idx, val, slot, row0, n_tiles = tail
+                if head:
+                    tail_len = _tail_lengths(
+                        idx.reshape(slots, -1), row_len, problem.col_rank,
+                        head)
+                ids_t, val_t, slot, row0, n_tiles = _tail_tiles(
+                    idx, val, row_len, tail_len, D)
+            extra += [put(a, shard2) for a in (slot, row0, n_tiles)]
             stored = int(n_tiles.sum()) * _TILE_STEP * _TILE_ROWS
-            extra += [put(a, shard2) for a in (slot, row0, n_tiles)] + [
-                block, put(np.argsort(problem.col_rank)[:head], rep,
-                           jnp.int32)]
-        elif gram_fn is not None and not problem.dense:
-            with tracing.phase("svm.bucket"):
-                plan = _bucket_plan(_pad_blocks(problem.row_len, Kp), D)
-                idx, val, slot = _bucket_rows(idx, val, plan)
-                stored, buckets = sum(a.size for a in idx), len(idx)
-                dw_operands = _sorted_dw_operands(
-                    dw_mode, idx, val, slot, dtype)
-            extra += [put(a, shard2) for a in (slot, *dw_operands)]
-        shard_rows = block_sharding(mesh, rank=4) if head else shard3
+            if head:
+                with tracing.phase("svm.head"):
+                    block, head_nonzeros = _place_head(
+                        mesh, idx, val, row_len, tail_len, problem.col_rank,
+                        head, dtype)
+                extra += [block, put(np.argsort(problem.col_rank)[:head], rep,
+                                     jnp.int32)]
+            idx, val = ids_t, val_t
+        shard_rows = block_sharding(mesh, rank=4) if tiled else shard3
         dev_args = jax.block_until_ready([
             put(np.zeros((problem.n_features,)), rep, dtype),
             idx if problem.dense else put(idx, shard_rows),
@@ -1449,7 +1261,7 @@ def compile_svm_fit(
             *extra,
         ])
     _set_layout_gauges(
-        slots, stored, int(np.count_nonzero(problem.val)), buckets,
+        slots, stored, int(np.count_nonzero(problem.val)),
         extra[0].nbytes if extra else 0, Kp // D,
         stored if problem.dense else slots * head,
         _combine_scales(config, problem.n_blocks)[1], bool(step_lanes),
@@ -1491,32 +1303,6 @@ def _place_head(mesh: Mesh, idx, val, row_len, tail_len, col_rank,
     for piece in zip(*(a.transpose(1, 0, 2) for a in entries)):
         block = scatter(block, *(jax.device_put(a, by_rows) for a in piece))
     return block, int(np.count_nonzero(entries[3]))
-
-
-def _sorted_dw_operands(dw_mode, ids, val, slot, dtype):
-    """The extra operands of FLINK_MS_SVM_DW=sorted|presorted, each (D, a
-    device's stored entries): the bucketed entries in feature order
-    (host-side, once a layout).  sorted -> (perm, ids): the round end
-    gathers the flattened contributions through perm.  presorted ->
-    (val_sorted, ids, src_slot): values are stored already sorted, so the
-    round end's only gather is src_slot into the (C·H) Δα table."""
-    if dw_mode == "direct":
-        return []
-    D = slot.shape[0]
-    flat = np.concatenate([a.reshape(D, -1) for a in ids], axis=1)
-    order = np.argsort(flat, axis=1, kind="stable").astype(np.int32)
-    ids_sorted = np.take_along_axis(flat, order, axis=1)
-    if dw_mode == "sorted":
-        return [order, ids_sorted]
-    # the slot of every stored entry: a bucket's slots under each of its
-    # entry rows
-    ends = np.cumsum([a.shape[2] for a in ids])
-    slot_of = np.concatenate(
-        [np.tile(slot[:, end - a.shape[2]:end], (1, a.shape[1]))
-         for a, end in zip(ids, ends)], axis=1)
-    val_flat = np.concatenate([a.reshape(D, -1) for a in val], axis=1)
-    return [np.take_along_axis(val_flat, order, axis=1).astype(dtype),
-            ids_sorted, np.take_along_axis(slot_of, order, axis=1)]
 
 
 def svm_fit(

@@ -13,7 +13,7 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 # distinct names in the string literals of flink_ms_tpu/, native/ and
 # chip_smoke.py; lower it whenever a name goes
-MAX_ENV_NAMES = 117
+MAX_ENV_NAMES = 113
 
 _KNOB = re.compile(r"\b(?:TPUMS|FLINK_MS)_[A-Z0-9_]+")
 _BENCH = re.compile(r"\bBENCH_[A-Z0-9_]+")

@@ -83,6 +83,13 @@ def test_kernel_is_bit_identical_to_the_xla_step(rng, h_rows, steps, C,
 
 # -- whole fits -------------------------------------------------------------------
 
+def _kernel_on_the_cpu(monkeypatch, on=True):
+    """The rule keeps the kernel to a TPU; a test that wants it interpreted
+    (or the XLA step back) patches the rule, which the fit cache keys on."""
+    monkeypatch.setattr(svm, "resolve_step", lambda platform, inner, *a: (
+        "kernel" if on and inner == "gram" else "dynamic"))
+
+
 def _documents(rng, n, d, nnz_row):
     """Rows of ``nnz_row`` distinct features: sparse below d / 2, dense (by
     the program's own rule) where every feature is named."""
@@ -109,7 +116,7 @@ def test_fit_under_the_kernel_is_the_dynamic_fit(rng, monkeypatch, layout,
     mesh = make_mesh(devices)
     weights, in_kernel = {}, {}
     for step in ("dynamic", "kernel"):
-        monkeypatch.setenv("FLINK_MS_SVM_STEP", step)
+        _kernel_on_the_cpu(monkeypatch, on=step == "kernel")
         weights[step] = svm_fit(data, cfg, mesh, problem=problem).weights
         in_kernel[step] = obs_metrics.get_registry().gauge(
             "tpums_svm_step_kernel_chains").value
@@ -120,7 +127,7 @@ def test_fit_under_the_kernel_is_the_dynamic_fit(rng, monkeypatch, layout,
 
 
 def test_kernel_fit_chained_in_two_segments_is_one_long_fit(rng, monkeypatch):
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "kernel")
+    _kernel_on_the_cpu(monkeypatch)
     data = _documents(rng, 400, 200, 8)
     problem = prepare_svm_blocked(data, 12, seed=0)
     cfg = SVMConfig(local_iterations=problem.rows_per_block, mode="add",
@@ -149,23 +156,23 @@ def test_the_vmem_rule_follows_the_rows_a_chain():
     ("tpu", "scatter", jnp.float32, 49, "dynamic"),  # no Gram step to run
     ("tpu", "gram", jnp.bfloat16, 49, "dynamic"),   # f32 state only
     ("tpu", "gram", jnp.float32, 114, "dynamic"),   # past the VMEM rule
+    ("tpu", "gram", jnp.float32, 113, "kernel"),    # the longest chain held
+    ("tpu", "gram", np.float32, 83, "kernel"),      # a numpy dtype's name
+    ("tpu", "gram", jnp.float16, 49, "dynamic"),
+    ("tpu", "scatter", jnp.bfloat16, 114, "dynamic"),
+    ("cpu", "scatter", jnp.float32, 49, "dynamic"),
+    ("cpu", "gram", jnp.bfloat16, 114, "dynamic"),
+    (None, "gram", jnp.float32, 49, "dynamic"),     # a mesh of no platform
 ])
-def test_auto_resolves_from_what_the_fit_can_see(monkeypatch, platform, inner,
-                                                 dtype, h_rows, resolved):
-    monkeypatch.delenv("FLINK_MS_SVM_STEP", raising=False)
+def test_the_step_resolves_from_what_the_fit_can_see(platform, inner, dtype,
+                                                     h_rows, resolved):
     assert resolve_step(platform, inner, dtype, h_rows, h_rows) == resolved
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "dynamic")
-    assert resolve_step(platform, inner, dtype, h_rows, h_rows) == "dynamic"
 
 
-def test_forced_kernel_runs_or_refuses(monkeypatch):
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "kernel")
-    assert resolve_step("cpu", "gram", jnp.float32, 49, 49) == "kernel"
-    # the scatter engine has no Gram step: the knob does not reach it
-    assert resolve_step("cpu", "scatter", jnp.float32, 49, 49) == "dynamic"
-    for dtype, h_rows in ((jnp.bfloat16, 49), (jnp.float32, 114)):
-        with pytest.raises(ValueError, match="FLINK_MS_SVM_STEP=kernel"):
-            resolve_step("tpu", "gram", dtype, h_rows, h_rows)
+def test_more_local_steps_than_vmem_holds_keep_the_xla_step():
+    # the hoisted draws of 100 local passes do not fit beside the Gram block
+    assert resolve_step("tpu", "gram", jnp.float32, 83, 83) == "kernel"
+    assert resolve_step("tpu", "gram", jnp.float32, 83, 8300) == "dynamic"
 
 
 def _primitives(jaxpr, found):
@@ -180,9 +187,7 @@ def _primitives(jaxpr, found):
 def test_a_cpu_fit_traces_the_kernel_only_when_asked(rng, monkeypatch, step,
                                                      traced):
     if step:
-        monkeypatch.setenv("FLINK_MS_SVM_STEP", step)
-    else:
-        monkeypatch.delenv("FLINK_MS_SVM_STEP", raising=False)
+        _kernel_on_the_cpu(monkeypatch)
     data = _documents(rng, 200, 100, 6)
     problem = prepare_svm_blocked(data, 8, seed=0)
     cfg = SVMConfig(local_iterations=problem.rows_per_block, inner="gram")

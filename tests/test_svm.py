@@ -276,21 +276,22 @@ def test_gram_inner_matches_scatter(rng):
 
 
 def test_gram_kernel_step_bit_identical_to_dynamic(rng, monkeypatch):
-    """FLINK_MS_SVM_STEP=kernel (the Pallas kernel of ops/sdca_pallas.py,
-    interpreted off the chip: the draws hoisted out of the loop, every
-    access a select against the draw) runs the identical index sequence
-    and only selects values or adds exact zeros, so the trained weights
-    must be BIT-identical to the dynamic gather/scatter step that "auto"
-    resolves to on the CPU."""
+    """The Pallas kernel of ops/sdca_pallas.py (interpreted off the chip,
+    where ``resolve_step`` has to be patched to pick it: the draws hoisted
+    out of the loop, every access a select against the draw) runs the
+    identical index sequence and only selects values or adds exact zeros,
+    so the trained weights must be BIT-identical to the dynamic
+    gather/scatter step that every CPU fit runs."""
     data = _sparse_blob(rng, n=500, d=250, nnz_row=10)
     mesh = make_mesh(4)
     p = prepare_svm_blocked(data, 16, seed=0)
     cfg = SVMConfig(iterations=6, local_iterations=p.rows_per_block,
                     regularization=1e-3, mode="add", sigma_prime=4.0,
                     inner="gram")
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "dynamic")
+    from flink_ms_tpu.ops import svm
+
     w_dyn = svm_fit(data, cfg, mesh, problem=p).weights
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", "kernel")
+    monkeypatch.setattr(svm, "resolve_step", lambda *a: "kernel")
     w_k = svm_fit(data, cfg, mesh, problem=p).weights
     np.testing.assert_array_equal(w_k, w_dyn)
 
@@ -320,31 +321,6 @@ def test_segmented_fit_bit_identical_to_one_shot(rng):
             w_r, a_r = fit(jnp.asarray(n, jnp.int32), *args, start=start)
         np.testing.assert_array_equal(np.asarray(w_r), np.asarray(w_one))
         np.testing.assert_array_equal(np.asarray(a_r), np.asarray(a_one))
-
-
-def test_gram_sorted_dw_matches_direct(rng, monkeypatch):
-    """FLINK_MS_SVM_DW=sorted reduces the round-end Xᵀ Δα through a
-    presorted segment-sum instead of an unsorted scatter-add — same
-    numbers (reassociated), multi-device."""
-    data = _sparse_blob(rng, n=500, d=250, nnz_row=10)
-    lam = 1e-3
-    mesh = make_mesh(8)
-    p = prepare_svm_blocked(data, 32, seed=0)
-    cfg = SVMConfig(iterations=6, local_iterations=p.rows_per_block,
-                    regularization=lam, mode="add", sigma_prime=4.0,
-                    inner="gram")
-    w_direct = svm_fit(data, cfg, mesh, problem=p).weights
-    monkeypatch.setenv("FLINK_MS_SVM_DW", "sorted")
-    w_sorted = svm_fit(data, cfg, mesh, problem=p).weights
-    np.testing.assert_allclose(w_sorted, w_direct, rtol=2e-4, atol=1e-6)
-    # presorted (selectable; "auto" stays direct everywhere per the chip
-    # A/B): values stored feature-sorted at prepare time, runtime gathers
-    # only the (C·H) Δα table — same reduction order as "sorted", so
-    # allclose to direct and EQUAL to sorted
-    monkeypatch.setenv("FLINK_MS_SVM_DW", "presorted")
-    w_pre = svm_fit(data, cfg, mesh, problem=p).weights
-    np.testing.assert_allclose(w_pre, w_direct, rtol=2e-4, atol=1e-6)
-    np.testing.assert_array_equal(w_pre, w_sorted)
 
 
 def test_gram_auto_gating(rng, monkeypatch):

@@ -2,7 +2,7 @@
 the data, what `prepare_svm_blocked` builds for it, the compiled round against
 the benchmark's plain dense reference (`benchmark/reference_cocoa_dense.py`:
 numpy, float64, a plain SDCA chain on a local copy of w) in both combines, and
-against the bucketed layout forced on the same rows; its scopes, precisions
+against the sparse layout forced on the same rows; its scopes, precisions
 and gauges; the synth law, the counts and the rehearsal of the cell
 `epsilon-cocoa-plus.dense-rounds`."""
 
@@ -98,7 +98,7 @@ def test_the_rule_is_a_pure_function_of_the_counts(n, d, nnz, itemsize, dense):
     assert svm.stores_rows_dense(n, d, nnz, itemsize) is dense
 
 
-def test_full_rows_take_the_dense_layout_and_sparse_rows_the_bucketed_one():
+def test_full_rows_take_the_dense_layout_and_sparse_rows_the_sparse_one():
     dense, _ = full_rows()
     assert prepare_svm_blocked(dense, 4).dense
     rng = np.random.default_rng(0)
@@ -259,7 +259,7 @@ def test_dense_round_agrees_with_the_dense_reference(mode, sigma_prime, devices,
 @pytest.mark.parametrize("mode", ["avg", "add"])
 @pytest.mark.parametrize("inner", ["gram", "scatter"])
 @pytest.mark.parametrize("devices", [1, 4])
-def test_dense_layout_agrees_with_the_bucketed_layout_forced_on_the_same_rows(
+def test_dense_layout_agrees_with_the_sparse_layout_forced_on_the_same_rows(
         devices, inner, mode, monkeypatch):
     data, X = full_rows(in_order=False)
     dense, _, w, alpha = run_program(data, 6, mode, 2, devices=devices, inner=inner)
@@ -301,7 +301,7 @@ def lowered_round(inner="gram"):
     cfg = SVMConfig(local_iterations=problem.rows_per_block,
                     regularization=LAM, seed=SEED, mode="add", inner=inner)
     fit, args = compile_svm_fit(problem, cfg, make_mesh(1))
-    _, gram_fn, _, _ = svm._cached_fit(problem, cfg, make_mesh(1))
+    _, gram_fn, _ = svm._cached_fit(problem, cfg, make_mesh(1))
     return (jax.jit(lambda *a: fit(1, *a)).lower(*args).as_text(debug_info=True),
             gram_fn.lower(args[2]).as_text(debug_info=True) if gram_fn else "")
 
@@ -365,17 +365,24 @@ def test_gauges_read_what_the_layout_implies_on_both_layouts(
     assert got["tpums_svm_rows"] == slots and got["tpums_svm_row_width"] == 24
     assert got["tpums_svm_dense_entries"] == slots * 24
     assert got["tpums_svm_pad_entries"] == (slots - 61) * 24  # pad rows x d
-    assert got["tpums_svm_buckets"] == 0
     assert got["tpums_svm_gram_bytes"] == padded_chains * 11 * 11 * 4
     assert got["tpums_svm_sigma_prime"] == want_sigma
     assert "dense rows" in svm.layout_report()
     assert f"sigma' {want_sigma:g}" in svm.layout_report()
     monkeypatch.setattr(svm, "stores_rows_dense", lambda *a: False)
-    run_program(data, 6, mode, 1, sigma_prime, devices)
+    _, args, _, _ = run_program(data, 6, mode, 1, sigma_prime, devices)
     got = gauges()
-    assert got["tpums_svm_dense_entries"] == 0 and got["tpums_svm_buckets"] == 1
+    # the same rows sparse: 24 entries a row, three steps of 8 in one block
+    # of tile rows on every device that holds a chain (of four devices the
+    # last holds the two empty ones), no cell held dense
+    in_use = [[3]] * min(devices, 3) + [[0]] * (devices - 3)
+    assert np.asarray(args[10]).tolist() == in_use
+    assert got["tpums_svm_rows"] * got["tpums_svm_row_width"] == pytest.approx(
+        3 * min(devices, 3) * svm._TILE_STEP * svm._TILE_ROWS)
+    assert got["tpums_svm_dense_entries"] == got["tpums_svm_head_columns"] == 0
     assert got["tpums_svm_sigma_prime"] == want_sigma
     assert "sparse rows" in svm.layout_report()
+    assert "in tiles" in svm.layout_report()
 
 
 def test_svm_train_says_which_layout_served_a_dense_file(tmp_path, capsys):

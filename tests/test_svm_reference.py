@@ -1,8 +1,8 @@
 """The CoCoA program against the benchmark's plain reference
 (`benchmark/reference_cocoa.py`: numpy, float64, CSR, nothing of the padded
-arrays, the length buckets, the Gram matrix or the dw modes), on rows of
-unequal length; the Gram engine's length-bucketed rows (PR 32) against the
-CSR and the padded rectangles they are cut from; the synthetic documents of
+arrays, the tiles or the Gram matrix), on rows of unequal length; the Gram
+engine's tiles of whole rows (no head: every CPU fit) against the CSR and the
+padded rectangles they are cut from; the synthetic documents of
 `benchmark/synth_cocoa.py`; and what PR 31 added to `ops/svm.py` for
 whoever profiles it: named scopes, gauges, a counter."""
 
@@ -104,9 +104,7 @@ def test_bfloat16_state_misses_the_same_tolerance(inner):
     assert ref.rel_err(by_example(alpha, slots, data.n_examples), a_ref) > 20 * TOL
 
 
-@pytest.mark.parametrize("dw", ["direct", "sorted", "presorted"])
-def test_every_dw_mode_agrees_and_carries_its_scope(dw, monkeypatch):
-    monkeypatch.setenv("FLINK_MS_SVM_DW", dw)
+def test_the_scatter_add_agrees_and_carries_its_scope():
     data = uneven_documents()
     problem, w, _ = run_program(data, 4, "gram", "avg", 2)
     _, w_ref, _ = run_reference(data, 4, "avg", 2)
@@ -142,7 +140,7 @@ def test_the_scatter_engine_has_no_margins_scope(lowered):
 def test_the_gram_build_carries_its_scope():
     problem = prepare_svm_blocked(uneven_documents(), 4, seed=SEED)
     cfg = SVMConfig(local_iterations=problem.rows_per_block, inner="gram")
-    _, gram_fn, _, _ = svm._cached_fit(problem, cfg, make_mesh(1))
+    _, gram_fn, _ = svm._cached_fit(problem, cfg, make_mesh(1))
     text = gram_fn.lower(jnp.asarray(problem.idx), jnp.asarray(problem.val)
                          ).as_text(debug_info=True)
     assert "svm.gram" in text
@@ -166,17 +164,22 @@ def test_gauges_and_round_counter_read_what_the_layout_implies(inner, chains, de
     got = gauges()
     assert got["tpums_svm_rows"] == padded_chains * rows
     if inner == "gram":
-        # what the round streams: the bucketed rows, pad rows of a bucket
-        # included; row_width is their mean over the row slots
-        stored = sum(a.size for a in args[1])
-        assert stored == sum(a.size for a in args[2])
-        assert stored < padded_chains * rows * 120
-        assert got["tpums_svm_buckets"] == len(args[1]) > 1
+        # what the round streams: the tiles that hold an entry, the pads
+        # inside them included; row_width is their mean over the row slots
+        assert args[1].shape == args[2].shape == (
+            devices, args[9].shape[1], svm._TILE_STEP, svm._TILE_ROWS)
+        in_use = np.asarray(args[10])
+        # the device with the row of 120 needs 15 steps of 8, the others 2
+        # (rows of 9 entries at most), the one of two empty chains none
+        assert in_use.shape == (devices, 1) and sorted(in_use[:, 0]) == (
+            [0, 2, 2, 15] if devices == 4 else [15])
+        assert not np.asarray(args[2])[0, in_use[0, 0]:].any()
+        stored = int(in_use.sum()) * svm._TILE_STEP * svm._TILE_ROWS
+        assert len(args) == 11 and got["tpums_svm_head_columns"] == 0
     else:
         stored = padded_chains * rows * 120  # every row padded to the longest
         assert args[1].size == args[2].size == stored
         assert got["tpums_svm_row_width"] == 120
-        assert got["tpums_svm_buckets"] == 0
     assert got["tpums_svm_rows"] * got["tpums_svm_row_width"] == pytest.approx(
         stored, rel=1e-12)
     assert got["tpums_svm_pad_entries"] == stored - len(data.indices)
@@ -191,16 +194,17 @@ def test_gauges_and_round_counter_read_what_the_layout_implies(inner, chains, de
     assert counter.value - before == 5
 
 
-# -- the Gram engine's length-bucketed rows (PR 32) ----------------------------
+# -- the Gram engine's tiles of whole rows (no head) ---------------------------
 
-def bucketed(data, chains, devices):
-    """(problem, plan, ids, val, slot) as `compile_svm_fit` lays them out."""
+def tiled(data, chains, devices):
+    """(problem, ids, val, slot, row0, n_tiles) as `compile_svm_fit` places
+    them for a fit without a head, back on the host."""
     problem = prepare_svm_blocked(data, chains, seed=SEED)
-    padded = -(-chains // devices) * devices
-    pad = lambda a: svm._pad_blocks(a, padded)
-    plan = svm._bucket_plan(pad(problem.row_len), devices)
-    return (problem, plan,
-            *svm._bucket_rows(pad(problem.idx), pad(problem.val), plan))
+    cfg = SVMConfig(local_iterations=problem.rows_per_block,
+                    regularization=LAM, seed=SEED, inner="gram")
+    _, args = compile_svm_fit(problem, cfg, make_mesh(devices))
+    assert len(args) == 11  # no head block, no head ids
+    return (problem, *(np.asarray(args[i]) for i in (1, 2, 8, 9, 10)))
 
 
 def equal_length_documents(n=50, d=40, length=12, seed=5):
@@ -214,25 +218,28 @@ def equal_length_documents(n=50, d=40, length=12, seed=5):
 
 
 @pytest.mark.parametrize("chains, devices", [(1, 1), (4, 1), (6, 4), (16, 4), (1, 4)])
-def test_buckets_hold_every_nonzero_once_at_its_rows_slot(chains, devices):
+def test_tiles_hold_every_nonzero_once_at_its_rows_slot(chains, devices,
+                                                        monkeypatch):
     data = uneven_documents()
     n = data.n_examples
-    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(
-        data, chains, devices)
-    per_device = bucket_of.shape[1]
+    # blocks of 8 tile rows: several blocks a device, as at the cell's size
+    monkeypatch.setattr(svm, "_TILE_ROWS", 8)
+    problem, ids, val, slot, row0, n_tiles = tiled(data, chains, devices)
+    per_device = -(-chains // devices) * problem.rows_per_block
     example_of = np.full(devices * per_device, -1)
     example_of[:n] = np.random.default_rng(SEED).permutation(n)
     got = []
-    for b, (i, v) in enumerate(zip(ids, val)):
-        assert i.shape == v.shape == (devices, widths[b], rows[b])
-        at = sum(rows[:b])
-        for dev in range(devices):
-            entry, row = np.nonzero(v[dev])
-            examples = example_of[dev * per_device + slot[dev, at + row]]
-            got.append(np.stack([examples, i[dev, entry, row],
-                                 v[dev, entry, row].view(np.int32)], axis=1))
-            # a stored entry without a value is a pad: id 0
-            assert not i[dev][v[dev] == 0].any()
+    for dev in range(devices):
+        used = n_tiles[dev, 0]
+        # nothing is stored past the tiles the round loops over
+        assert not val[dev, used:].any() and not ids[dev, used:].any()
+        tile, entry, row = np.nonzero(val[dev, :used])
+        examples = example_of[
+            dev * per_device + slot[dev, row0[dev, tile] + row]]
+        got.append(np.stack([examples, ids[dev, tile, entry, row],
+                             val[dev, tile, entry, row].view(np.int32)], axis=1))
+        # a stored entry without a value is a pad: id 0
+        assert not ids[dev][val[dev] == 0].any()
     got = np.concatenate(got)
     want = np.stack([np.repeat(np.arange(n), np.diff(data.indptr)),
                      data.indices,
@@ -245,64 +252,72 @@ def test_buckets_hold_every_nonzero_once_at_its_rows_slot(chains, devices):
     assert np.array_equal(lens, (problem.val != 0).sum(-1).reshape(-1))
 
 
-def test_rows_without_entries_fall_in_no_bucket_and_the_longest_gets_its_own():
+def test_empty_rows_and_chains_cost_no_tile_and_a_block_follows_its_longest_row(
+        monkeypatch):
     data = uneven_documents()  # row 20 is empty, row 7 holds 120 entries
-    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(data, 6, 4)
-    flat = bucket_of.reshape(-1)
-    lens = svm._pad_blocks(problem.row_len, 8).reshape(-1)
+    monkeypatch.setattr(svm, "_TILE_ROWS", 8)
+    problem, ids, val, slot, row0, n_tiles = tiled(data, 6, 4)
+    lens = svm._pad_blocks(problem.row_len, 8).reshape(4, 22)
+    assert lens.reshape(-1)[61:].sum() == 0  # 5 pad rows, 2 empty chains
     order = np.random.default_rng(SEED).permutation(data.n_examples)
-    assert lens[61:].sum() == 0 and len(lens) == 88  # 5 pad rows, 2 empty chains
-    assert (flat[61:] == -1).all()
-    assert flat[np.flatnonzero(order == 20)[0]] == -1
-    assert (flat[:61] >= 0).sum() == 60
-    assert widths[-1] == 120 and widths[0] == 8 and len(widths) == 3
-    top = val[-1]
-    assert sum((top[dev] != 0).any(axis=0).sum() for dev in range(4)) == 1
-    where = np.flatnonzero(order == 7)[0]
-    assert flat[where] == len(widths) - 1
-    dev, local = divmod(where, bucket_of.shape[1])
-    assert slot[dev, sum(rows[:-1])] == local
-    # every other bucket row of the top bucket is a pad aimed at slot 0
-    assert slot[:, sum(rows[:-1]):].sum() == local
+    # the last device holds the two empty chains: no tile at all
+    assert lens[3].sum() == 0 and n_tiles[3, 0] == 0 and not val[3].any()
+    for dev in range(4):
+        # rows longest first, three blocks of 8: each as deep as its first row
+        by_length = np.sort(lens[dev])[::-1]
+        assert np.array_equal(lens[dev][slot[dev, :22]], by_length)
+        steps = -(-by_length[::8] // 8)
+        assert n_tiles[dev, 0] == steps.sum()
+        assert np.array_equal(row0[dev, :steps.sum()],
+                              np.repeat(np.arange(3) * 8, steps))
+    # the row of 120 leads its device's first block, 15 steps deep, alone
+    # past the second step; the other devices' rows hold 9 entries at most
+    dev, local = divmod(np.flatnonzero(order == 7)[0], 22)
+    assert slot[dev, 0] == local and (n_tiles[:3, 0] >= 15).sum() == 1
+    assert (val[dev, 2:15] != 0).any(axis=(0, 1)).sum() == 1
+    # the empty document sorts behind every row that holds an entry
+    dev, local = divmod(np.flatnonzero(order == 20)[0], 22)
+    assert np.flatnonzero(slot[dev, :22] == local)[0] >= (lens[dev] > 0).sum()
 
 
-def test_equal_lengths_give_one_bucket_that_is_the_padded_rectangle():
-    data = equal_length_documents()
-    problem, (widths, rows, bucket_of), ids, val, slot = bucketed(data, 5, 1)
-    assert widths == (12,) and rows == (50,) and (bucket_of == 0).all()
-    assert np.array_equal(ids[0][0], problem.idx.reshape(50, 12).T)
-    assert np.array_equal(val[0][0], problem.val.reshape(50, 12).T)
-    assert np.array_equal(slot[0], np.arange(50))
+@pytest.mark.parametrize("length", [12, 8, 1])
+def test_rows_of_one_length_store_no_position_past_the_next_multiple_of_8(
+        length):
+    data = equal_length_documents(length=length)
+    problem, ids, val, slot, row0, n_tiles = tiled(data, 5, 1)
+    depth = -(-length // 8)
+    assert n_tiles[0, 0] == depth == ids.shape[1]  # one block of 50 rows
+    assert np.array_equal(slot[0, :50], np.arange(50))  # nothing to reorder
+    stored = val[0].reshape(depth * 8, -1)
+    assert np.array_equal(stored[:length, :50],
+                          problem.val.reshape(50, length).T)
+    assert np.array_equal(ids[0].reshape(depth * 8, -1)[:length, :50],
+                          problem.idx.reshape(50, length).T)
+    assert not stored[length:].any() and not stored[:, 50:].any()
     _, w_gram, a_gram = run_program(data, 5, "gram", "avg", 2, devices=1)
     _, w_padded, a_padded = run_program(data, 5, "scatter", "avg", 2, devices=1)
     assert ref.rel_err(w_gram, w_padded) < TOL
     assert ref.rel_err(a_gram, a_padded) < TOL
 
 
-@pytest.mark.parametrize("lo, hi", [(1, 1), (4, 256), (3, 13), (1, 100000), (120, 120)])
-def test_the_ladder_is_short_rising_and_ends_at_the_longest_row(lo, hi):
-    widths = svm._bucket_widths(lo, hi)
-    assert widths[-1] == hi and len(widths) <= svm._BUCKET_CAP
-    assert all(a < b for a, b in zip(widths, widths[1:]))
-    assert all(w % svm._BUCKET_STEP == 0 for w in widths[:-1])
-    assert widths[0] >= lo
-    if lo == hi:
-        assert widths == [hi]
-
-
-def test_the_cells_lengths_keep_their_padding_under_a_fifth():
+def test_the_cells_lengths_keep_their_padding_under_a_sixteenth():
+    """Whole rows in tiles, longest first: 4.7% of the stored positions are
+    pads at the cell's lengths (the ladder of length buckets that the tiles
+    replaced stored 10.7%, the rectangle padded to 256 71.5%)."""
     with open(os.path.join(REPO, "benchmark", "configs", "rcv1-cocoa.json")) as f:
         cfg = json.load(f)
     lens = synth_cocoa.row_lengths(cfg)
-    widths = np.asarray(svm._bucket_widths(int(lens.min()), int(lens.max())))
-    stored = widths[np.searchsorted(widths, lens)].sum()
-    assert 1 - lens.sum() / stored < 0.12 < 0.715  # the padded rectangle's share
+    chains = cfg["blocks"]
+    slots = np.zeros(chains * -(-len(lens) // chains), np.int64)
+    slots[:len(lens)] = lens
+    stored = svm._tile_steps(slots).sum() * svm._TILE_STEP * svm._TILE_ROWS
+    assert 1 - lens.sum() / stored < 0.0625 < 0.12 < 0.715
 
 
 @pytest.mark.parametrize("mode", ["avg", "add"])
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("chains", [1, 4, 16])
-def test_bucketed_round_agrees_with_the_reference_and_the_padded_round(
+def test_tiled_round_agrees_with_the_reference_and_the_padded_round(
         chains, devices, mode):
     data = uneven_documents()
     _, w, alpha = run_program(data, chains, "gram", mode, 2, devices=devices)
@@ -314,6 +329,20 @@ def test_bucketed_round_agrees_with_the_reference_and_the_padded_round(
                                         devices=devices)
     assert ref.rel_err(w, w_padded) < TOL
     assert ref.rel_err(alpha, a_padded) < TOL
+
+
+@pytest.mark.parametrize("mode", ["avg", "add"])
+@pytest.mark.parametrize("devices", [1, 4])
+def test_a_round_over_several_blocks_of_tile_rows_agrees_with_the_reference(
+        devices, mode, monkeypatch):
+    """61 rows are one block of 1,024 tile rows; in blocks of 8 the round's
+    loop moves from block to block (`row0`), as it does at the cell's size."""
+    monkeypatch.setattr(svm, "_TILE_ROWS", 8)
+    data = uneven_documents()
+    _, w, alpha = run_program(data, 4, "gram", mode, 2, devices=devices)
+    slots, w_ref, a_ref = run_reference(data, 4, mode, 2)
+    assert ref.rel_err(w, w_ref) < TOL
+    assert ref.rel_err(by_example(alpha, slots, data.n_examples), a_ref) < TOL
 
 
 def boundary_sizes(jaxpr, d):
@@ -343,32 +372,17 @@ def test_the_rounds_gather_and_scatter_run_over_the_stored_entries(inner):
     sizes = boundary_sizes(
         jax.make_jaxpr(lambda *a: fit(1, *a))(*args).jaxpr,
         data.n_features)
-    padded = problem.idx.size
     if inner == "gram":
-        stored = sum(a.size for a in args[1])
-        assert sizes == {"gather": stored, "scatter-add": stored}
+        # one gather and one scatter-add, each a tile wide, in a loop over
+        # the tiles in use: together the stored entries
+        tile = svm._TILE_STEP * svm._TILE_ROWS
+        assert sizes == {"gather": tile, "scatter-add": tile}
+        stored = int(np.asarray(args[10]).sum()) * tile
         assert stored == gauges()["tpums_svm_rows"] * gauges()["tpums_svm_row_width"]
-        assert len(data.indices) <= stored < padded
+        assert len(data.indices) <= stored
     else:
         # one row of every chain a step, each of the padded width
         assert sizes["gather"] == sizes["scatter-add"] == 4 * 120
-
-
-@pytest.mark.parametrize("choice, resolved", [
-    ("auto", "dynamic"), ("dynamic", "dynamic"), ("kernel", "kernel")])
-def test_step_knob_accepts_its_three_values(choice, resolved, monkeypatch):
-    """What a Gram fit on the CPU runs under each value: "auto" keeps the
-    XLA step there, "kernel" interprets the Pallas kernel."""
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", choice)
-    assert svm._step_choice() == choice
-    assert svm.resolve_step("cpu", "gram", np.float32, 83, 83) == resolved
-
-
-@pytest.mark.parametrize("typo", ["onehot", "Dynamic", ""])
-def test_step_knob_rejects_anything_else(typo, monkeypatch):
-    monkeypatch.setenv("FLINK_MS_SVM_STEP", typo)
-    with pytest.raises(ValueError, match="FLINK_MS_SVM_STEP"):
-        svm._step_choice()
 
 
 # -- the synthetic documents ---------------------------------------------------

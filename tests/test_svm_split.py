@@ -1,7 +1,8 @@
 """The column split of the Gram engine's sparse layout (`ops/svm.py`, PR 41):
 the rule that sizes the dense head from what a fit can observe, the host
 pieces that cut every entry into exactly one of head and tail, the compiled
-round against the same fit with no head in both combines, the shapes' and the
+round against the same fit with no head (the same program without the head's
+block, ids and two products) in both combines, the shapes' and the
 program's independence of which features a seed drew, the round's scopes, the
 gauges and the trainer's line."""
 
@@ -109,20 +110,24 @@ def test_a_cpu_fit_takes_no_head_and_a_device_with_memory_takes_one(monkeypatch)
     assert gauges()["tpums_svm_head_columns"] == want > 0
 
 
-@pytest.mark.parametrize("env, dtype", [
-    ({"FLINK_MS_SVM_DW": "sorted"}, jnp.float32),
-    ({"FLINK_MS_SVM_DW": "presorted"}, jnp.float32),
-    ({}, jnp.bfloat16)])
-def test_the_dw_knob_and_bf16_state_keep_whole_rows(env, dtype, monkeypatch):
-    for name, value in env.items():
-        monkeypatch.setenv(name, value)
+def test_bf16_state_on_a_device_with_memory_takes_no_head_and_runs_tiles(
+        monkeypatch):
     monkeypatch.setattr(svm, "device_memory", lambda device: 1 << 30)
     problem = prepare_svm_blocked(heavy_tailed(1), 8, seed=0)
     cfg = SVMConfig(local_iterations=problem.rows_per_block,
-                    regularization=LAM, inner="gram", dtype=dtype)
-    compile_svm_fit(problem, cfg, make_mesh(1))
-    assert gauges()["tpums_svm_head_columns"] == 0
-    assert gauges()["tpums_svm_buckets"] > 0
+                    regularization=LAM, inner="gram", dtype=jnp.bfloat16)
+    fit, args = compile_svm_fit(problem, cfg, make_mesh(1))
+    assert gauges()["tpums_svm_head_columns"] == 0 and len(args) == 11
+    # whole rows in tiles: 1,504 slots are two blocks of tile rows, as deep
+    # as their longest rows (40 and 8 entries: 5 steps and 1)
+    assert args[2].dtype == jnp.bfloat16 and args[2].shape == (
+        1, 6, svm._TILE_STEP, svm._TILE_ROWS)
+    assert int(np.asarray(args[10])[0, 0]) == 6
+    assert np.count_nonzero(np.asarray(args[2], np.float32)) == len(
+        heavy_tailed(1).values)
+    assert "in tiles" in svm.layout_report()
+    w, _ = fit(2, *args)
+    assert w.dtype == jnp.bfloat16 and np.abs(np.asarray(w, np.float32)).max() > 0
 
 
 def test_a_head_wider_than_the_matrix_is_refused():
@@ -264,7 +269,7 @@ def test_the_head_block_is_the_same_whatever_its_pieces(devices, monkeypatch):
     assert np.count_nonzero(whole) == gauges()["tpums_svm_head_nonzeros"]
 
 
-@pytest.mark.parametrize("head", HEADS)
+@pytest.mark.parametrize("head", HEADS + (0,))
 def test_chained_segments_of_a_split_fit_equal_one_long_fit(head):
     problem = prepare_svm_blocked(heavy_tailed(7), 16, seed=0)
     cfg = config(problem, "add")
@@ -277,21 +282,25 @@ def test_chained_segments_of_a_split_fit_equal_one_long_fit(head):
     np.testing.assert_array_equal(np.asarray(alpha), np.asarray(a_one))
 
 
-def test_two_seeds_of_one_length_sequence_share_shapes_and_the_program():
+@pytest.mark.parametrize("head", [128, 0])
+def test_two_seeds_of_one_length_sequence_share_shapes_and_the_program(head):
     """Which features a seed drew moves the tail lengths, the tiles in use
-    and the head's entries, and none of the operands' shapes: one compiled
-    program, one cache entry."""
+    and the head's entries (with no head: the stored ids alone), and none of
+    the operands' shapes: one compiled program, one cache entry."""
     mesh = make_mesh(1)
     svm._FIT_CACHE.clear()
-    shapes, in_head, programs = [], [], []
+    shapes, in_head, ids, programs = [], [], [], []
     for seed in (11, 12):
         problem = prepare_svm_blocked(heavy_tailed(seed), 8, seed=0)
         cfg = config(problem)
-        fit, args = compile_svm_fit(problem, cfg, mesh, head_columns=128)
+        fit, args = compile_svm_fit(problem, cfg, mesh, head_columns=head)
         shapes.append([(a.shape, a.dtype) for a in jax.tree.leaves(args)])
         in_head.append(gauges()["tpums_svm_head_nonzeros"])
+        ids.append(np.asarray(args[1]))
         programs.append(jax.jit(lambda *a: fit(1, *a)).lower(*args).as_text())
-    assert in_head[0] != in_head[1]  # the seeds drew other features
+    # the seeds drew other features
+    assert (in_head[0] != in_head[1]) == (head > 0)
+    assert not np.array_equal(ids[0], ids[1])
     assert shapes[0] == shapes[1] and programs[0] == programs[1]
     assert len(svm._FIT_CACHE) == 1
 
@@ -318,14 +327,21 @@ def under(ops, scope):
     return [name for stack, name in ops if scope in stack]
 
 
-def test_the_split_round_scopes_both_the_product_and_the_tails_loop():
+@pytest.mark.parametrize("head", [128, 0])
+def test_the_split_round_scopes_both_the_product_and_the_tails_loop(head):
+    """Each pass is one loop over the tiles, whatever their number (no
+    fusion a length bucket), and with a head one product beside it; without
+    a head the same round holds no product at all."""
     problem = prepare_svm_blocked(heavy_tailed(8), 8, seed=0)
     fit, args = compile_svm_fit(problem, config(problem), make_mesh(1),
-                                head_columns=128)
+                                head_columns=head)
     ops = ops_by_scope(fit, args)
     for scope, touch in (("svm.margins", "gather"), ("svm.dw", "scatter-add")):
         mine = under(ops, scope)
-        assert "while" in mine and "dot_general" in mine and touch in mine
+        assert mine.count("while") == 1
+        assert mine.count("dot_general") == (1 if head else 0)
+        # the tail's, and with a head the F ids' of w
+        assert mine.count(touch) == (2 if head else 1)
     # nothing of either pass escapes its scope
     outside = [name for stack, name in ops
                if not any(s in stack for s in ("svm.margins", "svm.dw",
@@ -333,30 +349,23 @@ def test_the_split_round_scopes_both_the_product_and_the_tails_loop():
     assert not {"dot_general", "gather", "scatter-add"} & set(outside)
 
 
-@pytest.mark.parametrize("layout", ["dense", "bucketed"])
-def test_the_other_layouts_rounds_have_no_loop_over_tiles(layout):
-    """The dense layout's two passes are products, no gather and no while;
-    the bucketed layout (no head) gathers with no while and no product:
-    both lower as they did before the split."""
-    if layout == "dense":
-        rng = np.random.default_rng(3)
-        X = rng.standard_normal((61, 24))
-        data = SparseData(
-            labels=np.where(rng.random(61) < 0.5, 1.0, -1.0),
-            indptr=np.arange(62) * 24, indices=np.tile(np.arange(24), 61),
-            values=X.reshape(-1), n_features=24)
-    else:
-        data = heavy_tailed(8)
+def test_the_other_layouts_rounds_have_no_loop_over_tiles():
+    """The dense layout's two passes are products, no gather and no while:
+    it lowers as it did before the split."""
+    rng = np.random.default_rng(3)
+    X = rng.standard_normal((61, 24))
+    data = SparseData(
+        labels=np.where(rng.random(61) < 0.5, 1.0, -1.0),
+        indptr=np.arange(62) * 24, indices=np.tile(np.arange(24), 61),
+        values=X.reshape(-1), n_features=24)
     problem = prepare_svm_blocked(data, 4, seed=0)
-    assert problem.dense == (layout == "dense")
+    assert problem.dense
     fit, args = compile_svm_fit(problem, config(problem), make_mesh(1))
     ops = ops_by_scope(fit, args)
     for scope in ("svm.margins", "svm.dw"):
         mine = under(ops, scope)
-        assert "while" not in mine
-        assert ("dot_general" in mine) == (layout == "dense")
-        assert bool({"gather", "scatter-add"} & set(mine)) == (
-            layout == "bucketed")
+        assert "while" not in mine and "dot_general" in mine
+        assert not {"gather", "scatter-add"} & set(mine)
 
 
 # -- gauges and the trainer's line ---------------------------------------------------
@@ -367,34 +376,50 @@ def gauges():
             if g["name"].startswith("tpums_svm_") and not g["labels"]}
 
 
+@pytest.mark.parametrize("head", [128, 0, None])
 @pytest.mark.parametrize("devices", [1, 4])
-def test_gauges_read_what_the_split_layout_streams(devices):
+def test_gauges_read_what_the_split_layout_streams(devices, head):
+    """With a head; with `head_columns=0`; and as a CPU fit decides for
+    itself (no memory reported: no head)."""
     data = heavy_tailed(9)
     problem = prepare_svm_blocked(data, 8, seed=0)
     mesh = make_mesh(devices)
-    _, args = compile_svm_fit(problem, config(problem), mesh, head_columns=128)
+    _, args = compile_svm_fit(problem, config(problem), mesh, head_columns=head)
+    head = head or 0
     got = gauges()
     slots = problem.n_blocks * problem.rows_per_block
-    in_head = int((problem.col_rank[data.indices] < 128).sum())
+    in_head = int((problem.col_rank[data.indices] < head).sum())
     stored = int(np.asarray(args[10]).sum()) * svm._TILE_STEP * svm._TILE_ROWS
-    assert got["tpums_svm_head_columns"] == 128
+    assert got["tpums_svm_head_columns"] == head
     assert got["tpums_svm_nonzeros"] == len(data.values)
     assert got["tpums_svm_head_nonzeros"] == in_head
-    assert got["tpums_svm_dense_entries"] == slots * 128
-    assert got["tpums_svm_rows"] == slots and got["tpums_svm_buckets"] == 0
+    assert got["tpums_svm_dense_entries"] == slots * head
+    assert got["tpums_svm_rows"] == slots
     assert got["tpums_svm_rows"] * got["tpums_svm_row_width"] == pytest.approx(stored)
     assert got["tpums_svm_pad_entries"] == stored - (len(data.values) - in_head)
-    assert np.asarray(args[11]).shape == (slots, 128)
+    # the tiles hold what the head does not
+    assert np.count_nonzero(np.asarray(args[2])) == len(data.values) - in_head
     report = svm.layout_report()
-    assert "split by column (128 head columns" in report
-    assert f"{100 * in_head / len(data.values):.1f}% of the non-zeros" in report
-    # no head: the gauges name none and the bucketed layout's stay as they were
-    compile_svm_fit(problem, config(problem), mesh)
-    got = gauges()
-    assert got["tpums_svm_head_columns"] == got["tpums_svm_head_nonzeros"] == 0
-    assert got["tpums_svm_nonzeros"] == len(data.values)
-    assert got["tpums_svm_dense_entries"] == 0 and got["tpums_svm_buckets"] > 0
-    assert "length buckets" in svm.layout_report()
+    if head:
+        assert np.asarray(args[11]).shape == (slots, 128)
+        assert np.asarray(args[12]).shape == (128,)
+        assert "split by column (128 head columns" in report
+        assert f"{100 * in_head / len(data.values):.1f}% of the non-zeros" in report
+    else:
+        # no block and no ids among the operands, and the line names none
+        assert len(args) == 11
+        assert f"sparse rows ({slots} x " in report and "in tiles)" in report
+
+
+@pytest.mark.parametrize("inner, how", [
+    ("gram", "in tiles)"), ("scatter", "one padded rectangle)")])
+def test_the_trainers_line_names_how_sparse_rows_without_a_head_are_stored(
+        inner, how):
+    problem = prepare_svm_blocked(heavy_tailed(9), 8, seed=0)
+    cfg = SVMConfig(local_iterations=problem.rows_per_block,
+                    regularization=LAM, inner=inner)
+    compile_svm_fit(problem, cfg, make_mesh(1))
+    assert how in svm.layout_report() and "split" not in svm.layout_report()
 
 
 def test_svm_train_names_the_split(tmp_path, capsys, monkeypatch):
