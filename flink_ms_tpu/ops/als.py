@@ -17,7 +17,7 @@ rungs rounded to multiples of 8 — FLINK_MS_ALS_BUCKET_RATIO) and each
 group's rating lists are padded to the class width, so normal-equation
 assembly is a short list of dense batched contractions — a row gather,
 then MXU matmuls, no scatter.  On a TPU, with an f32 exchange and a rank up
-to 64, in either mode, each bucket's gathered rows are contracted where
+to 128, in either mode, each bucket's gathered rows are contracted where
 the gather left them by one Pallas kernel (``assemble_pallas.py``: A
 and b from a single read, the transpose and implicit mode's confidence
 weights done in VMEM, written batch-minor
@@ -33,7 +33,8 @@ streams the whole (n, k, k) tensor per elimination step.)
 
 Two training modes, each timed by a cell of the benchmark:
 
-- explicit feedback (FlinkML parity; ``als-ml20m.retrain``): weighted-λ
+- explicit feedback (FlinkML parity; ``als-ml20m.retrain`` at rank 50,
+  ``netflix-als-f100.retrain`` at rank 100): weighted-λ
   regularization (reg_u = n_u, Zhou et al. ALS-WR) or plain λ;
 - implicit feedback (confidence-weighted, Hu-Koren-Volinsky;
   ``msd-ials.ials-retrain``):
@@ -965,19 +966,27 @@ def resolve_exchange(exchange_dtype: Optional[str],
     return exchange_dtype
 
 
+# The widest rank the assembly kernel takes: the top of what its tiles and
+# the Pallas solver's state (``assemble_pallas.tile_sizes``,
+# ``cholesky_pallas.solver_tile``).  Up to 64 the cells at ranks 50 and 64
+# priced it (PRs 26, 30, 42).  From 65 to 128 two chip readings at rank 100
+# set it (``netflix-als-f100.retrain``, 480,189 x 17,770, 99,072,112
+# ratings, one seed, 20 s windows, TPU v5e, PR 44): the kernel 1.534509
+# s/iter and 7.23 GB held, the einsum pair 1.936428 and 10.87 GB.
+_KERNEL_MAX_RANK = 128
+
+
 def resolve_assembly(platform: Optional[str], y_dtype, dtype, k: int,
                      precision: str = "highest") -> str:
     """How a sweep's buckets contract their gathered rows: "kernel"
     (``assemble_pallas.assemble_bucket``: A and b from one read of y, no
     relayout copy) or "einsum" (the pair XLA schedules itself).  The kernel
     engages where a chip run priced it — a TPU, an f32
-    exchange and solve, full-f32 or one-pass products, rank up to 64 (the
-    cells' are 50 and 64; the widest tiles compile and agree with float64
-    on the chip up to k = 128, but the MXU work grows with k squared where
-    the bytes do not, and nothing has timed that against the einsum pair:
-    PERF.md section 6, PR 26) — and everything else keeps the einsum pair
+    exchange and solve, full-f32 or one-pass products, rank 10-128
+    (``_KERNEL_MAX_RANK``, with the two readings at rank 100 that set it
+    above 64) — and everything else keeps the einsum pair
     unchanged: the bf16 exchange (``als_train``'s default on a TPU),
-    three-pass products and every CPU fit.  The mode does not enter:
+    three-pass products, ranks above 128 and every CPU fit.  The mode does not enter:
     implicit feedback's weights are one multiply in the kernel's VMEM
     (PERF.md section 6, PR 42).  It holds no width: on the chip the kernel
     is ahead at every width of the ML-20M ladder, w = 24 included."""
@@ -987,7 +996,7 @@ def resolve_assembly(platform: Optional[str], y_dtype, dtype, k: int,
         return "einsum"
     if precision == "high":
         return "einsum"  # Mosaic's matmul has no three-pass mode
-    return "kernel" if k <= 64 else "einsum"
+    return "kernel" if k <= _KERNEL_MAX_RANK else "einsum"
 
 
 def _exchange_and_assembly(config: "ALSConfig", platform: Optional[str]):
@@ -1000,15 +1009,42 @@ def _exchange_and_assembly(config: "ALSConfig", platform: Optional[str]):
         config.assembly_precision)
 
 
+def _solver_tiles(problem: "BlockedProblem", config: "ALSConfig",
+                  platform: Optional[str],
+                  per_chunk: Dict[str, bool]) -> Dict[str, int]:
+    """{solver layout: tile} over the Pallas solver's entries one compiled
+    sweep runs (``cholesky_pallas.solver_tile``): batch-major where a side
+    on the per-chunk route solves inside its ``lax.map`` steps, lane-major
+    everywhere else; empty under the ``lax`` solver."""
+    from .cholesky_pallas import solver_tile
+
+    if resolve_solver(platform) != "pallas":
+        return {}
+    k = config.num_factors
+    exchange, how = _exchange_and_assembly(config, platform)
+    itemsize = np.dtype(config.dtype).itemsize
+    y_itemsize = exchange.itemsize if exchange else itemsize
+    layouts = set()
+    for name, side in (("u", problem.u), ("i", problem.i)):
+        for w, r in zip(side.widths, side.rows):
+            in_scan = per_chunk[name] and _chunk_rows(
+                r, w, k, y_itemsize, itemsize, how, config.implicit,
+                True) is not None
+            layouts.add("batch_major" if in_scan else "lane_major")
+    return {layout: solver_tile(k, layout)[0] for layout in sorted(layouts)}
+
+
 def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
-                  k: int, per_chunk: Dict[str, bool]) -> None:
+                  k: int, per_chunk: Dict[str, bool],
+                  tiles: Optional[Dict[str, int]] = None) -> None:
     """The static choices of one compiled sweep, per side: the solve's route
     (``solves_per_chunk``) beside the bytes its normal equations take per
     device, how many buckets the kernel takes and their share of the padded
     ratings, and how many of them hand A to the solver lane-major from the
     kernel itself (with ``lanes`` on the materialised route, every bucket
     ``_chunk_rows`` leaves straight-line; the others are transposed after
-    their lax.map) and their share of the entities."""
+    their lax.map) and their share of the entities; then the rank, the
+    assembly's form and ``tiles`` (``_solver_tiles``)."""
     parts = []
     for name, side in (("u", problem.u), ("i", problem.i)):
         padded = sum(w * r for w, r in zip(side.widths, side.rows))
@@ -1024,7 +1060,12 @@ def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
                      f"padded ratings), lane-major hand-off on {len(direct)} "
                      f"({100.0 * sum(direct) / sum(side.rows):.1f}% of "
                      f"{sum(side.rows)} entities)")
-    print("[als] assembly: " + ", ".join(parts) + "; einsum pair elsewhere")
+    form = "Pallas kernel" if how == "kernel" else "einsum pair"
+    solver = "".join(f", {tile} {layout.replace('_', '-')}"
+                     for layout, tile in (tiles or {}).items())
+    print("[als] assembly: " + ", ".join(parts) + "; einsum pair elsewhere"
+          + f"; rank {k}: {form}" + (", solver tile" + solver[1:] if solver
+                                     else ""))
 
 
 def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
@@ -1128,7 +1169,8 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     lanes = how == "kernel" and resolve_solver(platform) == "pallas"
     per_chunk = _routes(problem, config, mesh)
     if platform == "tpu":
-        _log_assembly(problem, how, lanes, k, per_chunk)
+        _log_assembly(problem, how, lanes, k, per_chunk,
+                      _solver_tiles(problem, config, platform, per_chunk))
 
     def half_sweep(y_shard, flat, routed: bool, fused: bool):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
@@ -1512,7 +1554,11 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     larger side's (``normal_eq_bytes``); the rating slots the gather reads,
     each rating once a side plus the bucket ladder's padding (``entries``),
     and the padding alone (``pad_entries``); the zero slots that padding is
-    spread over, one strip a block (``pad_slots``)."""
+    spread over, one strip a block (``pad_slots``); the rank (``rank``) and
+    the systems a grid step of the Pallas solver takes (``solver_tile``: the
+    smallest over the entries this sweep runs, and one ``{kind=<layout>}``
+    child an entry, 0 for an entry it does not run and under the ``lax``
+    solver)."""
     D, k = num_blocks(mesh), config.num_factors
     itemsize = np.dtype(config.dtype).itemsize
     exchange, how = _exchange_and_assembly(config,
@@ -1537,6 +1583,13 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     reg.gauge("tpums_als_entries").set(entries)
     reg.gauge("tpums_als_pad_entries").set(entries - 2 * problem.nnz)
     reg.gauge("tpums_als_pad_slots").set(2 * D * _PAD_STRIP)
+    reg.gauge("tpums_als_rank").set(k)
+    tiles = _solver_tiles(problem, config, mesh.devices.flat[0].platform,
+                          per_chunk)
+    reg.gauge("tpums_als_solver_tile").set(min(tiles.values(), default=0))
+    for layout in ("lane_major", "batch_major"):
+        reg.gauge("tpums_als_solver_tile", kind=layout).set(
+            tiles.get(layout, 0))
 
 
 def compile_fit(

@@ -91,16 +91,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .cholesky_pallas import _round_up
+from .cholesky_pallas import LANES, _round_up  # LANES: entities per
+#                            lane-major output block, the solver's lane tile
 
 _GROUP = 8                 # entities per batched step: one f32 sublane tile
-LANES = 128               # entities per lane-major output block: one lane tile
 _MAX_WT = 1024             # rating rows per step once w is tiled (lane tiles)
 _VMEM_BUDGET = 10 << 20    # of the 16 MB scoped VMEM, for the pipelined blocks
 # the lane-major form holds, beside the same pipelined input blocks, the
 # resident (128, k+1, k) accumulator, its transpose and two (k, k, 128)
 # output blocks (13 MB at k = 64): more than the default scoped 16 MB
 _LANES_VMEM_LIMIT = 40 << 20
+
+
+def _lanes_vmem_limit(k: int) -> int:
+    """The scoped VMEM the lane-major form asks for.  Up to rank 64 the
+    constant the cells' programs were compiled with (the v5e compiler needs
+    22 MiB of it at k = 64).  Above, the resident blocks grow with k squared
+    and pass it: counted as the pipelined inputs' budget plus six blocks of
+    k + 1 rows (padded to 8 sublanes) x 128 lanes x 128 entities, f32, that
+    is 49 MiB at k = 100 and 61 at k = 128 where the compiler needs 32 and 50
+    (the least limit it accepts for a described v5e, bisected to the MiB at
+    w = 8, 64, 1,024 and 327,712; PR 44), of the chip's 128 MiB."""
+    if k <= 64:
+        return _LANES_VMEM_LIMIT
+    return _VMEM_BUDGET + 6 * _round_up(k + 1, 8) * _round_up(k, 128) * LANES * 4
 
 
 def _input_bytes(w: int, k: int) -> int:
@@ -129,7 +143,9 @@ def tile_sizes(w: int, k: int):
     8 x Wt rows, its transpose, the masked copy of a ragged tile) live in
     the rest of the scoped 16 MB: the widest case, w = 3000 as 1024-row
     tiles with a ragged last one, compiles and agrees with float64 on the
-    chip at k = 50, 64, 100, 128."""
+    chip at k = 50, 64, 100, 128 (and at k = 100 and 128 for a described
+    v5e at every width of ``netflix-als-f100``'s ladder, 8 to 327,712:
+    PR 44)."""
     if w > _MAX_WT:
         return _GROUP, _split_w(w)
     per_entity = _input_bytes(w, k) + 2 * _round_up(k, 8) * _round_up(k, 128) * 4
@@ -326,7 +342,7 @@ def assemble_bucket_lanes(y, t, *, precision: str, interpret: bool,
         scratch_shapes=[pltpu.VMEM((LANES, k + 1, k), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_LANES_VMEM_LIMIT),
+            vmem_limit_bytes=_lanes_vmem_limit(k)),
         interpret=interpret,
     )(y, t.astype(jnp.float32))
 

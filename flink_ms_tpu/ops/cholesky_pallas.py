@@ -1,7 +1,7 @@
 """Fused batched SPD solve as a Pallas TPU kernel.
 
 The ALS half-sweep ends in n independent k×k normal-equation solves
-(k = numFactors, 10-64; n = entities per block, 10^4-10^6).  Both XLA's
+(k = numFactors, 10-128; n = entities per block, 10^4-10^6).  Both XLA's
 ``lax.linalg.cholesky`` (a while-loop of dynamic slices — latency-bound)
 and the unrolled rank-1-downdate formulation (streams the whole (n, k, k)
 tensor from HBM once per elimination step — ~n·k³ bytes of traffic) are
@@ -25,6 +25,16 @@ in VMEM, so that A is read from HBM once and nothing rewrites it on the
 way: at the ML-20M shape 7.2 ms an iteration in ``als.solve`` where the
 reg add, the pad and the kernel took 18.3 (PERF.md section 5, PR 30).
 
+One rule, ``solver_tile``, gives every entry its tile and the VMEM it asks
+for.  Up to rank 64 that is the default scoped 16 MB (the ranks the cells
+``als-ml20m.retrain``, 50, and ``msd-ials.ials-retrain``, 64, time); from 65
+to 128 the kernel names its own limit, because one lane tile of systems no
+longer fits the default: at rank 100 the lane-major entries need 31 MiB and
+the batch-major one 21 MiB (what the v5e compiler reports, PERF.md section
+3, PR 44; ``netflix-als-f100.retrain`` times that rank).  The elimination is
+unrolled over the static k, so the kernel's trace and lowering grow with k
+squared: the price of a start, not of an iteration.
+
 The caller says where it runs: ``interpret=True`` is the interpreter-mode
 path CPU tests pin numerics with, ``interpret=False`` compiles for the TPU.
 ``ops/als._chol_solve`` derives it from its mesh's platform; selection of
@@ -37,14 +47,62 @@ blocked ALS [dep], reached from ``ALSImpl.scala:52`` (SURVEY.md §2.2).
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128  # one lane tile: the systems a grid step solves side by side
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def solver_tile(k: int, layout: str) -> Tuple[int, Optional[int]]:
+    """-> (tile, vmem_limit_bytes) of a solver entry at rank ``k``: the
+    systems per grid step, and the scoped VMEM the kernel asks Mosaic for
+    (None: the default 16 MB).  ``layout`` is "lane_major"
+    (``cholesky_solve_lanes`` and ``cholesky_solve_batched``'s default, A
+    arriving as (k, k, tile) blocks) or "batch_major" (A arriving as
+    (tile, k, k) blocks and transposed in VMEM).
+
+    Up to rank 64 every answer is the one the cells' programs were compiled
+    and timed with: a whole lane tile under the default limit, except the
+    batch-major entry from rank 57, which takes half a tile (its nine
+    k x k x tile buffers measured 18.87 MB at k = 64 against the 16 MB limit
+    on the installation it was written on).
+
+    Above 64 a lane tile no longer fits the default, and a smaller tile
+    does not help: in VMEM the systems lie on the lanes, and a tile of 32 or
+    64 occupies the same whole 128-lane tiles (the batch-major kernel needs
+    21 MiB at k = 100 with a tile of 128, 64 and 32 alike) and leaves VPU
+    lanes idle.  So the tile stays whole and the kernel asks for what it
+    needs, counted as eight buffers of k rows (padded to 8 sublanes) x k x
+    128 lanes of f32: the input block twice (double-buffered), the
+    downdated copy, the column and the row stack, and headroom.  The least
+    limit the v5e compiler accepts, bisected to the MiB (a described chip,
+    PR 44): lane-major 13 MiB at k = 64 (6.5 buffers), 31 at 100 (6.1), 50
+    at 128 (6.3); batch-major 9, 21 and 47.  The rule asks for 43 MB at
+    k = 100 and 67 at k = 128, of the v5e's 128 MiB of VMEM: a ceiling, not
+    an allocation."""
+    if k <= 64:
+        halve = (layout == "batch_major"
+                 and 9 * k * k * LANES * 4 > 14 * (1 << 20))
+        return (LANES // 2 if halve else LANES), None
+    return LANES, 8 * _round_up(k, 8) * k * LANES * 4
+
+
+def _compiler_params(vmem_limit: Optional[int]) -> dict:
+    """``pallas_call``'s keyword for a kernel that names its VMEM limit;
+    nothing for one that does not, so that its lowered program is the one
+    without the parameter."""
+    if vmem_limit is None:
+        return {}
+    return {"compiler_params":
+            pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))}
 
 
 def _solve_tile(M, b, k: int):
@@ -97,8 +155,10 @@ def _solve_kernel(a_ref, b_ref, *rest, k: int):
     x_ref[:] = _solve_tile(M, b_ref[:], k)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _solve_padded(At, bt, tile: int, interpret: bool, d=None):
+@functools.partial(jax.jit,
+                   static_argnames=("tile", "interpret", "vmem_limit"))
+def _solve_padded(At, bt, tile: int, interpret: bool, d=None,
+                  vmem_limit: Optional[int] = None):
     k = At.shape[0]
     n_pad = At.shape[2]
     lanes = pl.BlockSpec((k, tile), lambda i: (0, i))
@@ -110,6 +170,7 @@ def _solve_padded(At, bt, tile: int, interpret: bool, d=None):
         out_specs=lanes,
         out_shape=jax.ShapeDtypeStruct((k, n_pad), At.dtype),
         interpret=interpret,
+        **_compiler_params(vmem_limit),
     )(At, bt, *(() if d is None else (d,)))
 
 
@@ -125,8 +186,10 @@ def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
     x_ref[:] = jnp.transpose(_solve_tile(M, b, k), (1, 0))
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool):
+@functools.partial(jax.jit,
+                   static_argnames=("tile", "interpret", "vmem_limit"))
+def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool,
+                              vmem_limit: Optional[int] = None):
     n_pad, k = bb.shape
     kernel = functools.partial(_solve_kernel_batch_major, k=k)
     return pl.pallas_call(
@@ -139,24 +202,25 @@ def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool):
         out_specs=pl.BlockSpec((tile, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k), Ab.dtype),
         interpret=interpret,
+        **_compiler_params(vmem_limit),
     )(Ab, bb)
 
 
-def cholesky_solve_lanes(At, bt, d, *, interpret: bool, tile: int = 128):
+def cholesky_solve_lanes(At, bt, d, *, interpret: bool):
     """(A + d·I) x = b for systems that already lie batch-minor, as
     ``assemble_pallas.assemble_bucket_lanes`` writes them: At (k, k, n),
-    bt (k, n), d (n,) -> x (k, n), n a multiple of ``tile``.  A pad lane
+    bt (k, n), d (n,) -> x (k, n), n a multiple of the lane tile.  A pad lane
     carries A = 0, b = 0, d = 1: the identity system, x = 0."""
-    return _solve_padded(At, bt, tile, bool(interpret), d[None, :])
+    tile, vmem_limit = solver_tile(At.shape[0], "lane_major")
+    return _solve_padded(At, bt, tile, bool(interpret), d[None, :],
+                         vmem_limit=vmem_limit)
 
 
-def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
-                           layout="lane_major"):
+def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major"):
     """Batched SPD solve A x = b.  A (n, k, k), b (n, k) -> x (n, k).
 
-    ``tile`` batch elements ride the lane axis per grid step; VMEM holds
-    ~3·k²·tile·4 bytes (A tile, L, downdate temps) — tile=128 keeps k=64
-    under the ~16 MB budget.  ``interpret`` comes from the platform of the
+    ``solver_tile`` batch elements ride the lane axis per grid step.
+    ``interpret`` comes from the platform of the
     caller's mesh, never from the process's default backend: a host-side
     fit in a process that also holds a chip must still interpret.
 
@@ -171,14 +235,7 @@ def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
     chunk — the round-3 fused-mode AOT OOM), which batch_major sidesteps
     by never asking XLA for that layout."""
     n, k = b.shape
-    if layout == "batch_major":
-        # the batch-major kernel keeps ~9 k²·tile f32 buffers live (input
-        # block + VMEM transpose + downdate + column/row stacks); at k=64
-        # tile=128 that measured 18.87 MB against the 16 MB scoped-vmem
-        # limit (half-scale envelope OOM).  Halve the tile until the
-        # estimate fits with headroom.
-        while tile > 8 and 9 * k * k * tile * 4 > 14 * (1 << 20):
-            tile //= 2
+    tile, vmem_limit = solver_tile(k, layout)
     n_pad = _round_up(max(n, tile), tile)
     if layout == "batch_major":
         Ab = A.astype(jnp.float32)
@@ -192,7 +249,8 @@ def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
                 [Ab, jnp.broadcast_to(jnp.eye(k, dtype=Ab.dtype),
                                       (pad, k, k))], axis=0)
             bb = jnp.pad(bb, ((0, pad), (0, 0)))
-        return _solve_padded_batch_major(Ab, bb, tile, bool(interpret))[:n]
+        return _solve_padded_batch_major(
+            Ab, bb, tile, bool(interpret), vmem_limit=vmem_limit)[:n]
     At = jnp.transpose(A.astype(jnp.float32), (1, 2, 0))  # (k, k, n)
     bt = jnp.transpose(b.astype(jnp.float32), (1, 0))     # (k, n)
     if n_pad != n:
@@ -203,5 +261,5 @@ def cholesky_solve_batched(A, b, *, interpret: bool, tile: int = 128,
         )
         At = At.at[:, :, n:].set(eye_pad)
         bt = jnp.pad(bt, ((0, 0), (0, n_pad - n)))
-    x = _solve_padded(At, bt, tile, bool(interpret))
+    x = _solve_padded(At, bt, tile, bool(interpret), vmem_limit=vmem_limit)
     return jnp.transpose(x[:, :n], (1, 0))

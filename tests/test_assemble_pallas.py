@@ -476,7 +476,8 @@ def test_einsum_sweeps_trace_no_new_kernel(rng, monkeypatch, platform,
     (None, "float32", False, 144, 50, "highest", "einsum"),
     ("tpu", "float32", False, 144, 50, "high", "einsum"),       # no 3-pass
     ("tpu", "float32", False, 24, 50, "highest", "kernel"),     # every width
-    ("tpu", "float32", False, 144, 65, "highest", "einsum"),    # past the rank
+    ("tpu", "float32", False, 144, 100, "highest", "kernel"),   # PR 44's cell
+    ("tpu", "float32", False, 144, 129, "highest", "einsum"),   # past the rank
     ("tpu", "float32", False, 144, 200, "highest", "einsum"),   # the chip ran
 ])
 def test_resolver_and_what_it_traces(platform, y_dtype, implicit, w, k,

@@ -129,11 +129,13 @@ def test_als_fit_with_pallas_solver_matches_default(rng, monkeypatch):
 # (lax.map) solve of the 10M x 1M configuration
 _TPU_LOWERED = {
     "cholesky_pallas.py": [(50, "lane_major"), (64, "batch_major"),
-                           (50, "batch_major"), (64, "lane_major")],
+                           (50, "batch_major"), (64, "lane_major"),
+                           (100, "lane_major"), (100, "batch_major")],
     # (w, k) of the assembly kernel: the user side's narrowest and a middle
     # bucket of ML-20M, rank 64, and the item side's 64,728-wide bucket,
     # which goes through the tiled-w path with a ragged last tile
-    "assemble_pallas.py": [(24, 50), (144, 50), (328, 64), (64728, 50)],
+    "assemble_pallas.py": [(24, 50), (144, 50), (328, 64), (64728, 50),
+                           (8, 100), (744, 100), (19176, 100)],
     # (rows a chain, steps) of the SDCA kernel at the two CoCoA cells,
     # epsilon-cocoa-plus and rcv1-cocoa, and the longest chain it takes
     "sdca_pallas.py": [(49, 49), (83, 83), (113, 113)],
@@ -143,7 +145,10 @@ _TPU_LOWERED = {
 # (sub-blocks of 8), the first tiled one, and seven entities of 97,096
 # ratings (one lane tile, 95 w tiles); and rank 64
 _TPU_LOWERED_LANES = [(28505, 24, 50), (13236, 216, 50), (3695, 744, 50),
-                      (1935, 1120, 50), (7, 97096, 50), (4850, 328, 64)]
+                      (1935, 1120, 50), (7, 97096, 50), (4850, 328, 64),
+                      # netflix-als-f100.retrain's movie half, rank 100: its
+                      # two straight-line buckets
+                      (1592, 1120, 100), (6, 327712, 100)]
 
 
 def test_every_ops_pallas_kernel_has_a_lowering_case():
@@ -176,7 +181,7 @@ def test_assembly_kernel_lowers_for_tpu(w, k):
     from flink_ms_tpu.ops.assemble_pallas import assemble_bucket, tile_sizes
 
     r = 24
-    assert (tile_sizes(w, k)[1] < w) == (w == 64728)
+    assert (tile_sizes(w, k)[1] < w) == (w > 1024)
     lowered = jax.jit(
         lambda y, t: assemble_bucket(
             y, t, precision="highest", interpret=False)
@@ -270,3 +275,69 @@ def test_sdca_kernel_lowers_for_tpu(h_rows, steps):
     ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
     assert tuple(lowered.out_info.shape) == (hp, cp)
+
+
+# -- compiled for a described v5e: what lowering alone cannot refuse ----------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A TPU v5e chip that is described, not attached: the installed TPU
+    compiler refuses here what it would refuse on the chip (a kernel over
+    its scoped VMEM, which no lowering and no interpreted run shows)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("k,entry", [(100, "lane_major"), (100, "batch_major"),
+                                     (100, "lanes"), (128, "lanes")])
+def test_solver_compiles_for_a_v5e_above_rank_64(one_chip, k, entry):
+    """netflix-als-f100.retrain's rank on all three entries, and the top of
+    the stated range on the widest: under Mosaic's default scoped limit
+    every one of them is refused (30.88 MB of 16 at k = 100, PR 44)."""
+    n = 512
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    if entry == "lanes":
+        fn, args = (lambda At, bt, d: cholesky_solve_lanes(
+            At, bt, d, interpret=False)), (shape(k, k, n), shape(k, n), shape(n))
+    else:
+        fn, args = (lambda A, b: cholesky_solve_batched(
+            A, b, interpret=False, layout=entry)), (shape(n, k, k), shape(n, k))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("layout,r,w,k", [
+    # netflix-als-f100.retrain's two straight-line movie buckets, and the
+    # top of the stated range where the resident blocks are largest (whole
+    # sub-blocks of 128 entities; sub-blocks of 8 and a full tile of w)
+    ("lanes", 1592, 1120, 100), ("lanes", 6, 327712, 100),
+    ("lanes", 640, 64, 128), ("lanes", 640, 1024, 128),
+    # batch-major, as the users' lax.map steps run it: the default limit
+    ("batch", 9084, 216, 100), ("batch", 4150, 744, 128)])
+def test_assembly_compiles_for_a_v5e_above_rank_64(one_chip, layout, r, w, k):
+    """Under the 40 MB the lane-major form names up to rank 64 the compiler
+    refuses it at k = 128 (it needs 50 MiB): `_lanes_vmem_limit`."""
+    from flink_ms_tpu.ops.assemble_pallas import (
+        assemble_bucket, assemble_bucket_lanes)
+
+    assemble = assemble_bucket_lanes if layout == "lanes" else assemble_bucket
+    compiled = jax.jit(
+        lambda y, t: assemble(y, t, precision="highest", interpret=False)
+    ).lower(
+        jax.ShapeDtypeStruct((r, w, k), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((r, w), jnp.float32, sharding=one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,0 +1,70 @@
+"""The Pallas solver from rank 65 to 128 (PR 44), the range
+`netflix-als-f100.retrain` opened: `solver_tile`, the one rule that gives
+every entry its tile and the VMEM it asks for, and the three entries
+interpreted against numpy float64 at the bottom, the cell's rank and the top
+of the range.  A file of its own: the unrolled k-loops take half a minute to
+trace at rank 100, and the test runner hands out whole files.  The compile
+for a described v5e, which is what refuses a kernel over its VMEM, is in
+`test_cholesky_pallas.py` beside the other chip lowerings."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from flink_ms_tpu.ops.cholesky_pallas import (
+    LANES, cholesky_solve_batched, cholesky_solve_lanes, solver_tile)
+
+
+# ranks 65-128 (PR 44): the tile is whole and the kernel names its VMEM
+# limit; the arithmetic is the one unrolled body at every rank.  130 systems:
+# two grid steps, the second nearly all identity pad
+@pytest.mark.parametrize("entry", ["lane_major", "batch_major", "lanes"])
+@pytest.mark.parametrize("k", [65, 100, 128])
+def test_ranks_above_64_match_numpy(rng, k, entry):
+    n = 130
+    G = rng.standard_normal((n, k, k)).astype(np.float32)
+    A = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
+    b = rng.standard_normal((n, k)).astype(np.float32)
+    d = rng.uniform(0.5, 20.0, n).astype(np.float32)
+    if entry == "lanes":
+        n_pad = 2 * LANES
+        At = np.zeros((k, k, n_pad), np.float32)
+        At[:, :, :n] = A.transpose(1, 2, 0)
+        bt = np.zeros((k, n_pad), np.float32)
+        bt[:, :n] = b.T
+        dp = np.ones(n_pad, np.float32)
+        dp[:n] = d
+        x = np.asarray(cholesky_solve_lanes(
+            jnp.asarray(At), jnp.asarray(bt), jnp.asarray(dp), interpret=True))
+        assert (x[:, n:] == 0).all()     # pad lanes: the identity system
+        x = x[:, :n].T
+        A = A + d[:, None, None] * np.eye(k, dtype=np.float32)
+    else:
+        x = np.asarray(cholesky_solve_batched(
+            jnp.asarray(A), jnp.asarray(b), interpret=True, layout=entry))
+    x_ref = np.linalg.solve(A.astype(np.float64),
+                            b.astype(np.float64)[..., None])[..., 0]
+    np.testing.assert_allclose(x, x_ref, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("k,layout,want", [
+    # up to rank 64 the answers the cells' programs were compiled with: a
+    # whole lane tile and no named limit, half a tile batch-major from 57
+    (10, "lane_major", (128, None)), (50, "lane_major", (128, None)),
+    (64, "lane_major", (128, None)), (50, "batch_major", (128, None)),
+    (56, "batch_major", (128, None)), (57, "batch_major", (64, None)),
+    (64, "batch_major", (64, None)),
+    # above it the tile stays whole and the kernel asks for eight
+    # (k rows padded to 8) x k x 128-lane f32 buffers
+    (65, "lane_major", (128, 8 * 72 * 65 * 512)),
+    (100, "lane_major", (128, 8 * 104 * 100 * 512)),
+    (100, "batch_major", (128, 8 * 104 * 100 * 512)),
+    (128, "batch_major", (128, 8 * 128 * 128 * 512)),
+])
+def test_solver_tile_rule(k, layout, want):
+    assert solver_tile(k, layout) == want
+    tile, limit = want
+    if limit is not None:
+        # over what the v5e compiler needed (bisected, PR 44: 31 and 50 MiB
+        # lane-major at k = 100 and 128), under the chip's 128 MiB of VMEM
+        assert {100: 31 << 20, 128: 50 << 20}.get(k, 0) < limit < 96 << 20
