@@ -43,6 +43,11 @@ Two training modes, each timed by a cell of the benchmark:
   1 + α·r are applied inside the assembly kernel, or by XLA (scope
   ``als.weight``) before the einsum pair.
 
+A half-sweep whose gather table is too large for the chip's fast memory
+reads it through S row segments that fit (``table_segments`` decides from
+the table's bytes; ``cut_side`` cuts the lists at the segments' boundaries):
+a row gathered from fast memory costs a third of one from HBM.
+
 Each half-sweep either materialises its (per_block, k, k) normal equations
 and solves them in one batch, or solves every assembly chunk where it was
 assembled so that the tensor never exists: ``solves_per_chunk`` decides per
@@ -60,6 +65,7 @@ import hashlib
 import json
 import os
 import re
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -75,6 +81,7 @@ from ..parallel.mesh import (
     BLOCK_AXIS,
     block_sharding,
     device_memory,
+    fast_memory,
     host_device,
     num_blocks,
 )
@@ -186,6 +193,8 @@ class BlockedProblem:
     # lazily built routed-exchange plans, keyed by (D, mode choice) —
     # see _exchange_plan
     routing: dict = dataclasses.field(default_factory=dict, repr=False)
+    # lazily cut sides, keyed by (side, segments) — see _cuts
+    cuts: dict = dataclasses.field(default_factory=dict, repr=False)
 
     @property
     def n_users(self) -> int:
@@ -436,15 +445,18 @@ def prepare_blocked(
             u_perm, i_perm = u_order[3], i_order[3]
         # each side's pad gathers are spread over the opposite side's strip
         # (the tail of every block), found from its slots per block
-        with tracing.phase("als.prepare.fill"):
-            u_side = _fill_side(
-                u_idx, i_idx, ratings, len(user_ids), n_blocks, u_order,
-                i_perm, i_order[6], dtype
+        # side by side: each is a sort of all the ratings and a ragged fill,
+        # and numpy lets go of the interpreter's lock inside both
+        with tracing.phase("als.prepare.fill"), ThreadPoolExecutor(2) as pool:
+            u_side = pool.submit(
+                _fill_side, u_idx, i_idx, ratings, len(user_ids), n_blocks,
+                u_order, i_perm, i_order[6], dtype
             )
-            i_side = _fill_side(
-                i_idx, u_idx, ratings, len(item_ids), n_blocks, i_order,
-                u_perm, u_order[6], dtype
+            i_side = pool.submit(
+                _fill_side, i_idx, u_idx, ratings, len(item_ids), n_blocks,
+                i_order, u_perm, u_order[6], dtype
             )
+            u_side, i_side = u_side.result(), i_side.result()
     return BlockedProblem(
         n_blocks=n_blocks,
         user_ids=user_ids,
@@ -453,6 +465,127 @@ def prepare_blocked(
         u=u_side,
         i=i_side,
     )
+
+
+# ---------------------------------------------------------------------------
+# a gather table too large for fast memory, read through segments that fit
+# ---------------------------------------------------------------------------
+
+# What a gathered row costs is set by where its table lies (TPU v5e, PERF.md
+# sections 5 and 6): 1.33-1.41 ns from a table XLA keeps in the chip's fast
+# memory (``S(1)`` on the table's layout in the compiled program; both of
+# als-ml20m's tables, 71 MB the larger), 3.95 or 9.9 ns from one in HBM,
+# which of the two by the take's shape and erratically.  A half-sweep whose
+# table cannot lie there gathers from S row segments that can, its lists cut
+# at the segments' boundaries (``cut_side``).
+#
+# Beside a table the fast memory holds the assembly kernel's scoped limit
+# (``assemble_pallas._lanes_vmem_limit``) and this much of XLA's own (its
+# fusions' default scoped limit is 16 MiB).  Bracketed by compiles for a
+# described v5e (``scripts/als_compiled_layout.py``; nothing runs there, and
+# the chip then read what they said, PERF.md section 6, PR 45): beside the
+# 40 MB kernel als-ml20m's 71.0 MB table and msd-ials' four 73.2 MB
+# segments are all placed in S(1) and three segments of 97.6 MB are not;
+# beside the 49 MiB kernel netflix-als-f100's four of 61.5 MB are.  The
+# budget this leaves is 75.5 MB at rank 64 and 66.0 at 100.
+_FAST_MEMORY_SLACK = 16 << 20
+_CUT_THREADS = 8   # ``cut_side``'s, one bucket each
+
+
+def table_segments(rows: int, k: int, itemsize: int,
+                   fast_bytes: Optional[int], reserved: int) -> int:
+    """S: into how many row segments a half-sweep cuts the ``rows``-slot
+    factor table it gathers from (the strip among them).  1 wherever the
+    table, at the whole lane tiles its rows occupy, fits ``fast_bytes`` (a
+    device's fast memory, ``mesh.fast_memory``) less ``reserved`` (the
+    assembly kernel's scoped limit) less ``_FAST_MEMORY_SLACK``, and wherever
+    no fast memory is reported (the CPU); else the fewest equal segments of
+    its real rows that fit, each beside a strip of its own."""
+    if not fast_bytes:
+        return 1
+    row_bytes = -(-k // _LANES) * _LANES * itemsize
+    budget = fast_bytes - reserved - _FAST_MEMORY_SLACK
+    if rows * row_bytes <= budget:
+        return 1
+    real = rows - _PAD_STRIP
+    fit = max(int(budget // row_bytes) - _PAD_STRIP, 1)
+    return -(-real // fit)
+
+
+@dataclasses.dataclass
+class CutSide:
+    """One side's rating lists cut at the boundaries of ``segments`` equal
+    row ranges of the table its half-sweep gathers from (one device only).
+
+    Bucket j's ``(rows_j, w_j)`` lists are stored as S pieces ``(rows_j,
+    w_js)``: piece s holds, for every list, the run of its entries whose
+    opposite slot lies in segment s (contiguous, because ``_fill_side`` sorts
+    a list by slot), rebased to the segment and padded to ``w_js``
+    (``_piece_width``).  Segment s is read as ``seg_rows`` table rows
+    followed by a zero strip of its own, and a piece's pads are spread over
+    that strip by ``_PAD_STRIP``'s rule."""
+
+    segments: int     # S
+    seg_rows: int     # table rows a segment holds (the last may hold fewer)
+    widths: list      # per bucket: the S piece widths
+    idx: list         # per bucket: S arrays (1, rows_j, w_js) int32
+    val: list         # per bucket: S arrays of ratings, pad entries 0
+
+
+def _piece_width(w: int, share: float, longest: int) -> int:
+    """Width of a piece of a ``w``-wide bucket in a segment that holds
+    ``share`` of the opposite side's ratings: what a list of w entries paired
+    at random runs to there, w * share, plus six standard deviations of that
+    count, or the ``longest`` run found where the data are not paired so;
+    a multiple of 8, at most w.  From the degrees alone wherever the first
+    governs, as the buckets' own shapes are: the pieces of two days' ratings
+    with one degree law (the benchmark's seeds) have one shape and one
+    compiled program, where the longest run moves with every pairing (by
+    some 200 entries in 167,000 at netflix-als-f100: 0.6% of an iteration
+    and a compile of 90 s a seed; chip run, PERF.md section 6, PR 45)."""
+    spread = 6.0 * (w * share * (1.0 - share)) ** 0.5
+    fit = max(int(np.ceil(w * share + spread)), longest, 1)
+    return min(-(-fit // 8) * 8, w)
+
+
+def cut_side(side: SideLayout, opp: SideLayout, segments: int) -> CutSide:
+    """``side`` (one block) as a ``CutSide`` over the table of ``opp``'s
+    slots.  No rating is dropped or moved to another list: a list's entries
+    keep their order, and a list with no entry in a segment has pads alone
+    there."""
+    real = opp.per_block - _PAD_STRIP        # slots below the table's strip
+    seg_rows = -(-real // segments)
+    held = np.add.reduceat(opp.count[0, :real].astype(np.float64),
+                           np.arange(0, real, seg_rows))
+    shares = held / held.sum()               # of the ratings, by segment
+
+    def cut_bucket(ix, vl):
+        rows = ix.shape[1]
+        keep = ix[0] < real                              # not a pad
+        slots, values = ix[0][keep], vl[0][keep]         # list-major, ascending
+        seg = slots // seg_rows
+        row_of = np.repeat(np.arange(rows), np.count_nonzero(keep, axis=1))
+        runs = np.bincount(row_of * segments + seg,
+                           minlength=rows * segments).reshape(rows, segments)
+        widths, idx, val = [], [], []
+        for s in range(segments):
+            w = _piece_width(ix.shape[2], shares[s], int(runs[:, s].max()))
+            piece = seg_rows + _strip_slots(rows * w).reshape(1, rows, w)
+            rating = np.zeros((1, rows, w), vl.dtype)
+            into = np.arange(w) < runs[:, s, None]       # a run fills from 0
+            mine = seg == s
+            piece[0][into] = slots[mine] - s * seg_rows
+            rating[0][into] = values[mine]
+            widths.append(w)
+            idx.append(piece)
+            val.append(rating)
+        return tuple(widths), idx, val
+
+    # a bucket a task: numpy's passes release the interpreter's lock
+    with ThreadPoolExecutor(_CUT_THREADS) as pool:
+        widths, idx, val = zip(*pool.map(cut_bucket, side.idx, side.val))
+    return CutSide(segments=segments, seg_rows=seg_rows, widths=list(widths),
+                   idx=list(idx), val=list(val))
 
 
 # ---------------------------------------------------------------------------
@@ -693,7 +826,7 @@ def _chunk_rows(r, w, k, y_itemsize, itemsize, how, implicit,
 
 def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
                        precision, post=None, extra=None, platform=None,
-                       lanes=False):
+                       lanes=False, unroll=False):
     """One bucket's (A, b): gather the opposite factors for each row's
     rating list and contract over the rating axis on the MXU — by the
     Pallas kernel or the einsum pair, as ``resolve_assembly`` answers for
@@ -732,12 +865,45 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     as ``_chunk_rows`` cut them.
     Chunking is over the batch row axis only (the contraction axis w is
     untouched), so chunked and unchunked results are arithmetically
-    identical per row."""
-    r, w = idx.shape
-    k = y_all.shape[1]
-    how = resolve_assembly(platform, y_all.dtype, dtype, k, precision)
+    identical per row.
+    A cut bucket (``CutSide``) arrives as tuples: ``y_all`` the segments'
+    tables, ``idx`` and ``val`` the bucket's piece for each.  Every piece
+    is gathered and contracted as a bucket is and the pieces' A and b are
+    summed in the solve dtype before ``post``: the same products in
+    another order.  A step then takes the same rows of every piece.
+    ``unroll``: the steps of a chunked bucket run one after the other in
+    the program's text, not as a ``lax.map``.  For a piece that reads a
+    segment of a table (``_assemble_normal_eqs``): the compiler keeps a
+    61.5 MB segment in its fast memory through straight-line takes and
+    not where a loop carries it (netflix-als-f100's segment 0 under
+    ``lax.map``: its table in HBM and its takes in the 3.95 and 9.9 ns
+    forms, the three segments without a chunked piece in the fast form;
+    compiled for a described v5e, PERF.md section 6, PR 45)."""
+    if not isinstance(y_all, (tuple, list)):
+        y_all, idx, val = (y_all,), (idx,), (val,)
+    r = idx[0].shape[0]
+    k = y_all[0].shape[1]
+    how = resolve_assembly(platform, y_all[0].dtype, dtype, k, precision)
 
-    def compute(idx_c, val_c, extra_c, in_scan=False):
+    def compute(tables, idx_c, val_c, extra_c, in_scan=False):
+        A = b = None
+        for table, idx_s, val_s in zip(tables, idx_c, val_c):
+            A_s, b_s = contract(table, idx_s, val_s, in_scan)
+            A, b = (A_s, b_s) if A is None else (A + A_s, b + b_s)
+        if post is None:
+            return A, b
+        rows = A.shape[0]
+        n = -(-rows // _LANES) * _LANES
+        if not lanes or n == rows:
+            return post(A, b, extra_c, in_scan=in_scan)
+        # whole lane tiles for the Pallas solver: XLA fuses this pad into
+        # the pass that adds YᵀY and λ·reg (the docstring's ``lanes``)
+        def pad(a):
+            return jnp.pad(a, ((0, n - rows),) + ((0, 0),) * (a.ndim - 1))
+
+        return post(pad(A), pad(b), pad(extra_c), in_scan=in_scan)[:rows]
+
+    def contract(table, idx_c, val_c, in_scan):
         # the two scopes split als.assemble in a profile: the gather is
         # XLA's either way, the contraction is what `how` chose
         with jax.named_scope("als.gather"):
@@ -745,7 +911,7 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
             # point at real slots, the strip's).  The default mode's fill is a
             # select over all of y, which XLA folds into the einsum's
             # operands but would run as a pass of its own before a kernel
-            y = jnp.take(y_all, idx_c, axis=0,              # (r, w, k)
+            y = jnp.take(table, idx_c, axis=0,              # (r, w, k)
                          mode="clip" if how == "kernel" else None)
         # HIGHEST keeps f32 products (bf16 single-pass shifts the normal
         # equations enough to slow convergence at small lambda)
@@ -778,23 +944,13 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
                     t = val_c.astype(dtype)              # pads: val 0
                 b = jnp.einsum("rwk,rw->rk", y, t, precision=precision,
                                preferred_element_type=dtype)
-        if post is None:
-            return A, b
-        rows = A.shape[0]
-        n = -(-rows // _LANES) * _LANES
-        if not lanes or n == rows:
-            return post(A, b, extra_c, in_scan=in_scan)
-        # whole lane tiles for the Pallas solver: XLA fuses this pad into
-        # the pass that adds YᵀY and λ·reg (the docstring's ``lanes``)
-        def pad(a):
-            return jnp.pad(a, ((0, n - rows),) + ((0, 0),) * (a.ndim - 1))
+        return A, b
 
-        return post(pad(A), pad(b), pad(extra_c), in_scan=in_scan)[:rows]
-
-    C = _chunk_rows(r, w, k, y_all.dtype.itemsize, np.dtype(dtype).itemsize,
+    C = _chunk_rows(r, sum(i.shape[1] for i in idx), k,
+                    y_all[0].dtype.itemsize, np.dtype(dtype).itemsize,
                     how, implicit, post is not None)
     if C is None:
-        return compute(idx, val, extra)
+        return compute(y_all, idx, val, extra)
     # chunked: reshape to (n_chunks, C, ...) slabs and lax.map WITHOUT
     # batch_size, so the body genuinely computes C rows per step and only
     # one chunk's transients are ever live.  (lax.map's batch_size vmaps a
@@ -814,22 +970,34 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
             return a
         return jnp.pad(a, ((0, r_pad - r),) + ((0, 0),) * (a.ndim - 1))
 
-    idx_c = pad_rows(idx).reshape(n_chunks, C, w)
-    val_c = pad_rows(val).reshape(n_chunks, C, w)
+    def steps(a):
+        return pad_rows(a).reshape(n_chunks, C, a.shape[1])
+
+    idx_c = tuple(steps(a) for a in idx)
+    val_c = tuple(steps(a) for a in val)
     extra_c = None
     if extra is not None:
         extra_c = pad_rows(extra).reshape((n_chunks, C) + extra.shape[1:])
 
-    def one_chunk(args):
+    def one_chunk(tables, args):
         if extra is None:
-            return compute(args[0], args[1], None, in_scan=True)
-        return compute(args[0], args[1], args[2], in_scan=True)
+            return compute(tables, args[0], args[1], None, in_scan=True)
+        return compute(tables, args[0], args[1], args[2], in_scan=True)
 
     operands = (idx_c, val_c) if extra is None else (idx_c, val_c, extra_c)
-    out = jax.tree.map(
-        lambda t: t.reshape((r_pad,) + t.shape[2:])[:r],
-        jax.lax.map(one_chunk, operands),
-    )
+    if unroll:
+        # the same steps, batch-major as under the map, in the text; one
+        # jitted body, so the steps after the first are traced from it
+        step = jax.jit(one_chunk)
+        steps_out = [step(y_all, jax.tree.map(lambda t: t[c], operands))
+                     for c in range(n_chunks)]
+        out = jax.tree.map(lambda *t: jnp.concatenate(t, axis=0)[:r],
+                           *steps_out)
+    else:
+        out = jax.tree.map(
+            lambda t: t.reshape((r_pad,) + t.shape[2:])[:r],
+            jax.lax.map(partial(one_chunk, y_all), operands),
+        )
     if lanes and post is None:
         from .assemble_pallas import to_lanes
 
@@ -837,8 +1005,22 @@ def _bucket_normal_eqs(y_all, idx, val, implicit, alpha, dtype,
     return out
 
 
+def _segment_table(y_all, s: int, seg_rows: int):
+    """Segment ``s`` of the table ``y_all`` as a cut side's pieces address
+    it: its ``seg_rows`` rows (the last segment's fewer, zeros after them)
+    followed by a strip of ``_PAD_STRIP`` zero rows of its own.  A copy of
+    the segment, under ``als.gather``: small enough for the compiler to keep
+    where the takes read fastest (``table_segments``)."""
+    lo = s * seg_rows
+    hi = min(lo + seg_rows, y_all.shape[0] - _PAD_STRIP)
+    with jax.named_scope("als.gather"):
+        return jnp.pad(y_all[lo:hi],
+                       ((0, seg_rows + _PAD_STRIP - (hi - lo)), (0, 0)))
+
+
 def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
-                         precision="highest", platform=None, lanes=False):
+                         precision="highest", platform=None, lanes=False,
+                         seg_rows=None):
     """A_u = Σ w·y yᵀ and b_u = Σ t·y per slot, as batched MXU matmuls.
 
     y_all:   (n_slots_global, k) gathered opposite-side factor table
@@ -858,24 +1040,46 @@ def _assemble_normal_eqs(y_all, buckets, implicit, alpha, dtype,
     its zero pad up to a whole lane tile, joined along the lanes (one copy;
     no relayout, no pad pass, no system for the strip:
     ``_solve_factors_lanes``).
+
+    A cut side (``CutSide``, ``seg_rows`` its segments' rows): each bucket
+    is a tuple of S (idx, val) pieces.  The loop over segments is the outer
+    one, so that one segment's table (``_segment_table``) is live through
+    all of the side's buckets and then dead (61-73 MB each at the cells'
+    sizes, which the compiler then keeps in its fast memory in turn, where
+    all S at once cannot lie), and a segment's (A, b) over every bucket is
+    added to the sum of those before it in the solve dtype.
     """
-    As, bs = [], []
-    for idx, val in buckets:
-        A, b = _bucket_normal_eqs(
-            y_all, idx, val, implicit, alpha, dtype, precision,
-            platform=platform, lanes=lanes,
-        )
-        As.append(A)
-        bs.append(b)
-    if lanes:
-        return jnp.concatenate(As, axis=2), jnp.concatenate(bs, axis=1)
+    if seg_rows is None:
+        buckets = [(bucket,) for bucket in buckets]
     k = y_all.shape[1]
-    # zero systems for the block's strip (no bucket row covers it);
-    # count==0 regularization keeps them PD and the solve masks their
-    # results to zero, preserving the strip's zero factor rows
-    As.append(jnp.zeros((_PAD_STRIP, k, k), dtype))
-    bs.append(jnp.zeros((_PAD_STRIP, k), dtype))
-    return jnp.concatenate(As, axis=0), jnp.concatenate(bs, axis=0)
+    total = None
+    for s in range(len(buckets[0])):
+        with (contextlib.nullcontext() if seg_rows is None
+              else jax.named_scope(f"als.segment{s}")):
+            table = y_all if seg_rows is None else _segment_table(
+                y_all, s, seg_rows)
+            As, bs = [], []
+            for pieces in buckets:
+                idx, val = pieces[s]
+                A, b = _bucket_normal_eqs(
+                    table, idx, val, implicit, alpha, dtype, precision,
+                    platform=platform, lanes=lanes,
+                    unroll=seg_rows is not None,
+                )
+                As.append(A)
+                bs.append(b)
+            if not lanes:
+                # zero systems for the block's strip (no bucket row covers
+                # it); count==0 regularization keeps them PD and the solve
+                # masks their results to zero, preserving the strip's zero
+                # factor rows
+                As.append(jnp.zeros((_PAD_STRIP, k, k), dtype))
+                bs.append(jnp.zeros((_PAD_STRIP, k), dtype))
+            part = (jnp.concatenate(As, axis=2 if lanes else 0),
+                    jnp.concatenate(bs, axis=1 if lanes else 0))
+            total = part if total is None else (total[0] + part[0],
+                                                total[1] + part[1])
+    return total
 
 
 _FUSED_ENV = "FLINK_MS_ALS_FUSED"
@@ -1009,9 +1213,63 @@ def _exchange_and_assembly(config: "ALSConfig", platform: Optional[str]):
         config.assembly_precision)
 
 
+def _segments(problem: "BlockedProblem", config: "ALSConfig",
+              mesh: Mesh) -> Dict[str, int]:
+    """``table_segments`` of the table each half-sweep of one fit on
+    ``mesh`` gathers from (the user half reads the item table).  1 on a
+    mesh of more than one device (the gathered or routed table of D blocks
+    is not cut: no cell, no reading) and for the einsum pair on a TPU (the
+    bf16 exchange, ``als_train``'s default there: what its convolutions need
+    of the fast memory beside a table has not been read)."""
+    device = mesh.devices.flat[0]
+    exchange, how = _exchange_and_assembly(config, device.platform)
+    if num_blocks(mesh) > 1 or (device.platform == "tpu" and how != "kernel"):
+        return {"u": 1, "i": 1}
+    from .assemble_pallas import _lanes_vmem_limit
+
+    k = config.num_factors
+    itemsize = (exchange or np.dtype(config.dtype)).itemsize
+    return {name: table_segments(opp.per_block, k, itemsize,
+                                 fast_memory(device), _lanes_vmem_limit(k))
+            for name, opp in (("u", problem.i), ("i", problem.u))}
+
+
+def _cuts(problem: "BlockedProblem", config: "ALSConfig",
+          mesh: Mesh) -> Dict[str, Optional[CutSide]]:
+    """Each side's ``CutSide`` for one fit on ``mesh``, None where its
+    half-sweep gathers from the whole table (``_segments`` says 1).  Host
+    work, done once a problem and segment count (phase
+    ``als.prepare.segment``) and kept on the problem."""
+    out = {}
+    for name, segments in _segments(problem, config, mesh).items():
+        if segments > 1 and (name, segments) not in problem.cuts:
+            side, opp = ((problem.u, problem.i) if name == "u"
+                         else (problem.i, problem.u))
+            with tracing.phase("als.prepare.segment"):
+                problem.cuts[name, segments] = cut_side(side, opp, segments)
+        out[name] = problem.cuts[name, segments] if segments > 1 else None
+    return out
+
+
+def _calls(side, cut: Optional[CutSide], per_chunk: bool):
+    """(rows, width) of every ``_bucket_normal_eqs`` call one half-sweep
+    makes, bucket by bucket: the bucket; on a cut side each of its pieces
+    (materialised route, a call a piece) or their widths' sum (per-chunk
+    route, one call over all of a bucket's pieces)."""
+    if cut is None:
+        return [[(r, w)] for r, w in zip(side.rows, side.widths)]
+    if per_chunk:
+        return [[(r, sum(ws))] for r, ws in zip(side.rows, cut.widths)]
+    return [[(r, w) for w in ws] for r, ws in zip(side.rows, cut.widths)]
+
+
+_WHOLE = {"u": None, "i": None}   # no side cut: ``_cuts`` of most fits
+
+
 def _solver_tiles(problem: "BlockedProblem", config: "ALSConfig",
-                  platform: Optional[str],
-                  per_chunk: Dict[str, bool]) -> Dict[str, int]:
+                  platform: Optional[str], per_chunk: Dict[str, bool],
+                  cuts: Dict[str, Optional[CutSide]] = _WHOLE
+                  ) -> Dict[str, int]:
     """{solver layout: tile} over the Pallas solver's entries one compiled
     sweep runs (``cholesky_pallas.solver_tile``): batch-major where a side
     on the per-chunk route solves inside its ``lax.map`` steps, lane-major
@@ -1026,36 +1284,47 @@ def _solver_tiles(problem: "BlockedProblem", config: "ALSConfig",
     y_itemsize = exchange.itemsize if exchange else itemsize
     layouts = set()
     for name, side in (("u", problem.u), ("i", problem.i)):
-        for w, r in zip(side.widths, side.rows):
-            in_scan = per_chunk[name] and _chunk_rows(
-                r, w, k, y_itemsize, itemsize, how, config.implicit,
-                True) is not None
-            layouts.add("batch_major" if in_scan else "lane_major")
+        for bucket in _calls(side, cuts[name], per_chunk[name]):
+            for r, w in bucket:
+                in_scan = per_chunk[name] and _chunk_rows(
+                    r, w, k, y_itemsize, itemsize, how, config.implicit,
+                    True) is not None
+                layouts.add("batch_major" if in_scan else "lane_major")
     return {layout: solver_tile(k, layout)[0] for layout in sorted(layouts)}
 
 
 def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
                   k: int, per_chunk: Dict[str, bool],
-                  tiles: Optional[Dict[str, int]] = None) -> None:
-    """The static choices of one compiled sweep, per side: the solve's route
+                  tiles: Optional[Dict[str, int]] = None,
+                  cuts: Dict[str, Optional[CutSide]] = _WHOLE) -> None:
+    """The static choices of one compiled sweep, per side: the table its
+    gather reads, whole or in segments (``table_segments``); the solve's route
     (``solves_per_chunk``) beside the bytes its normal equations take per
     device, how many buckets the kernel takes and their share of the padded
     ratings, and how many of them hand A to the solver lane-major from the
     kernel itself (with ``lanes`` on the materialised route, every bucket
-    ``_chunk_rows`` leaves straight-line; the others are transposed after
-    their lax.map) and their share of the entities; then the rank, the
-    assembly's form and ``tiles`` (``_solver_tiles``)."""
+    ``_chunk_rows`` leaves straight-line, on a cut side in every piece; the
+    others are transposed after their lax.map) and their share of the
+    entities; then the rank, the assembly's form and ``tiles``
+    (``_solver_tiles``)."""
     parts = []
-    for name, side in (("u", problem.u), ("i", problem.i)):
-        padded = sum(w * r for w, r in zip(side.widths, side.rows))
+    for name, side, opp in (("u", problem.u, problem.i),
+                            ("i", problem.i, problem.u)):
+        calls = _calls(side, cuts[name], per_chunk[name])
+        padded = sum(r * w for bucket in calls for r, w in bucket)
         on = len(side.widths) if how == "kernel" else 0
-        direct = [r for w, r in zip(side.widths, side.rows)
+        direct = [bucket[0][0] for bucket in calls
                   if lanes and not per_chunk[name]
-                  and _chunk_rows(r, w, k, 4, 4, how, False, False) is None]
+                  and all(_chunk_rows(r, w, k, 4, 4, how, False, False) is None
+                          for r, w in bucket)]
+        cut = cuts[name]
+        table = (f"{cut.segments} segments of {cut.seg_rows}" if cut
+                 else f"1 segment of {opp.per_block}")
         parts.append(f"{name}-sweep solve "
                      f"{'per chunk' if per_chunk[name] else 'materialised'} "
                      f"({side.per_block * k * k * 4 / 1e9:.2f} GB of normal "
-                     f"equations), kernel on {on} of {len(side.widths)} "
+                     f"equations), table in {table} rows, "
+                     f"kernel on {on} of {len(side.widths)} "
                      f"buckets ({100.0 if on else 0.0:.1f}% of {padded} "
                      f"padded ratings), lane-major hand-off on {len(direct)} "
                      f"({100.0 * sum(direct) / sum(side.rows):.1f}% of "
@@ -1131,12 +1400,17 @@ def _solve_factors_lanes(At, bt, counts, rows, lam, weighted_reg,
     return jnp.where((counts > 0)[:, None], x.T, 0.0)
 
 
-def _flat_side_args(side: SideLayout, dtype, routed=None):
+def _flat_side_args(side: SideLayout, dtype, routed=None, cut=None):
     """Device-arg flattening of one side: bucket (idx, val) pairs then the
     count; a routed half-sweep appends its send plan and swaps the idx
-    arrays for their received-table remapping."""
+    arrays for their received-table remapping; a cut side (``CutSide``)
+    has S pairs a bucket, its pieces, in place of the bucket's one."""
     out = []
     for j in range(len(side.widths)):
+        if cut is not None:
+            for idx, val in zip(cut.idx[j], cut.val[j]):
+                out += [idx, val.astype(dtype)]
+            continue
         out += [
             routed.idx[j] if routed is not None else side.idx[j],
             side.val[j].astype(dtype),
@@ -1168,11 +1442,14 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     # layout (the per-chunk route below solves batch-major)
     lanes = how == "kernel" and resolve_solver(platform) == "pallas"
     per_chunk = _routes(problem, config, mesh)
+    cuts = _cuts(problem, config, mesh)
     if platform == "tpu":
         _log_assembly(problem, how, lanes, k, per_chunk,
-                      _solver_tiles(problem, config, platform, per_chunk))
+                      _solver_tiles(problem, config, platform, per_chunk,
+                                    cuts), cuts)
 
-    def half_sweep(y_shard, flat, routed: bool, fused: bool):
+    def half_sweep(y_shard, flat, routed: bool, fused: bool,
+                   cut: Optional[CutSide], rows: Tuple[int, ...]):
         # y_shard: (1, opp_pb, k) this device's shard of the opposite factors
         # the three named scopes are how a profile tells the sweep's device
         # time apart (they write metadata only; the program is the same)
@@ -1206,6 +1483,12 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             (bucket_args[2 * j][0], bucket_args[2 * j + 1][0])
             for j in range(len(bucket_args) // 2)
         ]
+        seg_rows = None
+        if cut is not None:
+            # S pieces a bucket, each against its segment of the table
+            S, seg_rows = cut.segments, cut.seg_rows
+            buckets = [tuple(buckets[j:j + S])
+                       for j in range(0, len(buckets), S)]
         yty = None
         if implicit:
             # als.gram: what implicit mode adds outside the buckets, the
@@ -1235,8 +1518,14 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
 
             xs = []
             off = 0
-            for idx_b, val_b in buckets:
-                rows_j = idx_b.shape[0]
+            if cut is not None:
+                # every step sums a bucket's pieces before it solves, so
+                # all S tables are live through the half-sweep
+                with jax.named_scope("als.assemble"):
+                    y_all = tuple(_segment_table(y_all, s, seg_rows)
+                                  for s in range(S))
+                buckets = [tuple(zip(*pieces)) for pieces in buckets]
+            for (idx_b, val_b), rows_j in zip(buckets, rows):
                 with jax.named_scope("als.assemble"):
                     xs.append(_bucket_normal_eqs(
                         y_all, idx_b, val_b, implicit, alpha, dtype,
@@ -1251,7 +1540,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             A, b = _assemble_normal_eqs(
                 y_all, buckets, implicit, alpha, dtype,
                 precision=config.assembly_precision, platform=platform,
-                lanes=lanes,
+                lanes=lanes, seg_rows=seg_rows,
             )
         with jax.named_scope("als.solve"):
             if lanes:
@@ -1261,8 +1550,7 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                     with jax.named_scope("als.gram"):
                         A = A + yty[:, :, None]
                 x = _solve_factors_lanes(
-                    A, b, counts[0], [idx_b.shape[0] for idx_b, _ in buckets],
-                    lam, weighted, platform)
+                    A, b, counts[0], rows, lam, weighted, platform)
             else:
                 if implicit:
                     with jax.named_scope("als.gram"):
@@ -1271,7 +1559,11 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                                    platform)
         return x[None]  # (1, per_block, k)
 
-    n_u_args = 2 * n_u_buckets + 1 + (1 if plan["u"] is not None else 0)
+    # (idx, val) pairs a side: one a bucket, S a bucket of a cut side
+    n_u_pairs, n_i_pairs = (
+        n * (cuts[name].segments if cuts[name] else 1)
+        for name, n in (("u", n_u_buckets), ("i", n_i_buckets)))
+    n_u_args = 2 * n_u_pairs + 1 + (1 if plan["u"] is not None else 0)
 
     def fit_body(iterations, uf, itf, *flat):
         u_flat, i_flat = flat[:n_u_args], flat[n_u_args:]
@@ -1280,10 +1572,12 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
             uf, itf = carry
             with jax.named_scope("als.user_half"):
                 uf = half_sweep(itf, u_flat, routed=plan["u"] is not None,
-                                fused=per_chunk["u"])
+                                fused=per_chunk["u"], cut=cuts["u"],
+                                rows=problem.u.rows)
             with jax.named_scope("als.item_half"):
                 itf = half_sweep(uf, i_flat, routed=plan["i"] is not None,
-                                 fused=per_chunk["i"])
+                                 fused=per_chunk["i"], cut=cuts["i"],
+                                 rows=problem.i.rows)
             return uf, itf
 
         # dynamic trip count (lowers to while_loop): one compiled program
@@ -1293,9 +1587,9 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     spec3 = P(BLOCK_AXIS, None, None)
     spec2 = P(BLOCK_AXIS, None)
     flat_specs = (
-        (spec3,) * (2 * n_u_buckets) + (spec2,)
+        (spec3,) * (2 * n_u_pairs) + (spec2,)
         + ((spec3,) if plan["u"] is not None else ())  # send_idx
-        + (spec3,) * (2 * n_i_buckets) + (spec2,)
+        + (spec3,) * (2 * n_i_pairs) + (spec2,)
         + ((spec3,) if plan["i"] is not None else ())
     )
     sharded_fit = shard_map(
@@ -1363,6 +1657,9 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
         resolve_solver(mesh.devices.flat[0].platform),
         _assembly_chunk_bytes(),
         tuple(sorted(_routes(problem, config, mesh).items())),
+        # a cut side's pieces are other argument shapes
+        tuple((name, cut and (cut.seg_rows, tuple(cut.widths)))
+              for name, cut in sorted(_cuts(problem, config, mesh).items())),
     )
     fn = _SWEEP_CACHE.pop(key, None)
     if fn is None:
@@ -1554,7 +1851,12 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     larger side's (``normal_eq_bytes``); the rating slots the gather reads,
     each rating once a side plus the bucket ladder's padding (``entries``),
     and the padding alone (``pad_entries``); the zero slots that padding is
-    spread over, one strip a block (``pad_slots``); the rank (``rank``) and
+    spread over, one strip a block (``pad_slots``); the segments of the
+    table each half gathers from (``table_segments{kind=u|i}``, 1 for a
+    table read whole) and the padded entries gathered from a segment, 0
+    where no side is cut (``segmented_entries``: a cut side's pieces carry
+    their own pads and count in ``entries`` as they are gathered); the rank
+    (``rank``) and
     the systems a grid step of the Pallas solver takes (``solver_tile``: the
     smallest over the entries this sweep runs, and one ``{kind=<layout>}``
     child an entry, 0 for an entry it does not run and under the ``lax``
@@ -1565,16 +1867,23 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
                                            mesh.devices.flat[0].platform)
     y_itemsize = exchange.itemsize if exchange else itemsize
     per_chunk = _routes(problem, config, mesh)
-    rows = fused_rows = chunks = entries = 0
+    cuts = _cuts(problem, config, mesh)
+    rows = fused_rows = chunks = entries = segmented = 0
     for name, side in (("u", problem.u), ("i", problem.i)):
         rows += D * side.per_block
         fused_rows += D * side.per_block * per_chunk[name]
-        for w, r in zip(side.widths, side.rows):
-            C = _chunk_rows(r, w, k, y_itemsize, itemsize, how,
-                            config.implicit, per_chunk[name])
-            chunks += D * (1 if C is None else -(-r // C))
-            entries += D * w * r
+        for bucket in _calls(side, cuts[name], per_chunk[name]):
+            for r, w in bucket:
+                C = _chunk_rows(r, w, k, y_itemsize, itemsize, how,
+                                config.implicit, per_chunk[name])
+                chunks += D * (1 if C is None else -(-r // C))
+                entries += D * w * r
+                segmented += D * w * r * (cuts[name] is not None)
     reg = obs_metrics.get_registry()
+    reg.gauge("tpums_als_segmented_entries").set(segmented)
+    for name, cut in cuts.items():
+        reg.gauge("tpums_als_table_segments", kind=name).set(
+            cut.segments if cut else 1)
     reg.gauge("tpums_als_rows").set(rows)
     reg.gauge("tpums_als_fused_rows").set(fused_rows)
     reg.gauge("tpums_als_chunks").set(chunks)
@@ -1585,7 +1894,7 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     reg.gauge("tpums_als_pad_slots").set(2 * D * _PAD_STRIP)
     reg.gauge("tpums_als_rank").set(k)
     tiles = _solver_tiles(problem, config, mesh.devices.flat[0].platform,
-                          per_chunk)
+                          per_chunk, cuts)
     reg.gauge("tpums_als_solver_tile").set(min(tiles.values(), default=0))
     for layout in ("lane_major", "batch_major"):
         reg.gauge("tpums_als_solver_tile", kind=layout).set(
@@ -1602,7 +1911,9 @@ def compile_fit(
     device-resident, block-sharded inputs.  ``fit_fn(iterations, *dev_args)``
     returns the factor shards as device arrays.  ``als_fit`` drives this;
     benchmarks call ``fit_fn`` directly so host<->device transfer stays out
-    of the timed region.  Phases: ``als.place`` (the host draw of starting
+    of the timed region.  Phases: ``als.prepare.segment`` (only where a
+    side's table is gathered in segments: its lists cut at their
+    boundaries, ``_cuts``), ``als.place`` (the host draw of starting
     factors, the slot layout, every ``device_put``) and ``als.sweep`` (the
     jitted sweep looked up or made, the layout gauges); the sweep is traced,
     lowered and compiled by the first ``fit_fn`` call, which the
@@ -1613,6 +1924,8 @@ def compile_fit(
     # the routing tables are host prep (phase als.prepare.route, where a
     # mesh of more than one device builds them), not placement
     plan = _exchange_plan(problem, D)
+    # so are a cut side's pieces (phase als.prepare.segment)
+    cuts = _cuts(problem, config, mesh)
 
     # enqueue only: device_put returns before the transfer ends, and its
     # tail falls to whoever waits first (the first fit_fn call)
@@ -1650,7 +1963,8 @@ def compile_fit(
 
         dev_args = [put(uf0, shard3), put(itf0, shard3)]
         for name, side in (("u", problem.u), ("i", problem.i)):
-            for a in _flat_side_args(side, dtype, routed=plan[name]):
+            for a in _flat_side_args(side, dtype, routed=plan[name],
+                                     cut=cuts[name]):
                 dev_args.append(put(a, shard2 if a.ndim == 2 else shard3))
     with tracing.phase("als.sweep"):
         fit_fn = _cached_sweep(problem, config, mesh)

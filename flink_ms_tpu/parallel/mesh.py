@@ -314,6 +314,18 @@ def device_memory(device) -> Optional[int]:
     return (stats or {}).get("bytes_limit")
 
 
+# On-chip vector memory (VMEM) of one TensorCore by ``device_kind``: the
+# space XLA marks ``S(1)`` in a compiled program's layouts.  No runtime
+# reports it; a kind that is not listed has none to plan with.
+_FAST_MEMORY_BYTES = {"TPU v5 lite": 128 << 20}
+
+
+def fast_memory(device) -> Optional[int]:
+    """One device's fast on-chip memory in bytes, None where none is known
+    (the CPU backend, a TPU generation nobody has read)."""
+    return _FAST_MEMORY_BYTES.get(device.device_kind)
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

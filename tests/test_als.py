@@ -209,6 +209,304 @@ def test_the_strip_stays_zero_and_moves_no_factor(rng, monkeypatch, mode,
         np.testing.assert_array_equal(got, old.reshape(-1, k)[side.perm])
 
 
+# -- a gather table read through segments that fit the fast memory ------------
+
+def _fast_memory_for(rows, k, segments):
+    """A fast memory beside which a table of `rows` slots of k f32 values
+    is read in `segments` segments (`table_segments`' own arithmetic)."""
+    from flink_ms_tpu.ops.assemble_pallas import _lanes_vmem_limit
+
+    fit = -(-(rows - A._PAD_STRIP) // segments) + A._PAD_STRIP
+    return _lanes_vmem_limit(k) + A._FAST_MEMORY_SLACK + fit * 512
+
+
+def _ratings_problem(rng, n_users=300, n_items=90, nnz=6000, lonely=10):
+    """Every id present; the last `lonely` items hold one rating each, so
+    their lists have no entry in all segments but one."""
+    nnz -= lonely
+    users = np.concatenate([np.arange(n_users),
+                            rng.integers(0, n_users, nnz - n_users)])
+    items = np.concatenate([np.arange(n_items),
+                            rng.integers(0, n_items, nnz - n_items)])
+    rng.shuffle(items)
+    users = np.concatenate([users, rng.integers(0, n_users, lonely)])
+    items = np.concatenate([items, n_items + np.arange(lonely)])
+    return users, items, rng.uniform(1, 5, len(users))
+
+
+@pytest.mark.parametrize("rows, k, fast, reserved, want", [
+    # the three ALS cells on a v5e (128 MiB; the kernel's limit 40 MB up to
+    # rank 64, 49 MiB at 100): both of als-ml20m's tables, msd-ials' songs
+    # and netflix' movies lie whole, the two user tables in four segments
+    (138621, 50, 128 << 20, 40 << 20, 1),
+    (26872, 50, 128 << 20, 40 << 20, 1),
+    (41268, 64, 128 << 20, 40 << 20, 1),
+    (571483, 64, 128 << 20, 40 << 20, 4),
+    (17898, 100, 128 << 20, 49 << 20, 1),
+    (480317, 100, 128 << 20, 49 << 20, 4),
+    # rank 129 takes two lane tiles a row
+    (100000, 129, 128 << 20, 61 << 20, 2),
+    # no fast memory reported (the CPU): the table is read whole
+    (571483, 64, None, 40 << 20, 1),
+    # a budget under one row still gives segments of a row
+    (1128, 8, 1, 0, 1000),
+])
+def test_segments_are_a_function_of_the_sizes(rows, k, fast, reserved, want):
+    assert A.table_segments(rows, k, 4, fast, reserved) == want
+    if fast and want > 1:
+        # the fewest: one segment fewer does not fit beside the kernel
+        seg = -(-(rows - A._PAD_STRIP) // (want - 1)) + A._PAD_STRIP
+        lane_bytes = -(-k // 128) * 512
+        assert seg * lane_bytes > fast - reserved - A._FAST_MEMORY_SLACK
+
+
+@pytest.mark.parametrize("w, share, longest, want", [
+    # netflix-als-f100's widest movie bucket in the heaviest users' segment:
+    # 327,712 * 0.719 + 6 sd (1,544), where the longest run found was 167,536
+    (327712, 0.719, 167536, 237176),
+    # the expectation governs: the width does not move with the longest run
+    (327712, 0.719, 237000, 237176),
+    # ... until the data are not paired at random: then the run does
+    (327712, 0.719, 250001, 250008),
+    (1120, 0.027, 56, 64),         # 30.2 + 6 * 5.4 = 62.8
+    (216, 0.085, 40, 48),
+    (24, 0.5, 24, 24),             # never wider than the bucket
+    (8, 0.0, 0, 8),                # an empty segment still has a piece
+])
+def test_a_pieces_width_comes_from_the_degrees_where_pairing_is_random(
+        w, share, longest, want):
+    assert A._piece_width(w, share, longest) == want
+
+
+@pytest.mark.parametrize("segments", [2, 3, 5])
+def test_cut_lists_keep_every_rating_in_order_and_pad_on_their_own_strip(
+        rng, segments):
+    """Each bucket's S pieces: a list's real entries lead and ascend, lie
+    inside the segment, and read together in segment order are the list as
+    `_fill_side` stored it (no rating dropped, moved or reordered); a
+    piece is as wide as `_piece_width` says and never narrower than its
+    longest run; position p of a piece's flat order pads on slot p mod
+    _PAD_STRIP of the segment's own strip, so no slot comes back within
+    _PAD_STRIP positions."""
+    P = A._PAD_STRIP
+    users, items, ratings = _ratings_problem(rng)
+    p = A.prepare_blocked(users, items, ratings, 1)
+    empty_runs = 0
+    for side, opp in ((p.i, p.u), (p.u, p.i)):
+        real = opp.per_block - P
+        cut = A.cut_side(side, opp, segments)
+        assert cut.segments == segments
+        assert cut.seg_rows == -(-real // segments)
+        held = [opp.count[0, lo:min(lo + cut.seg_rows, real)].sum()
+                for lo in range(0, real, cut.seg_rows)]
+        assert sum(held) == p.nnz
+        for j, (ix, vl) in enumerate(zip(side.idx, side.val)):
+            assert len(cut.idx[j]) == len(cut.val[j]) == segments
+            lists = [[] for _ in range(side.rows[j])]
+            for s in range(segments):
+                piece, rating = cut.idx[j][s][0], cut.val[j][s][0]
+                assert piece.dtype == np.int32
+                assert piece.shape == rating.shape == (side.rows[j],
+                                                       cut.widths[j][s])
+                pad = piece >= cut.seg_rows
+                runs = (~pad).sum(axis=1)
+                empty_runs += int((runs == 0).sum())
+                assert runs.max() <= piece.shape[1] == A._piece_width(
+                    side.widths[j], held[s] / p.nnz, int(runs.max()))
+                where = np.nonzero(pad.ravel())[0]
+                np.testing.assert_array_equal(
+                    piece.ravel()[where], cut.seg_rows + where % P)
+                assert (rating[pad] == 0).all() and (rating[~pad] != 0).all()
+                for row, (slots, vals, n) in enumerate(zip(piece, rating, runs)):
+                    assert not pad[row, :n].any() and pad[row, n:].all()
+                    assert (np.diff(slots[:n]) >= 0).all()
+                    assert (slots[:n] + s * cut.seg_rows < real).all()
+                    lists[row].append((slots[:n] + s * cut.seg_rows, vals[:n]))
+            for row, parts in enumerate(lists):
+                whole = ix[0][row] < real
+                np.testing.assert_array_equal(
+                    np.concatenate([a for a, _ in parts]), ix[0][row][whole])
+                np.testing.assert_array_equal(
+                    np.concatenate([v for _, v in parts]), vl[0][row][whole])
+    assert empty_runs > 0   # some list has no entry in some segment
+
+
+@pytest.mark.parametrize("segments", [2, 3])
+@pytest.mark.parametrize("route", ["materialised", "per chunk"])
+@pytest.mark.parametrize("assembly", ["einsum", "kernel"])
+def test_a_table_read_in_segments_gives_the_whole_tables_fit(
+        rng, monkeypatch, assembly, route, segments):
+    """Explicit mode, two iterations: with a fast memory that holds the
+    user table in `segments` pieces (and the smaller item table in fewer),
+    both halves' sums are the whole table's in another order."""
+    monkeypatch.setenv("FLINK_MS_ALS_FUSED", "1" if route == "per chunk" else "0")
+    # small enough that the widest pieces run under lax.map
+    monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES",
+                       "65536" if assembly == "kernel" else "8192")
+    if assembly == "kernel":
+        monkeypatch.setenv("FLINK_MS_ALS_SOLVER", "pallas")
+        monkeypatch.setattr(A, "resolve_assembly", _kernel)
+    k = 8
+    users, items, ratings = _ratings_problem(rng)
+    init = (rng.random((300, k), dtype=np.float32),
+            rng.random((100, k), dtype=np.float32))
+    cfg = A.ALSConfig(num_factors=k, iterations=2, lambda_=0.1,
+                      exchange_dtype=None)
+    mesh = make_mesh(1)
+    problem = A.prepare_blocked(users, items, ratings, 1)
+    fast = _fast_memory_for(problem.i.per_block, k, segments)
+    fits = []
+    try:
+        for memory in (None, fast):
+            monkeypatch.setattr(A, "fast_memory", lambda device: memory)
+            A._SWEEP_CACHE.clear()   # the resolver is not in its key
+            fits.append(A.als_fit(users, items, ratings, cfg, mesh,
+                                  problem=problem, init=init))
+            want = {"u": segments if memory else 1,
+                    "i": A.table_segments(problem.u.per_block, k, 4, memory,
+                                          fast - 128 * 512) if memory else 1}
+            assert A._segments(problem, cfg, mesh)["u"] == want["u"]
+    finally:
+        A._SWEEP_CACHE.clear()
+    whole, cut = fits
+    assert A._segments(problem, cfg, mesh)["i"] > segments
+    for got, ref in ((cut.user_factors, whole.user_factors),
+                     (cut.item_factors, whole.item_factors)):
+        assert np.abs(got - ref).max() / np.abs(ref).max() < 2e-5
+
+
+def test_with_no_fast_memory_the_sweep_is_the_parents_to_the_character():
+    """The CPU reports no fast memory: S = 1 on both sides and the jaxpr of
+    `fit_body` on als-ml20m's tiny twin is the one the commit before the
+    segments traced (its digest, taken from that commit's tree)."""
+    import hashlib
+    import json
+    import os
+
+    from benchmark import synth
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark/tests/tiny/als-tiny.json")) as f:
+        cell = json.load(f)
+    users, items, values, _ = synth.als_problem(cell, 1)
+    problem = A.prepare_blocked(users, items, values, 1)
+    cfg = A.ALSConfig(num_factors=cell["rank"], iterations=1,
+                      lambda_=cell["lambda"], dtype=jnp.float32,
+                      assembly_precision=cell["assembly_precision"],
+                      exchange_dtype=cell["exchange_dtype"])
+    mesh = make_mesh(1)
+    assert A._segments(problem, cfg, mesh) == {"u": 1, "i": 1}
+    assert A._cuts(problem, cfg, mesh) == {"u": None, "i": None}
+    fit_fn, dev_args = A.compile_fit(problem, cfg, mesh)
+    text = str(jax.make_jaxpr(lambda n, *a: fit_fn(n, *a))(
+        jnp.asarray(1, jnp.int32), *dev_args))
+    assert hashlib.sha1(text.encode()).hexdigest() == (
+        "016e8c13c90951ad32f65924e177579f0d11b732")
+
+
+@pytest.mark.parametrize("blocks, platform, assembly, want", [
+    (1, "cpu", "einsum", True),     # a test's fast memory cuts a CPU fit
+    (4, "cpu", "einsum", False),    # a mesh of several devices reads whole
+    (1, "tpu", "kernel", True),     # the cells' path
+    (1, "tpu", "einsum", False),    # a TPU's einsum pair (bf16 exchange)
+])
+def test_which_fits_read_their_tables_in_segments(rng, monkeypatch, blocks,
+                                                  platform, assembly, want):
+    """D > 1 holds S = 1 (the gathered table of D blocks is not cut), and
+    so does the einsum pair on a TPU; a fit over four devices with a fast
+    memory reported is the fit without one."""
+    import types
+
+    k = 8
+    users, items, ratings = _ratings_problem(rng)
+    problem = A.prepare_blocked(users, items, ratings, blocks)
+    cfg = A.ALSConfig(num_factors=k, iterations=1, exchange_dtype=None)
+    init = (rng.random((300, k), dtype=np.float32),
+            rng.random((100, k), dtype=np.float32))
+    if blocks > 1:
+        whole = A.als_fit(users, items, ratings, cfg, make_mesh(blocks),
+                          problem=problem, init=init)
+    fast = _fast_memory_for(problem.i.per_block, k, 3)   # the smaller
+    monkeypatch.setattr(A, "fast_memory", lambda device: fast)
+    A._SWEEP_CACHE.clear()
+    try:
+        if blocks > 1:
+            same = A.als_fit(users, items, ratings, cfg, make_mesh(blocks),
+                             problem=problem, init=init)
+            assert not problem.cuts
+            np.testing.assert_array_equal(same.user_factors,
+                                          whole.user_factors)
+            np.testing.assert_array_equal(same.item_factors,
+                                          whole.item_factors)
+    finally:
+        A._SWEEP_CACHE.clear()
+    monkeypatch.setattr(A, "resolve_assembly", lambda *a, **kw: assembly)
+    device = types.SimpleNamespace(platform=platform)
+    mesh = types.SimpleNamespace(
+        devices=np.array([device] * blocks), shape={A.BLOCK_AXIS: blocks})
+    got = A._segments(problem, cfg, mesh)
+    assert (got["i"] > 1) is want and (got["u"] > 1) is want
+
+
+def test_the_gauges_and_the_line_say_what_was_cut(rng, monkeypatch, capsys):
+    """`tpums_als_table_segments{kind}`, `tpums_als_segmented_entries`
+    beside `tpums_als_entries` (a cut side's pieces counted as gathered,
+    pads and all), the phase around the cut, and the words of the
+    `[als] assembly:` line."""
+    from flink_ms_tpu.obs import metrics as obs_metrics
+    from flink_ms_tpu.obs import tracing
+
+    k = 8
+    users, items, ratings = _ratings_problem(rng)
+    problem = A.prepare_blocked(users, items, ratings, 1)
+    cfg = A.ALSConfig(num_factors=k, iterations=1, exchange_dtype=None)
+    mesh = make_mesh(1)
+
+    def gauges():
+        return {(g["name"], g["labels"].get("kind")): g["value"]
+                for g in obs_metrics.get_registry().snapshot()["gauges"]
+                if g["name"].startswith("tpums_als_")}
+
+    A.compile_fit(problem, cfg, mesh)
+    whole = gauges()
+    assert whole["tpums_als_segmented_entries", None] == 0
+    assert whole["tpums_als_table_segments", "u"] == 1
+    assert whole["tpums_als_table_segments", "i"] == 1
+    # the user table (the item half's) in three segments, the item table whole
+    item_rows = problem.i.per_block
+    fast = _fast_memory_for(problem.u.per_block, k, 3)
+    assert A.table_segments(item_rows, k, 4, fast, fast - 512 * (
+        -(-(problem.u.per_block - 128) // 3) + 128) - A._FAST_MEMORY_SLACK) == 1
+    monkeypatch.setattr(A, "fast_memory", lambda device: fast)
+    before = len([p for p in tracing.phase_log()
+                  if p["name"] == "als.prepare.segment"])
+    A._SWEEP_CACHE.clear()
+    try:
+        A.compile_fit(problem, cfg, mesh)
+        A.compile_fit(problem, cfg, mesh)   # the cut is kept on the problem
+    finally:
+        A._SWEEP_CACHE.clear()
+    assert len([p for p in tracing.phase_log()
+                if p["name"] == "als.prepare.segment"]) == before + 1
+    got = gauges()
+    cut = problem.cuts["i", 3]
+    pieces = sum(a.size for bucket in cut.idx for a in bucket)
+    assert got["tpums_als_table_segments", "u"] == 1
+    assert got["tpums_als_table_segments", "i"] == 3
+    assert got["tpums_als_segmented_entries", None] == pieces
+    assert got["tpums_als_entries", None] == pieces + sum(
+        a.size for a in problem.u.idx)
+    assert got["tpums_als_pad_entries", None] == (
+        got["tpums_als_entries", None] - 2 * len(ratings))
+    assert got["tpums_als_entries", None] > whole["tpums_als_entries", None]
+    A._log_assembly(problem, "kernel", True, k, {"u": False, "i": False},
+                    cuts={"u": None, "i": cut})
+    u, i = capsys.readouterr().out.split("i-sweep")
+    assert f"table in 1 segment of {item_rows} rows" in u
+    assert f"table in 3 segments of {cut.seg_rows} rows" in i
+    assert f"of {pieces} padded ratings" in i
+
+
 def test_assembly_matches_numpy(rng):
     u, i, r = _synthetic(rng, n_users=12, n_items=9)
     k = 4

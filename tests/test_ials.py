@@ -153,6 +153,46 @@ def test_implicit_iteration_agrees_with_the_reference(rng, monkeypatch, route,
             assert "assemble_bucket" in {n for n, _ in seen}
 
 
+@pytest.mark.parametrize("segments", [2, 3])
+@pytest.mark.parametrize("route", ["0", "1"])
+@pytest.mark.parametrize("assembly", ["einsum", "kernel"])
+def test_a_table_read_in_segments_agrees_with_the_reference_and_the_whole(
+        rng, monkeypatch, assembly, route, segments):
+    """A fast memory that holds the user table in `segments` pieces (the
+    item table in one fewer): the weighted sums over a list's runs, added in
+    the solve dtype, are the list's; Y^T Y is the whole table's either
+    way.  Against the float64 reference at the tolerance of the uncut
+    sweep, and against the uncut sweep to float32 round-off."""
+    from flink_ms_tpu.ops.assemble_pallas import _lanes_vmem_limit
+
+    env = {"FLINK_MS_ALS_FUSED": route, "FLINK_MS_ALS_SOLVER": "pallas",
+           "FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES":
+               "4096" if assembly == "einsum" else "65536"}
+    _, _, _, whole, _, _ = one_iteration(rng, monkeypatch, 1, env,
+                                         assembly=assembly)
+    rows = 60 + A._PAD_STRIP
+    fit = -(-60 // segments) + A._PAD_STRIP
+    fast = _lanes_vmem_limit(8) + A._FAST_MEMORY_SLACK + fit * 512
+    monkeypatch.setattr(A, "fast_memory", lambda device: fast)
+    monkeypatch.setattr(A, "_SWEEP_CACHE", {})
+    problem, cfg, mesh, model, want_u, want_i = one_iteration(
+        np.random.default_rng(42), monkeypatch, 1, env, assembly=assembly)
+    # ... at three the 25 songs' table no longer fits whole either
+    assert A._segments(problem, cfg, mesh) == {"u": segments - 1,
+                                               "i": segments}
+    assert A.table_segments(rows, 8, 4, fast, _lanes_vmem_limit(8)) == segments
+    assert row_error(model.user_factors, want_u) < TOL
+    assert row_error(model.item_factors, want_i) < TOL
+    assert row_error(model.user_factors, whole.user_factors) < TOL / 4
+    assert row_error(model.item_factors, whole.item_factors) < TOL / 4
+    gauges = als_gauges()
+    cut = sum(a.size for (name, _), side in problem.cuts.items()
+              for bucket in side.idx for a in bucket)
+    assert gauges["tpums_als_segmented_entries"] == cut > 0
+    assert gauges["tpums_als_entries"] == cut + (
+        sum(a.size for a in problem.u.idx) if segments == 2 else 0)
+
+
 def test_the_sweep_decides_per_side_and_still_agrees(rng, monkeypatch):
     """A device whose memory holds four of the item side's tensors and not
     four of the user side's: users per chunk, items materialised."""
