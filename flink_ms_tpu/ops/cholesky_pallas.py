@@ -26,14 +26,25 @@ way: at the ML-20M shape 7.2 ms an iteration in ``als.solve`` where the
 reg add, the pad and the kernel took 18.3 (PERF.md section 5, PR 30).
 
 One rule, ``solver_tile``, gives every entry its tile and the VMEM it asks
-for.  Up to rank 64 that is the default scoped 16 MB (the ranks the cells
-``als-ml20m.retrain``, 50, and ``msd-ials.ials-retrain``, 64, time); from 65
-to 128 the kernel names its own limit, because one lane tile of systems no
-longer fits the default: at rank 100 the lane-major entries need 31 MiB and
-the batch-major one 21 MiB (what the v5e compiler reports, PERF.md section
-3, PR 44; ``netflix-als-f100.retrain`` times that rank).  The elimination is
-unrolled over the static k, so the kernel's trace and lowering grow with k
-squared: the price of a start, not of an iteration.
+for: a whole lane tile of systems at every rank, under the default scoped
+16 MB up to rank 64 (the ranks the cells ``als-ml20m.retrain``, 50, and
+``msd-ials.ials-retrain``, 64, time); from 65 to 128 the kernel names its
+own limit, because a lane tile of systems no longer fits the default: at
+rank 100 the lane-major entries need 31 MiB and the batch-major one 17 (what
+the v5e compiler reports, PERF.md section 3, PR 46;
+``netflix-als-f100.retrain`` times that rank).  The elimination is unrolled
+over the static k and shrinks with the pivot, a sublane group of rows and
+columns at a time (``_solve_tile``), so the kernel's trace and lowering grow
+with k squared: the price of a start, not of an iteration.  What an
+iteration pays is not the downdates' arithmetic.  The working matrix is 450
+to 1,300 vregs against 64 registers, and the compiler's schedule of the
+lane-major body sends every downdated vreg and most outer products through
+VMEM, one store a bundle: 43,500 stores in the 50,100 bundles of a tile at
+rank 100.  The batch-major body stores a third of that and fills the
+arithmetic slots instead, half of them with the sublane rotations, selects
+and permutes that gather and transpose the block's rows
+(``scripts/solver_bundles.py`` counts both without a chip; PERF.md section
+6, PR 46).
 
 The caller says where it runs: ``interpret=True`` is the interpreter-mode
 path CPU tests pin numerics with, ``interpret=False`` compiles for the TPU.
@@ -55,6 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128  # one lane tile: the systems a grid step solves side by side
+SUBLANES = 8  # an f32 vreg's rows: the step by which the elimination shrinks
 
 
 def _round_up(x: int, m: int) -> int:
@@ -69,29 +81,34 @@ def solver_tile(k: int, layout: str) -> Tuple[int, Optional[int]]:
     arriving as (k, k, tile) blocks) or "batch_major" (A arriving as
     (tile, k, k) blocks and transposed in VMEM).
 
-    Up to rank 64 every answer is the one the cells' programs were compiled
-    and timed with: a whole lane tile under the default limit, except the
-    batch-major entry from rank 57, which takes half a tile (its nine
-    k x k x tile buffers measured 18.87 MB at k = 64 against the 16 MB limit
-    on the installation it was written on).
-
-    Above 64 a lane tile no longer fits the default, and a smaller tile
-    does not help: in VMEM the systems lie on the lanes, and a tile of 32 or
-    64 occupies the same whole 128-lane tiles (the batch-major kernel needs
-    21 MiB at k = 100 with a tile of 128, 64 and 32 alike) and leaves VPU
-    lanes idle.  So the tile stays whole and the kernel asks for what it
-    needs, counted as eight buffers of k rows (padded to 8 sublanes) x k x
-    128 lanes of f32: the input block twice (double-buffered), the
-    downdated copy, the column and the row stack, and headroom.  The least
-    limit the v5e compiler accepts, bisected to the MiB (a described chip,
-    PR 44): lane-major 13 MiB at k = 64 (6.5 buffers), 31 at 100 (6.1), 50
-    at 128 (6.3); batch-major 9, 21 and 47.  The rule asks for 43 MB at
-    k = 100 and 67 at k = 128, of the v5e's 128 MiB of VMEM: a ceiling, not
-    an allocation."""
+    The tile is a whole lane tile at every rank and on every entry: in VMEM
+    the systems lie on the lanes, so a tile of 32 or 64 occupies the same
+    whole 128-lane vregs, does the same vector work for fewer systems and
+    needs the same memory (the batch-major kernel at k = 100 compiled under
+    the same limit with a tile of 128, 64 and 32, PR 44; at k = 64 half a
+    tile read 131 ns a system on the chip where a whole one reads 79, PR
+    46).  Up to rank 64 that fits Mosaic's default limit.  Above it the
+    kernel asks for what it needs, counted as eight buffers of k rows
+    (padded to 8 sublanes) x k x 128 lanes of f32: the input block twice
+    (double-buffered), the working copy, the column and the row stack, and
+    headroom.  The least limit the v5e compiler accepts, bisected to the MiB
+    for this body (a described chip, PR 46), at k = 57, 64, 100 and 128:
+    lane-major 11, 13, 31 and 50 MiB (6.1 to 6.5 buffers; as before the
+    elimination shrank, the whole tile being live at the first step);
+    batch-major 6, 7, 17 and 50 (3.4 buffers, 6.3 at k = 128; 9, 21 and 47
+    at 64, 100 and 128 while one 3-D transpose laid the block out).  A
+    batch-major call sits in a ``lax.map`` step between XLA's own passes,
+    which keep some 7 MiB of scoped VMEM of their own around it: the whole
+    tile with the 3-D transpose was refused there at k = 64 (16.55 MB asked
+    of 16, ``msd-ials``, PR 46); this body's 7 fit.  The default must do up to
+    rank 64: a kernel that names more than 16 MiB inside that step costs
+    ``msd-ials``' item table its place in the chip's fast memory
+    (``scripts/als_compiled_layout.py``, PR 46).  Both layouts get one
+    answer above it, sized for the larger: 43 MB at k = 100 and 67 at
+    k = 128, of the v5e's 128 MiB of VMEM, a ceiling and not an
+    allocation."""
     if k <= 64:
-        halve = (layout == "batch_major"
-                 and 9 * k * k * LANES * 4 > 14 * (1 << 20))
-        return (LANES // 2 if halve else LANES), None
+        return LANES, None
     return LANES, 8 * _round_up(k, 8) * k * LANES * 4
 
 
@@ -105,40 +122,71 @@ def _compiler_params(vmem_limit: Optional[int]) -> dict:
             pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))}
 
 
+def _row(x, j: int):
+    """x[j:j + 1] as the one ``slice`` an index expression ends in; ``_at(x,
+    j, axis)`` likewise is x with ``axis`` indexed at j.  The index
+    expression costs the tracer three times as much, and a step of
+    ``_solve_tile`` has eleven, in a body traced once a bucket."""
+    return jax.lax.slice_in_dim(x, j, j + 1, axis=0)
+
+
+_at = functools.partial(jax.lax.index_in_dim, keepdims=False)
+
+
 def _solve_tile(M, b, k: int):
     """A (k, k, T) SPD, b (k, T) -> x (k, T), T systems on the lanes.
 
     Right-looking Cholesky by rank-1 downdates, then the two triangular
     substitutions, fully unrolled over the static k — every op is
     vectorized over the T lanes.
+
+    The downdate runs on the trailing block only: after every SUBLANES
+    pivots the finished rows and columns leave the working matrix, so step
+    j touches (k - o)² entries, o = j rounded down to a sublane group, and
+    not k².  Above the pivot's group the full-tile form subtracted 0 from
+    entries no later step reads; what is read sees the same operations in
+    the same order, so x is the full-tile form's to the bit.  Mosaic drops
+    a vreg nobody reads, so the compiled full-tile body already skipped
+    those updates, and more: the chip's time is the same for both (PERF.md
+    section 6, PR 46).  The trailing block is what the tracer, the
+    interpreter and the compiler are spared.
     """
-    rows = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
-    cols = []                                     # cols[j]: (k, T), >=2D ops
+    zero = jnp.zeros((), M.dtype)
+    cols, diag = [], []         # cols[j]: (k, T), >=2D ops; diag[j]: (1, T)
     for j in range(k):
-        d = jax.lax.rsqrt(M[j, j:j + 1, :])       # (1, T)
-        col = M[:, j, :] * d                      # (k, T)
-        col = jnp.where(rows >= j, col, 0.0)      # zero rows above the pivot
-        cols.append(col)
-        M = M - col[:, None, :] * col[None, :, :]
-    # L[i, j] = cols[j][i]; diag entries as a (k, T) stack for the solves
-    diag = jnp.concatenate([c[j:j + 1, :] for j, c in enumerate(cols)], axis=0)
+        jj = j % SUBLANES                         # the pivot inside its group
+        if not jj:
+            if j:
+                # the leading axis is untiled and the second is cut at a
+                # whole sublane tile: whole vregs leave, no relayout
+                M = M[SUBLANES:, SUBLANES:, :]
+            m = M.shape[0]                        # k - o
+            rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+        d = jax.lax.rsqrt(_row(_at(M, jj, 0), jj))   # M[jj, jj:jj + 1, :]
+        col = _at(M, jj, 1) * d                   # M[:, jj, :], (k - o, T)
+        col = jnp.where(rows >= jj, col, 0.0)     # zero rows above the pivot
+        M = M - jnp.expand_dims(col, 1) * jnp.expand_dims(col, 0)
+        diag.append(_row(col, jj))                # L[j, j]
+        # the substitutions read whole columns, zero above the pivot
+        cols.append(col if m == k else jax.lax.pad(
+            col, zero, ((k - m, 0, 0), (0, 0, 0))))
 
     # forward solve L z = b with a running accumulator acc = Σ_p L[:,p]·z_p
     acc = jnp.zeros_like(b)
     zs = []                                       # zs[j]: (1, T)
     for j in range(k):
-        z = (b[j:j + 1, :] - acc[j:j + 1, :]) / diag[j:j + 1, :]
+        z = (_row(b, j) - _row(acc, j)) / diag[j]
         zs.append(z)
         acc = acc + cols[j] * z
     # back solve Lᵀ x = z: after fixing x_j, fold row j of L (gathered
     # from the column stack: L[j, p] = cols[p][j]) into acc
-    Lrows = jnp.stack([c for c in cols], axis=1)  # (k, k, T): [i, j, :]
+    Lrows = jnp.stack(cols, axis=1)               # (k, k, T): [i, j, :]
     acc = jnp.zeros_like(b)
     xs = [None] * k
     for j in reversed(range(k)):
-        x = (zs[j] - acc[j:j + 1, :]) / diag[j:j + 1, :]
+        x = (zs[j] - _row(acc, j)) / diag[j]
         xs[j] = x
-        acc = acc + Lrows[j, :, :] * x            # row j of L, (k, T)
+        acc = acc + _at(Lrows, j, 0) * x          # row j of L, (k, T)
     return jnp.concatenate(xs, axis=0)            # (k, T)
 
 
@@ -181,7 +229,11 @@ def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
     inside a lax.map/scan body that layout materialized as a degenerate-
     dim copy lane-padded x128 (62.5 GB for a (43648, 50, 50) chunk, the
     round-3 fused-mode AOT OOM)."""
-    M = jnp.transpose(a_ref[:], (1, 2, 0))        # (k, k, T) in VMEM
+    # (k, k, T) in VMEM, a row of every system at a time: A[:, i, :] is
+    # (T, k), and its 2-D transpose the slab M[i].  The one 3-D transpose
+    # of the block cost more than the factorization it fed (254 ns a system
+    # at k = 100 against 183 this way, TPU v5e, PR 46; section 7 there).
+    M = jnp.stack([a_ref[:, i, :].T for i in range(k)], axis=0)
     b = jnp.transpose(b_ref[:], (1, 0))           # (k, T)
     x_ref[:] = jnp.transpose(_solve_tile(M, b, k), (1, 0))
 

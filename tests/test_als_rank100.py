@@ -263,8 +263,9 @@ def als_gauges():
     # per-chunk route runs both (its straight-line buckets solve lane-major)
     ("pallas", "1", K, (128, 128, 128)),
     ("pallas", "0", K, (128, 128, 0)),
-    # rank 64 per chunk: the batch-major entry's half tile, as msd-ials runs
-    ("pallas", "1", 64, (64, 128, 64)),
+    # rank 64 per chunk, as msd-ials runs: whole tiles there too (the
+    # batch-major entry took half a tile at ranks 57-64 until PR 46)
+    ("pallas", "1", 64, (128, 128, 128)),
 ])
 def test_gauges_say_the_rank_and_the_solvers_tile(rng, monkeypatch, solver,
                                                   route, k, want):

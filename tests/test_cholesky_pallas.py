@@ -33,9 +33,8 @@ def test_matches_numpy(rng, k, n):
 @pytest.mark.parametrize("n", [1, 100, 257])
 def test_batch_major_matches_lane_major(rng, k, n):
     """The batch-major variant (per-tile VMEM transpose; forced inside
-    fused scan bodies, auto tile-halving at k=64) must agree with the
-    lane-major kernel — same elimination arithmetic, different operand
-    routing."""
+    fused scan bodies) must agree with the lane-major kernel — same
+    elimination arithmetic, different operand routing."""
     G = rng.standard_normal((n, k, k)).astype(np.float32)
     A = G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32)
     b = rng.standard_normal((n, k)).astype(np.float32)
@@ -130,7 +129,10 @@ def test_als_fit_with_pallas_solver_matches_default(rng, monkeypatch):
 _TPU_LOWERED = {
     "cholesky_pallas.py": [(50, "lane_major"), (64, "batch_major"),
                            (50, "batch_major"), (64, "lane_major"),
-                           (100, "lane_major"), (100, "batch_major")],
+                           (100, "lane_major"), (100, "batch_major"),
+                           # the first rank whose batch-major entry took
+                           # half a tile until PR 46: a whole one lowers
+                           (57, "batch_major")],
     # (w, k) of the assembly kernel: the user side's narrowest and a middle
     # bucket of ML-20M, rank 64, and the item side's 64,728-wide bucket,
     # which goes through the tiled-w path with a ragged last tile
@@ -299,11 +301,16 @@ def one_chip():
 
 
 @pytest.mark.parametrize("k,entry", [(100, "lane_major"), (100, "batch_major"),
-                                     (100, "lanes"), (128, "lanes")])
-def test_solver_compiles_for_a_v5e_above_rank_64(one_chip, k, entry):
+                                     (100, "lanes"), (128, "lanes"),
+                                     # a whole lane tile under the default
+                                     # limit at the ranks whose batch-major
+                                     # entry took half of one until PR 46
+                                     (57, "batch_major"), (64, "batch_major")])
+def test_solver_compiles_for_a_v5e(one_chip, k, entry):
     """netflix-als-f100.retrain's rank on all three entries, and the top of
     the stated range on the widest: under Mosaic's default scoped limit
-    every one of them is refused (30.88 MB of 16 at k = 100, PR 44)."""
+    each of those is refused (30.88 MB of 16 at k = 100, PR 44).  Up to rank
+    64 the default has to do (``solver_tile``), for a whole tile too."""
     n = 512
 
     def shape(*dims):

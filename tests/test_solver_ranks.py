@@ -48,12 +48,12 @@ def test_ranks_above_64_match_numpy(rng, k, entry):
 
 
 @pytest.mark.parametrize("k,layout,want", [
-    # up to rank 64 the answers the cells' programs were compiled with: a
-    # whole lane tile and no named limit, half a tile batch-major from 57
+    # up to rank 64 a whole lane tile and no named limit on every entry:
+    # the batch-major one took half a tile from rank 57 until PR 46
     (10, "lane_major", (128, None)), (50, "lane_major", (128, None)),
     (64, "lane_major", (128, None)), (50, "batch_major", (128, None)),
-    (56, "batch_major", (128, None)), (57, "batch_major", (64, None)),
-    (64, "batch_major", (64, None)),
+    (56, "batch_major", (128, None)), (57, "batch_major", (128, None)),
+    (64, "batch_major", (128, None)),
     # above it the tile stays whole and the kernel asks for eight
     # (k rows padded to 8) x k x 128-lane f32 buffers
     (65, "lane_major", (128, 8 * 72 * 65 * 512)),
@@ -65,6 +65,10 @@ def test_solver_tile_rule(k, layout, want):
     assert solver_tile(k, layout) == want
     tile, limit = want
     if limit is not None:
-        # over what the v5e compiler needed (bisected, PR 44: 31 and 50 MiB
-        # lane-major at k = 100 and 128), under the chip's 128 MiB of VMEM
-        assert {100: 31 << 20, 128: 50 << 20}.get(k, 0) < limit < 96 << 20
+        # over what the v5e compiler needed (bisected again for PR 46's
+        # body: 31 and 50 MiB lane-major at k = 100 and 128, 17 and 50
+        # batch-major), with the 7 MiB XLA keeps around a batch-major call
+        # inside a lax.map step, and under the chip's 128 MiB of VMEM
+        floor = {("lane_major", 100): 31, ("lane_major", 128): 50,
+                 ("batch_major", 100): 17 + 7, ("batch_major", 128): 50 + 7}
+        assert floor.get((layout, k), 0) << 20 < limit < 96 << 20
