@@ -116,12 +116,13 @@ class ALSConfig:
     # changes the bytes moved).  Normal equations still accumulate in the
     # solve dtype via preferred_element_type.  None = full precision;
     # "auto" (the default) resolves per backend in resolve_exchange():
-    # bfloat16 on TPU — 0.1786 against 0.2287 s/iter at the ML-20M shape
-    # with both on the einsum pair (PERF.md section 7 row 2, PR 24; since
-    # PR 26 the f32 exchange runs the assembly kernel and is ahead) at a
-    # +1.4e-5 relative train-RMSE delta vs an f64 reference (2026-07-31,
-    # earlier installation, not reproduced) — and full precision
-    # elsewhere.
+    # bfloat16 on TPU and full precision elsewhere.  At the ML-20M shape
+    # on one v5e the two answers are two cells of the benchmark, same
+    # shape and seeds: `als-ml20m-bf16x.retrain-bf16x` (this default: bf16
+    # rows, the einsum pair) 0.1771 s/iter beside `als-ml20m.retrain`
+    # (None: f32 rows, the kernel) 0.1299: the 2-byte take is no faster a
+    # row (1.48 ns against 1.42) and XLA relays rows and A out around its
+    # convolutions (chip runs, PR 49; PERF.md section 5; ROADMAP S5 (e)).
     exchange_dtype: Optional[str] = "auto"
 
 
@@ -1189,8 +1190,9 @@ def resolve_assembly(platform: Optional[str], y_dtype, dtype, k: int,
     exchange and solve, full-f32 or one-pass products, rank 10-128
     (``_KERNEL_MAX_RANK``, with the two readings at rank 100 that set it
     above 64) — and everything else keeps the einsum pair
-    unchanged: the bf16 exchange (``als_train``'s default on a TPU),
-    three-pass products, ranks above 128 and every CPU fit.  The mode does not enter:
+    unchanged: the bf16 exchange (``als_train``'s default on a TPU, timed
+    by the cell ``als-ml20m-bf16x.retrain-bf16x``), three-pass products,
+    ranks above 128 and every CPU fit.  The mode does not enter:
     implicit feedback's weights are one multiply in the kernel's VMEM
     (PERF.md section 6, PR 42).  It holds no width: on the chip the kernel
     is ahead at every width of the ML-20M ladder, w = 24 included."""
@@ -1213,14 +1215,25 @@ def _exchange_and_assembly(config: "ALSConfig", platform: Optional[str]):
         config.assembly_precision)
 
 
+def exchange_report(config: "ALSConfig", mesh: Mesh) -> str:
+    """``exchange <dtype>, <Pallas kernel | einsum pair>``: what the opposite
+    side's rows are gathered in and what contracts them in a fit of
+    ``config`` on ``mesh``, for the trainer's report line."""
+    exchange, how = _exchange_and_assembly(config,
+                                           mesh.devices.flat[0].platform)
+    return (f"exchange {jnp.dtype(exchange or config.dtype).name}, "
+            + ("Pallas kernel" if how == "kernel" else "einsum pair"))
+
+
 def _segments(problem: "BlockedProblem", config: "ALSConfig",
               mesh: Mesh) -> Dict[str, int]:
     """``table_segments`` of the table each half-sweep of one fit on
     ``mesh`` gathers from (the user half reads the item table).  1 on a
     mesh of more than one device (the gathered or routed table of D blocks
     is not cut: no cell, no reading) and for the einsum pair on a TPU (the
-    bf16 exchange, ``als_train``'s default there: what its convolutions need
-    of the fast memory beside a table has not been read)."""
+    bf16 exchange, ``als_train``'s default there; its cell,
+    ``als-ml20m-bf16x.retrain-bf16x``, has tables that fit whole, so what
+    its convolutions need of the fast memory beside a segment is unread)."""
     device = mesh.devices.flat[0]
     exchange, how = _exchange_and_assembly(config, device.platform)
     if num_blocks(mesh) > 1 or (device.platform == "tpu" and how != "kernel"):
@@ -1296,7 +1309,8 @@ def _solver_tiles(problem: "BlockedProblem", config: "ALSConfig",
 def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
                   k: int, per_chunk: Dict[str, bool],
                   tiles: Optional[Dict[str, int]] = None,
-                  cuts: Dict[str, Optional[CutSide]] = _WHOLE) -> None:
+                  cuts: Dict[str, Optional[CutSide]] = _WHOLE, *,
+                  exchange: str) -> None:
     """The static choices of one compiled sweep, per side: the table its
     gather reads, whole or in segments (``table_segments``); the solve's route
     (``solves_per_chunk``) beside the bytes its normal equations take per
@@ -1306,7 +1320,8 @@ def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
     ``_chunk_rows`` leaves straight-line, on a cut side in every piece; the
     others are transposed after their lax.map) and their share of the
     entities; then the rank, the assembly's form and ``tiles``
-    (``_solver_tiles``)."""
+    (``_solver_tiles``); last ``exchange``, the dtype the opposite side's
+    rows are gathered in."""
     parts = []
     for name, side, opp in (("u", problem.u, problem.i),
                             ("i", problem.i, problem.u)):
@@ -1334,7 +1349,7 @@ def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
                      for layout, tile in (tiles or {}).items())
     print("[als] assembly: " + ", ".join(parts) + "; einsum pair elsewhere"
           + f"; rank {k}: {form}" + (", solver tile" + solver[1:] if solver
-                                     else ""))
+                                     else "") + f"; exchange {exchange}")
 
 
 def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
@@ -1446,7 +1461,8 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
     if platform == "tpu":
         _log_assembly(problem, how, lanes, k, per_chunk,
                       _solver_tiles(problem, config, platform, per_chunk,
-                                    cuts), cuts)
+                                    cuts), cuts,
+                      exchange=jnp.dtype(exchange_dtype or dtype).name)
 
     def half_sweep(y_shard, flat, routed: bool, fused: bool,
                    cut: Optional[CutSide], rows: Tuple[int, ...]):
@@ -1860,7 +1876,10 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     the systems a grid step of the Pallas solver takes (``solver_tile``: the
     smallest over the entries this sweep runs, and one ``{kind=<layout>}``
     child an entry, 0 for an entry it does not run and under the ``lax``
-    solver)."""
+    solver); the bytes of a factor entry as it is exchanged and gathered
+    (``exchange_itemsize``: 2 under the bfloat16 exchange) and the padded
+    entries the einsum pair contracts (``einsum_entries``: all of
+    ``entries`` or, where the assembly kernel serves, 0)."""
     D, k = num_blocks(mesh), config.num_factors
     itemsize = np.dtype(config.dtype).itemsize
     exchange, how = _exchange_and_assembly(config,
@@ -1890,6 +1909,9 @@ def _set_layout_gauges(problem: BlockedProblem, config: ALSConfig,
     reg.gauge("tpums_als_normal_eq_bytes").set(
         max(problem.u.per_block, problem.i.per_block) * k * k * itemsize)
     reg.gauge("tpums_als_entries").set(entries)
+    reg.gauge("tpums_als_einsum_entries").set(
+        0 if how == "kernel" else entries)
+    reg.gauge("tpums_als_exchange_itemsize").set(y_itemsize)
     reg.gauge("tpums_als_pad_entries").set(entries - 2 * problem.nnz)
     reg.gauge("tpums_als_pad_slots").set(2 * D * _PAD_STRIP)
     reg.gauge("tpums_als_rank").set(k)

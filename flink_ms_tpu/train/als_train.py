@@ -17,8 +17,11 @@ Flags beyond the reference (TPU-native surface):
 On a TPU the fit prints one ``[als] assembly:`` line when its sweep is traced:
 per side, whether the solve is ``materialised`` or ``per chunk`` (chosen
 from the bytes of the side's normal equations and the device's memory;
-``FLINK_MS_ALS_FUSED=0|1`` forces it) and how many buckets run the assembly
-kernel.
+``FLINK_MS_ALS_FUSED=0|1`` forces it), how many buckets run the assembly
+kernel and, last, the dtype the factors are exchanged in.  The ``[ALS]``
+report line ends with the same two facts on any backend, e.g. ``exchange
+bfloat16, einsum pair`` (a TPU's answer to the default) or ``exchange
+float32, einsum pair`` (a CPU's).
 
 ``--temporaryPath`` (reference: stage loop intermediates to disk,
 ALSImpl.scala:42-44) switches the training loop from one fused XLA program
@@ -37,7 +40,7 @@ import numpy as np
 
 from ..core import formats as F
 from ..core.params import Params, field_delimiter_from
-from ..ops.als import ALSConfig, ALSModel, als_fit, rmse
+from ..ops.als import ALSConfig, ALSModel, als_fit, exchange_report, rmse
 from ..obs.tracing import phase_report
 from ..parallel.distributed import is_primary, maybe_init_distributed
 from ..parallel.mesh import compile_report, mesh_for_blocks
@@ -99,7 +102,8 @@ def run(params: Params) -> ALSModel | None:
         f"k={config.num_factors}, {config.iterations} iters, "
         f"{mesh.devices.size} device(s), {train_s:.2f}s "
         f"({train_s / max(config.iterations, 1):.3f} s/iter), "
-        f"train RMSE={rmse(model, users, items, ratings):.4f}"
+        f"train RMSE={rmse(model, users, items, ratings):.4f}; "
+        f"{exchange_report(config, mesh)}"
     )
     print(f"[ALS] {compile_report()}")
     print(f"[phases] {phase_report()}")

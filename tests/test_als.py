@@ -500,7 +500,7 @@ def test_the_gauges_and_the_line_say_what_was_cut(rng, monkeypatch, capsys):
         got["tpums_als_entries", None] - 2 * len(ratings))
     assert got["tpums_als_entries", None] > whole["tpums_als_entries", None]
     A._log_assembly(problem, "kernel", True, k, {"u": False, "i": False},
-                    cuts={"u": None, "i": cut})
+                    cuts={"u": None, "i": cut}, exchange="float32")
     u, i = capsys.readouterr().out.split("i-sweep")
     assert f"table in 1 segment of {item_rows} rows" in u
     assert f"table in 3 segments of {cut.seg_rows} rows" in i

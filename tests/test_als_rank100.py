@@ -290,15 +290,18 @@ def test_the_assembly_line_ends_in_the_rank_the_form_and_the_tiles(capsys):
                                  per_block=43 + A._PAD_STRIP)
     problem = types.SimpleNamespace(u=side, i=side)
     A._log_assembly(problem, "einsum", False, K, {"u": True, "i": False},
-                    {"batch_major": 128, "lane_major": 128})
+                    {"batch_major": 128, "lane_major": 128},
+                    exchange="bfloat16")
     line = capsys.readouterr().out
     # the parent's line, then what rank 65-128 adds
     assert line.startswith("[als] assembly: u-sweep solve per chunk (")
     assert line.rstrip().endswith(
         "; einsum pair elsewhere; rank 100: einsum pair, solver tile 128 "
-        "batch-major, 128 lane-major")
-    A._log_assembly(problem, "kernel", True, 50, {"u": False, "i": False})
-    assert capsys.readouterr().out.rstrip().endswith("; rank 50: Pallas kernel")
+        "batch-major, 128 lane-major; exchange bfloat16")
+    A._log_assembly(problem, "kernel", True, 50, {"u": False, "i": False},
+                    exchange="float32")
+    assert capsys.readouterr().out.rstrip().endswith(
+        "; rank 50: Pallas kernel; exchange float32")
 
 
 # -- the configuration ------------------------------------------------------------

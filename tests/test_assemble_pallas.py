@@ -184,7 +184,8 @@ def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     if limit:
         monkeypatch.setenv("FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES", str(limit))
     keep = {"u": False, "i": False}
-    A._log_assembly(_ladder_problem(), "kernel", True, 50, keep)
+    A._log_assembly(_ladder_problem(), "kernel", True, 50, keep,
+                    exchange="float32")
     line = capsys.readouterr().out
     u, i = line.split("i-sweep")
     assert "lane-major hand-off " + want[0] in u
@@ -192,11 +193,12 @@ def test_which_buckets_hand_off_lane_major(capsys, monkeypatch, limit, want):
     users_gb = (138493 + A._PAD_STRIP) * 50 * 50 * 4 / 1e9
     assert (f"u-sweep solve materialised ({users_gb:.2f} GB of normal "
             "equations)") in u
-    A._log_assembly(_ladder_problem(), "kernel", False, 50, keep)  # lax solver
+    A._log_assembly(_ladder_problem(), "kernel", False, 50, keep,
+                    exchange="float32")  # lax solver
     assert "hand-off on 0 (0.0%" in capsys.readouterr().out
     # a side on the per-chunk route keeps the batch-major hand-off
     A._log_assembly(_ladder_problem(), "kernel", True, 50,
-                    {"u": True, "i": False})
+                    {"u": True, "i": False}, exchange="float32")
     u, i = capsys.readouterr().out.split("i-sweep")
     assert "solve per chunk" in u and "hand-off on 0 (0.0%" in u
     assert "solve materialised" in i and "hand-off " + want[1] in i
