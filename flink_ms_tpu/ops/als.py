@@ -1352,7 +1352,8 @@ def _log_assembly(problem: "BlockedProblem", how: str, lanes: bool,
                                      else "") + f"; exchange {exchange}")
 
 
-def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
+def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False,
+                shared=False):
     if resolve_solver(platform) == "pallas":
         from .cholesky_pallas import cholesky_solve_batched
 
@@ -1361,7 +1362,7 @@ def _chol_solve(A, b, platform: Optional[str] = None, in_scan=False):
         # dim copy, 62.5 GB AOT OOM) -- force the batch-major variant
         layout = "batch_major" if in_scan else "lane_major"
         return cholesky_solve_batched(
-            A, b, interpret=platform != "tpu", layout=layout
+            A, b, interpret=platform != "tpu", layout=layout, shared=shared
         ).astype(A.dtype)
     L = jax.lax.linalg.cholesky(A)
     x = jax.lax.linalg.triangular_solve(
@@ -1380,12 +1381,15 @@ def _reg_diagonal(counts, lam, weighted_reg):
 
 
 def _solve_factors(A, b, counts, lam, weighted_reg, dtype,
-                   platform: Optional[str] = None, in_scan=False):
-    """Batched Cholesky solve of (A + λ·reg·I) x = b with empty rows masked."""
+                   platform: Optional[str] = None, in_scan=False,
+                   shared=False):
+    """Batched Cholesky solve of (A + λ·reg·I) x = b with empty rows masked.
+    ``shared``: one of many such calls in the program (a bucket of a side
+    solved per chunk), which the Pallas solver then traces as one body."""
     k = A.shape[-1]
     diag = _reg_diagonal(counts, lam, weighted_reg)
     A = A + diag[:, None, None] * jnp.eye(k, dtype=dtype)
-    x = _chol_solve(A, b, platform, in_scan=in_scan)
+    x = _chol_solve(A, b, platform, in_scan=in_scan, shared=shared)
     return jnp.where((counts > 0)[:, None], x, 0.0)
 
 
@@ -1530,7 +1534,8 @@ def _make_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
                         with jax.named_scope("als.gram"):
                             A = A + yty[None, :, :]
                     return _solve_factors(A, bb, cnt, lam, weighted, dtype,
-                                          platform, in_scan=in_scan)
+                                          platform, in_scan=in_scan,
+                                          shared=True)
 
             xs = []
             off = 0

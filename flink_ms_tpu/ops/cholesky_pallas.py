@@ -34,8 +34,10 @@ rank 100 the lane-major entries need 31 MiB and the batch-major one 17 (what
 the v5e compiler reports, PERF.md section 3, PR 46;
 ``netflix-als-f100.retrain`` times that rank).  The elimination is unrolled
 over the static k and shrinks with the pivot, a sublane group of rows and
-columns at a time (``_solve_tile``), so the kernel's trace and lowering grow
-with k squared: the price of a start, not of an iteration.  What an
+columns at a time (``_solve_tile``), so the body's trace and lowering grow
+with k squared: the price of a start, not of an iteration, and the calls of a
+side solved per chunk, one a bucket, pay the trace once between them
+(``shared``, ``_solve_tile_shared``).  What an
 iteration pays is not the downdates' arithmetic.  The working matrix is 450
 to 1,300 vregs against 64 registers, and the compiler's schedule of the
 lane-major body sends every downdated vreg and most outer products through
@@ -64,6 +66,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..obs import metrics as obs_metrics
 
 LANES = 128  # one lane tile: the systems a grid step solves side by side
 SUBLANES = 8  # an f32 vreg's rows: the step by which the elimination shrinks
@@ -126,7 +130,8 @@ def _row(x, j: int):
     """x[j:j + 1] as the one ``slice`` an index expression ends in; ``_at(x,
     j, axis)`` likewise is x with ``axis`` indexed at j.  The index
     expression costs the tracer three times as much, and a step of
-    ``_solve_tile`` has eleven, in a body traced once a bucket."""
+    ``_solve_tile`` has eleven: 1,100 of them in a trace of the body at rank
+    100."""
     return jax.lax.slice_in_dim(x, j, j + 1, axis=0)
 
 
@@ -148,9 +153,14 @@ def _solve_tile(M, b, k: int):
     the same order, so x is the full-tile form's to the bit.  Mosaic drops
     a vreg nobody reads, so the compiled full-tile body already skipped
     those updates, and more: the chip's time is the same for both (PERF.md
-    section 6, PR 46).  The trailing block is what the tracer, the
-    interpreter and the compiler are spared.
+    section 6, PR 46).  The trailing block is what the trace, the
+    interpreter and each call's lowering are spared.
+
+    The counter below is a Python statement: it counts traces of the body,
+    not calls of the kernel.
     """
+    obs_metrics.get_registry().counter(
+        "tpums_als_solver_body_traces_total").inc()
     zero = jnp.zeros((), M.dtype)
     cols, diag = [], []         # cols[j]: (k, T), >=2D ops; diag[j]: (1, T)
     for j in range(k):
@@ -190,7 +200,26 @@ def _solve_tile(M, b, k: int):
     return jnp.concatenate(xs, axis=0)            # (k, T)
 
 
-def _solve_kernel(a_ref, b_ref, *rest, k: int):
+# The body behind a jit, for a caller that says ``shared``: a side solved per
+# chunk has a ``pallas_call`` a bucket, batch-major where the bucket takes
+# several steps and lane-major where it takes one (each its own padded batch,
+# hence its own trace of the kernel function: 19 at ``netflix-als-f100``, 13
+# at ``msd-ials``), and all of them hand the body the same (k, k, 128) and
+# (k, 128) f32 on both layouts, so a process traces it once per (k, T, dtype)
+# and the other calls reuse the jaxpr: a body costs 2.4 s to trace at rank 100
+# and 1.9 at 64 on the chip's host, at every start, cache hit or not (PERF.md
+# section 6, PR 50).  Pallas inlines the inner jaxpr when it lowers the
+# kernel: the module Mosaic gets is the plain body's to the byte
+# (``tests/test_cholesky_pallas.py`` holds that).  A materialised side makes
+# one call and keeps the plain body: nothing to share, and the jitted body is
+# traced a dozen interpreter frames deeper, which is not free on that host
+# (where the tracer's calls then straddle a 16 KB chunk of CPython's frame
+# stack, every crossing maps and unmaps one: 2.7 s on one rank-50 body at
+# ``als-ml20m-bf16x``, same section).
+_solve_tile_shared = jax.jit(_solve_tile, static_argnames=("k",))
+
+
+def _solve_kernel(a_ref, b_ref, *rest, k: int, shared: bool = False):
     """One tile: A (k, k, T), b (k, T) [, d (1, T)] -> x (k, T).  With d the
     system solved is A + d·I: the regularisation reaches the diagonal here,
     in VMEM, and no pass over A in HBM adds it."""
@@ -200,18 +229,18 @@ def _solve_kernel(a_ref, b_ref, *rest, k: int):
         on_diag = (jax.lax.broadcasted_iota(jnp.int32, (k, k, 1), 0)
                    == jax.lax.broadcasted_iota(jnp.int32, (k, k, 1), 1))
         M = M + jnp.where(on_diag, d_ref[0][:][None], 0.0)
-    x_ref[:] = _solve_tile(M, b_ref[:], k)
+    x_ref[:] = (_solve_tile_shared if shared else _solve_tile)(M, b_ref[:], k)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tile", "interpret", "vmem_limit"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret",
+                                             "vmem_limit", "shared"))
 def _solve_padded(At, bt, tile: int, interpret: bool, d=None,
-                  vmem_limit: Optional[int] = None):
+                  vmem_limit: Optional[int] = None, shared: bool = False):
     k = At.shape[0]
     n_pad = At.shape[2]
     lanes = pl.BlockSpec((k, tile), lambda i: (0, i))
     return pl.pallas_call(
-        functools.partial(_solve_kernel, k=k),
+        functools.partial(_solve_kernel, k=k, shared=shared),
         grid=(n_pad // tile,),
         in_specs=[pl.BlockSpec((k, k, tile), lambda i: (0, 0, i)), lanes]
         + ([] if d is None else [pl.BlockSpec((1, tile), lambda i: (0, i))]),
@@ -222,7 +251,8 @@ def _solve_padded(At, bt, tile: int, interpret: bool, d=None,
     )(At, bt, *(() if d is None else (d,)))
 
 
-def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
+def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int,
+                              shared: bool = False):
     """Batch-major tile: A (T, k, k), b (T, k) -> x (T, k).  The lane-major
     transpose happens INSIDE the kernel (VMEM-resident vector shuffles),
     so XLA never lays out a lane-major operand for the whole array —
@@ -235,15 +265,17 @@ def _solve_kernel_batch_major(a_ref, b_ref, x_ref, *, k: int):
     # at k = 100 against 183 this way, TPU v5e, PR 46; section 7 there).
     M = jnp.stack([a_ref[:, i, :].T for i in range(k)], axis=0)
     b = jnp.transpose(b_ref[:], (1, 0))           # (k, T)
-    x_ref[:] = jnp.transpose(_solve_tile(M, b, k), (1, 0))
+    x = (_solve_tile_shared if shared else _solve_tile)(M, b, k)
+    x_ref[:] = jnp.transpose(x, (1, 0))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tile", "interpret", "vmem_limit"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret",
+                                             "vmem_limit", "shared"))
 def _solve_padded_batch_major(Ab, bb, tile: int, interpret: bool,
-                              vmem_limit: Optional[int] = None):
+                              vmem_limit: Optional[int] = None,
+                              shared: bool = False):
     n_pad, k = bb.shape
-    kernel = functools.partial(_solve_kernel_batch_major, k=k)
+    kernel = functools.partial(_solve_kernel_batch_major, k=k, shared=shared)
     return pl.pallas_call(
         kernel,
         grid=(n_pad // tile,),
@@ -268,7 +300,8 @@ def cholesky_solve_lanes(At, bt, d, *, interpret: bool):
                          vmem_limit=vmem_limit)
 
 
-def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major"):
+def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major",
+                           shared: bool = False):
     """Batched SPD solve A x = b.  A (n, k, k), b (n, k) -> x (n, k).
 
     ``solver_tile`` batch elements ride the lane axis per grid step.
@@ -285,7 +318,12 @@ def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major"):
     lax.map body XLA materializes the whole-array lane-major relayout as
     a degenerate-dim copy lane-padded x128 (62.5 GB for a (43648, 50, 50)
     chunk — the round-3 fused-mode AOT OOM), which batch_major sidesteps
-    by never asking XLA for that layout."""
+    by never asking XLA for that layout.
+
+    ``shared``: the caller makes many calls at this rank in one program (a
+    side solved per chunk: one a bucket, in either layout), so the kernels
+    call the elimination through ``_solve_tile_shared`` and trace it once
+    between them."""
     n, k = b.shape
     tile, vmem_limit = solver_tile(k, layout)
     n_pad = _round_up(max(n, tile), tile)
@@ -302,7 +340,8 @@ def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major"):
                                       (pad, k, k))], axis=0)
             bb = jnp.pad(bb, ((0, pad), (0, 0)))
         return _solve_padded_batch_major(
-            Ab, bb, tile, bool(interpret), vmem_limit=vmem_limit)[:n]
+            Ab, bb, tile, bool(interpret), vmem_limit=vmem_limit,
+            shared=shared)[:n]
     At = jnp.transpose(A.astype(jnp.float32), (1, 2, 0))  # (k, k, n)
     bt = jnp.transpose(b.astype(jnp.float32), (1, 0))     # (k, n)
     if n_pad != n:
@@ -313,5 +352,6 @@ def cholesky_solve_batched(A, b, *, interpret: bool, layout="lane_major"):
         )
         At = At.at[:, :, n:].set(eye_pad)
         bt = jnp.pad(bt, ((0, 0), (0, n_pad - n)))
-    x = _solve_padded(At, bt, tile, bool(interpret), vmem_limit=vmem_limit)
+    x = _solve_padded(At, bt, tile, bool(interpret), vmem_limit=vmem_limit,
+                      shared=shared)
     return jnp.transpose(x[:, :n], (1, 0))

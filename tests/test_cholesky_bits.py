@@ -3,9 +3,9 @@ elimination on the trailing block gives the full-tile form's x to the bit on
 every entry, and does the work and costs the tracer what the counts below
 say.  The full-tile body is kept here as the plain reference.  Files of
 their own beside `test_cholesky_pallas.py`, this one up to rank 64 and
-`test_cholesky_bits_wide.py` above it: an interpreted body takes the CPU
-compiler half a minute at rank 100 and most of one at 128, twice a case, and
-the test runner hands out whole files."""
+`test_solver_rank_100.py` and `test_solver_rank_128.py` above it: an
+interpreted body takes the CPU compiler half a minute at rank 100 and most
+of one at 128, twice a case, and the test runner hands out whole files."""
 
 import jax
 import jax.numpy as jnp
@@ -45,19 +45,31 @@ def _solve_tile_full(M, b, k: int):
     return jnp.concatenate(xs, axis=0)
 
 
-@pytest.fixture(scope="module")
-def full_tile_solver():
-    """A second copy of the solver's module whose three entries run the
-    full-tile body: its jitted functions are its own, so neither body is
-    ever answered from the other's trace."""
+def solver_copy(body=None):
+    """A second copy of the solver's module, with ``body`` for its
+    ``_solve_tile`` (plain, the lane-major kernel's) and
+    ``_solve_tile_shared`` (jitted, the batch-major kernel's) where one is
+    given: its jitted functions, and what they have traced, are its own, so
+    neither copy is ever answered from the other's trace.  Same file, same
+    lines: what the two lower to may be compared as text."""
     import importlib.util
 
+    # a name inside the package, so that the copy's relative imports resolve
     spec = importlib.util.spec_from_file_location(
-        "cholesky_pallas_full_tile", cholesky_pallas.__file__)
+        cholesky_pallas.__package__ + ".cholesky_pallas_copy",
+        cholesky_pallas.__file__)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    module._solve_tile = _solve_tile_full
+    if body is not None:
+        module._solve_tile = body
+        module._solve_tile_shared = jax.jit(body, static_argnames=("k",))
     return module
+
+
+@pytest.fixture(scope="module")
+def full_tile_solver():
+    """The solver with the full-tile body on its three entries."""
+    return solver_copy(_solve_tile_full)
 
 
 def assert_x_to_the_bit(rng, full_tile_solver, k, entry):

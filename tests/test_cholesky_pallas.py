@@ -11,8 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flink_ms_tpu.obs import metrics as obs_metrics
 from flink_ms_tpu.ops.cholesky_pallas import (
     cholesky_solve_batched, cholesky_solve_lanes)
+from test_cholesky_bits import solver_copy
 
 
 @pytest.mark.parametrize("k", [3, 8, 16, 50])
@@ -256,6 +258,88 @@ def test_cholesky_kernel_with_diagonal_lowers_for_tpu(k, n):
         jax.ShapeDtypeStruct((n,), jnp.float32),
     ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+def _f32(*dims):
+    return jax.ShapeDtypeStruct(dims, jnp.float32)
+
+
+@pytest.mark.parametrize("k", [50, 64, 100])
+def test_a_side_solved_per_chunk_traces_the_body_once_a_rank(k):
+    """Every padded batch is its own ``pallas_call`` and its own trace of
+    the kernel function (a side solved per chunk has one a bucket: 19 at
+    netflix-als-f100.retrain, 13 at msd-ials.ials-retrain, batch-major
+    where the bucket takes several steps, lane-major where it takes one);
+    under ``shared`` the elimination inside them is one jaxpr per (k, tile,
+    dtype) on both layouts, and a process traces it once.  A call that does
+    not say ``shared`` (a materialised side makes one) traces the plain
+    body, where it always did."""
+    solver = solver_copy()               # nothing traced yet
+    traces = obs_metrics.get_registry().counter(
+        "tpums_als_solver_body_traces_total")
+    before = traces.value
+
+    def trace(n, layout, **shared):
+        jax.make_jaxpr(lambda A, b: solver.cholesky_solve_batched(
+            A, b, interpret=False, layout=layout, **shared))(
+                _f32(n, k, k), _f32(n, k))
+
+    for n in (1408, 2688, 3968):
+        trace(n, "batch_major", shared=True)
+    trace(640, "lane_major", shared=True)
+    assert traces.value - before == 1
+    trace(640, "lane_major")
+    jax.make_jaxpr(lambda At, bt, d: solver.cholesky_solve_lanes(
+        At, bt, d, interpret=False))(_f32(k, k, 512), _f32(k, 512), _f32(512))
+    assert traces.value - before == 3
+
+
+@pytest.mark.parametrize("layout", ["lane_major", "batch_major"])
+@pytest.mark.parametrize("k", [16, 50])
+def test_the_shared_body_gives_the_plain_body_to_the_bit(rng, k, layout):
+    n = 100       # one lane tile: the program the numpy comparisons compiled
+    G = rng.standard_normal((n, k, k)).astype(np.float32)
+    A = jnp.asarray(G @ G.transpose(0, 2, 1) + 5.0 * np.eye(k, dtype=np.float32))
+    b = jnp.asarray(rng.standard_normal((n, k)).astype(np.float32))
+    x, x_plain = (np.asarray(cholesky_solve_batched(
+        A, b, interpret=True, layout=layout, shared=shared))
+        for shared in (True, False))
+    assert np.isfinite(x).all() and x.any()
+    np.testing.assert_array_equal(x, x_plain)
+
+
+@pytest.mark.parametrize("k,entry", [(64, "batch_major"), (100, "batch_major"),
+                                     (50, "lanes")])
+def test_the_shared_body_lowers_to_the_plain_body_for_tpu(k, entry):
+    """Pallas inlines the body's ``pjit`` equation when it lowers the
+    kernel: the module exported for the chip, Mosaic's kernel and all, is
+    the text the plain body gives, so the jit can change neither the
+    chip's kernel nor the compile cache's key unseen.  One call site and a
+    fresh copy of the module serve each: the kernel's locations name the
+    lines that wrote each operation and their callers, which for a shared
+    body are those of whoever traced it first."""
+    from jax import export
+
+    n = 1408
+
+    def module_text(shared):
+        solver = solver_copy()
+        if entry == "lanes":
+            tile, limit = solver.solver_tile(k, "lane_major")
+            fn, args = (lambda At, bt, d: solver._solve_padded(
+                At, bt, tile, False, d, vmem_limit=limit, shared=shared)), (
+                    _f32(k, k, n), _f32(k, n), _f32(1, n))
+        else:
+            tile, limit = solver.solver_tile(k, entry)
+            fn, args = (lambda Ab, bb: solver._solve_padded_batch_major(
+                Ab, bb, tile, False, vmem_limit=limit, shared=shared)), (
+                    _f32(n, k, k), _f32(n, k))
+        return export.export(jax.jit(fn), platforms=("tpu",))(
+            *args).mlir_module()
+
+    text, plain_text = [module_text(shared) for shared in (True, False)]
+    assert "tpu_custom_call" in text
+    assert text == plain_text
 
 
 @pytest.mark.parametrize("h_rows,steps", _TPU_LOWERED["sdca_pallas.py"])
