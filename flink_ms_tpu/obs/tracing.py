@@ -43,6 +43,25 @@ Event sinks, controlled by ``TPUMS_TRACE``:
 ``TPUMS_TRACE_SAMPLE`` (0..1) is the head-sampling knob: ``sample_trace``
 rolls it once per would-be trace root, so span cost scales with the
 sample rate, not the request rate.
+
+**Three ways to time a block, and which one a new call site takes.**
+
+- a piece of one REQUEST's path (a verb, a fan-out leg, a retry) ->
+  ``span``: head-sampled, on the wall clock, parented across threads and
+  processes by the wire tid, spilled to JSONL, free when untraced;
+- work done once a FRAME or once a CALL on a path that repeats (the
+  dispatcher's stages, a fit's enqueue) -> ``stage``: an interval on the
+  PROFILER's clock, beside the device's timeline, recorded nowhere unless
+  somebody profiles;
+- work done once a FIT, once a BUILD or once a PROCESS (set-up) ->
+  ``phase``: a ``stage`` that is also kept, in ``phase_log()`` and in
+  ``tpums_phase_seconds{kind}``, always on.
+
+There is no fourth, and none is to be added.  The one thing here that
+times NO block is the host's heartbeat (``obs/hostbeat.py``, re-exported
+below as ``start_heartbeat``, ``watch_thread``, ``stall_log``,
+``thread_readings``, ``host_report``): a stall is the absence of work, so
+no block is open to time it.
 """
 
 from __future__ import annotations
@@ -59,6 +78,13 @@ from collections import deque
 from typing import Callable, Deque, Dict, List, Optional
 
 from . import metrics as _metrics
+from .hostbeat import (  # noqa: F401  (the beat's readers live here)
+    host_report,
+    stall_log,
+    start_heartbeat,
+    thread_readings,
+    watch_thread,
+)
 
 TID_FIELD = "tid="
 _RING_CAP = 4096

@@ -199,6 +199,9 @@ def acquire_devices(host_pinned: bool = False) -> list:
         if not _acquired:
             _acquired = True
             _count_compiles()
+            # the host's heartbeat, with the caller (a trainer's or a driver's
+            # main thread) among the threads it reads
+            tracing.start_heartbeat(watch=threading.current_thread().name)
             if backend == "tpu":
                 if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
                     jax.config.update(

@@ -40,7 +40,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..obs import metrics as obs_metrics
-from ..obs.tracing import stage
+from ..obs.tracing import stage, watch_thread
 
 
 def batching_enabled() -> bool:
@@ -254,6 +254,7 @@ class TopKBatcher:
         # tracing.stage): ``topk.coalesce`` here, ``topk.frame`` and its
         # children in _dispatch and in the index, so whoever profiles the
         # process can name what the host was doing in each device gap
+        watch_thread("topk-batcher")
         while True:
             with stage("topk.coalesce"), self._cond:
                 while not self._queue and not self._closed:

@@ -41,7 +41,7 @@ import numpy as np
 from ..core import formats as F
 from ..core.params import Params, field_delimiter_from
 from ..ops.als import ALSConfig, ALSModel, als_fit, exchange_report, rmse
-from ..obs.tracing import phase_report
+from ..obs.tracing import host_report, phase_report
 from ..parallel.distributed import is_primary, maybe_init_distributed
 from ..parallel.mesh import compile_report, mesh_for_blocks
 from ..utils import profiling
@@ -107,6 +107,7 @@ def run(params: Params) -> ALSModel | None:
     )
     print(f"[ALS] {compile_report()}")
     print(f"[phases] {phase_report()}")
+    print(f"[host] {host_report()}")
 
     if not is_primary():  # one process materializes job output
         return model

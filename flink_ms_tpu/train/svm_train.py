@@ -21,7 +21,7 @@ import time
 
 from ..core import formats as F
 from ..core.params import Params
-from ..obs.tracing import phase_report
+from ..obs.tracing import host_report, phase_report
 from ..ops.svm import (SVMConfig, SVMModel, layout_report,
                        prepare_svm_blocked, svm_fit)
 from ..parallel.distributed import is_primary, maybe_init_distributed
@@ -71,6 +71,7 @@ def run(params: Params) -> SVMModel:
     )
     print(f"[SVM] {layout_report()}; {compile_report()}")
     print(f"[phases] {phase_report()}")
+    print(f"[host] {host_report()}")
 
     if not is_primary():  # one process materializes job output
         return model
