@@ -154,6 +154,10 @@ class SideLayout:
     lives in *slot order* on device — ``perm`` maps dense entity index to
     its global slot ``block * per_block + local`` — so bucket outputs are
     contiguous rows and the solve writes factors with no scatter.
+
+    The buckets' ``idx`` arrays are contiguous slices of one buffer a side,
+    as are the ``val`` (``_fill_side`` writes every rating once, into it):
+    whoever holds one bucket's array holds the side's buffer.
     """
 
     per_block: int            # slots per block (Σ_j rows[j] + _PAD_STRIP:
@@ -328,12 +332,56 @@ def _strip_slots(n: int) -> np.ndarray:
     return np.arange(n, dtype=np.int32) % _PAD_STRIP
 
 
+# Entries a step of the fill's two passes (key build; decode and scatter): a
+# step's temporaries, 2 MB each, are the allocator's to hand out again.  From
+# 32 MB each (2^22) they are mapped and faulted in anew every pass, as a whole
+# array is, and the fill takes 2.3x as long (PERF.md section 6, PR 52)
+_FILL_STEP = 1 << 18
+
+
+def _sorted_keys(row_idx, col_idx, opp_perm, n_rows, n_slots):
+    """One side's ratings by (row, opposite slot) -> ``(key, order, cb, ib)``.
+
+    Where row, slot and entry fit 64 bits together (read from the sizes:
+    ``n_rows``, the ``n_slots`` of the opposite table, the entries) the key
+    is ``row << (cb + ib) | slot << ib | entry`` and comes back sorted, as a
+    value, in place: numpy's 64-bit ``sort`` is vectorised where its
+    ``argsort`` is not, or less, and what a sorted entry needs (its row, its
+    slot, its place in the input) are bit fields of its key, so nothing is
+    gathered through an order; ``order`` is None.  Keys are distinct, and
+    two ratings of one (row, slot) pair lie in input order.  Else (1M x 1M
+    with 2^27 ratings would be 67 bits) the key is the fused
+    ``row << 32 | slot`` in INPUT order, ``ib`` is 0 and ``order`` its
+    ``argsort``, which leaves such a pair in either order; both fields fit
+    by construction, a slot being an int32 in ``idx``.  No cell of the
+    benchmark takes that branch, and what it costs at a real size is unread
+    (PERF.md section 7)."""
+    nnz = row_idx.shape[0]
+    cb = max(n_slots - 1, 1).bit_length()
+    ib = max(nnz - 1, 1).bit_length()
+    fits = max(n_rows - 1, 1).bit_length() + cb + ib <= 64
+    if not fits:
+        cb, ib = 32, 0
+    key = np.empty(nnz, np.uint64)
+    for s in range(0, nnz, _FILL_STEP):
+        k = row_idx[s:s + _FILL_STEP].astype(np.uint64)
+        k <<= np.uint64(cb)
+        k |= opp_perm[col_idx[s:s + _FILL_STEP]].astype(np.uint64)
+        if fits:
+            k <<= np.uint64(ib)
+            k |= np.arange(s, s + k.shape[0], dtype=np.uint64)
+        key[s:s + _FILL_STEP] = k
+    if fits:
+        key.sort()
+        return key, None, cb, ib
+    return key, np.argsort(key), cb, ib
+
+
 def _fill_side(
-    row_idx, col_idx, vals, n_rows, n_blocks, side_order, opp_perm,
-    opp_per_block, dtype
+    keys, vals, n_rows, n_blocks, side_order, opp_per_block, dtype
 ) -> SideLayout:
     """Build one side's bucketed arrays from its precomputed ``_side_order``
-    result.  ``opp_perm`` maps the opposite side's dense indices to its
+    result and its ``_sorted_keys``, whose slots are the opposite side's
     global slots (the positions valid against the all_gather'd factor
     table); ``opp_per_block`` is the opposite side's slots per block, whose
     last _PAD_STRIP are the strip: factor rows guaranteed zero — pad
@@ -342,60 +390,59 @@ def _fill_side(
     routed exchange), the position p of a bucket's flat (rows, w) order the
     strip's slot p mod _PAD_STRIP: within a list addresses ascend up to
     where the strip wraps, and no slot is named twice within _PAD_STRIP
-    consecutive positions."""
+    consecutive positions.
+
+    The ratings were sorted once and each is written once, into one buffer
+    a side of which the buckets' arrays are slices.  Within a list entries
+    ascend by opposite slot, and two ratings of one (row, slot) pair keep
+    the input's order wherever the key holds the entry (every shape the
+    benchmark has): the layout is then a function of the input's order
+    alone, which the unstable ``argsort`` of before never promised (such a
+    pair's two ``val`` may lie the other way round than they did)."""
+    key, order, cb, ib = keys
     deg, block_of, bucket_of, perm, widths, rows, per_block = side_order
-    nb = len(widths)
+    rows_of, widths_of = np.asarray(rows), np.asarray(widths)
+    # bucket j is flat[starts[j]:starts[j + 1]], (n_blocks, rows[j], widths[j])
+    starts = np.concatenate([[0], np.cumsum(n_blocks * rows_of * widths_of)])
+    flat_idx = np.empty(starts[-1], np.int32)
+    flat_val = np.zeros(starts[-1], dtype)
     strip = (np.arange(n_blocks, dtype=np.int32)[:, None, None] * opp_per_block
              + (opp_per_block - _PAD_STRIP))
-    idx = [
-        strip + _strip_slots(rows[j] * widths[j]).reshape(rows[j], widths[j])
-        for j in range(nb)
-    ]
-    val = [np.zeros((n_blocks, rows[j], widths[j]), dtype) for j in range(nb)]
+    idx, val = [], []
+    for j in range(len(widths)):
+        shape = (n_blocks, rows[j], widths[j])
+        idx.append(flat_idx[starts[j]:starts[j + 1]].reshape(shape))
+        val.append(flat_val[starts[j]:starts[j + 1]].reshape(shape))
+        np.add(strip, _strip_slots(rows[j] * widths[j]).reshape(shape[1:]),
+               out=idx[j])
     count = np.zeros((n_blocks, per_block), dtype)
-
-    # ratings sorted by owning entity -> contiguous per-entity runs; the
-    # secondary sort by opposite slot makes each rating list's factor
-    # gather walk HBM in ascending address order (contractions are
-    # order-invariant, so this only changes DMA locality).  One argsort of
-    # a fused (row << 32 | col) key is ~4x faster than lexsort at ML-20M
-    # scale; both dimensions are dense indices so they fit the key by
-    # construction — the guard only trips on absurd (2^31 entities) inputs
-    col_global = opp_perm[col_idx].astype(np.int64)
-    if n_rows < (1 << 31) and col_global.size and int(col_global.max()) < (1 << 32):
-        key = (row_idx.astype(np.uint64) << np.uint64(32)) | col_global.astype(
-            np.uint64
-        )
-        order_r = np.argsort(key)
-    else:  # pragma: no cover - beyond any realistic id space
-        order_r = np.lexsort((col_global, row_idx))
-    ent_start = np.searchsorted(row_idx[order_r], np.arange(n_rows + 1))
-    col_sorted = col_global[order_r]
-    val_sorted = vals[order_r]
-
     local = perm - block_of * per_block  # slot within block
     offsets = np.concatenate([[0], np.cumsum(rows)])
     count[(block_of, local)] = deg.astype(dtype)
 
-    for j in range(nb):
-        sel = np.nonzero(bucket_of == j)[0]  # dense entity ids in bucket j
-        if len(sel) == 0:
-            continue
-        lens = deg[sel]
-        total = int(lens.sum())
-        if total == 0:
-            continue
-        # ragged fill: src positions into the entity-sorted rating arrays,
-        # dst positions into the flattened (D*rows_j, w_j) bucket arrays
-        rep = np.repeat(np.arange(len(sel)), lens)
-        intra = np.arange(total) - np.repeat(
-            np.concatenate([[0], np.cumsum(lens)[:-1]]), lens
-        )
-        src = np.repeat(ent_start[sel], lens) + intra
-        flat_row = block_of[sel] * rows[j] + (local[sel] - offsets[j])
-        dst = np.repeat(flat_row * widths[j], lens) + intra
-        idx[j].reshape(-1)[dst] = col_sorted[src]
-        val[j].reshape(-1)[dst] = val_sorted[src]
+    # the ratings lie sorted by owning entity -> contiguous per-entity
+    # runs, in dense-index order; the secondary sort by opposite slot makes
+    # each rating list's factor gather walk HBM in ascending address order
+    # (contractions are order-invariant, so this only changes DMA locality).
+    # Ragged fill, all buckets at once: an entity's list starts at `first`
+    # in the flat buffer and at `cumsum(deg)` in the sorted ratings, so the
+    # sorted entry at place p goes to `ahead[its row] + p`
+    first = starts[bucket_of] + widths_of[bucket_of] * (
+        block_of * rows_of[bucket_of] + local - offsets[bucket_of])
+    ahead = first - (np.cumsum(deg) - deg)
+    entry_mask, slot_mask = np.uint64((1 << ib) - 1), np.uint64((1 << cb) - 1)
+    for s in range(0, key.shape[0], _FILL_STEP):
+        if order is None:
+            k = key[s:s + _FILL_STEP]
+            entry = (k & entry_mask).view(np.int64)
+            k = k >> np.uint64(ib)
+        else:
+            entry = order[s:s + _FILL_STEP]
+            k = key[entry]
+        dst = ahead[(k >> np.uint64(cb)).view(np.int64)]
+        dst += np.arange(s, s + k.shape[0])
+        flat_idx[dst] = k & slot_mask
+        flat_val[dst] = vals[entry]
     return SideLayout(
         per_block=per_block,
         n_rows=n_rows,
@@ -423,8 +470,8 @@ def prepare_blocked(
     FLINK_MS_ALS_BUCKET_RATIO env, 1.5) — multi-process launchers should
     pass it explicitly so every host builds identical shapes.  Phases: the
     root ``als.prepare`` with ``als.prepare.order`` (dense ids, degrees,
-    ladder, perms) and ``als.prepare.fill`` (both sides' key sort and
-    ragged fill)."""
+    ladder, perms) and ``als.prepare.fill`` (both sides' key sort, its
+    child ``als.prepare.fill.sort``, then both sides' ragged fill)."""
     with tracing.phase("als.prepare"):
         with tracing.phase("als.prepare.order"):
             users = np.asarray(users)
@@ -446,18 +493,19 @@ def prepare_blocked(
             u_perm, i_perm = u_order[3], i_order[3]
         # each side's pad gathers are spread over the opposite side's strip
         # (the tail of every block), found from its slots per block
-        # side by side: each is a sort of all the ratings and a ragged fill,
-        # and numpy lets go of the interpreter's lock inside both
+        # side by side, in two stages: each side's sort of all the ratings,
+        # then its ragged fill; numpy lets go of the interpreter's lock
+        # inside both
         with tracing.phase("als.prepare.fill"), ThreadPoolExecutor(2) as pool:
-            u_side = pool.submit(
-                _fill_side, u_idx, i_idx, ratings, len(user_ids), n_blocks,
-                u_order, i_perm, i_order[6], dtype
-            )
-            i_side = pool.submit(
-                _fill_side, i_idx, u_idx, ratings, len(item_ids), n_blocks,
-                i_order, u_perm, u_order[6], dtype
-            )
-            u_side, i_side = u_side.result(), i_side.result()
+            with tracing.phase("als.prepare.fill.sort"):
+                keys = list(pool.map(
+                    _sorted_keys, (u_idx, i_idx), (i_idx, u_idx),
+                    (i_perm, u_perm), (len(user_ids), len(item_ids)),
+                    (n_blocks * i_order[6], n_blocks * u_order[6])))
+            u_side, i_side = pool.map(
+                _fill_side, keys, (ratings, ratings),
+                (len(user_ids), len(item_ids)), (n_blocks, n_blocks),
+                (u_order, i_order), (i_order[6], u_order[6]), (dtype, dtype))
     return BlockedProblem(
         n_blocks=n_blocks,
         user_ids=user_ids,
@@ -1700,6 +1748,25 @@ def _cached_sweep(problem: BlockedProblem, config: ALSConfig, mesh: Mesh):
 _STAGE_RE = re.compile(r"^iter_(\d+)\.npz$")
 
 
+def _ties_ascending(ix: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """``v`` with every run of one list (last axis) whose ``ix`` are equal
+    sorted ascending: what a bucket's ``val`` is whichever way the fill's
+    sort left two ratings of one (row, slot) pair."""
+    tie = ix[..., 1:] == ix[..., :-1]
+    if not tie.any():
+        return v
+    member = np.zeros(ix.shape, bool)     # tied to a neighbour of its list
+    member[..., 1:] = tie
+    follows = member.copy()               # ... to the one before it
+    member[..., :-1] |= tie
+    at = np.flatnonzero(member)
+    run = np.cumsum(~follows.reshape(-1)[at])
+    v = v.copy()
+    flat = v.reshape(-1)
+    flat[at] = flat[at[np.lexsort((flat[at], run))]]
+    return v
+
+
 def _staging_meta(problem: "BlockedProblem", config: "ALSConfig",
                   init, platform: "Optional[str]" = None) -> dict:
     """Identity of a training run; a snapshot from a different dataset,
@@ -1720,7 +1787,9 @@ def _staging_meta(problem: "BlockedProblem", config: "ALSConfig",
     # a block ended in before the strip, and pads aimed at block 0's as they
     # were then: the identity does not depend on _PAD_STRIP, and a snapshot
     # written before the strip keeps resuming (its factors are in dense-id
-    # order, which no layout touches)
+    # order, which no layout touches).  Ratings of one (user, item) pair are
+    # hashed in ascending order: the identity is the ratings', whatever
+    # order the fill's sort, this host's or another's, left them in
     fold = _PAD_STRIP - 1
     opp_pb = problem.i.per_block
     idx = [
@@ -1729,8 +1798,11 @@ def _staging_meta(problem: "BlockedProblem", config: "ALSConfig",
         for ix in problem.u.idx
     ]
     perm = problem.u.perm - problem.u.perm // problem.u.per_block * fold
+    # (ties are read from the slots as laid out, where a list's pads differ)
+    val = [_ties_ascending(ix, v)
+           for ix, v in zip(problem.u.idx, problem.u.val)]
     hd = hashlib.sha1()
-    for a in [perm, problem.user_ids, problem.item_ids] + idx + problem.u.val:
+    for a in [perm, problem.user_ids, problem.item_ids] + idx + val:
         hd.update(np.ascontiguousarray(a).tobytes())
     return {
         "data": hd.hexdigest(),

@@ -28,7 +28,14 @@ ALSImpl.scala:42-44) switches the training loop from one fused XLA program
 to per-iteration steps with the factors materialized to disk at every
 iteration boundary — and resumes from the latest snapshot on restart
 (training checkpoint/resume, SURVEY.md §5).  A copy of the final factors is
-also staged under that path.
+also staged under that path.  A snapshot is resumed only by a run of the
+same ratings, configuration and starting point; the ratings' identity is
+theirs alone, whatever order the file lists them in.  ONE RETRAIN, ONCE:
+ratings that name a (user, item) pair more than once (a log with re-rates)
+had, until PR 52, an identity that depended on how the host's sort left two
+such ratings, so a snapshot of such ratings written before PR 52 is not
+resumed and the run starts from iteration 0; ratings of distinct pairs
+resume as before.
 """
 
 from __future__ import annotations

@@ -71,6 +71,184 @@ def test_prepare_blocked_layout(rng):
         assert per_row.max() <= w
 
 
+def _reference_fill_side(
+    row_idx, col_idx, vals, n_rows, n_blocks, side_order, opp_perm,
+    opp_per_block, dtype
+):
+    """``_fill_side`` as it stood before the ratings were sorted as values
+    and written once (its body, unchanged): an ``argsort`` of the fused key,
+    three sorted copies, and a ragged fill a bucket."""
+    deg, block_of, bucket_of, perm, widths, rows, per_block = side_order
+    nb = len(widths)
+    strip = (np.arange(n_blocks, dtype=np.int32)[:, None, None] * opp_per_block
+             + (opp_per_block - A._PAD_STRIP))
+    idx = [
+        strip + A._strip_slots(rows[j] * widths[j]).reshape(rows[j], widths[j])
+        for j in range(nb)
+    ]
+    val = [np.zeros((n_blocks, rows[j], widths[j]), dtype) for j in range(nb)]
+    count = np.zeros((n_blocks, per_block), dtype)
+
+    col_global = opp_perm[col_idx].astype(np.int64)
+    if n_rows < (1 << 31) and col_global.size and int(col_global.max()) < (1 << 32):
+        key = (row_idx.astype(np.uint64) << np.uint64(32)) | col_global.astype(
+            np.uint64
+        )
+        order_r = np.argsort(key)
+    else:  # pragma: no cover - beyond any realistic id space
+        order_r = np.lexsort((col_global, row_idx))
+    ent_start = np.searchsorted(row_idx[order_r], np.arange(n_rows + 1))
+    col_sorted = col_global[order_r]
+    val_sorted = vals[order_r]
+
+    local = perm - block_of * per_block  # slot within block
+    offsets = np.concatenate([[0], np.cumsum(rows)])
+    count[(block_of, local)] = deg.astype(dtype)
+
+    for j in range(nb):
+        sel = np.nonzero(bucket_of == j)[0]  # dense entity ids in bucket j
+        if len(sel) == 0:
+            continue
+        lens = deg[sel]
+        total = int(lens.sum())
+        if total == 0:
+            continue
+        # ragged fill: src positions into the entity-sorted rating arrays,
+        # dst positions into the flattened (D*rows_j, w_j) bucket arrays
+        rep = np.repeat(np.arange(len(sel)), lens)
+        intra = np.arange(total) - np.repeat(
+            np.concatenate([[0], np.cumsum(lens)[:-1]]), lens
+        )
+        src = np.repeat(ent_start[sel], lens) + intra
+        flat_row = block_of[sel] * rows[j] + (local[sel] - offsets[j])
+        dst = np.repeat(flat_row * widths[j], lens) + intra
+        idx[j].reshape(-1)[dst] = col_sorted[src]
+        val[j].reshape(-1)[dst] = val_sorted[src]
+    return A.SideLayout(
+        per_block=per_block,
+        n_rows=n_rows,
+        perm=perm,
+        widths=widths,
+        rows=rows,
+        idx=idx,
+        val=val,
+        count=count,
+    )
+
+
+def _fill_problem(kind, rng):
+    """(users, items, ratings) in no order, by the shape of the degrees;
+    only ``duplicates`` rates a (user, item) pair more than once."""
+    n_users, n_items = 90, 70
+    if kind == "duplicates":
+        u = rng.integers(0, 12, 600)
+        i = rng.integers(0, 9, 600)      # 108 pairs under 600 ratings
+        return u, i, np.arange(1.0, 601.0)
+    if kind == "ragged":
+        # skewed on both sides: degrees from one to most of the other side
+        share = (rng.pareto(1.2, n_users)[:, None] + 0.02) * (
+            rng.pareto(1.2, n_items)[None, :] + 0.02)
+        mask = rng.uniform(size=share.shape) < np.minimum(share, 0.95)
+    elif kind == "empty rung":
+        # users of 1-8 ratings and of 33-40: the rungs between hold nobody
+        mask = np.zeros((n_users, n_items), bool)
+        for row, n in enumerate(np.where(np.arange(n_users) % 3 == 0,
+                                         rng.integers(33, 41, n_users),
+                                         rng.integers(1, 9, n_users))):
+            mask[row, rng.choice(n_items, n, replace=False)] = True
+    else:
+        assert kind == "one wide"
+        # one user of 900 ratings beside users of at most 8
+        n_items = 1000
+        mask = np.zeros((n_users, n_items), bool)
+        for row, n in enumerate(rng.integers(1, 9, n_users)):
+            mask[row, rng.choice(n_items, n, replace=False)] = True
+        mask[17] = False
+        mask[17, rng.choice(n_items, 900, replace=False)] = True
+    u, i = np.nonzero(mask)
+    order = rng.permutation(len(u))
+    return u[order], i[order], rng.uniform(0.5, 5.0, len(u))
+
+
+def _both_fills(u, i, r, blocks, dtype=np.float32, sparse=False):
+    """Both sides through ``_sorted_keys`` + ``_fill_side`` and through the
+    reference, from one pair of ``_side_order`` results -> [(got, want)] for
+    users, items.  ``sparse``: the sides are said to be of 2^31 rows and
+    2^31 slots, as a sparse id space would have them, which no 64-bit key
+    holds beside the entry."""
+    _, u_idx = A._dense_ids(u)
+    _, i_idx = A._dense_ids(i)
+    n_u, n_i = int(u_idx.max()) + 1, int(i_idx.max()) + 1
+    u_order = A._side_order(u_idx, n_u, blocks, 1.5)
+    i_order = A._side_order(i_idx, n_i, blocks, 1.5)
+    r = np.asarray(r, np.float64)
+    got, want = [], []
+    for row, col, n, mine, theirs in ((u_idx, i_idx, n_u, u_order, i_order),
+                                      (i_idx, u_idx, n_i, i_order, u_order)):
+        keys = A._sorted_keys(row, col, theirs[3],
+                              1 << 31 if sparse else n,
+                              1 << 31 if sparse else blocks * theirs[6])
+        assert (keys[1] is not None) == sparse   # an order: the argsort's
+        got.append(A._fill_side(keys, r, n, blocks, mine, theirs[6], dtype))
+        want.append(_reference_fill_side(row, col, r, n, blocks, mine,
+                                         theirs[3], theirs[6], dtype))
+    return list(zip(got, want))
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+@pytest.mark.parametrize("sparse", [False, True],
+                         ids=["value sort", "argsort"])
+@pytest.mark.parametrize("kind", ["ragged", "empty rung", "one wide"])
+def test_the_fill_is_the_one_it_replaces(rng, monkeypatch, kind, sparse,
+                                         blocks):
+    """Sorted as values (row, slot and entry fit the key) or through
+    ``argsort`` of the fused key (they do not), written once into one
+    buffer a side, some tens of entries a step: every array of both sides
+    is the bucket-by-bucket fill's, dtype included."""
+    monkeypatch.setattr(A, "_FILL_STEP", 37)   # lists straddle the steps
+    u, i, r = _fill_problem(kind, rng)
+    for side, (got, want) in enumerate(_both_fills(u, i, r, blocks,
+                                                   sparse=sparse)):
+        assert (got.per_block, got.n_rows, got.widths, got.rows) == (
+            want.per_block, want.n_rows, want.widths, want.rows)
+        if kind != "ragged" and side == 0:
+            # the users' ladder (ratio 1.5) has lost the rungs nobody is on
+            assert max(np.divide(got.widths[:-1], got.widths[1:])) > 2
+        for name in ("perm", "count"):
+            a, b = getattr(got, name), getattr(want, name)
+            assert a.dtype == b.dtype and np.array_equal(a, b), name
+        for name in ("idx", "val"):
+            mine, theirs = getattr(got, name), getattr(want, name)
+            assert len(mine) == len(theirs) == len(want.widths)
+            for a, b in zip(mine, theirs):
+                assert a.dtype == b.dtype and a.shape == b.shape, name
+                assert np.array_equal(a, b), name
+                assert a.flags.c_contiguous
+        # one buffer a side: a bucket's array is a slice of it
+        assert len({id(a.base.base) for a in got.idx}) == 1
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+def test_ratings_of_one_pair_keep_the_inputs_order(rng, monkeypatch, blocks):
+    """Two ratings of one (user, item) pair have one (row, slot): ``idx`` is
+    the reference's, a list holds the same ratings at each slot, and where
+    the reference's unstable sort leaves them in either order the value sort
+    leaves them in the input's (ratings here ascend with the input)."""
+    monkeypatch.setattr(A, "_FILL_STEP", 37)
+    u, i, r = _fill_problem("duplicates", rng)
+    repeated = 0
+    for got, want in _both_fills(u, i, r, blocks):
+        for a, b, x, y in zip(got.idx, want.idx, got.val, want.val):
+            assert np.array_equal(a, b)
+            # (slot, rating) pairs of a list, as a multiset
+            assert np.array_equal(np.sort(a * 1000.0 + x, axis=-1),
+                                  np.sort(b * 1000.0 + y, axis=-1))
+            same_slot = (a[..., 1:] == a[..., :-1]) & (x[..., 1:] > 0)
+            assert (x[..., 1:] > x[..., :-1])[same_slot].all()
+            repeated += int(same_slot.sum())
+    assert repeated > 500   # both sides: most of the 600 repeat a pair
+
+
 @pytest.mark.parametrize("blocks", [1, 4])
 @pytest.mark.parametrize("strip", [1, 3, 8, None])
 def test_pads_are_spread_over_the_opposite_strip(rng, monkeypatch, strip,
@@ -991,27 +1169,77 @@ def test_staged_fit_resumes_from_snapshot(rng, tmp_path):
     )
 
 
-@pytest.mark.parametrize("blocks, parent_digest", [
-    (1, "145d4516bb755f3a9f7edccb37ee0abe5f28a618"),
-    (4, "09c7057c1104001430d7714831c359b6bcd2cd46"),
+@pytest.mark.parametrize("pairs, blocks, parent_digest", [
+    ("repeated", 1, "145d4516bb755f3a9f7edccb37ee0abe5f28a618"),
+    ("repeated", 4, "09c7057c1104001430d7714831c359b6bcd2cd46"),
+    ("rated once", 1, "f63bcb9931c03881173ffae648c58d781894c16f"),
+    ("rated once", 4, "3b90ac684f9f1c3adc2df953d8c89b1eff8b7663"),
 ])
 @pytest.mark.parametrize("strip", [None, 8])
 def test_staging_identity_is_the_one_from_before_the_strip(monkeypatch, strip,
-                                                           blocks,
+                                                           pairs, blocks,
                                                            parent_digest):
     """A snapshot written before PR 34 (one dummy slot a block) must keep
     resuming: the run's identity hashes the layout with the strip folded
     away, so it reads what commit 0f8ff06 computed for the same ratings
-    (the digests are that commit's), whatever the strip's size."""
+    (the digests are that commit's, all four: ``git archive 0f8ff06``,
+    this draw, ``_staging_meta(...)["data"]``), whatever the strip's size.
+
+    THE MARKED CASE, ``repeated``: 390 of the draw's 1,809 ratings repeat a
+    (user, item) pair.  Until PR 52 two such ratings lay in whichever order
+    an unstable ``argsort`` left them (a matter of the host's numpy and its
+    vector units, not of the ratings), and the identity hashed that order.
+    They now lie in the input's order and are hashed in ascending order, so
+    the identity is the ratings' alone; that commit's digest for them cannot
+    be had again, and a snapshot of such ratings from before PR 52 trains
+    anew, once (``als_train``'s notes on ``--temporaryPath`` say so).  The
+    digests such ratings have from PR 52 on are pinned here instead."""
     if strip is not None:
         monkeypatch.setattr(A, "_PAD_STRIP", strip)
     rng = np.random.default_rng(34)
     u = np.repeat(np.arange(90), rng.integers(1, 40, 90))
     i = rng.integers(0, 50, len(u))
+    if pairs == "rated once":
+        _, first = np.unique(u * 50 + i, return_index=True)
+        first.sort()
+        u, i = u[first], i[first]
     r = rng.uniform(1, 5, len(u))
-    p = A.prepare_blocked(u, i, r, blocks)
     cfg = A.ALSConfig(num_factors=4, iterations=2, lambda_=0.1)
-    assert A._staging_meta(p, cfg, None, "cpu")["data"] == parent_digest
+
+    def identity(order):
+        p = A.prepare_blocked(u[order], i[order], r[order], blocks)
+        return A._staging_meta(p, cfg, None, "cpu")["data"]
+
+    digest = identity(np.arange(len(u)))
+    if pairs == "rated once":
+        assert digest == parent_digest
+    else:
+        assert digest != parent_digest
+        assert digest == {1: "f4883bf99d75f4125d2a60cdefa040cb17caa650",
+                          4: "72ef51b6df2e53421ac71748351e8a7b77c9e52f"}[blocks]
+    # the ratings', not their order's: the same log shuffled resumes too
+    assert identity(rng.permutation(len(u))) == digest
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_ties_ascending_sorts_runs_of_one_slot_and_nothing_else(dtype):
+    ix = np.array([[[3, 5, 5, 5, 9, 9, 200, 7],
+                    [7, 7, 8, 8, 8, 200, 201, 202]],
+                   [[1, 2, 3, 4, 5, 6, 200, 201],
+                    [4, 4, 4, 4, 4, 4, 4, 4]]], np.int32)
+    v = np.array([[[9, 3, 1, 2, 5, 4, 0, 9],
+                   [2, 1, 6, 5, 4, 0, 0, 0]],
+                  [[8, 7, 6, 5, 4, 3, 0, 0],
+                   [8, 7, 6, 5, 4, 3, 2, 1]]], dtype)
+    kept = v.copy()
+    got = A._ties_ascending(ix, v)
+    assert got.dtype == v.dtype and np.array_equal(v, kept)
+    assert np.array_equal(got, np.array(
+        [[[9, 1, 2, 3, 4, 5, 0, 9], [1, 2, 4, 5, 6, 0, 0, 0]],
+         [[8, 7, 6, 5, 4, 3, 0, 0], [1, 2, 3, 4, 5, 6, 7, 8]]], dtype))
+    # (a run ends with its list: the first ends on slot 7 and the next
+    # begins with two of it); nothing tied, nothing copied
+    assert A._ties_ascending(ix[1, :1], v[1, :1]).base is v
 
 
 def test_staged_mismatched_snapshot_ignored(rng, tmp_path):

@@ -34,12 +34,17 @@ TOL = 2e-4
 V5E_BYTES = 16909336064  # `bytes_limit` of one TPU v5e chip (chip run, PR 33)
 
 
-def plays_problem(rng, n_users=60, n_items=25, nnz=420):
-    """Triples with play counts that vary (1..30), every id present."""
+def plays_problem(rng, n_users=60, n_items=25, nnz=420, once=False):
+    """Triples with play counts that vary (1..30), every id present; some
+    fifty name a (user, item) pair a second time, none where ``once``."""
     users = np.concatenate([np.arange(n_users), rng.integers(0, n_users, nnz - n_users)])
     items = np.concatenate([np.arange(n_items), rng.integers(0, n_items, nnz - n_items)])
     rng.shuffle(items)
     plays = np.minimum(np.floor(rng.random(nnz) ** -0.7), 30.0)
+    if once:
+        first = np.sort(np.unique(users * n_items + items, return_index=True)[1])
+        assert set(users[first]) == set(users) and set(items[first]) == set(items)
+        return users[first], items[first], plays[first]
     return users, items, plays
 
 
@@ -82,8 +87,8 @@ def test_reference_counts_a_repeated_pair_twice_and_reads_the_play_count(rng):
 # -- the program against the reference ----------------------------------------
 
 def one_iteration(rng, monkeypatch, devices, env, memory=None,
-                  assembly="einsum", **config):
-    users, items, plays = plays_problem(rng)
+                  assembly="einsum", once=False, **config):
+    users, items, plays = plays_problem(rng, once=once)
     if assembly == "kernel":
         # the resolver answered as a TPU would, the kernel interpreted; it
         # is not in the sweep's cache key
@@ -168,15 +173,18 @@ def test_a_table_read_in_segments_agrees_with_the_reference_and_the_whole(
     env = {"FLINK_MS_ALS_FUSED": route, "FLINK_MS_ALS_SOLVER": "pallas",
            "FLINK_MS_ALS_ASSEMBLY_CHUNK_BYTES":
                "4096" if assembly == "einsum" else "65536"}
+    # each pair played once: the two sweeps are compared to round-off, and
+    # which way round two plays of one pair lie is the fill's to choose
     _, _, _, whole, _, _ = one_iteration(rng, monkeypatch, 1, env,
-                                         assembly=assembly)
+                                         assembly=assembly, once=True)
     rows = 60 + A._PAD_STRIP
     fit = -(-60 // segments) + A._PAD_STRIP
     fast = _lanes_vmem_limit(8) + A._FAST_MEMORY_SLACK + fit * 512
     monkeypatch.setattr(A, "fast_memory", lambda device: fast)
     monkeypatch.setattr(A, "_SWEEP_CACHE", {})
     problem, cfg, mesh, model, want_u, want_i = one_iteration(
-        np.random.default_rng(42), monkeypatch, 1, env, assembly=assembly)
+        np.random.default_rng(42), monkeypatch, 1, env, assembly=assembly,
+        once=True)
     # ... at three the 25 songs' table no longer fits whole either
     assert A._segments(problem, cfg, mesh) == {"u": segments - 1,
                                                "i": segments}

@@ -227,7 +227,8 @@ def _svm_setup():
 
 
 ALS_TREE = {"als.prepare": None, "als.prepare.order": "als.prepare",
-            "als.prepare.fill": "als.prepare", "als.place": None,
+            "als.prepare.fill": "als.prepare",
+            "als.prepare.fill.sort": "als.prepare.fill", "als.place": None,
             "als.sweep": None}
 TOPK_TREE = {"topk.build": None, "topk.build.place": "topk.build",
              "topk.build.warm_scatter": "topk.build",
