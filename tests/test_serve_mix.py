@@ -248,23 +248,164 @@ def test_health_and_metrics_carry_the_update_path(mix):
         assert name in reply, name
 
 
-def test_the_jitted_scatter_is_the_eager_one_to_the_bit_and_compiles_once():
+def resident(rows, layout):
+    """`rows` on one device, or row-sharded over a mesh of 4 of the suite's
+    8 CPU devices as `DeviceFactorIndex._pack` lays them out."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ms_tpu.parallel.mesh import BLOCK_AXIS, make_mesh
+
+    if layout == "one_device":
+        return jax.device_put(rows, jax.devices()[0])
+    mesh = make_mesh(devices=jax.devices()[:4])
+    return jax.device_put(rows, NamedSharding(mesh, P(BLOCK_AXIS, None)))
+
+
+def test_the_jitted_scatter_is_the_eager_one_to_the_bit_and_compiles_once():
+    import jax.numpy as jnp
 
     rng = np.random.default_rng(1)
-    matrix = jax.device_put(rng.standard_normal((4096, 24), dtype=np.float32))
-    topk._scatter_rows(matrix, np.zeros(8, np.int32), np.zeros((8, 24), np.float32))
-    compiled = topk._scatter_fn._cache_size()
-    for _ in range(3):
+    host = rng.standard_normal((4096, 24), dtype=np.float32)
+    matrix = topk._scatter_rows(
+        resident(host, "one_device"), np.zeros(8, np.int32),
+        np.zeros((8, 24), np.float32), 0)
+    compiled = topk._scatter_program()._cache_size()
+    for count in (8, 3, 5):
         pos = rng.integers(0, 4096, 8).astype(np.int32)
-        pos[-1] = pos[0]  # the pad repeats the first row: same value twice
         vec = rng.standard_normal((8, 24), dtype=np.float32)
-        vec[-1] = vec[0]
-        got = topk._scatter_rows(matrix, pos, vec)
-        assert np.array_equal(np.asarray(got), np.asarray(matrix.at[pos].set(vec)))
-        assert not np.array_equal(np.asarray(got), np.asarray(matrix))
-        matrix = got  # the old matrix was not donated: it was just read
-    assert topk._scatter_fn._cache_size() == compiled
+        want = np.array(jnp.asarray(host).at[pos[:count]].set(vec[:count]))
+        assert not np.array_equal(want, host)
+        # (a host view of the matrix itself would hold its buffer, and the
+        # CPU runtime would then copy in place of taking the donation)
+        got = topk._scatter_rows(matrix, pos, vec, count)
+        assert matrix.is_deleted()  # donated: the result is its buffer
+        host = np.array(got)
+        assert np.array_equal(host, want)
+        matrix = got
+        del got
+    assert topk._scatter_program()._cache_size() == compiled
+
+
+@pytest.mark.parametrize("layout", ["one_device", "mesh_of_4"])
+@pytest.mark.parametrize("case", ["whole_batch", "count_below_the_shape",
+                                  "a_position_twice", "count_zero"])
+def test_the_update_program_writes_the_real_rows_in_batch_order(layout, case):
+    """`_scatter_program` against `matrix.at[pos].set(vec)` over the real
+    rows: rows past the count are not written, a position that occurs twice
+    ends with the later row, a count of 0 (the warm-up) hands the same
+    values back; on one device and in the `shard_map` form, which the
+    matrix's sharding alone picks."""
+    rng = np.random.default_rng(7)
+    n, width, cap = 4096, 24, 32
+    for _ in range(3):
+        base = rng.standard_normal((n, width), dtype=np.float32)
+        pos = rng.choice(n, cap, replace=False).astype(np.int32)
+        vec = rng.standard_normal((cap, width), dtype=np.float32)
+        count = {"whole_batch": cap, "count_below_the_shape": 11,
+                 "a_position_twice": 11, "count_zero": 0}[case]
+        if case == "a_position_twice":
+            pos[7] = pos[2]  # both real: row 7 of the batch stays
+            pos[20] = pos[3]  # past the count: never written
+        want = base.copy()
+        for i in range(count):
+            want[pos[i]] = vec[i]
+        matrix = resident(base, layout)
+        sharding = matrix.sharding
+        got = topk._scatter_rows(matrix, pos, vec, count)
+        assert matrix.is_deleted()
+        assert got.sharding == sharding
+        assert np.array_equal(np.asarray(got), want)
+        if case == "count_zero":
+            assert np.array_equal(np.asarray(got), base)
+        else:
+            assert not np.array_equal(want, base)
+    if layout == "mesh_of_4":
+        assert topk._scatter_program(sharding.mesh) \
+            is not topk._scatter_program()
+
+
+@pytest.mark.parametrize("layout", ["one_device", "mesh_of_4"])
+def test_donation_is_taken_on_the_cpu_without_a_word(layout, recwarn):
+    """The host-pinned replicas and this suite run the program on the CPU
+    backend: the argument is donated there too, and jax says nothing (a
+    backend that cannot take a donation warns and copies)."""
+    rng = np.random.default_rng(8)
+    matrix = resident(rng.standard_normal((512, 8), dtype=np.float32), layout)
+    pos, vec = np.arange(4, dtype=np.int32), np.ones((4, 8), np.float32)
+    out = topk._scatter_rows(matrix, pos, vec, 4)
+    out.block_until_ready()
+    assert matrix.is_deleted() and not out.is_deleted()
+    assert not [w for w in recwarn if "donat" in str(w.message).lower()]
+    lowered = topk._scatter_program(
+        None if layout == "one_device" else out.sharding.mesh).lower(
+        out, pos, vec, np.int32(4)).as_text()
+    assert "tf.aliasing_output = 0" in lowered or "jax.buffer_donor" in lowered
+
+
+@pytest.mark.parametrize("sharded", ["0", "1"])
+def test_the_old_handle_is_deleted_and_the_index_still_answers(
+        monkeypatch, sharded):
+    """Three drains, each donating the matrix it found: the handle the index
+    held before is deleted every time, and a TOPK afterwards is the
+    reference's over the replayed rows."""
+    monkeypatch.setenv("TPUMS_TOPK_SHARDED", sharded)
+    monkeypatch.setenv("TPUMS_TOPK_TIER", "exact")
+    table = ModelTable()
+    index = topk.DeviceFactorIndex(table)
+    rng = np.random.default_rng(9)
+    base = rng.standard_normal((256, 8), dtype=np.float32)
+    index.bulk_load([str(i + 1) for i in range(256)], base)
+    assert index._is_sharded == (sharded == "1")
+    drains = counter("tpums_topk_update_drains_total")
+    in_place = counter("tpums_topk_update_drains_in_place_total")
+    q = rng.standard_normal(8).astype(np.float32)
+    replayed = base.copy()
+    for drain in range(3):
+        held = index._matrix
+        for row in rng.choice(256, 5, replace=False):
+            replayed[row] = rng.standard_normal(8).astype(np.float32) * 3
+            table.put(f"{row + 1}-I",
+                      ";".join("%.9g" % x for x in replayed[row]))
+        got = index.topk(q, 10)
+        assert held.is_deleted() and not index._matrix.is_deleted()
+        scores = replayed @ q
+        order = np.argsort(-scores)[:10]
+        assert [item for item, _ in got] == [str(i + 1) for i in order]
+        assert np.allclose([s for _, s in got], scores[order], atol=1e-5)
+    # every drain went through the aliasing program, in either layout
+    assert counter("tpums_topk_update_drains_total") == drains + 3
+    assert counter("tpums_topk_update_drains_in_place_total") == in_place + 3
+    assert index.full_builds == 1
+
+
+def test_the_warm_up_of_a_build_leaves_row_0_as_loaded():
+    """`_assemble` warms the scatter on the NEW matrix, which the program
+    takes: a warm-up that wrote would zero row 0, and one that threw its
+    result away would delete the matrix."""
+    index = topk.DeviceFactorIndex(ModelTable())
+    base = np.random.default_rng(10).standard_normal(
+        (64, 8), dtype=np.float32)
+    assembled = index._assemble([str(i + 1) for i in range(64)], base, 8)
+    assert not assembled["matrix"].is_deleted()
+    assert np.array_equal(np.asarray(assembled["matrix"]), base)
+    index.bulk_load([str(i + 1) for i in range(64)], base)
+    assert index.topk(base[0], 1)[0] == (
+        "1", pytest.approx(float(base[0] @ base[0]), rel=1e-6))
+
+
+def test_in_place_drains_are_all_the_drains_of_a_live_job(mix):
+    """`mix_in_place_share`'s two counters on one device: every drain of the
+    module's job, whatever the tests before this one wrote, took its
+    matrix."""
+    lines, toward = mix.updates(12)
+    for line, slot in zip(lines, toward):
+        mix.write(line, slot)
+    mix.consumed()
+    mix.ask([0])
+    drains = counter("tpums_topk_update_drains_total")
+    assert drains > 0
+    assert counter("tpums_topk_update_drains_in_place_total") == drains
 
 
 def test_a_partial_drain_leaves_the_oldest_remaining_stamp():
@@ -401,9 +542,10 @@ def test_the_contract_gained_one_configuration_and_one_cell():
 
 def test_a_scatter_the_device_refuses_loses_no_row_and_fails_no_query(
         monkeypatch, capsys):
-    """The scatter's result is a second matrix.  Where the device has no
-    room for it the rows wait for the next frame, the frame answers from
-    the matrix it has, and the failure is counted."""
+    """A scatter that fails before it has taken its argument (the refusal
+    PR 54 met was a second matrix with no room): the rows wait for the next
+    frame, the frame answers from the matrix it has, and the failure is
+    counted."""
     table = ModelTable()
     index = topk.DeviceFactorIndex(table)
     rng = np.random.default_rng(2)
@@ -411,11 +553,11 @@ def test_a_scatter_the_device_refuses_loses_no_row_and_fails_no_query(
     index.bulk_load([str(i + 1) for i in range(64)], base)
     real, calls = topk._scatter_rows, []
 
-    def refuse_once(matrix, pos, vec):
+    def refuse_once(matrix, pos, vec, count):
         calls.append(1)
         if len(calls) == 1:
             raise RuntimeError("RESOURCE_EXHAUSTED: no room for 4.0G")
-        return real(matrix, pos, vec)
+        return real(matrix, pos, vec, count)
 
     q = np.zeros(8, np.float32)
     q[0] = 1.0
@@ -483,3 +625,53 @@ def test_a_rebuild_that_fails_puts_its_keys_back_with_their_counts(
     assert index.topk(q, 1)[0] == ("7", pytest.approx(70.0))
     assert counter("tpums_topk_updates_applied_total") == applied + 1
     assert counter("tpums_topk_updates_coalesced_total") == coalesced + 1
+
+
+def test_a_scatter_that_fails_with_the_matrix_starts_a_rebuild_and_loses_no_key(
+        monkeypatch, capsys):
+    """The matrix is donated to the scatter.  A call that raises after it
+    has taken its argument leaves the index a deleted handle: the keys of
+    the drain go back, one device error is counted, a rebuild from the
+    table starts, the frame fails as one of a failed build does, and the
+    frame after the swap reads the written row."""
+    table = ModelTable()
+    index = topk.DeviceFactorIndex(table)
+    base = np.random.default_rng(5).standard_normal((64, 8), dtype=np.float32)
+    for i, row in enumerate(base):
+        table.put(f"{i + 1}-I", ";".join("%.9g" % x for x in row))
+    q = np.zeros(8, np.float32)
+    q[0] = 1.0
+    row = base[6].copy()
+    row[0] = 50.0
+    table.put("7-I", ";".join("%.9g" % x for x in row))
+    assert index.topk(q, 1)[0] == ("7", pytest.approx(50.0))  # the build
+    assert index.full_builds == 1
+    errors = counter("tpums_topk_device_errors_total")
+    applied = counter("tpums_topk_updates_applied_total")
+
+    real, calls = topk._scatter_rows, []
+
+    def take_and_fail_once(matrix, pos, vec, count):
+        calls.append(count)
+        if len(calls) > 1:  # the rebuild's warm-up
+            return real(matrix, pos, vec, count)
+        matrix.delete()
+        raise RuntimeError("INTERNAL: the program failed in flight")
+
+    monkeypatch.setattr(topk, "_scatter_rows", take_and_fail_once)
+    row[0] = 70.0
+    table.put("7-I", ";".join("%.9g" % x for x in row))
+    stamp = index._dirty["7-I"]
+    with pytest.raises(RuntimeError, match="lost its device matrix"):
+        index.topk(q, 1)
+    assert "update scatter failed" in capsys.readouterr().err
+    assert counter("tpums_topk_device_errors_total") == errors + 1
+    assert counter("tpums_topk_updates_applied_total") == applied
+    index._rebuild_thread.join(30.0)
+    assert not index._rebuild_thread.is_alive() and calls == [1, 0]
+    assert index.full_builds == 2 and not index._matrix.is_deleted()
+    assert index.topk(q, 1)[0] == ("7", pytest.approx(70.0))
+    # the key went back with the stamp it had, or the rebuild's own drain
+    # took it: either way no put is waiting unseen
+    assert index._dirty.get("7-I", stamp) == stamp
+    assert counter("tpums_topk_device_errors_total") == errors + 1
