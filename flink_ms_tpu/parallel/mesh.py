@@ -307,6 +307,24 @@ def row_bucket(n: int, n_shards: int, floor: int = 8) -> int:
     return n_shards * (1 << (per_shard - 1).bit_length())
 
 
+def row_capacity(n: int, floor: int = 1024) -> int:
+    """Rows a matrix of ``n`` live rows on ONE device is allocated at: the
+    live rows and spare positions for ids that arrive later (the top-k
+    index writes a new id into the next of them, ``serve/topk.py``).  Over
+    a mesh ``row_bucket``'s pad rows are that capacity already.
+
+    The spare rows are scanned by every query like the live ones, so they
+    are what every reader pays for a writer's new ids: ``n / 256`` of them
+    (0.4% of a scan; a power of two as ``row_bucket`` takes would be 68%
+    at 10,000,000 rows, and a sixteenth, 6.25%, more than the serving
+    cells' whole bound), at least ``floor`` (one whole drain of the
+    index's ``apply_cap`` new ids always fits), the total a multiple of
+    1024 rows.  A catalog that outgrows them is copied to the capacity of
+    its new size where the device has room for both."""
+    spare = max(max(n, 0) >> 8, floor)
+    return -(-(max(n, 0) + spare) // 1024) * 1024
+
+
 def device_memory(device) -> Optional[int]:
     """One device's memory in bytes as the runtime reports it, None where
     it reports none (the CPU backend; a device that is only described)."""

@@ -13,9 +13,24 @@ index's dirty set (``add_change_listener``), and at query time
   m changed rows written into the resident matrix's own buffer, which is
   donated to the program — O(m), not O(catalog), on the device too), so a
   streaming online-SGD load never forces full rebuilds on the query path;
-- genuinely new item ids trigger ONE background rebuild thread while
-  queries keep answering from the current (briefly stale) index — the
-  rebuild swaps in atomically when ready.
+- a genuinely new item id is applied by the same drain: the matrix is
+  allocated at a CAPACITY above its live rows (``mesh.row_capacity`` on one
+  device, ``mesh.row_bucket``'s pad rows over a mesh), the new row is
+  written at the next free position, and the query programs are told how
+  many rows are live (a device scalar compared with the row number in the
+  score's epilogue, so one compile serves every live count of a capacity
+  and a spare row can never reach an answer).  No rebuild, and ids already
+  served keep their positions;
+- when no free position is left the matrix is copied on the device into one
+  of the next capacity, off the lock, and swapped in as a rebuild is; where
+  the device has no room for both, new ids WAIT (counted, said once on
+  stderr) and everything already served keeps being served and updated;
+- a rebuild (a changed row width, a matrix lost to a failed update, a
+  writer that outran the query path, a new id on the IVF tier, which keeps
+  no spare positions) runs on ONE background thread while queries keep
+  answering from the current (briefly stale) index, and swaps in
+  atomically.  It takes the rows the index serves overlaid by the table's,
+  so a catalog installed by ``bulk_load`` does not revert to the table.
 
 The first query after startup pays the initial build (the benchmark's
 ``index_build_s``).
@@ -26,9 +41,10 @@ RETRIEVAL TIERS (round 11).  Two levers lift the catalog ceiling from the
 - **Sharded exact tier** — on a multi-device host the factor matrix is
   laid out as a permanently mesh-resident array, row-sharded over
   ``make_mesh()``'s block axis and padded to the shared power-of-two
-  bucket discipline (``mesh.row_bucket``; pad rows carry a ``-1e30``
-  score bias so they can never surface; the padded matrix exists on the
-  devices only, each shard put from its own rows, ``_pack``).  A batched
+  bucket discipline (``mesh.row_bucket``; pad rows score ``-1e30`` by
+  the live-row compare above so they can never surface; the padded matrix
+  exists on the devices only, each shard put from its own rows,
+  ``_pack``).  A batched
   TOPK is then ONE compiled ``shard_map`` program per batch-shape
   bucket: each device scores and ``top_k``'s its own row slice, an
   ``all_gather`` of the (D, B, k) partials feeds a tiny cross-shard
@@ -220,10 +236,25 @@ def _unpack_results(packed: np.ndarray):
 # extra MXU passes are not what a query waits for.
 _SCORE_PRECISION = "highest"
 
-# score bias stamped on pad rows (and on masked ANN candidate slots) so
-# they can never win a top-k over any real row; float32-safe margin below
-# any realistic factor dot product
+# the score of a row that is not live (spare capacity, a shard's pad rows,
+# a masked ANN candidate slot), so that it can never win a top-k over any
+# real row; float32-safe margin below any realistic factor dot product
 _PAD_SCORE = np.float32(-1e30)
+
+# A new id is written into spare capacity by the drain that finds it.  False
+# is the behaviour before PR 57, a background rebuild for every new id:
+# nothing in the program sets it; the benchmark's control
+# ``rebuild_on_insert`` patches it to show that its limits tell the two apart.
+_INSERTS_IN_PLACE = True
+
+# rows of one device-to-host strip when a rebuild reads back the rows the
+# index serves and the table lacks (0.21 GB of 200-wide rows)
+_FETCH_STRIP = 1 << 18
+# ONE put of more than 4 GiB took 26 s on a TPU v5e where 4.0 GB take 0.4
+# (``ann._PUT_STRIP``'s reason): a placement of more than ``_ONE_PUT_BYTES``
+# goes in strips of ``_PUT_STRIP`` rows
+_ONE_PUT_BYTES = 4 << 30
+_PUT_STRIP = 1 << 20
 
 # rows the index remembers in ``apply_log`` (a 20 s window at 600 rows/s is
 # 12,000; an entry is a key and three numbers)
@@ -314,6 +345,116 @@ def _scatter_rows(matrix, pos, vec, count):
     return _scatter_program(mesh)(matrix, pos, vec, np.int32(count))
 
 
+def _mask_spare_rows(scores, live, first=0):
+    """Traced, the score's epilogue in every exact query program: the
+    scores of rows from ``live`` on (spare capacity, pad rows) become
+    ``_PAD_SCORE``.  ``scores``: ``(..., rows)``, row ``first`` the first of
+    them (a shard's offset); ``live``: the traced int32 count of live rows,
+    so one compile serves every live count.  On a TPU the compare is one
+    small fusion over a row-number vector and the select rides the matmul's
+    own output fusion: no pass over the scores of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    at = first + jax.lax.broadcasted_iota(
+        jnp.int32, scores.shape, scores.ndim - 1)
+    return jnp.where(at < live, scores, _PAD_SCORE)
+
+
+_build_programs: Optional[tuple] = None
+
+
+def _build_jits():
+    """-> ``(place, grow, strip)``, the three programs that move whole rows
+    of one device's matrix, compiled once a shape.  ``place(matrix, rows,
+    start)``: ``rows`` written into the DONATED ``matrix`` from row
+    ``start`` -> the matrix and one element of it, a token to wait on once
+    the matrix itself has been donated to the next call.  ``grow(matrix,
+    capacity)``: a zero matrix of ``capacity`` rows with ``matrix`` as its
+    first rows (not donated: the old one serves until the swap).  ``strip(matrix, start, rows)``: ``rows`` rows of
+    ``matrix`` from ``start``, for the way back to the host."""
+    global _build_programs
+    if _build_programs is None:
+        from functools import partial
+
+        import jax
+        import jax.numpy as jnp
+
+        @partial(jax.jit, donate_argnums=0)
+        def place(matrix, rows, start):
+            matrix = jax.lax.dynamic_update_slice_in_dim(
+                matrix, rows, start, axis=0)
+            return matrix, matrix[0, 0]
+
+        @partial(jax.jit, static_argnums=1)
+        def grow(matrix, capacity):
+            return jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros((capacity, matrix.shape[1]), matrix.dtype),
+                matrix, 0, axis=0)
+
+        @partial(jax.jit, static_argnums=2)
+        def strip(matrix, start, rows):
+            return jax.lax.dynamic_slice_in_dim(matrix, start, rows, axis=0)
+
+        _build_programs = (place, grow, strip)
+    return _build_programs
+
+
+def _free_bytes(dev) -> Optional[int]:
+    """Bytes ``dev`` has free by its runtime's own count, None where the
+    backend reports no memory (the CPU): whoever asks then has room."""
+    stats = dev.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+
+
+def _one_put_serves(rows: np.ndarray, capacity: int, dev) -> bool:
+    """Whether ``rows`` go to ``dev`` in ONE put, to be copied into the zero
+    matrix of ``capacity`` rows there (``_build_jits``' ``grow``): up to
+    ``_ONE_PUT_BYTES``, where the device has room for the rows beside the
+    matrix.  One put returns at once and flies under the caller's next work
+    (3.3 s of id dict at 5M rows); strips hold the caller a transfer each
+    (1.15 s for 4 GB, PERF.md §6)."""
+    if not 0 < rows.nbytes <= _ONE_PUT_BYTES:
+        return False
+    free = _free_bytes(dev)
+    return free is None or rows.nbytes + capacity * rows.shape[1] * 4 <= free
+
+
+def _place_in_strips(rows: np.ndarray, capacity: int, dev):
+    """``rows`` as the first rows of a zero ``(capacity, k)`` matrix on
+    ``dev``, without a padded copy on the host and with no put of more than
+    ``_PUT_STRIP`` rows: strips of one size (the last one starts early
+    enough to be whole and rewrites what the one before it gave) are put
+    from views of ``rows`` and written into the donated buffer.  Those in
+    flight take no more than half of what the device has free beside the
+    matrix (five of a 10M x 200 catalog's ten on a 16 GB chip), two where
+    the backend reports no memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from .ann import _whole_chunks
+
+    place = _build_jits()[0]
+    n, k = rows.shape
+    free = _free_bytes(dev)
+    matrix = jnp.zeros((capacity, k), jnp.float32, device=dev)
+    if not n:
+        return matrix
+    strip, starts = _whole_chunks(n, _PUT_STRIP)
+    in_flight = 2 if free is None else max(
+        2, int((free - capacity * k * 4) // (2 * strip * k * 4)))
+    placed = []
+    for lo in starts:
+        if len(placed) == in_flight:
+            placed.pop(0).block_until_ready()
+        matrix, token = place(
+            matrix, jax.device_put(rows[lo:lo + strip], dev), np.int32(lo))
+        placed.append(token)
+    return matrix
+
+
 _sharded_program_cache: dict = {}
 
 
@@ -321,7 +462,8 @@ def _sharded_topk_program(mesh):
     """One jitted shard_map top-k per mesh (jax re-specializes per
     (n_pad, B, k) shape bucket): every device scores its own row slice
     against the whole query batch, takes a LOCAL top-k, globalizes the
-    row indices by its shard offset, and an ``all_gather`` of the
+    row indices by its shard offset (rows from ``live`` on, the pad rows,
+    score ``_PAD_SCORE``: ``_mask_spare_rows``), and an ``all_gather`` of the
     (D, B, k_local) partials feeds the final merge ``top_k`` — O(D*k)
     work replicated on every shard, tiny next to the O(n/D) scan.  The
     catalog never moves: only the merged (B, k) winners leave the
@@ -339,23 +481,24 @@ def _sharded_topk_program(mesh):
     from ..parallel.mesh import BLOCK_AXIS
 
     @partial(jax.jit, static_argnums=3)
-    def sharded_topk(matrix, bias, qs, k):
+    def sharded_topk(matrix, live, qs, k):
         @partial(
             shard_map, mesh=mesh,
-            in_specs=(P(BLOCK_AXIS, None), P(BLOCK_AXIS), P(None, None)),
+            in_specs=(P(BLOCK_AXIS, None), P(), P(None, None)),
             out_specs=P(None, None),
             check_vma=False,
         )
-        def run(m, b, q):
+        def run(m, n_live, q):
             with jax.named_scope("topk.shard_score"):
+                first = jax.lax.axis_index(BLOCK_AXIS) * m.shape[0]
                 # (B, n/D) scores of this shard's rows
-                scores = jnp.matmul(
-                    q, m.T, precision=_SCORE_PRECISION) + b[None, :]
+                scores = _mask_spare_rows(
+                    jnp.matmul(q, m.T, precision=_SCORE_PRECISION),
+                    n_live, first)
             with jax.named_scope("topk.shard_select"):
                 k_local = min(k, m.shape[0])
                 s, i = jax.lax.top_k(scores, k_local)
-                gi = (i + jax.lax.axis_index(BLOCK_AXIS)
-                      * m.shape[0]).astype(jnp.int32)
+                gi = (i + first).astype(jnp.int32)
             with jax.named_scope("topk.merge"):
                 s_all = jax.lax.all_gather(s, BLOCK_AXIS)  # (D, B, k_local)
                 g_all = jax.lax.all_gather(gi, BLOCK_AXIS)
@@ -365,7 +508,7 @@ def _sharded_topk_program(mesh):
                 return _pack_results(
                     ms, jnp.take_along_axis(g_cat, mi, axis=1))
 
-        return run(matrix, bias, qs)
+        return run(matrix, live, qs)
 
     _sharded_program_cache[mesh] = sharded_topk
     return sharded_topk
@@ -383,7 +526,7 @@ class DeviceFactorIndex:
         self._ids: List[str] = []
         self._id_pos: dict = {}   # id -> row index in the device matrix
         self._matrix = None  # (n_pad, k) device array (maybe mesh-sharded)
-        self._n_real = 0
+        self._n_real = 0     # live rows: positions below it hold an id
         self._k_real = 0  # real factor width
         self._topk_fn = None
         self._topk_many_fn = None
@@ -404,8 +547,8 @@ class DeviceFactorIndex:
             os.environ.get("TPUMS_ANN_RECALL_MIN", 0.95))
         self._is_sharded = False
         self._mesh = None        # set when the sharded layout engages
-        self._bias = None        # (n_pad,) pad-row score bias (sharded)
-        self._n_pad = 0
+        self._live = None        # n_real as a device scalar, for the programs
+        self._n_pad = 0          # the matrix's rows: the capacity
         self._ann = None         # serve.ann.IVFIndex when the tier is built
         self._said_ann_unsharded = False
         # retrieval-plane health (obs/scrape.fleet_signals): rebuild rate,
@@ -460,6 +603,25 @@ class DeviceFactorIndex:
             "tpums_topk_updates_coalesced_total")
         self._obs_update_visible = reg.histogram(
             "tpums_topk_update_visible_seconds")
+        # new ids: rows written into spare capacity by a drain, their own
+        # put -> scatter enqueued (the histogram above sees updates of ids
+        # the index knew), ids that found no free position and no room for
+        # a larger matrix (each waiting id counted once), and the copies
+        # into a larger one; live rows and the rows of the matrix
+        self._obs_inserts_applied = reg.counter(
+            "tpums_topk_inserts_applied_total")
+        self._obs_inserts_refused = reg.counter(
+            "tpums_topk_inserts_refused_total")
+        self._obs_grows = reg.counter("tpums_topk_grows_total")
+        self._obs_insert_visible = reg.histogram(
+            "tpums_topk_insert_visible_seconds")
+        self._obs_rows_live = reg.gauge("tpums_topk_rows_live")
+        self._obs_rows_capacity = reg.gauge("tpums_topk_rows_capacity")
+        # key -> (first put time, puts) of the new ids that wait for a
+        # matrix with room (under self._lock); the operator is told once an
+        # exhaustion
+        self._unplaced: dict = {}
+        self._said_full = False
         # (key, t_put, t_applied, puts) of the last applied rows
         # (``apply_log``); deque.append is atomic
         self._apply_log: Deque[tuple] = deque(maxlen=_APPLY_LOG_CAP)
@@ -594,7 +756,8 @@ class DeviceFactorIndex:
 
     def apply_log(self) -> List[tuple]:
         """``(key, t_put, t_applied, puts)`` of the last rows scattered in
-        place (at most ``_APPLY_LOG_CAP``), oldest first: ``perf_counter``
+        place (at most ``_APPLY_LOG_CAP``; updates of known ids and new ids
+        alike), oldest first: ``perf_counter``
         instants of the key's first put since its last drain and of the
         scatter's enqueue, after which every dispatch reads the row, and
         the puts of the key that this one row stands for (all but one of
@@ -602,7 +765,10 @@ class DeviceFactorIndex:
         return list(self._apply_log)
 
     def update_stats(self) -> dict:
-        """The update path in four numbers, for ``ServingJob.health``."""
+        """The update path in numbers, for ``ServingJob.health``: rows
+        scattered in place (new ids among them), puts coalesced, drains,
+        the mean put -> visible of updates; new ids written into spare
+        capacity, new ids that wait for room, live rows and capacity."""
         seen = self._obs_update_visible
         return {
             "applied": int(self._obs_updates_applied.value),
@@ -610,19 +776,29 @@ class DeviceFactorIndex:
             "drains": int(self._obs_update_drains.value),
             "visible_mean_ms": (1e3 * seen.sum / seen.count
                                 if seen.count else None),
+            "inserted": int(self._obs_inserts_applied.value),
+            "waiting_for_room": len(self._unplaced),
+            "rows_live": self._n_real,
+            "rows_capacity": self._n_pad,
         }
 
     # -- building -----------------------------------------------------------
 
-    def _snapshot_rows(self):
-        """-> (ids, rows ndarray (n, width), width).
+    def _snapshot_rows(self, serving: bool = False):
+        """-> (ids, rows ndarray (n, width), width): the table's rows and,
+        for a rebuild of an index that is ``serving``, the rows it serves
+        whose ids the table does not hold (``_rows_the_table_lacks``: a
+        catalog that ``bulk_load`` installed), after the table's.
 
         Width policy: the index width is the MODAL separator count across
         the snapshot (cheap C-level ``str.count``), so a single truncated
         or over-long payload is dropped rather than poisoning the build —
         and because rows are pre-filtered by token count, a reshape can
         never misalign rows (compensating short/long pairs are filtered
-        out, not averaged away by a total-size check).
+        out, not averaged away by a total-size check).  The rows the index
+        serves vote with their own width, so one odd payload cannot turn
+        a bulk-loaded catalog out either; a table whose rows outvote them
+        at another width does, and the index says so.
 
         Fast path: join the width-consistent payloads and parse ONCE with
         numpy's C float parser — ~25x less Python-loop work than
@@ -635,19 +811,43 @@ class DeviceFactorIndex:
                 continue
             ids.append(key[: -len(self.suffix)])
             payloads.append(payload.rstrip(";"))
-        if not ids:
+        held_ids, held = (self._rows_the_table_lacks(set(ids)) if serving
+                          else ([], None))
+        if not ids and not held_ids:
             return [], np.zeros((0, 0), np.float32), None
         counts = np.fromiter(
             (p.count(";") + 1 for p in payloads),
             dtype=np.int64, count=len(payloads),
         )
-        width = int(np.bincount(counts).argmax())
+        votes = np.bincount(counts, minlength=held.shape[1] + 1
+                            if held_ids else 0)
+        if held_ids:
+            votes[held.shape[1]] += len(held_ids)
+        width = int(votes.argmax())
+        if held_ids and held.shape[1] != width:
+            print(f"[topk] the table's rows are {width} wide: {len(held_ids)} "
+                  f"rows of width {held.shape[1]} that only the index held "
+                  "are dropped", file=sys.stderr)
+            held_ids, held = [], None
         keep = counts == width
         if not keep.all():
             ids = [i for i, k in zip(ids, keep) if k]
             payloads = [p for p, k in zip(payloads, keep) if k]
+        if held_ids:
+            t_ids, t_rows, _ = self._parse_rows(ids, payloads, width)
+            if not t_ids:
+                return held_ids, held, width
+            return t_ids + held_ids, np.concatenate([t_rows, held]), width
         if not ids or width <= 0:
             return [], np.zeros((0, 0), np.float32), None
+        return self._parse_rows(ids, payloads, width)
+
+    @staticmethod
+    def _parse_rows(ids, payloads, width):
+        """Payloads of ``width`` tokens each -> (ids, rows, width), without
+        the rows a token of which is no number."""
+        if not ids:
+            return [], np.zeros((0, width), np.float32), width
         try:
             # one C-level parse of every payload (np.array over one big
             # split — same pattern as formats.parse_svm_range_payload;
@@ -670,7 +870,58 @@ class DeviceFactorIndex:
                 continue
             out_ids.append(id_)
             rows.append(vec)
-        return out_ids, np.asarray(rows, dtype=np.float32), width
+        return (out_ids, np.asarray(rows, dtype=np.float32).reshape(-1, width),
+                width)
+
+    def _rows_the_table_lacks(self, table_ids: set):
+        """-> (ids, rows) that the index serves and the table does not hold,
+        read back from the device; ([], None) where there are none, or no
+        matrix to read (none built yet, or lost to a failed update: such a
+        rebuild has the table alone).  Called by the rebuild thread WITHOUT
+        the index lock: each strip of ``_FETCH_STRIP`` rows that holds such
+        a row is sliced under the lock (the matrix is donated to every
+        drain, so no handle of it may be kept across one) and copied to the
+        host outside it.  Such rows never change under the rebuild: a write
+        reaches the index through the table only."""
+        with self._lock:
+            if self._matrix is None or self._matrix.is_deleted():
+                return [], None
+            ids, n_pad = list(self._ids), self._n_pad
+            n_shards = (self._matrix.sharding.num_devices
+                        if self._is_sharded else 1)
+            width = self._matrix.shape[1]
+        absent = np.fromiter(
+            (pos for pos, id_ in enumerate(ids)
+             if id_ is not None and id_ not in table_ids), dtype=np.int64)
+        if not len(absent):
+            return [], None
+        from .ann import _whole_chunks
+
+        strip_of = _build_jits()[2]
+        held = np.empty((len(absent), width), np.float32)
+        per = n_pad // n_shards
+        rows, starts = _whole_chunks(per, _FETCH_STRIP)
+        for first in range(0, n_pad, per):
+            done = first
+            for lo in starts:
+                a, b = np.searchsorted(
+                    absent, [max(first + lo, done), first + lo + rows])
+                done = first + lo + rows
+                if a == b:
+                    continue
+                with self._lock:
+                    matrix = self._matrix
+                    if (matrix is None or matrix.is_deleted()
+                            or matrix.shape[0] != n_pad):
+                        raise RuntimeError(
+                            "the index was replaced under its rebuild")
+                    data = next(
+                        shard.data for shard in matrix.addressable_shards
+                        if (shard.index[0].start or 0) == first)
+                    part = strip_of(data, np.int32(lo), rows)
+                    del data, matrix
+                held[a:b] = np.asarray(part)[absent[a:b] - first - lo]
+        return [ids[pos] for pos in absent.tolist()], held
 
     def _mesh_if_sharding(self, n_rows: int):
         """The mesh to shard over, or None for the single-device layout.
@@ -686,16 +937,22 @@ class DeviceFactorIndex:
             return None
         return mesh
 
-    def _pack(self, rows):
+    def _pack(self, rows, spare: bool = True):
         """Place the factor rows on device ->
-        ``(matrix, bias, n_pad, is_sharded)``.
+        ``(matrix, n_pad, is_sharded)``, ``n_pad`` the matrix's rows: the
+        live rows first, then zero rows that the query programs mask
+        (``_mask_spare_rows``) and a new id is written into.
 
-        Single-device: the exact array, no padding (unchanged from the
-        host-pinned plane).  Sharded: rows are padded to the shared
-        power-of-two per-shard bucket (``mesh.row_bucket``) and laid out
-        row-sharded over the mesh's block axis, with a same-sharded bias
-        vector stamping ``_PAD_SCORE`` on pad rows so they can never win
-        a merge — the padding keeps XLA at a handful of compiled shapes
+        Single-device: ``mesh.row_capacity`` rows.  Where one put serves
+        (``_one_put_serves``) the rows alone are put here and ``_assemble``
+        joins the spare rows on the device once the id dict is made;
+        otherwise strips into a zero matrix (``_place_in_strips``).
+        Without ``spare`` (the IVF tier's build, which reorders the rows
+        into a matrix of its own) the exact array in one put and
+        ``n_pad`` its rows.  Sharded: rows are
+        padded to the shared power-of-two per-shard bucket
+        (``mesh.row_bucket``) and laid out row-sharded over the mesh's
+        block axis — the padding keeps XLA at a handful of compiled shapes
         over the catalog's whole growth curve.
 
         The padded matrix exists on the devices only.  Each device's row
@@ -712,16 +969,23 @@ class DeviceFactorIndex:
         in 32 s on a host of four TPU v5e chips; one after the other in
         1.7 (PERF.md §6, PR 36).  So here ``topk.build.place`` holds the
         transfers themselves; the single-device put stays asynchronous
-        and ``_assemble`` builds ``id_pos`` under it."""
+        and ``_assemble`` builds ``id_pos`` under it (all but the last two
+        strips awaited)."""
         import jax
 
         rows = np.asarray(rows, dtype=np.float32)
         mesh = self._mesh_if_sharding(rows.shape[0])
         if mesh is None:
+            from ..parallel.mesh import row_capacity
+
+            dev = _target_device()
+            n_pad = row_capacity(rows.shape[0]) if spare else rows.shape[0]
             with phase("topk.build.place"):
-                matrix = jax.device_put(rows, _target_device())
+                matrix = (_place_in_strips(rows, n_pad, dev)
+                          if spare and not _one_put_serves(rows, n_pad, dev)
+                          else jax.device_put(rows, dev))
             self._obs_host_copy_bytes.set(0)
-            return matrix, None, rows.shape[0], False
+            return matrix, n_pad, False
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.mesh import BLOCK_AXIS, num_blocks, row_bucket
@@ -741,16 +1005,27 @@ class DeviceFactorIndex:
                     blocks[dev] = np.zeros((hi - lo, k), np.float32)
                     blocks[dev][:max(n - lo, 0)] = rows[lo:n]
                     copied += blocks[dev].nbytes
-            bias = np.zeros((n_pad,), np.float32)
-            bias[n:] = _PAD_SCORE
         with phase("topk.build.place"):
             matrix = jax.make_array_from_single_device_arrays(
                 (n_pad, k), sharding,
                 [jax.device_put(block, dev).block_until_ready()
                  for dev, block in blocks.items()])
-            bias = jax.device_put(bias, NamedSharding(mesh, P(BLOCK_AXIS)))
         self._obs_host_copy_bytes.set(copied)
-        return matrix, bias, n_pad, True
+        return matrix, n_pad, True
+
+    def _live_scalar(self, n_real: int, matrix):
+        """``n_real`` as the int32 device scalar the query programs compare
+        row numbers with: on ``matrix``'s device, or on every device of its
+        mesh.  Put when the count changes (a swap, a drain that held new
+        ids), never per query."""
+        import jax
+
+        sharding = matrix.sharding
+        if getattr(sharding, "mesh", None) is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            sharding = NamedSharding(sharding.mesh, P())
+        return jax.device_put(np.int32(n_real), sharding)
 
     def _wants_ann(self, n: int) -> bool:
         """Whether a build of ``n`` rows tries the IVF tier: the tier knob
@@ -814,16 +1089,19 @@ class DeviceFactorIndex:
         first wait so that one device's transfer flies under it; where the
         IVF tier is tried, after it, since the tier decides the positions),
         ``.ann`` (only where the tier builds; its own children ``.train``,
-        ``.assign``, ``.lists``, ``.recall``) and ``.warm_scatter`` (a
+        ``.assign``, ``.lists``, ``.recall``), ``.spare`` (one device, where
+        one put served: the rows' copy into the zero matrix that has the
+        spare rows, and what is left of the transfer) and ``.warm_scatter`` (a
         compile or load of the update scatter at this matrix's shape, run
         over no row, and what is left of one device's transfer)."""
         with phase("topk.build"):
-            matrix = bias = ann = id_pos = None
+            matrix = live = ann = id_pos = None
             n_pad, sharded = 0, False
             n_real = len(ids)
-            if len(rows):
-                matrix, bias, n_pad, sharded = self._pack(rows)
             tries_ann = self._wants_ann(len(rows))
+            if len(rows):
+                # the IVF tier takes the exact rows and keeps no spare ones
+                matrix, n_pad, sharded = self._pack(rows, spare=not tries_ann)
             if tries_ann and sharded:
                 tries_ann = False
                 if not self._said_ann_unsharded:
@@ -853,6 +1131,11 @@ class DeviceFactorIndex:
                     # against the threads that answer queries while a
                     # rebuild runs
                     id_pos = {id_: i for i, id_ in enumerate(ids)}
+            if matrix is not None and matrix.shape[0] < n_pad:
+                # one put of the rows alone has flown under the id dict:
+                # now their copy into the zero matrix with the spare rows
+                with phase("topk.build.spare"):
+                    matrix = _build_jits()[1](matrix, n_pad)
             if len(rows) and not self._counter_mode:
                 # warm the fixed-shape update scatter at the NEW matrix
                 # shape so the first streaming update never pays a compile
@@ -865,10 +1148,12 @@ class DeviceFactorIndex:
                         dtype=np.float32)
                     matrix = _scatter_rows(matrix, pos, vec, 0)
                     matrix.block_until_ready()
+            if matrix is not None:
+                live = self._live_scalar(n_real, matrix)
             return {
                 "ids": ids, "id_pos": id_pos,
                 "n_real": n_real, "k_real": width, "matrix": matrix,
-                "bias": bias, "n_pad": n_pad, "sharded": sharded, "ann": ann,
+                "live": live, "n_pad": n_pad, "sharded": sharded, "ann": ann,
             }
 
     def _swap_locked(self, a: dict) -> None:
@@ -878,18 +1163,35 @@ class DeviceFactorIndex:
         self._n_real = a["n_real"]
         self._k_real = a["k_real"]
         self._matrix = a["matrix"]
-        self._bias = a["bias"]
+        self._live = a["live"]
         self._n_pad = a["n_pad"]
         self._is_sharded = a["sharded"]
         self._ann = a["ann"]
-        n_shards = a["matrix"].sharding.num_devices if a["sharded"] else 1
-        self._obs_shards.set(n_shards)
-        self._obs_shard_rows.set(a["n_pad"] // n_shards)
-        self._obs_pad_rows.set(a["n_pad"] - a["n_real"])
+        self._observe_rows_locked()
         self._built_once = True
         self.full_builds += 1
         self._obs_rebuilds.inc()
         self._peek_applied.clear()
+        self._readmit_unplaced_locked()
+
+    def _observe_rows_locked(self) -> None:
+        """The installed layout's gauges, after a swap, a copy into a
+        larger matrix or a drain that held new ids."""
+        n_shards = (self._matrix.sharding.num_devices
+                    if self._is_sharded else 1)
+        self._obs_shards.set(n_shards)
+        self._obs_shard_rows.set(self._n_pad // n_shards)
+        self._obs_pad_rows.set(self._n_pad - self._n_real)
+        self._obs_rows_live.set(self._n_real)
+        self._obs_rows_capacity.set(self._n_pad)
+
+    def _readmit_unplaced_locked(self) -> None:
+        """A new matrix is in: the new ids that waited for room re-enter the
+        dirty set with the stamps they had, and the next exhaustion is said
+        again."""
+        waiting, self._unplaced = self._unplaced, {}
+        self._said_full = False
+        self._put_back(waiting)
 
     def _build_locked(self) -> None:
         """Full build, called under self._lock."""
@@ -908,18 +1210,20 @@ class DeviceFactorIndex:
         build whose table snapshot parsed to exactly ``(ids, rows)``.
         ``benchmark/drivers/topk_open.py`` uses it to stand up 5M–17M-row
         catalogs without materializing as many payload strings through
-        the table; later updates via the table flow through the
-        normal dirty-set maintenance (unknown ids trigger a rebuild whose
-        snapshot reads the TABLE, so a bulk-loaded catalog absent from
-        the table reverts — this is a load ramp, not a second source of
-        truth).
+        the table; later writes via the table flow through the normal
+        dirty-set maintenance: a known id is overwritten in place, an
+        unknown id is written into spare capacity, and a rebuild, when one
+        does run, takes the rows the index serves overlaid by the table's,
+        so the installed catalog stays served whether or not the table
+        holds it.  (A matrix LOST to a failed update is the exception: its
+        rows are gone with it, and that rebuild has the table alone.)
 
-        An f32 ``rows`` is not copied on the host: the device matrix (in
-        the sharded layout, every shard that is all real rows) is put
-        from a view of the caller's array.  The caller leaves it
-        unmodified while the index holds this catalog: the transfer reads
-        it after ``device_put`` has returned, and a CPU backend's array
-        may go on sharing its memory."""
+        An f32 ``rows`` is not copied on the host: the device matrix is
+        put from views of the caller's array (one device: in strips; the
+        sharded layout: every shard that is all real rows).  The caller
+        leaves it unmodified while the index holds this catalog: the
+        transfers read it after ``device_put`` has returned, and a CPU
+        backend's shard may go on sharing its memory."""
         rows = np.asarray(rows, dtype=np.float32)
         if rows.ndim != 2 or len(ids) != rows.shape[0]:
             raise ValueError("bulk_load needs ids aligned with (n, k) rows")
@@ -933,24 +1237,39 @@ class DeviceFactorIndex:
                                rows.shape[1] if rows.size else None))
 
     def _apply_updates_locked(self, dirty: dict, allow_rebuild: bool = True) -> bool:
-        """In-place device update of already-indexed rows (``dirty``: key ->
-        (first put time, puts)); new ids kick one background rebuild and
-        stay invisible (stale index) until it lands.  False where the
-        device refused the scatter: nothing was applied.
+        """In-place device update (``dirty``: key -> (first put time,
+        puts)): a row of an indexed id is written over, a row of a new id is
+        written at the next free position of the matrix's spare capacity,
+        which then becomes live.  Both ride ONE scatter of the drain, in
+        the drain's order.  False where the device refused the scatter:
+        nothing was applied and no id was registered.
 
-        The payload parse is vectorized: all in-index rows of the batch
-        are joined and parsed with ONE numpy C float pass into a (B, k)
-        matrix, then scattered into the device matrix in a single op —
-        per-row ``float()`` loops only run on the fallback path (payloads
-        with empty/non-numeric tokens), preserving its exact semantics.
+        A new id that finds no free position: where the device has room
+        for a larger matrix beside this one, a background copy into the
+        next capacity starts (``_start_rebuild_locked(grow=True)``) and the
+        id waits in the dirty set for the swap; where it has not, the id
+        waits in ``_unplaced`` (counted once, said once on stderr) until a
+        matrix with room is installed.  On the IVF tier, which keeps no
+        spare positions, a new id starts a rebuild as it always did.
+
+        The payload parse is vectorized: all rows of the batch are joined
+        and parsed with ONE numpy C float pass into a (B, k) matrix, then
+        scattered into the device matrix in a single op — per-row
+        ``float()`` loops only run on the fallback path (payloads with
+        empty/non-numeric tokens), preserving its exact semantics.
 
         Stages ``topk.maintain.parse`` (table reads, the join and the float
-        parse) and ``topk.maintain.scatter`` (the padded batch and the
-        scatter's enqueue), both inside the caller's ``topk.maintain``."""
+        parse), ``topk.maintain.scatter`` (the padded batch and the
+        scatter's enqueue) and, in a drain that held new ids,
+        ``topk.maintain.insert`` (their ids registered, the live count
+        put), all inside the caller's ``topk.maintain``."""
         suffix = self.suffix
         suffix_len = len(suffix)
         k_real = self._k_real
-        # (pos, payload, key, (t_put, puts)) of the rows to scatter
+        takes_new = (_INSERTS_IN_PLACE and self._ann is None
+                     and self._matrix is not None)
+        # (pos or None for a new id, payload, key, (t_put, puts)) of the
+        # rows to scatter
         candidates: list = []
         slow: list = []  # the same, needing the per-row parse
         structural = False
@@ -962,7 +1281,7 @@ class DeviceFactorIndex:
                 if payload is None:
                     continue
                 pos = self._id_pos.get(key[:-suffix_len])
-                if pos is None:
+                if pos is None and not takes_new:
                     structural = True  # new item: needs rebuild
                     continue
                 p = payload.rstrip(";")
@@ -977,22 +1296,22 @@ class DeviceFactorIndex:
                         ";".join(c[1] for c in candidates).split(";"),
                         dtype=np.float32)
                     updates_pos = [c[0] for c in candidates]
-                    updates_vec = flat.reshape(len(candidates), k_real)
+                    updates_vec = list(flat.reshape(len(candidates), k_real))
                     applied = [c[2:] for c in candidates]
                 except ValueError:
                     # an empty/garbled token somewhere in the batch: re-route
                     # every candidate through the exact per-row path
                     slow.extend(candidates)
-            if slow:
-                updates_vec = list(updates_vec)
-                for pos, payload, key, waited in slow:
-                    vec = [float(t) for t in payload.split(";") if t]
-                    if len(vec) != k_real:
-                        structural = True  # width change: needs rebuild
-                        continue
-                    updates_pos.append(pos)
-                    updates_vec.append(vec)
-                    applied.append((key, waited))
+            for pos, payload, key, waited in slow:
+                vec = [float(t) for t in payload.split(";") if t]
+                if len(vec) != k_real:
+                    structural = True  # width change: needs rebuild
+                    continue
+                updates_pos.append(pos)
+                updates_vec.append(vec)
+                applied.append((key, waited))
+            new_ids, crowded = self._seat_new_ids(
+                updates_pos, updates_vec, applied)
         if len(updates_pos) and self._matrix is not None:
             try:
                 with stage("topk.maintain.scatter"):
@@ -1007,17 +1326,101 @@ class DeviceFactorIndex:
                       f"wait for the next frame: {e}", file=sys.stderr)
                 return False
             t_applied = time.perf_counter()
+            if new_ids:
+                with stage("topk.maintain.insert"):
+                    self._id_pos.update(
+                        zip(new_ids, range(self._n_real,
+                                           self._n_real + len(new_ids))))
+                    self._ids.extend(new_ids)
+                    self._n_real += len(new_ids)
+                    self._live = self._live_scalar(self._n_real, self._matrix)
+                    self._observe_rows_locked()
+                    self._obs_inserts_applied.inc(len(new_ids))
             self.inplace_updates += len(applied)
             self._obs_updates_applied.inc(len(applied))
             self._obs_update_drains.inc()
             if in_place:
                 self._obs_update_drains_in_place.inc()
-            for key, (t_put, puts) in applied:
-                self._obs_update_visible.observe(t_applied - t_put)
+            first_new = self._n_real - len(new_ids)
+            for pos, (key, (t_put, puts)) in zip(updates_pos, applied):
+                (self._obs_insert_visible if pos >= first_new
+                 else self._obs_update_visible).observe(t_applied - t_put)
                 self._apply_log.append((key, t_put, t_applied, puts))
+        if crowded:
+            self._hold_crowded_locked(crowded, allow_rebuild)
         if structural and allow_rebuild:
             self._start_rebuild_locked()
         return True
+
+    def _seat_new_ids(self, updates_pos, updates_vec, applied):
+        """Give the batch's new ids (position None) the free positions from
+        ``_n_real`` on, in batch order -> (their ids, in that order; the
+        ``{key: waited}`` of those that found none, taken out of the three
+        lists).  Nothing is registered here: the caller does that once the
+        scatter is enqueued."""
+        free = self._n_pad - self._n_real
+        new_ids, crowded, keep = [], {}, []
+        for j, pos in enumerate(updates_pos):
+            if pos is None:
+                key, waited = applied[j]
+                if len(new_ids) == free:
+                    crowded[key] = waited
+                    continue
+                updates_pos[j] = self._n_real + len(new_ids)
+                new_ids.append(key[:-len(self.suffix)])
+            keep.append(j)
+        if crowded:
+            for column in (updates_pos, updates_vec, applied):
+                column[:] = [column[j] for j in keep]
+        return new_ids, crowded
+
+    def _room_to_grow(self, n_pad: int) -> bool:
+        """Whether the matrix's device can hold a matrix of ``n_pad`` rows
+        BESIDE the one it holds, with a quarter of it over for the frames'
+        scores.  A backend that reports no memory (the CPU) has room."""
+        free = _free_bytes(self._matrix.devices().pop())
+        return free is None or free >= 1.25 * n_pad * self._matrix.shape[1] * 4
+
+    def _hold_crowded_locked(self, crowded: dict, allow_rebuild: bool) -> None:
+        """New ids of a drain that found no free position.  One device with
+        room for a larger matrix: they go back to the dirty set and a copy
+        into the next capacity starts (``allow_rebuild`` false: one is in
+        flight, they wait for its swap).  A mesh: a rebuild, which lays the
+        rows out anew at the next bucket.  No room: they wait in
+        ``_unplaced`` until some swap brings a matrix with room, each id
+        counted once, and the operator is told once."""
+        from ..parallel.mesh import row_capacity
+
+        if not allow_rebuild:
+            return  # peeked, not drained: still in the dirty set
+        if self._is_sharded:
+            self._put_back(crowded)
+            self._start_rebuild_locked()
+            return
+        n_pad = row_capacity(self._n_pad)
+        if self._room_to_grow(n_pad):
+            self._put_back(crowded)
+            self._start_rebuild_locked(grow=n_pad)
+            return
+        fresh = 0
+        for key, (t_put, puts) in crowded.items():
+            waiting = self._unplaced.get(key)
+            fresh += waiting is None
+            self._unplaced[key] = (
+                (t_put, puts) if waiting is None
+                else (waiting[0], waiting[1] + puts))
+        self._obs_inserts_refused.inc(fresh)
+        if not self._said_full:
+            self._said_full = True
+            gb = n_pad * self._matrix.shape[1] * 4 / 1e9
+            print(f"[topk] the index is FULL: all {self._n_pad} rows of its "
+                  f"device matrix hold an id, and the device has no room for "
+                  f"a larger one beside it ({gb:.1f} GB more). New ids are "
+                  "NOT served and wait (tpums_topk_inserts_refused_total "
+                  "counts them); every id already served stays served and "
+                  "takes updates. To take them: restart or bulk_load this "
+                  "catalog where it has room (a larger device, or a mesh of "
+                  "devices: the row-sharded layout).", file=sys.stderr)
 
     def _scatter_rows_locked(self, updates_pos, updates_vec) -> bool:
         """Write ≤apply_cap changed rows into the device matrix at ONE
@@ -1044,9 +1447,42 @@ class DeviceFactorIndex:
         self._matrix = _scatter_rows(given, pos, vec, count)
         return given.is_deleted()
 
-    def _start_rebuild_locked(self) -> None:
+    def _start_rebuild_locked(self, grow: int = 0) -> None:
+        """Start the ONE background thread that replaces the matrix: a
+        rebuild from the rows the index serves overlaid by the table's, or,
+        with ``grow`` (one device only), a copy of the matrix as it lies
+        into one of ``grow`` rows.  While it runs queries peek the dirty
+        set and never drain it, so what they apply meanwhile is applied
+        again to the new matrix after the swap."""
         if self._rebuild_thread is not None and self._rebuild_thread.is_alive():
             return  # one rebuild in flight; later dirt re-triggers after swap
+
+        def grow_matrix():
+            try:
+                with phase("topk.grow"):
+                    with self._lock:
+                        self._raise_if_matrix_lost_locked()
+                        # enqueued under the lock: after every drain so far,
+                        # before any to come (which goes to the old matrix
+                        # and, peeked, again to this one after the swap)
+                        matrix = _build_jits()[1](self._matrix, grow)
+                    pos = np.zeros((self.apply_cap,), dtype=np.int32)
+                    vec = np.zeros((self.apply_cap, matrix.shape[1]),
+                                   dtype=np.float32)
+                    matrix = _scatter_rows(matrix, pos, vec, 0)
+                    matrix.block_until_ready()
+                    with self._lock:
+                        self._matrix, self._n_pad = matrix, grow
+                        self._observe_rows_locked()
+                        self._obs_grows.inc()
+                        self._peek_applied.clear()
+                        self._readmit_unplaced_locked()
+            except Exception as e:
+                with self._lock:
+                    self._peek_applied.clear()
+                self._obs_device_errors.inc()
+                print(f"[topk] the copy into a matrix of {grow} rows failed; "
+                      f"new ids wait: {e}", file=sys.stderr)
 
         def rebuild():
             drained = {}
@@ -1064,7 +1500,7 @@ class DeviceFactorIndex:
                     replay_snap = self._replay_backlog
                     self._replay_backlog = 0
                 with phase("topk.build.snapshot"):
-                    ids, rows, width = self._snapshot_rows()
+                    ids, rows, width = self._snapshot_rows(serving=True)
                 # device placement, scatter warm-up, and the (potentially
                 # seconds-long) IVF k-means all run OFF the index lock —
                 # queries keep answering from the current index meanwhile
@@ -1085,7 +1521,8 @@ class DeviceFactorIndex:
                       file=sys.stderr)
 
         self._rebuild_thread = threading.Thread(
-            target=rebuild, name="topk-rebuild", daemon=True
+            target=grow_matrix if grow else rebuild,
+            name="topk-grow" if grow else "topk-rebuild", daemon=True
         )
         self._rebuild_thread.start()
 
@@ -1099,6 +1536,11 @@ class DeviceFactorIndex:
         with self._dirty_lock:
             depth = len(self._dirty) + self._replay_backlog
             oldest = self._oldest_dirty_ts
+        if self._unplaced:
+            # new ids that wait for room are unabsorbed writes too
+            depth += len(self._unplaced)
+            first = min(t_put for t_put, _ in self._unplaced.values())
+            oldest = first if oldest is None else min(oldest, first)
         self._obs_dirty_depth.set(depth)
         self._obs_staleness.set(
             max(time.perf_counter() - oldest, 0.0)
@@ -1210,7 +1652,7 @@ class DeviceFactorIndex:
         with stage("topk.enqueue"):
             if self._is_sharded:
                 fn = _sharded_topk_program(self._mesh)
-                packed = fn(self._matrix, self._bias, q, k_eff)
+                packed = fn(self._matrix, self._live, q, k_eff)
                 self._obs_sharded_frames.inc()
             else:
                 if self._topk_many_fn is None:
@@ -1219,16 +1661,17 @@ class DeviceFactorIndex:
                     import jax
                     import jax.numpy as jnp
 
-                    @partial(jax.jit, static_argnums=2)
-                    def topk_many_fn(matrix, qs, k):
+                    @partial(jax.jit, static_argnums=3)
+                    def topk_many_fn(matrix, live, qs, k):
                         with jax.named_scope("topk.score"):
-                            scores = jnp.matmul(  # (B, n_items)
-                                qs, matrix.T, precision=_SCORE_PRECISION)
+                            scores = _mask_spare_rows(jnp.matmul(  # (B, n_pad)
+                                qs, matrix.T, precision=_SCORE_PRECISION), live)
                         with jax.named_scope("topk.select"):
                             return _pack_results(*jax.lax.top_k(scores, k))
 
                     self._topk_many_fn = topk_many_fn
-                packed = self._topk_many_fn(self._matrix, q, k_eff)
+                packed = self._topk_many_fn(
+                    self._matrix, self._live, q, k_eff)
         return self._fetch(packed)
 
     def _fetch(self, packed, counts: int = 0):
@@ -1298,16 +1741,16 @@ class DeviceFactorIndex:
                     import jax
                     import jax.numpy as jnp
 
-                    @partial(jax.jit, static_argnums=2)
-                    def topk_fn(matrix, query, k):
+                    @partial(jax.jit, static_argnums=3)
+                    def topk_fn(matrix, live, query, k):
                         with jax.named_scope("topk.score"):
-                            scores = jnp.matmul(  # (n_items,)
-                                matrix, query, precision=_SCORE_PRECISION)
+                            scores = _mask_spare_rows(jnp.matmul(  # (n_pad,)
+                                matrix, query, precision=_SCORE_PRECISION), live)
                         with jax.named_scope("topk.select"):
                             return _pack_results(*jax.lax.top_k(scores, k))
 
                     self._topk_fn = topk_fn
-                packed = self._topk_fn(self._matrix, q, k_eff)
+                packed = self._topk_fn(self._matrix, self._live, q, k_eff)
             scores, idx = self._fetch(packed)
             with stage("topk.format"):
                 return [

@@ -504,3 +504,58 @@ def test_the_update_scatter_owns_its_relayouts_on_a_v5e(
     assert stats.temp_size_in_bytes < 0.1e9
     assert matrix_bytes <= stats.alias_size_in_bytes < matrix_bytes + 1e6
     assert stats.output_size_in_bytes < matrix_bytes + 1e6
+
+
+@pytest.mark.parametrize("layout", ["one_chip", "v5e_2x2"])
+def test_the_frame_program_masks_its_spare_rows_inside_the_score_on_a_v5e(
+        v5e_2x2, one_chip, layout):
+    """`serve/topk`'s exact frame programs at the serving cells' sizes with
+    their spare rows (one chip: `mesh.row_capacity(5,000,000)`; the 2x2 mesh:
+    four shards of 4,194,304; this file holds it because one file a run may
+    describe a chip).  The live count is an operand, so one compile serves
+    every live count; the compare is a fusion of its own over a row-number
+    vector (one byte a row, not a pass over the scores), and the select
+    rides the matmul's output fusion: the `(8, rows)` scores are written
+    ONCE, by that fusion, and read once, by the top-k.  A select in a fusion
+    of its own would read and write them again: 0.4 ms of the paced cell's
+    7 ms frame."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flink_ms_tpu.parallel.mesh import BLOCK_AXIS, row_capacity
+    from flink_ms_tpu.serve import topk
+    from flink_ms_tpu.serve.table import ModelTable
+
+    if layout == "one_chip":
+        index = topk.DeviceFactorIndex(ModelTable(), "-I")
+        index.bulk_load(["1", "2", "3"], np.eye(3, 8, dtype=np.float32))
+        index.topk_many(np.ones((2, 8), np.float32), 2)  # makes the program
+        program, rows = index._topk_many_fn, row_capacity(5_000_000)
+        per_device = rows
+        assert rows == 5_019_648
+        held = everywhere = one_chip
+    else:
+        mesh = Mesh(np.array(v5e_2x2.devices), (BLOCK_AXIS,))
+        program, rows, per_device = (
+            topk._sharded_topk_program(mesh), 4 * 4_194_304, 4_194_304)
+        held = NamedSharding(mesh, P(BLOCK_AXIS, None))
+        everywhere = NamedSharding(mesh, P())
+
+    def shape(dtype, sharding, *dims):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    text = program.lower(
+        shape(jnp.float32, held, rows, 200), shape(jnp.int32, everywhere),
+        shape(jnp.float32, everywhere, 8, 200), 10).compile().as_text()
+    entry = text.split("ENTRY ")[1]
+    scores = re.findall(rf"(%\S+) = f32\[8,{per_device}\]\S* (\S+?)\(", entry)
+    assert len(scores) == 1 and scores[0][1] == "fusion", scores
+    fused = re.search(rf"{re.escape(scores[0][0])} = .*?calls=(%[\w.-]+)",
+                      entry).group(1)
+    body = re.search(rf"\n{re.escape(fused)} .*?\n}}", text, re.S).group(0)
+    assert " convolution(" in body and " select(" in body
+    # the matrix is read where it lies: no copy, no transpose of it
+    assert not re.findall(
+        rf"= f32\[{per_device},200\]\S* (?:copy|transpose)\(", text)
+    assert re.findall(rf"= pred\[{per_device}\]\S* fusion\(", entry)

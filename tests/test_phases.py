@@ -239,7 +239,8 @@ SVM_TREE = {"svm.prepare": None, "svm.gram_build": None, "svm.place": None,
 
 @pytest.mark.parametrize("path, tree", [
     ("als", ALS_TREE),
-    ("topk", TOPK_TREE),
+    # one device: the rows' one put, then their join with the spare rows
+    ("topk", dict(TOPK_TREE, **{"topk.build.spare": "topk.build"})),
     ("topk-sharded", dict(TOPK_TREE, **{"topk.build.pad": "topk.build"})),
     ("svm-gram", SVM_TREE),
 ])
@@ -259,7 +260,8 @@ def test_setup_paths_record_their_named_phases(path, tree, log, monkeypatch):
     if path.startswith("topk"):
         # the dict is made before the first wait (one device: under the transfer)
         assert by["topk.build.place"]["end"] <= by["topk.build.ids"]["start"]
-        assert by["topk.build.ids"]["end"] \
+        assert by["topk.build.ids"]["end"] <= by.get(
+            "topk.build.spare", by["topk.build.warm_scatter"])["start"] \
             <= by["topk.build.warm_scatter"]["start"]
     for name, entry in by.items():
         children = T.phase_children(entry, entries)
@@ -315,9 +317,14 @@ def test_routing_tables_are_host_prep_with_a_root_of_their_own(
     assert routes[-1]["end"] <= log()["als.place"]["start"]
 
 
-def test_table_path_and_background_rebuild_snapshot_under_their_own_root(log):
+def test_table_path_and_background_rebuild_snapshot_under_their_own_root(
+        log, monkeypatch):
+    from flink_ms_tpu.serve import topk
     from flink_ms_tpu.serve.table import ModelTable
     from flink_ms_tpu.serve.topk import DeviceFactorIndex
+
+    # a new id starts a rebuild, as on the IVF tier (in place otherwise)
+    monkeypatch.setattr(topk, "_INSERTS_IN_PLACE", False)
 
     table = ModelTable(2)
     index = DeviceFactorIndex(table, "-I")

@@ -239,7 +239,7 @@ def test_sharded_steady_path_zero_catalog_copies(catalog, monkeypatch):
 
     fn = topk_mod._sharded_topk_program(shard._mesh)
     traced = jax.make_jaxpr(lambda m, b, qs: fn(m, b, qs, 10))(
-        shard._matrix, shard._bias, q)
+        shard._matrix, shard._live, q)
     out_shapes = [tuple(v.aval.shape) for v in traced.jaxpr.outvars]
     assert out_shapes == [(8, 20)]
 
@@ -304,8 +304,9 @@ def test_rebuild_counter_and_staleness_gauges(catalog, monkeypatch):
     idx.topk(np.ones(d, dtype=np.float32), 3)
     base = idx._obs_rebuilds.value
     assert base >= 1  # the initial build counted
-    # a NEW id is structural: background rebuild increments the counter
-    table.put("brand-new-I", ";".join("1.0" for _ in range(d)))
+    # a row of ANOTHER WIDTH is structural (a new id no longer is: it is
+    # written into spare capacity): the background rebuild counts
+    table.put("brand-new-I", ";".join("1.0" for _ in range(d + 1)))
     idx.topk(np.ones(d, dtype=np.float32), 3)
     deadline = time.time() + 10
     while time.time() < deadline:

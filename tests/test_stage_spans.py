@@ -336,9 +336,9 @@ def _lowered_topk(which):
     q = np.ones((4, 8), np.float32)
     if which == "single":
         index.topk(q[0], 3)
-        return index._topk_fn.lower(index._matrix, q[0], 3)
+        return index._topk_fn.lower(index._matrix, index._live, q[0], 3)
     index.topk_many(q, 3)
-    return index._topk_many_fn.lower(index._matrix, q, 3)
+    return index._topk_many_fn.lower(index._matrix, index._live, q, 3)
 
 
 @pytest.mark.parametrize("program, scopes", [

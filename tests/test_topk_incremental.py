@@ -1,6 +1,8 @@
 """Incremental top-k index: streaming row updates are applied in place on
-device (no O(catalog) rebuild on the query path), new items land through a
-background rebuild, and query latency stays flat under a concurrent writer
+device (no O(catalog) rebuild on the query path), new items land in spare
+capacity by the same drain (through a background rebuild where the program's
+switch says so, the path the IVF tier still takes), and query latency stays
+flat under a concurrent writer
 (one SGD row update must not trigger a multi-second full
 re-scan per query at catalog scale)."""
 
@@ -12,6 +14,7 @@ import pytest
 
 from flink_ms_tpu.core import formats as F
 from flink_ms_tpu.serve.table import ModelTable
+from flink_ms_tpu.serve import topk as topk_mod
 from flink_ms_tpu.serve.topk import DeviceFactorIndex
 
 
@@ -43,7 +46,10 @@ def test_row_update_applied_in_place_without_full_rebuild(rng):
     assert index.inplace_updates >= 1
 
 
-def test_new_item_lands_via_background_rebuild(rng):
+@pytest.mark.parametrize("in_place", [True, False])
+def test_new_item_lands_in_place_or_via_background_rebuild(
+        rng, monkeypatch, in_place):
+    monkeypatch.setattr(topk_mod, "_INSERTS_IN_PLACE", in_place)
     table = ModelTable(4)
     k = 5
     _fill(table, 20, k, rng)
@@ -53,6 +59,8 @@ def test_new_item_lands_via_background_rebuild(rng):
     assert index.full_builds == 1
 
     table.put("999-I", ";".join(repr(float(x)) for x in (q * 50.0)))
+    if in_place:
+        assert index.topk(q, 3)[0][0] == "999"  # the first query ranks it
     # the query path stays up (stale) while the rebuild runs; eventually
     # the new item appears at rank 1
     deadline = time.time() + 20
@@ -62,12 +70,15 @@ def test_new_item_lands_via_background_rebuild(rng):
             break
         time.sleep(0.02)
     assert got[0][0] == "999"
-    assert index.full_builds == 2  # exactly one background rebuild
+    # in place: the first query after the put already ranks it, nothing is
+    # rebuilt; otherwise exactly one background rebuild
+    assert index.full_builds == (1 if in_place else 2)
 
 
-def test_update_during_rebuild_not_lost(rng):
+def test_update_during_rebuild_not_lost(rng, monkeypatch):
     """A row update arriving while a structural rebuild is in flight must
     survive the matrix swap (the peek-don't-drain rule)."""
+    monkeypatch.setattr(topk_mod, "_INSERTS_IN_PLACE", False)
     table = ModelTable(4)
     k = 4
     _fill(table, 30, k, rng)
@@ -78,8 +89,8 @@ def test_update_during_rebuild_not_lost(rng):
     # make rebuilds slow enough to race against
     orig_snapshot = index._snapshot_rows
 
-    def slow_snapshot():
-        out = orig_snapshot()
+    def slow_snapshot(serving=False):
+        out = orig_snapshot(serving)
         time.sleep(0.5)
         return out
 
@@ -199,12 +210,14 @@ def test_snapshot_first_row_truncated_does_not_poison_width(rng):
     assert len(ids) == 20 and "0" not in ids
 
 
-def test_failed_background_rebuild_keeps_serving_and_is_counted(rng):
+def test_failed_background_rebuild_keeps_serving_and_is_counted(
+        rng, monkeypatch):
     """A device error inside the background rebuild must not take serving
     down — but it is counted (``tpums_topk_device_errors_total``), so the
     chip smoke, which reads the counter as zero, fails on one."""
     from flink_ms_tpu.obs.metrics import get_registry
 
+    monkeypatch.setattr(topk_mod, "_INSERTS_IN_PLACE", False)
     table = ModelTable(4)
     k = 4
     _fill(table, 20, k, rng)

@@ -12,7 +12,8 @@ import pytest
 
 from benchmark import reference
 from flink_ms_tpu.obs import metrics as obs_metrics
-from flink_ms_tpu.parallel.mesh import BLOCK_AXIS, make_mesh, row_bucket
+from flink_ms_tpu.parallel.mesh import (
+    BLOCK_AXIS, make_mesh, row_bucket, row_capacity)
 from flink_ms_tpu.serve import topk as topk_mod
 from flink_ms_tpu.serve.table import ModelTable
 
@@ -144,10 +145,11 @@ def scopes_in_the_program():
     ids, rows = catalog(1026, 29)
     index = build(ids, rows)
     text = topk_mod._sharded_topk_program(index._mesh).lower(
-        index._matrix, index._bias, jnp.zeros((8, RANK)), 10
+        index._matrix, index._live, jnp.zeros((8, RANK)), 10
     ).compile().as_text()  # the compiled HLO names each operation's path
-    for op in ("topk.shard_score/dot_general", "topk.shard_score/add",
-               "topk.shard_select/top_k", "topk.shard_select/axis_index",
+    for op in ("topk.shard_score/dot_general", "topk.shard_score/axis_index",
+               "topk.shard_score/jit(_where)/select_n",
+               "topk.shard_select/top_k",
                "topk.merge/all_gather", "topk.merge/top_k",
                "topk.merge/jit(take_along_axis)/gather"):
         assert f'op_name="jit(sharded_topk)/shard_map/{op}"' in text
@@ -170,8 +172,10 @@ def counters_and_gauges(monkeypatch):
     single = topk_mod.DeviceFactorIndex(ModelTable(), "-I")
     single.bulk_load(ids, rows)
     assert not single._is_sharded
+    # one device: the live rows and the spare ones for ids to come
     assert (gauge("tpums_topk_shards"), gauge("tpums_topk_shard_rows"),
-            gauge("tpums_topk_pad_rows")) == (1, 1026, 0)
+            gauge("tpums_topk_pad_rows")) == (
+        1, row_capacity(1026), row_capacity(1026) - 1026)
     before = counter("tpums_topk_sharded_frames_total")
     single.topk_many(queries(3, 1), 10)
     assert counter("tpums_topk_sharded_frames_total") == before
@@ -201,17 +205,17 @@ def built_from_the_unpadded_rows(n, monkeypatch):
     assert not_whole == {4096: 0, 4059: 1, 2049: 2}[n]
     assert gauge("tpums_topk_build_host_copy_bytes") \
         == not_whole * per * RANK * 4
-    assert n_pad in made and max(made) < n_pad * RANK  # the bias; no (n_pad, k)
+    assert max(made, default=0) < n_pad * RANK  # no (n_pad, k) on the host
     padded = np.zeros((n_pad, RANK), np.float32)
     padded[:n] = rows
     mesh = index._mesh
     assert index._matrix.sharding == NamedSharding(mesh, P(BLOCK_AXIS, None))
-    assert index._bias.sharding == NamedSharding(mesh, P(BLOCK_AXIS))
+    # the pad rows are told from the live ones by the count, on every device
+    assert index._live.sharding == NamedSharding(mesh, P())
+    assert int(index._live) == n
     assert index._matrix.shape == (n_pad, RANK)
     assert np.array_equal(np.asarray(index._matrix).view(np.uint32),
                           padded.view(np.uint32))
-    bias = np.asarray(index._bias)
-    assert not bias[:n].any() and (bias[n:] == topk_mod._PAD_SCORE).all()
     for shard in index._matrix.addressable_shards:  # each device its own rows
         assert np.array_equal(np.asarray(shard.data), padded[shard.index])
     q = queries(6, n)
